@@ -385,8 +385,8 @@ def _measure_ab(model, reqs, *, slots, chunk, prime=None, arrivals=None,
     measured with INTERLEAVED timed passes — baseline, optimized,
     baseline, optimized, ... — so the sandbox's minutes-scale speed
     drift hits both sides equally instead of whichever side ran last
-    (the same alternate-the-measurements discipline as the tunnel-
-    instability playbook in PERF.md). Two warm passes per engine on the
+    (alternate the measurements, never run one side after the other).
+    Two warm passes per engine on the
     SAME arrival schedule as the timed runs first: warm pass one
     compiles the miss-path programs while populating the store, pass
     two the hit-path restore/suffix-chunk programs; matching the

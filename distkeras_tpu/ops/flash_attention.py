@@ -19,9 +19,11 @@ leave the chip's on-chip memory and the matmuls stay MXU-shaped:
 Layouts: public API is the framework's (B, T, H, D) attention layout
 (``MultiHeadSelfAttention.attention_fn`` contract); kernels run (B, H, T, D).
 Compute is f32 inside the kernels regardless of input dtype (bf16 in, bf16
-out — the MXU accumulates f32 anyway). Falls back to interpreter mode off
-TPU (the 8-device CPU test mesh), and to the XLA-fused dense path when the
-sequence does not tile (T not divisible by the block size).
+out — the MXU accumulates f32 anyway). Mosaic-compiled on a TPU and
+interpreted anywhere else (``ops.kernel_mode.pallas_interpret`` decides,
+for all kernels alike); takes the XLA-fused dense path when the sequence
+does not tile (T not divisible by the block size) — ``effective_path``
+says which.
 """
 
 from __future__ import annotations
@@ -32,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from distkeras_tpu.ops.kernel_mode import pallas_interpret
+
 # 512 measured on v5e (MFU_ATTRIB.jsonl, d512/L8/seq512 training step):
 # bq=bk=128 -> 0.191, 256 -> 0.243, 512 -> 0.284 vs 0.255 XLA dense — the
 # MXU wants 512-wide score matmuls; blocks clamp to T for shorter seqs
@@ -39,10 +43,6 @@ DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 # full K+V per (batch, head) program must fit comfortably in ~16 MB VMEM
 _VMEM_KV_BUDGET_BYTES = 8 * 1024 * 1024
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _causal_mask(s, iq, bq, j, bk):
@@ -415,7 +415,7 @@ def flash_attention(
         return dense_attention(q, k, v, causal=causal)
     # (B, T, H, D) -> (B, H, T, D) for the kernels, and back
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    out = _flash(qt, kt, vt, causal, bq, bk, not _on_tpu())
+    out = _flash(qt, kt, vt, causal, bq, bk, pallas_interpret())
     return jnp.swapaxes(out, 1, 2)
 
 

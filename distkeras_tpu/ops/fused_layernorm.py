@@ -19,8 +19,9 @@ Layout: x flattens to (rows, D) and tiles over row blocks; gamma/beta ride
 along as a replicated (1, D) block. dgamma/dbeta come out of the backward
 kernel as per-block partial sums, reduced in XLA. Requires D % 128 == 0
 (lane width) — other widths take the plain jnp path, as do rows that
-don't fill one sublane tile. Falls back to interpreter mode off TPU (the
-8-device CPU test mesh), chosen at trace time like the other kernels.
+don't fill one sublane tile. Mosaic-compiled on a TPU and interpreted
+anywhere else (``ops.kernel_mode.pallas_interpret``), chosen at trace
+time like the other kernels.
 """
 
 from __future__ import annotations
@@ -32,14 +33,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from distkeras_tpu.ops.kernel_mode import pallas_interpret
+
 LANE = 128
 DEFAULT_BLOCK_ROWS = 256
 # x, dy, dx blocks live in VMEM together (f32); stay well under ~16 MB/core
 _VMEM_ROW_BUDGET_BYTES = 4 * 1024 * 1024
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _reference_layer_norm(x, gamma, beta, epsilon):
@@ -192,7 +191,7 @@ def fused_layer_norm(x, gamma, beta, epsilon=1e-5):
     x2 = x.reshape(n_rows, d)
     block_rows = _block_rows_for(n_rows, d)
     out = _fused(
-        x2, gamma, beta, float(epsilon), block_rows, not _on_tpu()
+        x2, gamma, beta, float(epsilon), block_rows, pallas_interpret()
     )
     return out.reshape(x.shape)
 

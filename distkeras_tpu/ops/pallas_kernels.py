@@ -17,8 +17,9 @@ these Pallas kernels for ops worth owning:
   they enter the kernel as a (1, 2) SMEM scalar block instead of being
   baked in like lr/betas/eps.
 
-Kernels compile with Mosaic on TPU and fall back to interpreter mode on
-CPU (tests run on the 8-device CPU mesh), chosen at trace time.
+Kernels compile with Mosaic on a TPU and run in the Pallas interpreter
+anywhere else (``ops.kernel_mode.pallas_interpret``; tests run on the
+8-device CPU mesh), chosen at trace time.
 
 Layout: each parameter leaf is raveled and tiled to (rows, 128) f32 blocks
 (lane width 128, sublane multiple 8 — see the Pallas TPU guide's tiling
@@ -36,13 +37,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distkeras_tpu.ops.kernel_mode import pallas_interpret
+
 LANE = 128
 BLOCK_ROWS = 512  # (512, 128) f32 = 256 KiB per buffer — comfortably in VMEM
 _MIN_KERNEL_SIZE = 8 * LANE  # below one f32 tile, jnp is cheaper
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _block_rows_for(n: int) -> int:
@@ -213,7 +212,7 @@ class FusedSGD:
         )
 
     def fused_apply(self, params, grads, state):
-        interpret = not _on_tpu()
+        interpret = pallas_interpret()
         if self.momentum == 0.0:
             new_params = jax.tree.map(
                 lambda p, g: _leaf_sgd(p, g, self.learning_rate, interpret),
@@ -270,7 +269,7 @@ class FusedAdam:
         )
 
     def fused_apply(self, params, grads, state):
-        interpret = not _on_tpu()
+        interpret = pallas_interpret()
         m_tree, v_tree, count = state
         t = (count + 1).astype(jnp.float32)
         c1 = 1.0 / (1.0 - self.b1**t)

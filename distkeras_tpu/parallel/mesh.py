@@ -13,15 +13,9 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# THE shard_map accessor for the whole repo: ``jax.shard_map`` only became
-# a public top-level name in newer JAX; older installs keep it under
-# ``jax.experimental.shard_map`` with the same (f, mesh, in_specs,
-# out_specs) signature. Every parallel module routes through this alias so
-# the version probe lives in exactly one place.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed JAX
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+# THE shard_map accessor for the whole repo: every parallel module routes
+# through this alias.
+shard_map = jax.shard_map
 
 
 def local_devices(n=None):
@@ -61,12 +55,12 @@ def _largest_factor(n):
 
 def force_cpu_mesh(num_devices: int = 8) -> None:
     """Pin a ``num_devices``-virtual-device CPU platform. Must run before
-    the JAX backend initializes (the forced host device count is read from
-    XLA_FLAGS at backend init, and the platform pin must be a config update
-    because env-var selection can be overridden by pre-registered plugins).
-    This is the one supported way to exercise multi-device code paths
-    without accelerator hardware — tests/conftest.py and every example's
-    ``--cpu`` flag route through the same mechanism."""
+    the JAX backend initializes: the forced host device count is read from
+    XLA_FLAGS at backend init, and ``jax.config.update`` selects the cpu
+    platform for this process whatever ``JAX_PLATFORMS`` says. This is the
+    one supported way to exercise multi-device code paths without
+    accelerator hardware — tests/conftest.py and every example's ``--cpu``
+    flag route through the same mechanism."""
     import os
 
     flags = os.environ.get("XLA_FLAGS", "")

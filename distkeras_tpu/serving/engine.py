@@ -90,26 +90,20 @@ def _on_backend_compile(event, secs, **_kw):
         _MINT_SCOPE.compiles += 1
 
 
-def _ensure_mint_listener() -> bool:
-    """Register the process-wide compile listener once; False when
-    the monitoring API is unavailable (the wrapper then degrades to
-    first-call-per-program detection)."""
+def _ensure_mint_listener() -> None:
+    """Register the process-wide compile listener once."""
     global _MINT_LISTENER_ON
     if _MINT_LISTENER_ON:
-        return True
+        return
     with _MINT_LISTENER_LOCK:
         if _MINT_LISTENER_ON:
-            return True
-        try:
-            from jax._src import monitoring
+            return
+        from jax._src import monitoring
 
-            monitoring.register_event_duration_secs_listener(
-                _on_backend_compile
-            )
-        except Exception:  # noqa: BLE001 — private-API boundary
-            return False
+        monitoring.register_event_duration_secs_listener(
+            _on_backend_compile
+        )
         _MINT_LISTENER_ON = True
-        return True
 
 
 class _MintTimer:
@@ -118,30 +112,17 @@ class _MintTimer:
     on the calling thread) once per real backend compile, so a call
     during which it fired records the wall time the calling thread
     just lost on the stepper's ``obs.CompileLedger``. Off the mint
-    path this costs two thread-local attribute writes per call; when
-    the monitoring API is absent (an exotic jax build) it degrades
-    to first-call-per-program detection, which still catches every
-    bucketed family's one compile."""
+    path this costs two thread-local attribute writes per call."""
 
-    __slots__ = ("fn", "key", "stepper", "_monitored", "_called")
+    __slots__ = ("fn", "key", "stepper")
 
     def __init__(self, fn, key, stepper):
         self.fn = fn
         self.key = str(key)
         self.stepper = stepper
-        self._monitored = _ensure_mint_listener()
-        self._called = False
+        _ensure_mint_listener()
 
     def __call__(self, *args):
-        if not self._monitored:
-            first, self._called = not self._called, True
-            t0 = time.perf_counter()
-            out = self.fn(*args)
-            if first:
-                self.stepper._record_mint(
-                    self.key, time.perf_counter() - t0, args
-                )
-            return out
         scope = _MINT_SCOPE
         prev_key, prev_n = scope.key, scope.compiles
         scope.key, scope.compiles = self.key, 0
@@ -753,6 +734,10 @@ class DecodeStepper:
         self.spec_verify_steps = 0
         self.spec_fallback_steps = 0
         self.spec_drafted_tokens = 0
+        # drafter exceptions swallowed by spec_step (admission or
+        # proposal): the request survives at plain-decode pace, and this
+        # count is the only trace of it — health() and stats() show it
+        self.spec_draft_failures = 0
         # prefix-store failures are degraded to misses, never surfaced
         # to the request (the cache is an optimization, not a dependency)
         self.prefix_fetch_failures = 0
@@ -2678,6 +2663,7 @@ class DecodeStepper:
                 try:
                     drafter.admit(i, prompt)
                 except Exception:  # noqa: BLE001 — draft is best-effort
+                    self.spec_draft_failures += 1
                     drafter.invalidate(
                         np.arange(self.num_slots) == i
                     )
@@ -2690,6 +2676,7 @@ class DecodeStepper:
             try:
                 dtoks, dcnt = drafter.propose(active, self.draft_k, seqs)
             except Exception:  # noqa: BLE001 — draft is best-effort
+                self.spec_draft_failures += 1
                 drafter.invalidate(active)
                 dtoks = np.zeros((self.num_slots, self.draft_k), np.int32)
                 dcnt = np.zeros((self.num_slots,), np.int32)
@@ -4478,6 +4465,11 @@ class ServingEngine:
             out["speculative_tokens_per_window"] = (
                 round(batcher.counters["spec_tokens"] / w, 2)
                 if w else None
+            )
+            # drafter exceptions spec_step swallowed (the fall-back to
+            # the plain step is otherwise invisible: tokens still match)
+            out["speculative_draft_failures"] = int(
+                self._stepper.spec_draft_failures
             )
         if batcher is not None and getattr(self._stepper, "paged", False):
             # pool pressure for routers/load balancers: the fraction of

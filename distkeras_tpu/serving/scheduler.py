@@ -1963,6 +1963,9 @@ class ContinuousBatcher:
                 "draft_k": st.draft_k,
                 "verify_steps": int(st.spec_verify_steps),
                 "fallback_steps": int(st.spec_fallback_steps),
+                "draft_failures": int(
+                    getattr(st, "spec_draft_failures", 0)
+                ),
                 "windows": windows,
                 "drafted_tokens": drafted,
                 "accepted_draft_tokens": accepted,

@@ -62,6 +62,24 @@ def _window_unroll(model) -> bool:
 # ---------------------------------------------------------------- core cache
 
 
+def _cast_for_compute(params, x, cdtype):
+    """Carry ``cdtype`` into the forward pass. Every layer computes in
+    its input's dtype (weights are cast to ``x.dtype`` where they are
+    used), so a floating input is cast and the f32 master weights are
+    left alone. Integer inputs (token ids) must stay exact — bf16 holds
+    8 bits of an id — so there the floating weights are cast instead and
+    the embedding's output carries the dtype through the model."""
+    if cdtype is None:
+        return params, x
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return params, x.astype(cdtype)
+    return jax.tree.map(
+        lambda p: p.astype(cdtype)
+        if jnp.issubdtype(p.dtype, jnp.floating) else p,
+        params,
+    ), x
+
+
 def _walk_layers(model):
     """Every layer reachable from ``model`` — delegates to THE canonical
     traversal (``models.sequential.walk_layers``, driven by the
@@ -189,8 +207,7 @@ class WorkerCore:
             train_fwd = jax.checkpoint(train_fwd)
 
         def compute_loss(params, state, rng, x, y):
-            if cdtype is not None:
-                x = x.astype(cdtype)
+            params, x = _cast_for_compute(params, x, cdtype)
             y_pred, new_state = train_fwd(params, state, rng, x)
             y_pred = y_pred.astype(jnp.float32)
             # layers that emit regularizers through state (MoE routing's
@@ -334,8 +351,7 @@ class WorkerCore:
             return params, state, opt_state, rng, acc, mets
 
         def eval_step(params, state, x, y):
-            if cdtype is not None:
-                x = x.astype(cdtype)
+            params, x = _cast_for_compute(params, x, cdtype)
             y_pred, _ = model_apply(params, state, x, train=False)
             y_pred = y_pred.astype(jnp.float32)
             mets = {"loss": loss_fn(y_pred, y)}

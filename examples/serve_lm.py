@@ -92,8 +92,10 @@ def main():
                  "pick one")
 
     from distkeras_tpu.parallel.backend import setup_backend
+    from distkeras_tpu.utils.compile_cache import enable_compile_cache
 
-    setup_backend(cpu=args.cpu, cpu_devices=1, fallback_cpu_devices=1)
+    # the chip, or an error; --cpu asks for the virtual CPU mesh
+    enable_compile_cache(setup_backend(cpu=args.cpu, cpu_devices=1))
 
     from distkeras_tpu import SingleTrainer
     from distkeras_tpu.data.dataset import Dataset

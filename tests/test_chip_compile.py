@@ -1,0 +1,144 @@
+"""The main path's kernels, compiled for a described v5e chip (no chip needed).
+
+The TPU's compiler is installed with JAX and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``). Interpret mode
+cannot see what Mosaic refuses — a slice off the tiling, too much VMEM — so
+each kernel family of the trainer's path is lowered here with
+``interpret=False`` at the widths ``chip_smoke.py`` runs, and the compiled
+module must hold its ``tpu_custom_call``. Nothing runs; a compile that
+passes is not a chip run.
+
+The topology is described inside a module-scoped fixture, in the test's own
+process, and nothing touches it at import: only one process may hold the
+TPU library, and under pytest-xdist every worker imports every test file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; keep it off around these."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+# (batch, seq, heads, head_dim, dtype): chip_smoke's head dim 256 and the
+# zoo's common head dim 64, both at seq 2048
+FLASH_SHAPES = [
+    pytest.param(4, 2048, 8, 256, jnp.bfloat16, id="hd256-bf16"),
+    pytest.param(4, 2048, 8, 64, jnp.bfloat16, id="hd64-bf16"),
+]
+
+
+@pytest.mark.parametrize("b,t,h,d,dtype", FLASH_SHAPES)
+def test_flash_forward_compiles_for_v5e(one_chip, b, t, h, d, dtype):
+    from distkeras_tpu.ops.flash_attention import _flash, effective_path
+
+    path, bq, bk = effective_path(t, d)
+    assert path == "flash"
+    x = jax.ShapeDtypeStruct((b, h, t, d), dtype, sharding=one_chip)
+    _compile(lambda q, k, v: _flash(q, k, v, True, bq, bk, False), x, x, x)
+
+
+@pytest.mark.parametrize("b,t,h,d,dtype", FLASH_SHAPES)
+def test_flash_backward_compiles_for_v5e(one_chip, b, t, h, d, dtype):
+    from distkeras_tpu.ops.flash_attention import _flash, effective_path
+
+    _, bq, bk = effective_path(t, d)
+    x = jax.ShapeDtypeStruct((b, h, t, d), dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = _flash(q, k, v, True, bq, bk, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    # forward, dq and dk/dv kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+def _ln_shapes(one_chip, rows=8192, d=2048, dtype=jnp.bfloat16):
+    x = jax.ShapeDtypeStruct((rows, d), dtype, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    return x, g
+
+
+def test_fused_layernorm_forward_compiles_for_v5e(one_chip):
+    from distkeras_tpu.ops.fused_layernorm import _block_rows_for, _fused
+
+    x, g = _ln_shapes(one_chip)
+    rows = _block_rows_for(*x.shape)
+    _compile(lambda x, g, b: _fused(x, g, b, 1e-5, rows, False), x, g, g)
+
+
+def test_fused_layernorm_backward_compiles_for_v5e(one_chip):
+    from distkeras_tpu.ops.fused_layernorm import _block_rows_for, _fused
+
+    x, g = _ln_shapes(one_chip)
+    rows = _block_rows_for(*x.shape)
+
+    def loss(x, g, b):
+        return jnp.sum(_fused(x, g, b, 1e-5, rows, False).astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), x, g, g)
+
+
+def test_adam_leaf_compiles_for_v5e(one_chip):
+    from distkeras_tpu.ops.pallas_kernels import _leaf_adam
+
+    p = jax.ShapeDtypeStruct((2048, 8192), jnp.float32, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((1, 2), jnp.float32, sharding=one_chip)
+    _compile(
+        functools.partial(
+            _leaf_adam, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8, interpret=False
+        ),
+        p, p, p, p, c,
+    )
+
+
+def test_momentum_leaf_compiles_for_v5e(one_chip):
+    from distkeras_tpu.ops.pallas_kernels import _leaf_sgd_momentum
+
+    p = jax.ShapeDtypeStruct((2048, 8192), jnp.float32, sharding=one_chip)
+    _compile(
+        functools.partial(
+            _leaf_sgd_momentum, lr=0.01, mu=0.9, nesterov=False,
+            interpret=False,
+        ),
+        p, p, p,
+    )

@@ -326,7 +326,10 @@ def test_sharded_speculative_verify_matches_solo(lm, lm_ref):
 
     rng = np.random.default_rng(12)
     prompts = [
-        ((7 + np.arange(14)) % 13).astype(np.int32),  # repetitive
+        # period 5 over 14 tokens: the closing bigram has recurred, so
+        # the n-gram drafter proposes at once (a period of 13 recurs
+        # only in its last token, and no proposal ever fires)
+        ((7 + np.arange(14)) % 5).astype(np.int32),  # repetitive
         rng.integers(0, 61, 9).astype(np.int32),  # incompressible
     ]
     params = [None, SamplingParams(temperature=0.8, seed=5)]
@@ -340,7 +343,9 @@ def test_sharded_speculative_verify_matches_solo(lm, lm_ref):
                        draft_k=3, prefix_cache=None)
     got = spec_drive(st, prompts, params, 8)
     assert got == want
+    assert solo.spec_verify_steps > 0  # the traffic does propose
     assert st.spec_verify_steps > 0  # the sharded verify actually ran
+    assert st.spec_draft_failures == 0
 
 
 def test_sharded_swap_roundtrip_matches_solo(lm, lm_ref):
@@ -487,3 +492,30 @@ def test_dkt_top_renders_mesh_column():
     out = format_table(samples)
     assert "== 127.0.0.1:9001  mesh=tp:4 " in out
     assert "== 127.0.0.1:9002  mesh=solo " in out
+
+
+@pytest.mark.parametrize("mesh", [None, "tp:2"], ids=["solo", "tp2"])
+def test_swallowed_draft_failures_are_counted_in_health(lm, lm_ref, mesh):
+    """A drafter that raises never fails the request — decode falls to
+    the plain step and the tokens still match — so the only trace is
+    the counter: ``health()`` and ``stats()`` must show it."""
+    from distkeras_tpu.serving import ServingEngine
+
+    class RaisingDrafter(NgramDrafter):
+        def propose(self, active, k, seqs):
+            raise RuntimeError("draft source down")
+
+    eng = ServingEngine(
+        lm, num_slots=2, paged=True, page_size=4, prefix_cache=None,
+        speculative=RaisingDrafter(), draft_k=3, mesh=mesh,
+    ).start()
+    try:
+        p = ((7 + np.arange(14)) % 5).astype(np.int32)
+        out = eng.generate(p, 6)
+        assert out[len(p):].tolist() == _solo(lm_ref, p, 6)
+        health, spec = eng.health(), eng.stats()["speculative"]
+    finally:
+        eng.stop()
+    assert health["speculative_draft_failures"] >= 1
+    assert spec["draft_failures"] == health["speculative_draft_failures"]
+    assert spec["verify_steps"] == 0 and spec["fallback_steps"] >= 1

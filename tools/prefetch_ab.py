@@ -26,19 +26,13 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import resolve_backend  # noqa: E402
+from bench import setup_backend  # noqa: E402
 
 
 def main() -> None:
-    resolved = resolve_backend()
-    if resolved is None or resolved[0] == "cpu":
-        print(json.dumps({"metric": "prefetch_ab", "error": "no TPU"}))
-        return
-    platform, config_pin = resolved
+    platform = setup_backend()  # the chip, or an error
     import jax
 
-    if config_pin is not None:
-        jax.config.update("jax_platforms", config_pin)
     from distkeras_tpu.utils.compile_cache import enable_compile_cache
 
     # each run() builds a fresh trainer (fresh jit closures): the

@@ -12,8 +12,8 @@ SAME CNN architecture (zoo.mnist_cnn: 32/32-pool-64/64-pool convs +
 dense 256 + dropout + softmax 10) at the reference's batch size 32.
 
 For the same-host ratio, the companion measurement is our framework's
-CPU fallback (``python bench.py`` on this host, batch 128 windows) and,
-for the chip claim, the committed TPU record (``BENCH_TPU.json``).
+fair CPU run (``tools/fair_cpu_bench.py``) and, for the chip claim, the
+committed TPU record of 2026-07-31 (``BENCH_TPU.json``).
 
 Writes REFERENCE_PATTERN.json and prints one JSON line:
     {"metric": "reference_pattern_train_samples_per_sec", "value": N,
