@@ -33,6 +33,7 @@ around the device phases.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -53,6 +54,28 @@ from distkeras_tpu.serving.scheduler import (
     WrongRoleError,
 )
 from distkeras_tpu.utils.profiling import annotate
+
+
+def _span(name, **args):
+    """``annotate`` with arguments: a span on the profiler's timeline
+    (so on the device trace's clock) that carries integers the program
+    has counted anyway. With no trace running it is a flag test."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+def _host_bytes(tree) -> int:
+    """Bytes of the leaves of ``tree`` that live on the host (NumPy
+    arrays and scalars, not ``jax.Array``s): what a jitted call that
+    takes ``tree`` uploads before it can run."""
+    import jax
+
+    return sum(
+        int(getattr(leaf, "nbytes", 0))
+        for leaf in jax.tree_util.tree_leaves(tree)
+        if not isinstance(leaf, jax.Array)
+    )
 
 
 def _bucket_pow2(n: int, cap: int) -> int:
@@ -408,19 +431,21 @@ class _InflightStep:
         if self._toks is None:
             raise RuntimeError("decode step already collected")
         st, active = self._stepper, self.active
-        toks = np.asarray(self._toks)  # the one device->host fetch
-        self._toks = None
-        st._lens[active] = np.minimum(
-            st._lens[active] + 1, st._lens_cap
-        )
-        # the RNG counter mirrors the length discipline exactly: a
-        # failed call advanced nothing, a successful one advanced each
-        # active slot once — replay through blame probes is this line
-        st._spos[active] += 1
-        if st._grammar:
-            st._advance_grammar(
-                toks.reshape(-1, 1), np.where(active, 1, 0)
+        with _span("serving/collect"):
+            toks = np.asarray(self._toks)  # the one device->host fetch
+            self._toks = None
+            st._lens[active] = np.minimum(
+                st._lens[active] + 1, st._lens_cap
             )
+            # the RNG counter mirrors the length discipline exactly: a
+            # failed call advanced nothing, a successful one advanced
+            # each active slot once — replay through blame probes is
+            # this line
+            st._spos[active] += 1
+            if st._grammar:
+                st._advance_grammar(
+                    toks.reshape(-1, 1), np.where(active, 1, 0)
+                )
         return toks
 
 
@@ -683,6 +708,7 @@ class DecodeStepper:
                 for _ in self._gen._stages
             ]
         self._lens = np.ones((b,), np.int32)  # host mirror; >=1 always
+        self.host_arg_bytes_step = 0  # of the last decode-step call
         self._step_fns = {}  # masked flag -> compiled decode step
         self._admit_fns = {}  # prefill-length bucket -> compiled admit
         self._chunk_fns = {}  # chunk-length bucket -> compiled chunk
@@ -757,6 +783,25 @@ class DecodeStepper:
     def speculative(self) -> bool:
         return self.drafter is not None
 
+    @property
+    def _params(self):
+        """The parameter tree, every program's first argument."""
+        return self._params_tree
+
+    @_params.setter
+    def _params(self, tree):
+        # what of the tree is on the host is summed here, once a
+        # binding: the ``host_arg_bytes`` of every program call's span
+        # starts from it
+        self._params_tree = tree
+        self._params_host_bytes = _host_bytes(tree)
+
+    def _host_arg_bytes(self, host) -> int:
+        """What a program call uploads before it can run: the tree's
+        share on the host and ``host``, the arguments built in NumPy
+        for this call."""
+        return self._params_host_bytes + sum(a.nbytes for a in host)
+
     def paged_stats(self) -> dict:
         """Pool / allocator / device-prefix-index observability for the
         engine's ``stats()`` (empty when dense)."""
@@ -776,6 +821,8 @@ class DecodeStepper:
         )
         out["compiled_step_buckets"] = sorted(self._pstep_fns)
         out["compiled_chunk_buckets"] = sorted(self._pchunk_fns)
+        # what the last decode-step call handed over from the host
+        out["host_arg_bytes_step"] = self.host_arg_bytes_step
         return out
 
     @property
@@ -1563,22 +1610,24 @@ class DecodeStepper:
                 self._compiling()
                 fn = self._build_chunk_fn_paged(cb, pbt)
                 self._pchunk_fns = {**self._pchunk_fns, key: fn}
-            with annotate("serving/prefill_chunk"):
-                self._pools = fn(
-                    self._params, self._pools, toks,
-                    self._table_row(slot, pbt), np.int32(pos),
-                )
+            host = (toks, self._table_row(slot, pbt), np.int32(pos))
+            with _span(
+                "serving/prefill_chunk",
+                host_arg_bytes=self._host_arg_bytes(host),
+            ):
+                self._pools = fn(self._params, self._pools, *host)
             return n
         fn = self._chunk_fns.get(cb)
         if fn is None:
             self._compiling()
             fn = self._build_chunk_fn(cb)
             self._chunk_fns = {**self._chunk_fns, cb: fn}
-        with annotate("serving/prefill_chunk"):
-            self._caches = fn(
-                self._params, self._caches, toks, np.int32(slot),
-                np.int32(pos),
-            )
+        host = (toks, np.int32(slot), np.int32(pos))
+        with _span(
+            "serving/prefill_chunk",
+            host_arg_bytes=self._host_arg_bytes(host),
+        ):
+            self._caches = fn(self._params, self._caches, *host)
         return n
 
     def _table_bucket(self) -> int:
@@ -1736,9 +1785,18 @@ class DecodeStepper:
         no generation has ever compiled is a compile STORM
         (``xla.compile.storm`` on the tape + the
         ``serving_compile_storms`` gauge)."""
+        with self._warm():
+            self._warmup()
+
+    @contextlib.contextmanager
+    def _warm(self):
+        """A warm method's body: whatever it mints records
+        ``trigger="warmup"`` on the compile ledger, and one span names
+        it on the profiler's timeline."""
         self._warming = True
         try:
-            self._warmup()
+            with annotate("serving/warmup"):
+                yield
         finally:
             self._warming = False
 
@@ -1764,11 +1822,10 @@ class DecodeStepper:
                         **self._pstep_fns, (pbt, False): fn
                     }
                 table = np.zeros((self.num_slots, pbt), np.int32)
-                with annotate("serving/warmup"):
-                    self._ctx, self._pools, _ = fn(
-                        self._params, self._ctx, self._pools,
-                        self._lens.copy(), active, table, *sargs,
-                    )
+                self._ctx, self._pools, _ = fn(
+                    self._params, self._ctx, self._pools,
+                    self._lens.copy(), active, table, *sargs,
+                )
                 if pbt >= self._max_pages_bucket:
                     break
                 pbt *= 2
@@ -1778,25 +1835,23 @@ class DecodeStepper:
                 if vfn is None:
                     vfn = self._build_verify_fn_paged(*key)
                     self._pverify_fns = {**self._pverify_fns, key: vfn}
-                with annotate("serving/warmup"):
-                    self._ctx, self._pools, _, _ = vfn(
-                        self._params, self._ctx, self._pools,
-                        self._lens.copy(), active,
-                        np.zeros((self.num_slots, self._kb), np.int32),
-                        np.zeros((self.num_slots,), np.int32), table,
-                        *sargs,
-                    )
+                self._ctx, self._pools, _, _ = vfn(
+                    self._params, self._ctx, self._pools,
+                    self._lens.copy(), active,
+                    np.zeros((self.num_slots, self._kb), np.int32),
+                    np.zeros((self.num_slots,), np.int32), table,
+                    *sargs,
+                )
                 self.drafter.warmup()
             return
         fn = self._step_fns.get(False)
         if fn is None:
             fn = self._build_step_fn()
             self._step_fns = {**self._step_fns, False: fn}
-        with annotate("serving/warmup"):
-            self._ctx, self._caches, _ = fn(
-                self._params, self._ctx, self._caches,
-                self._lens.copy(), active, *sargs,
-            )
+        self._ctx, self._caches, _ = fn(
+            self._params, self._ctx, self._caches,
+            self._lens.copy(), active, *sargs,
+        )
         if self.drafter is not None:
             # compile the verify (all writes masked: numerically a
             # no-op) and let the drafter warm its own programs, so a
@@ -1806,13 +1861,12 @@ class DecodeStepper:
             if fn is None:
                 fn = self._build_verify_fn(c)
                 self._verify_fns = {**self._verify_fns, (c, False): fn}
-            with annotate("serving/warmup"):
-                self._ctx, self._caches, _, _ = fn(
-                    self._params, self._ctx, self._caches,
-                    self._lens.copy(), active,
-                    np.zeros((self.num_slots, self._kb), np.int32),
-                    np.zeros((self.num_slots,), np.int32), *sargs,
-                )
+            self._ctx, self._caches, _, _ = fn(
+                self._params, self._ctx, self._caches,
+                self._lens.copy(), active,
+                np.zeros((self.num_slots, self._kb), np.int32),
+                np.zeros((self.num_slots,), np.int32), *sargs,
+            )
             self.drafter.warmup()
 
     def warm_prefill_buckets(self) -> None:
@@ -1828,8 +1882,7 @@ class DecodeStepper:
         safe on an IDLE bank (the dense paths write masked-garbage
         rows through slot 0, overwritten before anything attends
         them — the standing restore argument)."""
-        self._warming = True
-        try:
+        with self._warm():
             cb = 1
             while True:
                 cbb = min(cb, self.max_len)
@@ -1858,21 +1911,19 @@ class DecodeStepper:
                             **self._pchunk_fns, key: fn
                         }
                     # empty table row -> null sentinel page
-                    with annotate("serving/warmup"):
-                        self._pools = fn(
-                            self._params, self._pools, toks,
-                            self._table_row(0, pbt), np.int32(0),
-                        )
+                    self._pools = fn(
+                        self._params, self._pools, toks,
+                        self._table_row(0, pbt), np.int32(0),
+                    )
                 else:
                     fn = self._chunk_fns.get(cbb)
                     if fn is None:
                         fn = self._build_chunk_fn(cbb)
                         self._chunk_fns = {**self._chunk_fns, cbb: fn}
-                    with annotate("serving/warmup"):
-                        self._caches = fn(
-                            self._params, self._caches, toks,
-                            np.int32(0), np.int32(0),
-                        )
+                    self._caches = fn(
+                        self._params, self._caches, toks,
+                        np.int32(0), np.int32(0),
+                    )
                 if cb >= self.max_len:
                     break
                 cb <<= 1
@@ -1892,13 +1943,10 @@ class DecodeStepper:
                     if fn is None:
                         fn = self._build_admit_fn(pb)
                         self._admit_fns = {**self._admit_fns, pb: fn}
-                    with annotate("serving/warmup"):
-                        self._caches = fn(
-                            self._params, self._caches, row,
-                            np.int32(0),
-                        )
-        finally:
-            self._warming = False
+                    self._caches = fn(
+                        self._params, self._caches, row,
+                        np.int32(0),
+                    )
 
     def warm_constrained_buckets(self) -> None:
         """Compile the grammar-MASKED step/verify variants off the
@@ -1915,8 +1963,7 @@ class DecodeStepper:
         before ``mark_warmed()``; O(log pages) masked-step programs
         plus two verify variants. All writes masked (inactive bank):
         the slot bank is numerically untouched."""
-        self._warming = True
-        try:
+        with self._warm():
             active = np.zeros(self.num_slots, bool)
             sargs = self._sampling_args()
             vocab = self._gen._emb.vocab_size
@@ -1928,11 +1975,10 @@ class DecodeStepper:
                 if fn is None:
                     fn = self._build_step_fn(True)
                     self._step_fns = {**self._step_fns, True: fn}
-                with annotate("serving/warmup"):
-                    self._ctx, self._caches, _ = fn(
-                        self._params, self._ctx, self._caches,
-                        self._lens.copy(), active, *sargs, tmask,
-                    )
+                self._ctx, self._caches, _ = fn(
+                    self._params, self._ctx, self._caches,
+                    self._lens.copy(), active, *sargs, tmask,
+                )
                 if self.drafter is not None:
                     key = (self._kb + 1, True)
                     vfn = self._verify_fns.get(key)
@@ -1941,12 +1987,11 @@ class DecodeStepper:
                         self._verify_fns = {
                             **self._verify_fns, key: vfn
                         }
-                    with annotate("serving/warmup"):
-                        self._ctx, self._caches, _, _ = vfn(
-                            self._params, self._ctx, self._caches,
-                            self._lens.copy(), active, cand, cnt,
-                            *sargs, tmask,
-                        )
+                    self._ctx, self._caches, _, _ = vfn(
+                        self._params, self._ctx, self._caches,
+                        self._lens.copy(), active, cand, cnt,
+                        *sargs, tmask,
+                    )
                 return
             # the masked STEP tracks the longest OCCUPIED table, so
             # it needs every pow2 bucket; verify windows always run
@@ -1961,12 +2006,11 @@ class DecodeStepper:
                 if fn is None:
                     fn = self._build_step_fn_paged(pbt, True)
                     self._pstep_fns = {**self._pstep_fns, key: fn}
-                with annotate("serving/warmup"):
-                    self._ctx, self._pools, _ = fn(
-                        self._params, self._ctx, self._pools,
-                        self._lens.copy(), active, table, *sargs,
-                        tmask,
-                    )
+                self._ctx, self._pools, _ = fn(
+                    self._params, self._ctx, self._pools,
+                    self._lens.copy(), active, table, *sargs,
+                    tmask,
+                )
                 if pbt >= self._max_pages_bucket:
                     break
                 pbt *= 2
@@ -1986,14 +2030,11 @@ class DecodeStepper:
                             **self._pverify_fns, vkey: vfn
                         }
                     extra = (tmask,) if vmasked else ()
-                    with annotate("serving/warmup"):
-                        self._ctx, self._pools, _, _ = vfn(
-                            self._params, self._ctx, self._pools,
-                            self._lens.copy(), active, cand, cnt,
-                            table, *sargs, *extra,
-                        )
-        finally:
-            self._warming = False
+                    self._ctx, self._pools, _, _ = vfn(
+                        self._params, self._ctx, self._pools,
+                        self._lens.copy(), active, cand, cnt,
+                        table, *sargs, *extra,
+                    )
 
     def warm_restore_buckets(self) -> None:
         """Compile every pow2 swap-restore bucket OFF the serving
@@ -2008,8 +2049,7 @@ class DecodeStepper:
         restore keys on. Only safe on an IDLE bank — the dense path
         writes (masked-garbage) rows through slot 0. Mints record
         ``trigger="warmup"``."""
-        self._warming = True
-        try:
+        with self._warm():
             dt = np.dtype(self._gen.kv_dtype)
             nh, hd = self._nh, self._hd
             pb, buckets = 1, set()
@@ -2057,8 +2097,6 @@ class DecodeStepper:
                 )
                 row = np.zeros((1, self.max_len), np.int32)
                 self._ctx = self._row_fn(self._ctx, row, np.int32(0))
-        finally:
-            self._warming = False
 
     def _build_admit_fn(self, pb: int):
         """Compiled whole-prefix prefill for bucket ``pb``: positions
@@ -2504,34 +2542,39 @@ class DecodeStepper:
         # bookkeeping: a failed step leaves the slot bank exactly as it
         # was, which is what makes the batcher's blame retries sound
         self._fire("stepper.step", active=active)
-        tmask = self._build_tmask(active)  # None unless constrained
-        masked = tmask is not None
-        sargs = self._sampling_args()
-        extra = (tmask,) if masked else ()
-        if self.paged:
-            pbt = self._table_bucket()
-            key = (pbt, masked)
-            fn = self._pstep_fns.get(key)
-            if fn is None:
-                self._compiling()
-                fn = self._build_step_fn_paged(pbt, masked)
-                self._pstep_fns = {**self._pstep_fns, key: fn}
-            with annotate("serving/step"):
+        with _span("serving/step_args"):
+            tmask = self._build_tmask(active)  # None unless constrained
+            masked = tmask is not None
+            if self.paged:
+                pbt = self._table_bucket()
+                key = (pbt, masked)
+                fn = self._pstep_fns.get(key)
+                if fn is None:
+                    self._compiling()
+                    fn = self._build_step_fn_paged(pbt, masked)
+                    self._pstep_fns = {**self._pstep_fns, key: fn}
+                tables = (self._tables_array(pbt),)
+            else:
+                fn = self._step_fns.get(masked)
+                if fn is None:
+                    self._compiling()
+                    fn = self._build_step_fn(masked)
+                    self._step_fns = {**self._step_fns, masked: fn}
+                tables = ()
+            # every argument of the step that is built on the host
+            host = (
+                self._lens.copy(), active, *tables,
+                *self._sampling_args(), *((tmask,) if masked else ()),
+            )
+            self.host_arg_bytes_step = self._host_arg_bytes(host)
+        with _span("serving/step", host_arg_bytes=self.host_arg_bytes_step):
+            if self.paged:
                 self._ctx, self._pools, toks = fn(
-                    self._params, self._ctx, self._pools,
-                    self._lens.copy(), active,
-                    self._tables_array(pbt), *sargs, *extra,
+                    self._params, self._ctx, self._pools, *host
                 )
-        else:
-            fn = self._step_fns.get(masked)
-            if fn is None:
-                self._compiling()
-                fn = self._build_step_fn(masked)
-                self._step_fns = {**self._step_fns, masked: fn}
-            with annotate("serving/step"):
+            else:
                 self._ctx, self._caches, toks = fn(
-                    self._params, self._ctx, self._caches,
-                    self._lens.copy(), active, *sargs, *extra,
+                    self._params, self._ctx, self._caches, *host
                 )
         return _InflightStep(self, active, toks)
 
@@ -3238,7 +3281,9 @@ class ServingEngine:
         self.batcher = (
             None
             if self._stepper is None
-            else ContinuousBatcher(self._stepper, **self._batcher_cfg)
+            else ContinuousBatcher(
+                self._stepper, span=_span, **self._batcher_cfg
+            )
         )
         from distkeras_tpu.data.dataset import Dataset
         from distkeras_tpu.predictors import ModelPredictor
@@ -3709,7 +3754,9 @@ class ServingEngine:
         # this replica — a restarted engine can never serve pages
         # against a promise its predecessor made
         self.kv_epoch = int.from_bytes(os.urandom(4), "big")
-        batcher = ContinuousBatcher(stepper, **self._batcher_cfg)
+        batcher = ContinuousBatcher(
+            stepper, span=_span, **self._batcher_cfg
+        )
         self.batcher = batcher
         self._launch_scheduler(batcher)
         if self.recorder is not None:

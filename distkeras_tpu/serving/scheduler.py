@@ -77,6 +77,31 @@ import numpy as np
 _NO_EVICT = object()  # "no eviction pending" sentinel (step loop)
 
 
+class _NoSpan:
+    """The span this module opens when nobody handed it a profiler's:
+    the face of ``jax.profiler.TraceAnnotation`` (a context manager
+    that takes arguments at its open and, through ``set_metadata``, at
+    its close) and nothing behind it."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **args):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def _no_span(name, **args):
+    return _NO_SPAN
+
+
 class _Inflight:
     """One dispatched-but-uncollected device step, scheduler-side: the
     active mask / sequences it was issued against, the wall/mint
@@ -304,6 +329,7 @@ class ServeRequest:
         self.events: list[dict] = []  # trace ledger (traced reqs only)
         self.prefill_chunks = 0  # stepper.prefill_chunk calls, this req
         self.iterations = 0  # scheduler iterations this slot advanced
+        self.page_waited = False  # admission held it for KV pages
         self._done = threading.Event()
 
     # -- lifecycle (called by the batcher, under its lock) ------------------
@@ -420,7 +446,7 @@ class ContinuousBatcher:
 
     def __init__(self, stepper, queue_capacity=64, prefill_chunk=None,
                  quarantine_steps=64, registry=None, recorder=None,
-                 qos=None, overlap=False, shed_gate=None):
+                 qos=None, overlap=False, shed_gate=None, span=None):
         """``quarantine_steps``: scheduler iterations a slot sits out
         after a device step is blamed on its request (its cache rows are
         suspect, and a systematically poisonous traffic shape should not
@@ -479,7 +505,14 @@ class ContinuousBatcher:
         rung 2 — deterministic decode makes the clamped reply an
         exact prefix of the full one), and the admission phase feeds
         it each admitted request's queue sojourn so the CoDel side
-        has a signal."""
+        has a signal.
+
+        ``span``: ``span(name, **args)`` opens a span on the profiler's
+        timeline (the engine passes ``jax.profiler.TraceAnnotation``
+        behind a function; this module imports no JAX). Every phase of
+        an iteration runs under one, named ``serving/<phase>`` inside
+        ``serving/iter``; a count known only when a span closes is set
+        there with ``set_metadata``. None opens nothing."""
         from distkeras_tpu.serving.qos import _QosQueues
 
         self.stepper = stepper
@@ -536,6 +569,11 @@ class ContinuousBatcher:
         self._draining = False
         self._stopped = False
         self.recorder = recorder
+        self._span = _no_span if span is None else span
+        # this iteration's counts, made once when admission ends: the
+        # ``serving/iter`` span and the recorder's iteration line both
+        # read them from here
+        self._iter_counts: dict = {}
         from distkeras_tpu.obs import MetricsRegistry, OverlapLedger
 
         self.registry = registry if registry is not None else MetricsRegistry()
@@ -573,6 +611,9 @@ class ContinuousBatcher:
                 "prefill_failures",  # begin_admit/prefill_chunk raised
                 "pool_exhausted",  # admissions failed typed overloaded
                 # (paged KV: page reservation could not be met)
+                "page_waits",  # iterations whose admission held the
+                # head-of-line request because the pool was short
+                "page_wait_requests",  # requests so held (each once)
                 "quarantines",  # slots sent to probation
                 # speculative decode (0 on non-speculative steppers)
                 "spec_windows",  # slot-windows processed via verify
@@ -806,9 +847,13 @@ class ContinuousBatcher:
         the next step and return without waiting on it. Emitted token
         order per request is identical — only where the wall-clock goes
         differs."""
-        if self.overlap:
-            return self._step_overlapped()
-        return self._step_sequential()
+        run = self._step_overlapped if self.overlap else self._step_sequential
+        if self.idle:
+            return run()  # a pass over an idle bank: no span for it
+        with self._span("serving/iter") as it:
+            progressed = run()
+            it.set_metadata(**self._iter_counts)
+        return progressed
 
     def _step_sequential(self) -> bool:
         """The strictly sequential iteration (the pre-overlap loop,
@@ -945,22 +990,23 @@ class ContinuousBatcher:
         on its earlier, pre-step view."""
         if not self._preemptible:
             return False
-        with self._lock:
-            free = sum(
-                s is None and i not in self._quarantined
-                for i, s in enumerate(self._slots)
-            )
-            fits = free >= blocked.n and (
-                not getattr(self.stepper, "paged", False)
-                or self._pages_for_request(blocked)
-                <= self.stepper.available_pages
-            )
-            preempt = (
-                None if fits else self._pick_victim_locked(blocked)
-            )
-        if preempt is None:
-            return False
-        return self._preempt(*preempt)
+        with self._span("serving/preempt"):
+            with self._lock:
+                free = sum(
+                    s is None and i not in self._quarantined
+                    for i, s in enumerate(self._slots)
+                )
+                fits = free >= blocked.n and (
+                    not getattr(self.stepper, "paged", False)
+                    or self._pages_for_request(blocked)
+                    <= self.stepper.available_pages
+                )
+                preempt = (
+                    None if fits else self._pick_victim_locked(blocked)
+                )
+            if preempt is None:
+                return False
+            return self._preempt(*preempt)
 
     def _admit_phase(self, preempt_now: bool):
         """Host scheduling work at the top of an iteration: quarantine
@@ -971,11 +1017,18 @@ class ContinuousBatcher:
         request admission could not place (the preemption candidate).
         ``preempt_now``: the sequential loop preempts here; the
         overlapped loop defers to ``_preempt_phase`` after collect."""
+        with self._span("serving/admit") as sp:
+            progressed, blocked, admitted = self._admit(preempt_now)
+            sp.set_metadata(admitted=admitted)
+        return progressed, blocked
+
+    def _admit(self, preempt_now: bool):
         now = time.monotonic()
         admitted = []
         paged = getattr(self.stepper, "paged", False)
         page_budget = self.stepper.available_pages if paged else None
         blocked = None  # head-of-line candidate admission could not place
+        page_wait = False  # ... and it was pages that it lacked
         preempt = None
         with self._lock:
             self._sched_iters += 1
@@ -1011,6 +1064,11 @@ class ContinuousBatcher:
                     if need > page_budget:
                         self._queue.appendleft(req)
                         blocked = req
+                        page_wait = True
+                        self.counters["page_waits"] += 1
+                        if not req.page_waited:
+                            req.page_waited = True
+                            self.counters["page_wait_requests"] += 1
                         break
                     page_budget -= need
                 group = free[taken:taken + req.n]
@@ -1077,13 +1135,30 @@ class ContinuousBatcher:
         progressed = self._spend_prefill_budget() or preempted
         progressed = self._export_prefilled() or progressed
         progressed = self._fork_completions() or progressed
-        return progressed, blocked
+        counts = {
+            "iter": self._sched_iters, "active": 0,
+            "prefilling": len(self._prefill_left),
+            "queue_depth": len(self._queue),
+        }
+        if paged:
+            total = self.stepper.total_pages
+            counts["pages_in_use"] = total - self.stepper.free_pages
+            counts["pages_total"] = total
+            counts["page_waits"] = int(page_wait)
+        self._iter_counts = counts
+        return progressed, blocked, len(admitted)
 
     def _mask_phase(self):
         """Deadline-sweep slots that produce no tokens (mid-prefill,
         awaiting-fork) and compute the decode active mask + optional
         per-slot host sequences. Runs immediately before dispatch in
         both loop modes."""
+        with self._span("serving/mask"):
+            active, seqs = self._mask()
+            self._iter_counts["active"] = int(active.sum())
+        return active, seqs
+
+    def _mask(self):
         now = time.monotonic()
         with self._lock:
             # deadline sweep for slots still mid-prefill AND groups
@@ -1148,6 +1223,16 @@ class ContinuousBatcher:
         EOS / deadline checks in emission order, stream pushes (before
         any eviction they trigger), WFQ charging, speculative
         acceptance counters, and the recorder's iteration line."""
+        with self._span("serving/emit") as sp:
+            emitted = self._emit(
+                active, step_t0, mints0, toks, counts, blamed, used_verify
+            )
+            sp.set_metadata(emitted=emitted)
+        return True
+
+    def _emit(self, active, step_t0, mints0, toks, counts, blamed,
+              used_verify) -> int:
+        """``_finish_step``'s body; returns the tokens emitted."""
         now = time.monotonic()
         if self._led_total() > mints0:
             # a mint landed inside the decode phase: every traced
@@ -1160,9 +1245,18 @@ class ContinuousBatcher:
                 noted.add(id(r))
                 self._note_mints(r, mints0, step_t0, now)
         emitted_total = 0
+        n_active = int(active.sum())
+        # the queue and the pool as this iteration's admission left
+        # them, on the tape beside what the step emitted
+        c = self._iter_counts
+        pool = {
+            "queue_depth": c.get("queue_depth"),
+            "pages_in_use": c.get("pages_in_use"),
+            "page_waits": c.get("page_waits"),
+        }
         with self._lock:
             self.counters["steps"] += 1
-            self.counters["occupancy_sum"] += int(active.sum())
+            self.counters["occupancy_sum"] += n_active
             for i in blamed:
                 req = self._slots[i]
                 if req is None:
@@ -1197,10 +1291,10 @@ class ContinuousBatcher:
                 if self.recorder is not None:
                     self.recorder.record(
                         "scheduler.iteration", iter=self._sched_iters,
-                        active=int(active.sum()), emitted=0,
-                        blamed=blamed,
+                        active=n_active, emitted=0, blamed=blamed,
+                        **pool,
                     )
-                return True  # every active slot was blamed this round
+                return 0  # every active slot was blamed this round
             blamed_set = set(blamed)
             for i, req in enumerate(self._slots):
                 if req is None or not active[i] or i in blamed_set:
@@ -1265,11 +1359,11 @@ class ContinuousBatcher:
             # record nothing): what the slot bank did this tick
             self.recorder.record(
                 "scheduler.iteration", iter=self._sched_iters,
-                active=int(active.sum()), emitted=emitted_total,
+                active=n_active, emitted=emitted_total,
                 spec=bool(used_verify.any()),
-                blamed=blamed if blamed else None,
+                blamed=blamed if blamed else None, **pool,
             )
-        return True
+        return emitted_total
 
     # -- disaggregated prefill export ---------------------------------------
 

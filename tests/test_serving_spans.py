@@ -1,0 +1,271 @@
+"""The serving scheduler's spans on the profiler's timeline (PR 25): one
+``serving/iter`` an iteration with its phases inside it, page and
+host-argument counts on them, and nothing of it when nobody hands the
+batcher a span factory.
+
+The traced tests read the profile back through the benchmark's own helper
+(``benchmark/layer_metrics/_program_spans.py``), so the names and the
+arguments the six per-layer metrics read are pinned where they are made.
+"""
+
+from __future__ import annotations
+
+import ast
+import glob
+import inspect
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark.layer_metrics import _program_spans  # noqa: E402
+from distkeras_tpu.serving import scheduler  # noqa: E402
+from distkeras_tpu.serving.scheduler import ContinuousBatcher, ServeRequest  # noqa: E402
+from test_serving import FakeStepper  # noqa: E402
+
+PHASES = ("serving/admit", "serving/mask", "serving/step_args",
+          "serving/step", "serving/collect", "serving/emit")
+
+
+def _lm():
+    from distkeras_tpu.models import zoo
+
+    return zoo.transformer_lm(vocab_size=61, seq_len=32, d_model=32,
+                              num_heads=2, depth=2)
+
+
+def _traced(tmp_path, drive):
+    """``drive()`` under the profiler (host annotations only, as the
+    benchmark takes its traces); the plain form of the profile's spans."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = drive()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+    _program_spans.load.cache_clear()
+    return out, _program_spans.load(path)
+
+
+def _generate(engine, n_requests=4, steps=6):
+    reqs = [engine.submit((np.arange(3 + i, dtype=np.int32) * 7) % 61, steps)
+            for i in range(n_requests)]
+    return [list(r.result(120)) for r in reqs]
+
+
+@pytest.fixture(scope="module")
+def paged_engine():
+    from distkeras_tpu.serving import ServingEngine
+
+    engine = ServingEngine(_lm(), num_slots=2, paged=True, page_size=4,
+                           prefill_chunk=4)
+    engine.start()
+    _generate(engine, 2)  # compiles, off the traced drives
+    yield engine
+    engine.stop()
+
+
+def test_every_iteration_span_holds_its_phases_on_its_thread(
+        paged_engine, tmp_path):
+    _, plain = _traced(tmp_path, lambda: _generate(paged_engine))
+    its = _program_spans.iterations(plain)
+    assert len(its) >= 6
+    alloc = paged_engine._stepper._kv_alloc
+    for it in its:
+        # a child lies inside its parent on the parent's thread: that is
+        # how ``iterations`` found it; here, that each phase is there
+        assert set(PHASES) - {"serving/collect", "serving/emit"} <= set(it["spans"])
+        assert it["self_ns"] <= it["dur_ns"]
+        a = it["args"]
+        assert a["pages_total"] == alloc.total_pages
+        assert 0 < a["pages_in_use"] <= a["pages_total"]
+        assert 1 <= a["active"] <= 2 and a["page_waits"] in (0, 1)
+        assert a["iter"] > 0 and a["queue_depth"] >= 0 and a["prefilling"] >= 0
+    # the overlapped loop collects, then emits, the step the iteration
+    # before dispatched: every iteration but the first after an idle bank
+    assert sum("serving/collect" in it["spans"] for it in its) >= len(its) - 2
+    assert all(("serving/collect" in it["spans"]) == ("serving/emit" in it["spans"])
+               for it in its)
+    iters = [it["args"]["iter"] for it in its]
+    assert iters == sorted(set(iters))
+    emitted = sum(a["emitted"] for it in its
+                  for _s, _d, a in it["spans"].get("serving/emit", []))
+    assert 0 < emitted <= 4 * 6
+    admitted = sum(a["admitted"] for it in its
+                   for _s, _d, a in it["spans"]["serving/admit"])
+    assert admitted <= 4
+    # a prefill chunk is a child of admission
+    for it in its:
+        for s, d, a in it["spans"].get("serving/prefill_chunk", []):
+            assert any(s >= s0 and s + d <= s0 + d0
+                       for s0, d0, _a in it["spans"]["serving/admit"])
+            assert a["host_arg_bytes"] > 0
+
+
+def test_pages_in_use_on_the_span_is_the_allocator_s(paged_engine, tmp_path):
+    """One request alone, so that the pool stands still while it decodes:
+    what admission reserved is what the span and the allocator both say."""
+    def drive():
+        req = paged_engine.submit(np.arange(5, dtype=np.int32), 8)
+        req.result(120)
+        return paged_engine._stepper.pages_for(5, 8)
+
+    need, plain = _traced(tmp_path, drive)
+    its = _program_spans.iterations(plain)
+    held = {it["args"]["pages_in_use"] for it in its if it["args"]["active"]}
+    index_only = paged_engine._stepper.prefix_index.reclaimable()
+    assert held and all(need <= h <= need + index_only for h in held)
+    assert paged_engine.stats()["paged"]["pages_in_use"] <= max(held)
+
+
+def test_step_span_carries_the_host_bytes_of_the_call(tmp_path):
+    """Host-side leaves of the parameter tree are counted once a binding
+    and ride every step's span with the small per-step arrays; once the
+    tree is on the device only the small arrays are left."""
+    import jax
+
+    from distkeras_tpu.serving import ServingEngine
+
+    lm = _lm()
+    lm.params = jax.tree_util.tree_map(np.asarray, lm.params)
+    tree_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(lm.params))
+    engine = ServingEngine(lm, num_slots=2, paged=True, page_size=4,
+                           prefill_chunk=4)
+    engine.start()
+    try:
+        stepper = engine._stepper
+        assert stepper._params_host_bytes == tree_bytes > 0
+        _generate(engine, 1)
+        _, plain = _traced(tmp_path / "host", lambda: _generate(engine, 2))
+        on_host = _program_spans.span_values(
+            _program_spans.iterations(plain), "serving/step", "host_arg_bytes")
+        # lens, mask, the (2, bucket) page table, five sampler arrays
+        small = {2 * 4 + 2 + 2 * b * 4 + 5 * 2 * 4 for b in (1, 2, 4, 8)}
+        assert on_host and {v - tree_bytes for v in on_host} <= small
+        assert engine.stats()["paged"]["host_arg_bytes_step"] == on_host[-1]
+
+        stepper._params = jax.device_put(stepper._params)
+        assert stepper._params_host_bytes == 0
+        _, plain = _traced(tmp_path / "device", lambda: _generate(engine, 2))
+        on_device = _program_spans.span_values(
+            _program_spans.iterations(plain), "serving/step", "host_arg_bytes")
+        assert on_device and set(on_device) <= small
+    finally:
+        engine.stop()
+
+
+def test_a_request_that_waits_for_pages_is_counted(tmp_path):
+    """A pool that holds one request's reservation and not two: the second
+    waits at the head of the queue while a slot stands free, and the
+    counters and the iteration's span say so."""
+    from distkeras_tpu.serving import ServingEngine
+
+    # 12 + 12 tokens reserve 6 pages of 4; 8 pages hold one such request
+    engine = ServingEngine(_lm(), num_slots=2, paged=True, page_size=4,
+                           num_pages=9, prefill_chunk=4, prefix_cache=False)
+    engine.start()
+    try:
+        def drive():
+            reqs = [engine.submit((np.arange(12, dtype=np.int32) + 5 * i) % 61, 12)
+                    for i in range(2)]
+            return [len(r.result(120)) for r in reqs]
+
+        lens, plain = _traced(tmp_path, drive)
+        assert lens == [24, 24]
+        stats = engine.stats()
+        assert stats["page_wait_requests"] == 1
+        assert stats["page_waits"] >= 1 and stats["pool_exhausted"] == 0
+        its = _program_spans.iterations(plain)
+        waited = [it for it in its if it["args"]["page_waits"]]
+        assert waited and len(waited) <= stats["page_waits"]
+        assert all(it["args"]["active"] == 1 and it["args"]["queue_depth"] == 1
+                   for it in waited)
+        events = [e for e in engine.recorder.snapshot()
+                  if e["kind"] == "scheduler.iteration"]
+        assert any(e.get("page_waits") == 1 for e in events)
+    finally:
+        engine.stop()
+
+
+# ------------------------------------------------- the batcher without JAX
+
+
+class _Recorded:
+    """A span factory that keeps what it is given."""
+
+    def __init__(self):
+        self.spans = []
+
+    def __call__(self, name, **args):
+        self.spans.append((name, args))
+        return self
+
+    def __enter__(self):
+        self._open = self.spans[-1]
+        return _Closing(self._open[1])
+
+    def __exit__(self, *exc):
+        return False
+
+
+class _Closing:
+    def __init__(self, args):
+        self._args = args
+
+    def set_metadata(self, **args):
+        self._args.update(args)
+
+
+def _serve(span, overlap):
+    b = ContinuousBatcher(FakeStepper(), overlap=overlap, span=span)
+    reqs = [b.submit(ServeRequest(np.arange(2 + i), 3 + i)) for i in range(3)]
+    for _ in range(40):
+        b.step()
+    return [list(r.result(1)) for r in reqs]
+
+
+def test_the_scheduler_module_imports_no_jax():
+    tree = ast.parse(inspect.getsource(scheduler))
+    names = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names |= {a.name.split(".")[0] for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            names.add((node.module or "").split(".")[0])
+    assert "jax" not in names and "jaxlib" not in names
+    assert not any(getattr(v, "__name__", "").split(".")[0] == "jax"
+                   for v in vars(scheduler).values() if inspect.ismodule(v))
+
+
+@pytest.mark.parametrize("overlap", [False, True], ids=["sequential", "overlapped"])
+def test_tokens_are_the_same_with_the_spans_on_and_off(overlap, monkeypatch):
+    import jax
+
+    def refuse(*a, **kw):
+        raise AssertionError("the default span factory reached the profiler")
+
+    rec = _Recorded()
+    with_spans = _serve(rec, overlap)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    assert _serve(None, overlap) == with_spans
+    names = [n for n, _ in rec.spans]
+    assert set(names) == {"serving/iter", "serving/admit", "serving/mask",
+                          "serving/emit"}
+    # no span for a pass over an idle bank: 40 calls, far fewer iterations
+    iters = [a for n, a in rec.spans if n == "serving/iter"]
+    assert 4 <= len(iters) <= 12
+    assert [a["iter"] for a in iters] == list(range(1, len(iters) + 1))
+    assert sum(a["emitted"] for n, a in rec.spans if n == "serving/emit") == 3 + 4 + 5
+    assert sum(a["admitted"] for n, a in rec.spans if n == "serving/admit") == 3
+    assert "pages_in_use" not in iters[0]  # a dense bank has no pool
