@@ -547,11 +547,14 @@ class DecodeStepper:
         The compiled programs are the SAME bodies as solo; XLA's
         partitioner inserts the collectives (one psum per attention/
         MLP pair). ``mesh=None`` (the default) leaves every code path
-        byte-for-byte as before. Requires ``num_heads %% N == 0`` —
+        byte-for-byte as before; the stepper's copy of the weights is
+        then ``model.params`` with its host leaves placed on the
+        default device, once. Requires ``num_heads %% N == 0`` —
         validated loudly here, at bundle load. The nested draft
         stepper (``ModelDrafter``) always runs solo: a draft worth
         serving fits one device, and its proposals are verified by the
         sharded target anyway."""
+        import jax
         import jax.numpy as jnp
 
         from distkeras_tpu.predictors import CachedSequenceGenerator
@@ -613,7 +616,6 @@ class DecodeStepper:
         self._kv_sh = None
         self._repl_sh = None
         if mesh is not None:
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec
 
             from distkeras_tpu.parallel.mesh import serving_mesh
@@ -641,7 +643,12 @@ class DecodeStepper:
                 jnp.zeros((b, t), jnp.int32), self._repl_sh
             )
         else:
-            self._params = model.params
+            # the stepper's OWN resident copy, placed once: a host leaf
+            # (a bundle's NumPy tree) goes to the device here, so no
+            # program call uploads it again; a leaf that is a
+            # ``jax.Array`` already is bound as it is, no second copy.
+            # ``model.params`` stays as handed in
+            self._params = jax.device_put(model.params)
             self._ctx = jnp.zeros((b, t), jnp.int32)
         self.paged = bool(paged)
         self.page_size = int(page_size)
@@ -790,16 +797,20 @@ class DecodeStepper:
 
     @_params.setter
     def _params(self, tree):
-        # what of the tree is on the host is summed here, once a
-        # binding: the ``host_arg_bytes`` of every program call's span
-        # starts from it
+        """Bind ``tree`` (``None`` drops the stepper's copy) and sum
+        what of it is on the host, once a binding: the
+        ``host_arg_bytes`` of every program call's span starts from it.
+        The constructor binds device arrays on both branches, so the
+        sum is 0; anything else means a caller re-bound host arrays,
+        which every call then uploads: a fault to look for."""
         self._params_tree = tree
         self._params_host_bytes = _host_bytes(tree)
 
     def _host_arg_bytes(self, host) -> int:
-        """What a program call uploads before it can run: the tree's
-        share on the host and ``host``, the arguments built in NumPy
-        for this call."""
+        """What a program call uploads before it can run: ``host``, the
+        arguments built in NumPy for this call, and the tree's share on
+        the host, which is 0 unless host arrays were re-bound to
+        ``_params`` after the constructor placed them (a fault)."""
         return self._params_host_bytes + sum(a.nbytes for a in host)
 
     def paged_stats(self) -> dict:
@@ -3729,6 +3740,12 @@ class ServingEngine:
         if self._stop_evt.wait(self._restart_delays.delay(self._restarts)):
             return  # shutdown arrived during the backoff
         try:
+            # the dead generation's copy of the weights goes before the
+            # new stepper places its own from ``model.params``, so that
+            # a restart never holds the served tree twice (the old
+            # stepper itself outlives this call: its programs keep it
+            # in a reference cycle)
+            self._stepper._params = None
             stepper = DecodeStepper(self.model, **self._stepper_cfg)
             stepper.on_compile = self._extend_grace
             # compile the decode step HERE, on the supervisor thread,

@@ -130,9 +130,10 @@ def test_pages_in_use_on_the_span_is_the_allocator_s(paged_engine, tmp_path):
 
 
 def test_step_span_carries_the_host_bytes_of_the_call(tmp_path):
-    """Host-side leaves of the parameter tree are counted once a binding
-    and ride every step's span with the small per-step arrays; once the
-    tree is on the device only the small arrays are left."""
+    """The stepper places a NumPy tree when it binds it (PR 26), so only
+    the small per-step arrays ride a step's span; host arrays bound to
+    ``_params`` afterwards, the fault the counter is there to show, ride
+    every call with them."""
     import jax
 
     from distkeras_tpu.serving import ServingEngine
@@ -145,22 +146,23 @@ def test_step_span_carries_the_host_bytes_of_the_call(tmp_path):
     engine.start()
     try:
         stepper = engine._stepper
-        assert stepper._params_host_bytes == tree_bytes > 0
+        assert stepper._params_host_bytes == 0 < tree_bytes
         _generate(engine, 1)
-        _, plain = _traced(tmp_path / "host", lambda: _generate(engine, 2))
-        on_host = _program_spans.span_values(
-            _program_spans.iterations(plain), "serving/step", "host_arg_bytes")
-        # lens, mask, the (2, bucket) page table, five sampler arrays
-        small = {2 * 4 + 2 + 2 * b * 4 + 5 * 2 * 4 for b in (1, 2, 4, 8)}
-        assert on_host and {v - tree_bytes for v in on_host} <= small
-        assert engine.stats()["paged"]["host_arg_bytes_step"] == on_host[-1]
-
-        stepper._params = jax.device_put(stepper._params)
-        assert stepper._params_host_bytes == 0
         _, plain = _traced(tmp_path / "device", lambda: _generate(engine, 2))
         on_device = _program_spans.span_values(
             _program_spans.iterations(plain), "serving/step", "host_arg_bytes")
+        # lens, mask, the (2, bucket) page table, five sampler arrays
+        small = {2 * 4 + 2 + 2 * b * 4 + 5 * 2 * 4 for b in (1, 2, 4, 8)}
         assert on_device and set(on_device) <= small
+        assert engine.stats()["paged"]["host_arg_bytes_step"] == on_device[-1]
+
+        stepper._params = lm.params
+        assert stepper._params_host_bytes == tree_bytes
+        _, plain = _traced(tmp_path / "host", lambda: _generate(engine, 2))
+        on_host = _program_spans.span_values(
+            _program_spans.iterations(plain), "serving/step", "host_arg_bytes")
+        assert on_host and {v - tree_bytes for v in on_host} <= small
+        assert engine.stats()["paged"]["host_arg_bytes_step"] == on_host[-1]
     finally:
         engine.stop()
 

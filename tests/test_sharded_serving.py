@@ -149,11 +149,16 @@ def test_heads_divisibility_is_loud_at_load(lm):
 
 
 def test_mesh_none_is_bit_for_bit_unchanged(lm):
+    import jax
+
     st = DecodeStepper(lm, num_slots=2)
     assert st.mesh is None and st.mesh_spec is None
     assert st.mesh_devices == 1
-    # no placement ran: the stepper reads the model's own tree
-    assert st._params is lm.params
+    # leaves on the device already are bound as they are: no copy ran
+    bound = jax.tree_util.tree_leaves(st._params)
+    assert bound and all(
+        a is b for a, b in zip(bound, jax.tree_util.tree_leaves(lm.params))
+    )
 
 
 # --------------------------------------------- identity: every path
