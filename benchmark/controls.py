@@ -24,7 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import harness, loadgen, reference, spec  # noqa: E402
+from benchmark import harness, loadgen, spec  # noqa: E402
 from benchmark.harness import log  # noqa: E402
 
 
@@ -34,7 +34,8 @@ def train_control(cell: dict, seed: int, precision: str = "int8") -> dict:
 
     from benchmark import drive_train
 
-    w = reference.widths(cell["config"])
+    family = cell["family"]
+    w = family.widths(cell["config"])
     traffic = cell["traffic"]
     rows = int(traffic["batch_per_chip"]) * int(cell["cell"]["chips"])
     rng = np.random.default_rng(seed)
@@ -42,8 +43,8 @@ def train_control(cell: dict, seed: int, precision: str = "int8") -> dict:
                for _ in range(int(traffic["check"]["steps"]))]
     lr, k = float(traffic["learning_rate"]), int(traffic["window"])
     with jax.default_matmul_precision("highest"):
-        ref = reference.train_readings(w, seed, batches, lr, moment_after=k)
-        low = reference.train_readings(w, seed, batches, lr, precision, k)
+        ref = family.train_readings(w, seed, batches, lr, moment_after=k)
+        low = family.train_readings(w, seed, batches, lr, precision, k)
     ok, rows_ = drive_train.compare(low, ref, traffic["check"]["limits"])
     return {"seed": seed, "correct": ok,
             "compared": {name: [value, limit] for name, value, limit in rows_}}
@@ -59,9 +60,8 @@ def serve_control(cell: dict, seed: int, seconds: float, bits: int = 4) -> dict:
     out = drive_serve.run(cell, args, time.perf_counter(), watch,
                           overrides={"weight_bits": bits})
     return {"seed": seed, "correct": out["correct"],
-            "compared": {"widest_logit_gap": [
-                out["readings"]["widest_logit_gap"],
-                cell["config"]["serving"]["check"]["gap_limit"]]},
+            "compared": {name: [value, limit]
+                         for name, value, limit in out["compared"]},
             "attempted": out["attempted"], "failed": out["failed"]}
 
 

@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from benchmark import loadgen, model_build, reference
+from benchmark import loadgen
 from benchmark.harness import (
     Profile, annotate, log, memory_stat, peak_bytes, percentile)
 
@@ -36,17 +36,20 @@ class Record:
         self.sequence = None
 
 
-def boot_engine(w: dict, serving: dict, seed: int, tmp: str):
-    """Weights from the seed on the device, quantized by the program, saved
-    and loaded as a bundle (what a serving host does), then the paged
-    engine with the configuration file's slots and pages."""
+def boot_engine(family, w: dict, traffic: dict, serving: dict, seed: int,
+                tmp: str):
+    """Weights from the seed on the device, in the program's own model as
+    the cell's family builds it, quantized by the program, saved and loaded
+    as a bundle (what a serving host does), then the paged engine with the
+    configuration file's slots and pages."""
     import jax
 
     from distkeras_tpu.ops.quantization import quantize_model
     from distkeras_tpu.serving import ServingEngine
     from distkeras_tpu.utils.serialization import save_serving_bundle
 
-    model = model_build.build_program_model(w, reference.make_weights(w, seed))
+    model = family.build_program_model(
+        w, family.make_weights(w, seed), traffic)
     quantize_model(model, bits=int(serving["weight_bits"]))
     path = os.path.join(tmp, "bundle.dkt")
     save_serving_bundle(path, model)
@@ -199,14 +202,15 @@ def run(cell: dict, args, t_start: float, watch, overrides: dict | None = None) 
 
     config, traffic = cell["config"], cell["traffic"]
     serving = {**config["serving"], **(overrides or {})}
-    w = reference.widths(config)
+    family = cell["family"]
+    w = family.widths(config)
     if traffic["loop"] != "closed":
         raise ValueError(f"loop {traffic['loop']!r}: only closed-loop mixes "
                          f"have a driver yet (PERF.md, open questions)")
     lead = float(traffic["lead_s"])
 
     with tempfile.TemporaryDirectory() as tmp:
-        engine = boot_engine(w, serving, args.seed, tmp)
+        engine = boot_engine(family, w, traffic, serving, args.seed, tmp)
     engine._stepper.warmup()
     engine._stepper.warm_prefill_buckets()
     engine.compile_ledger.mark_warmed()
@@ -295,9 +299,9 @@ def run(cell: dict, args, t_start: float, watch, overrides: dict | None = None) 
     widest, n_tokens = 0.0, 0
     if sample:
         with jax.default_matmul_precision("highest"):
-            weights = reference.make_weights(w, args.seed)
+            weights = family.make_weights(w, args.seed)
             for r in sample:
-                g, _ = reference.token_gaps(
+                g, _ = family.token_gaps(
                     weights, w, r.sequence, len(r.request["prompt"]))
                 widest, n_tokens = max(widest, float(g.max())), n_tokens + len(g)
             del weights
@@ -317,5 +321,5 @@ def run(cell: dict, args, t_start: float, watch, overrides: dict | None = None) 
         "setup_s": setup_s, "compiled_in_window": compiled_in_window + storms,
         "peak_bytes": peak, "profile": profile, "e2e": e2e,
         "counters": counters, "samples": {"itl_gaps_s": gaps},
-        "readings": {"widest_logit_gap": widest},
+        "compared": [("widest_logit_gap", widest, limit)],
     }

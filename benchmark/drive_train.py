@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from benchmark import loadgen, model_build, reference
+from benchmark import loadgen, reference
 from benchmark.harness import Profile, annotate, log, peak_bytes
 
 
@@ -55,24 +55,15 @@ class TrainRig:
     """The program's trainer, core and placed state: the one object that
     set-up drives through its first steps and the window then takes."""
 
-    def __init__(self, w: dict, traffic: dict, chips: int, seed: int, weights):
+    def __init__(self, model, traffic: dict, chips: int, seed: int):
+        """``model``: the program's own, as the cell's family built it from
+        the seeded weights, with what the mix asks for attached."""
         import jax
 
         from distkeras_tpu import SingleTrainer, SynchronousDistributedTrainer
         from distkeras_tpu.utils.tree import host_copy
 
-        self.w, self.chips = w, chips
         self.rows = int(traffic["batch_per_chip"]) * chips
-        model = model_build.build_program_model(w, weights)
-        if traffic["attention"] == "flash":
-            from distkeras_tpu.ops.flash_attention import (
-                attach_flash_attention, effective_path)
-
-            attached = attach_flash_attention(model)
-            path = effective_path(w["seq"], w["d"] // w["heads"])[0]
-            if attached != w["layers"] or path != "flash":
-                raise RuntimeError(f"flash attention: {attached} attached, "
-                                   f"effective path {path!r}")
         common = dict(
             loss="next_token_crossentropy", learning_rate=traffic["learning_rate"],
             metrics=(), batch_size=int(traffic["batch_per_chip"]), num_epoch=1,
@@ -135,7 +126,8 @@ class TrainRig:
 def run(cell: dict, args, t_start: float, watch) -> dict:
     import jax
 
-    w = reference.widths(cell["config"])
+    family = cell["family"]
+    w = family.widths(cell["config"])
     traffic, chips = cell["traffic"], int(cell["cell"]["chips"])
     k = int(traffic["window"])
     check_steps = int(traffic["check"]["steps"])
@@ -144,9 +136,9 @@ def run(cell: dict, args, t_start: float, watch) -> dict:
                          f"of windows of {k} steps")
     rng = np.random.default_rng(args.seed)
 
-    weights = reference.make_weights(w, args.seed)
-    rig = TrainRig(w, traffic, chips, args.seed, weights)
-    del weights
+    rig = TrainRig(
+        family.build_program_model(w, family.make_weights(w, args.seed), traffic),
+        traffic, chips, args.seed)
     rows = rig.rows
 
     def next_window():
@@ -202,7 +194,7 @@ def run(cell: dict, args, t_start: float, watch) -> dict:
     gc.collect()
     t_ref = time.perf_counter()
     with jax.default_matmul_precision("highest"):
-        ref = reference.train_readings(
+        ref = family.train_readings(
             w, args.seed, first_batches, float(traffic["learning_rate"]),
             moment_after=k)
     ok, compared = compare(program, ref, traffic["check"]["limits"])
@@ -216,7 +208,7 @@ def run(cell: dict, args, t_start: float, watch) -> dict:
     return {
         "correct": ok and falls, "attempted": steps, "failed": 0,
         "setup_s": setup_s, "compiled_in_window": compiled_in_window,
-        "peak_bytes": peak, "profile": profile,
+        "peak_bytes": peak, "profile": profile, "compared": compared,
         "e2e": {"train_tokens_per_s_per_chip": rate},
         "counters": {"traced_steps": traced_steps, "batch": int(traffic["batch_per_chip"]),
                      "steps": steps, "compile_seconds_setup": compile_s},

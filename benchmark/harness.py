@@ -162,11 +162,17 @@ def check_shares(metrics: dict, operands: dict) -> None:
 
 
 def result_line(*, correct: bool, attempted: int, failed: int, metrics: dict,
-                device: dict, breakdown: dict | None = None) -> str:
+                device: dict, breakdown: dict | None = None,
+                compared: list | None = None) -> str:
+    """``compared``: the (name, value, limit) rows that decided ``correct``,
+    under a key of their own that comes last."""
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown:
         out["breakdown"] = breakdown
+    if compared:
+        out["check"] = {name: {"value": float(value), "limit": float(limit)}
+                        for name, value, limit in compared}
     return json.dumps(out)
 
 

@@ -1,5 +1,5 @@
 """``decode_step_roofline``: what one decode step has to compute and move at
-the window's mean batch and mean cached length (``flops.decode_step``)
+the window's mean batch and mean cached length (the family's ``decode_step``)
 against the median device time of the step program in the traced seconds."""
 
 from benchmark import flops
@@ -9,10 +9,12 @@ from benchmark.harness import log
 def read(ctx):
     step = ((ctx.get("trace") or {}).get("programs") or {}).get("decode_step")
     c = ctx["counters"]
-    if step is None or not c.get("mean_batch") or not c.get("mean_cached"):
+    count = getattr(ctx["family"], "decode_step", None)
+    if step is None or count is None or not c.get("mean_batch") \
+            or not c.get("mean_cached"):
         return None
     serving = ctx["config"]["serving"]
-    need = flops.decode_step(
+    need = count(
         ctx["widths"], c["mean_batch"], c["mean_cached"],
         weight_bytes=serving["weight_bytes"], kv_bytes=serving["kv_bytes"])
     share = flops.roofline_share(

@@ -1,6 +1,7 @@
 """``flash_attn_roofline``: what the flash kernels' forward and backward
-passes of one step need (``flops.flash_attention_train``) against their time
-a step in the traced steps."""
+passes of one step need (the family's ``flash_attention_train``; a family that
+runs no such kernel has no such count, and the metric is left out) against
+their time a step in the traced steps."""
 
 from benchmark import flops
 from benchmark.harness import log
@@ -9,9 +10,10 @@ from benchmark.harness import log
 def read(ctx):
     kernels = (ctx.get("trace") or {}).get("kernels") or {}
     steps = ctx["counters"].get("traced_steps")
-    if not steps or "flash" not in kernels:
+    count = getattr(ctx["family"], "flash_attention_train", None)
+    if not steps or count is None or "flash" not in kernels:
         return None
-    need = flops.flash_attention_train(ctx["widths"], ctx["counters"]["batch"])
+    need = count(ctx["widths"], ctx["counters"]["batch"])
     share = flops.roofline_share(
         need["flops"], need["bytes_fwd"] + need["bytes_bwd"],
         kernels["flash"]["total_s"] / steps,

@@ -10,7 +10,8 @@ the harness then leaves the metric out of the line. ``ctx`` holds
     counters  {name: number} read from the program or the harness
     trace     the dict ``trace_reduce.reduce_trace`` returns (traced runs)
     e2e       {name: value} of this run's end-to-end metrics
-    widths    the configuration's sizes; ``config``, ``traffic``, ``peaks``,
+    family    the configuration's family module (``spec.load_family``), and
+    widths    its ``widths(config)``; ``config``, ``traffic``, ``peaks``,
     chips     the cell's number of chips
     operands  {metric: {...}} filled here, printed when a share passes 105%
 """

@@ -3,8 +3,9 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Finds the cell in ``BENCHMARK.json``, its configuration under
-``configs/``, its traffic mix under ``traffic/`` and its per-layer metrics
-under ``layer_metrics/``, all by name; the traffic file's ``kind`` picks the
+``configs/``, the model family that file names under ``families/``, its
+traffic mix under ``traffic/`` and its per-layer metrics under
+``layer_metrics/``, all by name; the traffic file's ``kind`` picks the
 driver. Fails, and prints no result, without a TPU. The last line of
 standard output is the result; everything else goes on earlier lines.
 """
@@ -25,7 +26,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import harness, readers, reference, spec, trace_reduce  # noqa: E402
+from benchmark import harness, readers, spec, trace_reduce  # noqa: E402
 from benchmark.harness import log  # noqa: E402
 
 DRIVERS = {"train": "benchmark.drive_train", "serve": "benchmark.drive_serve"}
@@ -35,7 +36,7 @@ def per_layer_metrics(cell: dict, out: dict, device: dict, trace: dict) -> tuple
     ctx = {
         "samples": out["samples"],
         "counters": out["counters"], "trace": trace, "e2e": out["e2e"],
-        "widths": reference.widths(cell["config"]),
+        "family": cell["family"], "widths": cell["family"].widths(cell["config"]),
         "config": cell["config"], "traffic": cell["traffic"],
         "peaks": spec.load_peaks(device["kind"]), "chips": cell["cell"]["chips"],
         "operands": {},
@@ -118,7 +119,12 @@ def main(argv=None) -> int:
     log(f"end to end: {json.dumps(out['e2e'])}")
     print(harness.result_line(
         correct=out["correct"], attempted=out["attempted"], failed=out["failed"],
-        metrics=metrics, device=device, breakdown=breakdown), flush=True)
+        metrics=metrics, device=device, breakdown=breakdown,
+        compared=out["compared"]), flush=True)
+    # what decided ``correct``, as the last lines of standard error too
+    for name, value, limit in out["compared"]:
+        print(f"check: {name} = {value:.6g} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

@@ -1,6 +1,7 @@
 """Finds a cell's files by the names in BENCHMARK.json. Nothing here knows a
-cell, a configuration, a traffic mix or a metric by name: a later PR adds
-files and entries, and edits no file that is there (see README.md)."""
+cell, a configuration, a model family, a traffic mix or a metric by name: a
+later PR adds files and entries, and edits no file that is there (see
+README.md)."""
 
 from __future__ import annotations
 
@@ -50,9 +51,43 @@ def find_traffic(name: str, root: str = ROOT, bench: dict | None = None) -> str:
     raise SpecError(f"no traffic/{name}.json under {bench['paths']}")
 
 
+def _load_module(name: str, path: str):
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(name: str, root: str = ROOT, bench: dict | None = None):
+    """A model family's module: ``<a path of the benchmark>/families/
+    <name>.py`` in the first directory of ``paths`` that has it. The one
+    place that knows a model (README.md, "A model family")."""
+    bench = bench or load_benchmark(root)
+    if not NAME.match(str(name)):
+        raise SpecError(f"family {name!r}: not a name")
+    for base in bench["paths"]:
+        path = os.path.join(root, base, "families", name + ".py")
+        if os.path.exists(path):
+            return _load_module(
+                "benchmark_family_" + re.sub(r"\W", "_", name), path)
+    raise SpecError(f"no families/{name}.py under {bench['paths']}")
+
+
+def family_of(config: dict, root: str = ROOT, bench: dict | None = None):
+    """The family a configuration file names. A file that names none is an
+    error, not the first family there was."""
+    if "family" not in config:
+        raise SpecError(
+            f"configuration {config.get('name', '?')!r} names no family: "
+            f"add \"family\": \"<name>\" for a families/<name>.py under "
+            f"one of the benchmark's paths")
+    return load_family(config["family"], root, bench)
+
+
 def load_cell(workload: str, root: str = ROOT) -> dict:
-    """Everything one run needs: the cell, its configuration file, its
-    traffic file, the metrics it reports, each resolved by name."""
+    """Everything one run needs: the cell, its configuration file, the
+    family that file names, its traffic file, the metrics it reports, each
+    resolved by name."""
     bench = load_benchmark(root)
     cell = _by_name(bench["workloads"], workload, "workload")
     config_entry = _by_name(bench["configs"], cell["config"], "config")
@@ -70,7 +105,8 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
                      else m["moves"] in e2e_names)]
     return {
         "root": root, "bench": bench, "cell": cell, "config": config,
-        "traffic": traffic, "end_to_end": end_to_end, "per_layer": per_layer,
+        "family": family_of(config, root, bench), "traffic": traffic,
+        "end_to_end": end_to_end, "per_layer": per_layer,
         "run_seconds": bench["run_seconds"],
     }
 
@@ -85,10 +121,8 @@ def load_layer_metric(name: str, root: str = ROOT, bench: dict | None = None):
         if os.path.exists(stem + ".json"):
             return _load_json(stem + ".json")
         if os.path.exists(stem + ".py"):
-            mod_spec = importlib.util.spec_from_file_location(
+            mod = _load_module(
                 "layer_metric_" + re.sub(r"\W", "_", name), stem + ".py")
-            mod = importlib.util.module_from_spec(mod_spec)
-            mod_spec.loader.exec_module(mod)
             return {"reader": "python", "read": mod.read}
     raise SpecError(f"no layer_metrics/{name}.json or .py under {bench['paths']}")
 

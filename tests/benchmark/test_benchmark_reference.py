@@ -1,5 +1,5 @@
-"""The plain reference against ``zoo.transformer_lm`` at a tiny size, and
-the controls: a lower precision in the program's place has to read worse
+"""The GPT-2 family's plain reference (``benchmark/families/gpt2.py``)
+against ``zoo.transformer_lm`` at a tiny size, and the controls: a lower precision in the program's place has to read worse
 than the program does."""
 
 import os
@@ -14,15 +14,16 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import benchmark_tiny_cells as tiny  # noqa: E402
 
-from benchmark import model_build, reference  # noqa: E402
+from benchmark import reference  # noqa: E402
+from benchmark.families import gpt2  # noqa: E402
 from benchmark.drive_train import compare, worst_leaf_gap  # noqa: E402
 
-W = reference.widths(tiny.CONFIG)
+W = gpt2.widths(tiny.CONFIG)
 
 
 @pytest.fixture(scope="module")
 def weights():
-    return reference.make_weights(W, 2**31 + 9)
+    return gpt2.make_weights(W, 2**31 + 9)
 
 
 @pytest.fixture(scope="module")
@@ -31,22 +32,22 @@ def tokens():
 
 
 def test_weights_are_a_function_of_the_seed_alone(weights):
-    again = reference.make_weights(W, 2**31 + 9)
-    other = reference.make_weights(W, 2**31 + 10)
+    again = gpt2.make_weights(W, 2**31 + 9)
+    other = gpt2.make_weights(W, 2**31 + 10)
     same = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)), weights, again)
     assert all(jax.tree.leaves(same))
     assert not bool(jnp.array_equal(weights["0"]["tokens"], other["0"]["tokens"]))
     n = sum(x.size for x in jax.tree.leaves(weights))
-    assert n == reference.param_count(W)["total"]
+    assert n == gpt2.param_count(W)["total"]
 
 
 def test_forward_matches_the_program_s_model(weights, tokens):
-    model = model_build.build_program_model(W, weights)
+    model = gpt2.build_program_model(W, weights, tiny.TRAIN)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(model.predict(tokens))
         got = np.stack([
-            np.asarray(reference.logits(
-                weights, reference.hidden(weights, jnp.asarray(row), W), W))
+            np.asarray(gpt2.logits(
+                weights, gpt2.hidden(weights, jnp.asarray(row), W), W))
             for row in tokens])
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
 
@@ -54,7 +55,7 @@ def test_forward_matches_the_program_s_model(weights, tokens):
 def test_loss_and_gradients_match_the_program_s(weights, tokens):
     from distkeras_tpu.ops.losses import next_token_crossentropy
 
-    model = model_build.build_program_model(W, weights)
+    model = gpt2.build_program_model(W, weights, tiny.TRAIN)
 
     def program_loss(params):
         y, _ = model.apply(params, model.state, jnp.asarray(tokens), train=True)
@@ -62,7 +63,7 @@ def test_loss_and_gradients_match_the_program_s(weights, tokens):
 
     with jax.default_matmul_precision("highest"):
         want_loss, want_grads = jax.value_and_grad(program_loss)(weights)
-        got_loss, got_grads = reference.batch_grads(weights, tokens, W)
+        got_loss, got_grads = gpt2.batch_grads(weights, tokens, W)
     assert float(got_loss) == pytest.approx(float(want_loss), abs=1e-5)
     gap = worst_leaf_gap(np.asarray(reference.leaf_norms(got_grads)),
                          np.asarray(reference.leaf_norms(want_grads)))
@@ -73,7 +74,7 @@ def test_adam_step_is_optax_s(weights, tokens):
     import optax
 
     with jax.default_matmul_precision("highest"):
-        _, grads = reference.batch_grads(weights, tokens, W)
+        _, grads = gpt2.batch_grads(weights, tokens, W)
     opt = optax.adam(3e-3)
     updates, _ = opt.update(grads, opt.init(weights), weights)
     want = optax.apply_updates(weights, updates)
@@ -91,8 +92,8 @@ def test_training_control_in_int8_comes_out_not_correct(seed):
     batches = [rng.integers(0, W["vocab"], (2, W["seq"])).astype(np.int32)
                for _ in range(3)]
     with jax.default_matmul_precision("highest"):
-        ref = reference.train_readings(W, seed, batches, 3e-3)
-        low = reference.train_readings(W, seed, batches, 3e-3, "int8")
+        ref = gpt2.train_readings(W, seed, batches, 3e-3)
+        low = gpt2.train_readings(W, seed, batches, 3e-3, "int8")
     ok, rows = compare(low, ref, tiny.TRAIN["check"]["limits"])
     assert not ok, rows
     same, _ = compare(ref, ref, tiny.TRAIN["check"]["limits"])
@@ -104,10 +105,10 @@ def test_serving_control_in_int4_reads_a_wider_gap_than_int8(seed):
     """At each position of the same prompt and tokens, the token that int4
     weights put first lies further below the reference's best than the one
     int8 weights put first."""
-    weights = reference.make_weights(W, seed)
+    weights = gpt2.make_weights(W, seed)
     seq = np.random.default_rng(seed).integers(0, W["vocab"], 60)
     with jax.default_matmul_precision("highest"):
-        served, int4 = reference.token_gaps(weights, W, seq, 12, control="w_int4")
+        served, int4 = gpt2.token_gaps(weights, W, seq, 12, control="w_int4")
     assert served.shape == int4.shape == (48,)
     assert (served >= 0).all() and (int4 >= 0).all()
     assert int4.max() > tiny.CONFIG["serving"]["check"]["gap_limit"]

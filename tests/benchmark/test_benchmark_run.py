@@ -14,7 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import benchmark_tiny_cells as tiny  # noqa: E402
 
-from benchmark import drive_serve, drive_train, harness, model_build  # noqa: E402
+from benchmark import drive_serve, drive_train, harness  # noqa: E402
 
 REPO = tiny.REPO
 
@@ -23,13 +23,18 @@ REPO = tiny.REPO
 pytestmark = pytest.mark.e2e
 
 
-def _run(driver, traffic, chips=1, tmp="/tmp", **kw):
+def _run(driver, traffic, chips=1, tmp="/tmp", cell=None, **kw):
     import time
 
-    out = driver.run(tiny.cell(traffic, chips, root=str(tmp)), tiny.args(**kw),
-                     time.perf_counter(), harness.CompileWatch())
+    cell = cell or tiny.cell(traffic, chips, root=str(tmp))
+    out = driver.run(cell, tiny.args(**kw), time.perf_counter(),
+                     harness.CompileWatch())
     assert out["compiled_in_window"] == 0
     return out
+
+
+def _readings(out) -> dict:
+    return {name: value for name, value, _ in out["compared"]}
 
 
 @pytest.mark.parametrize("chips,window", [(1, 1), (4, 1), (1, 4), (4, 2)],
@@ -100,9 +105,10 @@ def test_an_open_loop_mix_has_no_driver_yet(tmp_path):
 def test_serving_from_altered_weights_is_not_correct(tmp_path, monkeypatch):
     """The engine serves from a head the reference never saw: its tokens
     are altered where they are produced."""
-    real = model_build.build_program_model
+    gpt2 = tiny.family("gpt2")
+    real = gpt2.build_program_model
 
-    def altered(w, weights):
+    def altered(w, weights, traffic):
         import jax
 
         head = str(w["layers"] + 2)
@@ -110,12 +116,12 @@ def test_serving_from_altered_weights_is_not_correct(tmp_path, monkeypatch):
             jax.random.PRNGKey(1), weights[head]["kernel"].shape)
         weights = {**weights, head: {**weights[head],
                                      "kernel": weights[head]["kernel"] + noise}}
-        return real(w, weights)
+        return real(w, weights, traffic)
 
-    monkeypatch.setattr(model_build, "build_program_model", altered)
+    monkeypatch.setattr(gpt2, "build_program_model", altered)
     out = _run(drive_serve, tiny.SERVE, 1, tmp_path)
     assert out["correct"] is False
-    assert out["readings"]["widest_logit_gap"] > tiny.CONFIG["serving"]["check"]["gap_limit"]
+    assert _readings(out)["widest_logit_gap"] > tiny.CONFIG["serving"]["check"]["gap_limit"]
 
 
 def test_serving_in_int4_the_program_s_own_lower_path_is_not_correct(tmp_path):
@@ -125,6 +131,31 @@ def test_serving_in_int4_the_program_s_own_lower_path_is_not_correct(tmp_path):
         tiny.cell(tiny.SERVE, root=str(tmp_path)), tiny.args(),
         time.perf_counter(), harness.CompileWatch(), overrides={"weight_bits": 4})
     assert out["correct"] is False
+
+
+@pytest.mark.parametrize("driver,traffic", [
+    (drive_train, tiny.TRAIN), (drive_serve, tiny.SERVE)], ids=["train", "serve"])
+def test_a_family_added_by_files_alone_drives_a_correct_run(
+        driver, traffic, tmp_path):
+    """The tiny cells through a family that a directory added to ``paths``
+    brings, under a configuration with other keys than GPT-2's: the drivers
+    take the model from the cell's family and from nowhere else. It is the
+    same block, so on the same seed the check reads what it reads through
+    ``gpt2``."""
+    tiny.add_throwaway_family(tmp_path)
+    cell = tiny.cell(traffic, root=str(tmp_path), config=tiny.THROWAWAY_CONFIG,
+                     bench_root=str(tmp_path))
+    assert cell["family"].__file__.startswith(str(tmp_path))
+    out = _run(driver, traffic, cell=cell)
+    assert out["correct"] is True and out["failed"] == 0 < out["attempted"]
+    same = _run(driver, traffic, 1, tmp_path)
+    assert same["correct"] is True
+    if driver is drive_train:  # the same steps on the same rows and weights
+        assert _readings(out) == _readings(same)
+    else:  # the window's sample differs with the threads' timing
+        limit = tiny.CONFIG["serving"]["check"]["gap_limit"]
+        assert 0 <= _readings(out)["widest_logit_gap"] <= limit
+        assert 0 <= _readings(same)["widest_logit_gap"] <= limit
 
 
 def test_a_share_above_105_percent_ends_the_run():
@@ -157,3 +188,16 @@ def test_result_line_has_the_contract_s_keys_only():
                 "memory_peak_bytes": 1})
     assert set(json.loads(line)) == {"correct", "attempted", "failed",
                                      "metrics", "device"}
+
+
+def test_result_line_ends_with_each_number_compared_beside_its_limit():
+    line = harness.result_line(
+        correct=False, attempted=3, failed=0, metrics={},
+        device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                "memory_peak_bytes": 1},
+        breakdown={"device_ops": [], "idle_gaps": []},
+        compared=[("loss_step1_abs_diff", 0.25, 0.002)])
+    result = json.loads(line)
+    assert list(result)[-1] == "check"
+    assert result["check"] == {
+        "loss_step1_abs_diff": {"value": 0.25, "limit": 0.002}}

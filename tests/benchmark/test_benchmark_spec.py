@@ -1,17 +1,23 @@
 """BENCHMARK.json against the contract's names and units, every cell's files
-found by name, and a throw-away cell added by files alone."""
+found by name, and a throw-away cell and a throw-away model family added by
+files alone."""
 
+import glob
 import json
 import os
+import re
 import shutil
 import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from benchmark import readers, spec  # noqa: E402
+import benchmark_tiny_cells as tiny  # noqa: E402
+
+from benchmark import harness, readers, spec  # noqa: E402
+
+REPO = tiny.REPO
 
 BENCH = spec.load_benchmark(REPO)
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -85,26 +91,75 @@ def test_every_cell_finds_its_files_and_reports_enough(workload):
     assert cell["traffic"]["kind"] in ("train", "serve")
 
 
-@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
-def test_configurations_carry_the_published_widths(config):
-    with open(os.path.join(REPO, config["file"])) as f:
-        cfg = json.load(f)
-    published = {"n_embd": 2048, "n_head": 16, "n_inner": 8192,
-                 "vocab_size": 50257, "n_positions": 2048, "n_layer": 24}
-    for key, value in published.items():
+class _Recorded(dict):
+    """A configuration file that notes which of its keys are read."""
+
+    def __init__(self, *a):
+        super().__init__(*a)
+        self.read = set()
+
+    def __getitem__(self, key):
+        self.read.add(key)
+        return super().__getitem__(key)
+
+    def get(self, key, default=None):
+        self.read.add(key)
+        return super().get(key, default)
+
+
+def _copy_with_a_throwaway_family(root):
+    """A copy of the benchmark with what a later PR would add beside it."""
+    shutil.copytree(os.path.join(REPO, "benchmark"), root / "benchmark")
+    return tiny.add_throwaway_family(root)
+
+
+@pytest.mark.parametrize(
+    "name", [c["name"] for c in BENCH["configs"]] + ["throwaway"])
+def test_configurations_carry_the_published_widths(name, tmp_path):
+    """Every configuration, whatever its family's keys are: the source's own
+    value of each size beside the value held, every cut listed. The last
+    case is a configuration with other keys than GPT-2's, in a copy."""
+    root, bench = REPO, BENCH
+    if name == "throwaway":
+        root, bench = str(tmp_path), _copy_with_a_throwaway_family(tmp_path)
+    config = spec._by_name(bench["configs"], name, "config")
+    with open(os.path.join(root, config["file"])) as f:
+        cfg = _Recorded(json.load(f))
+    assert cfg["published"], "the source's own sizes"
+    for key, value in cfg["published"].items():
         if key in config["reduced"]:
-            assert cfg[key] != value and cfg["reduced_from"][key][0] == value
+            assert cfg[key] != value
+            assert cfg["reduced_from"][key] == [value, cfg[key]]
         else:
             assert cfg[key] == value, key
     assert cfg["reduced"] == config["reduced"]
     assert "assumed" in cfg and "source" in cfg
     assert "precision" in cfg.get("serving", cfg.get("training"))
+    cfg.read.clear()
+    w = spec.family_of(cfg, root, bench).widths(cfg)
+    assert all(w[k] > 0 for k in ("vocab", "seq", "layers"))
+    sizes = {k for k in cfg.read if isinstance(cfg[k], int)}
+    assert sizes and sizes <= set(cfg["published"]), (
+        "a size that widths reads has no published value beside it")
+
+
+@pytest.mark.parametrize("name", ["cerebras-gpt-1.3b", "cerebras-gpt-1.3b-cut"])
+def test_the_cerebras_configurations_keep_their_six_numbers(name):
+    entry = spec._by_name(BENCH["configs"], name, "config")
+    with open(os.path.join(REPO, entry["file"])) as f:
+        cfg = json.load(f)
+    assert cfg["published"] == {
+        "n_embd": 2048, "n_head": 16, "n_inner": 8192, "vocab_size": 50257,
+        "n_positions": 2048, "n_layer": 24}
+    assert cfg["family"] == "gpt2"
+    w = spec.family_of(cfg, REPO, BENCH).widths(cfg)
+    assert (w["d"], w["heads"], w["inner"], w["vocab"], w["seq"]) == (
+        2048, 16, 8192, 50257, 2048)
+    assert w["layers"] == (6 if name.endswith("-cut") else 24)
 
 
 def _synthetic_run(cell):
     """What a traced run of ``cell`` hands the readers, with round numbers."""
-    from benchmark import reference
-
     return {
         "samples": {"itl_gaps_s": [0.25, 0.30, 0.35]},
         "counters": {"compile_seconds_setup": 1.5, "occupancy_sum_window": 90.0,
@@ -119,7 +174,8 @@ def _synthetic_run(cell):
                   "kernels": {"flash": {"count": 180.0, "total_s": 0.25}}},
         "e2e": {"train_tokens_per_s_per_chip": 40000.0,
                 "serve_tokens_per_s": 100.0, "setup_s": 20.0},
-        "widths": reference.widths(cell["config"]), "config": cell["config"],
+        "family": cell["family"], "widths": cell["family"].widths(cell["config"]),
+        "config": cell["config"],
         "traffic": cell["traffic"], "peaks": spec.load_peaks("TPU v5 lite"),
         "chips": cell["cell"]["chips"], "operands": {},
     }
@@ -216,3 +272,105 @@ def test_a_cell_is_added_by_files_alone(tmp_path):
 
     reqs = loadgen.make_requests(cell["traffic"], 5, 50257, 40)
     assert len(reqs) == 40 and min(len(r["prompt"]) for r in reqs) >= 1024
+
+
+# ------------------------------------------------------- model families
+
+REQUIRED = {"widths", "param_count", "make_weights", "build_program_model",
+            "train_readings", "token_gaps"}
+COUNTS = {"train_flops_per_token", "decode_step", "flash_attention_train"}
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_configuration_s_family_keeps_the_contract(config):
+    """README.md, "A model family": the names every family brings, and the
+    counts it may bring."""
+    with open(os.path.join(REPO, config["file"])) as f:
+        family = spec.family_of(json.load(f), REPO, BENCH)
+    for name in REQUIRED:
+        assert callable(getattr(family, name, None)), name
+    for name in COUNTS & set(dir(family)):
+        assert callable(getattr(family, name)), name
+
+
+def test_the_harness_takes_from_a_family_the_contract_s_names_and_no_others():
+    """The drivers, the controls, ``run.py`` and the metrics reach a model
+    through the cell's family alone, by the names the contract lists; and
+    nothing of the harness outside ``families/`` names a width of one model
+    or imports the program's models."""
+    taken = set()
+    sources = glob.glob(os.path.join(REPO, "benchmark", "*.py")) + glob.glob(
+        os.path.join(REPO, "benchmark", "layer_metrics", "*.py"))
+    for path in sources:
+        with open(path) as f:
+            src = f.read()
+        taken |= set(re.findall(r"\bfamily\.([a-z_]+)\(", src))
+        taken |= set(re.findall(r'getattr\(ctx\["family"\], "([a-z_]+)"', src))
+        for word in ("n_embd", "n_inner", "n_head", "zoo.", "distkeras_tpu.models",
+                     '"heads"', '"inner"'):
+            assert word not in src, (path, word)
+    assert taken <= REQUIRED | COUNTS, taken - REQUIRED - COUNTS
+    assert COUNTS <= taken and {"widths", "make_weights", "build_program_model",
+                                "train_readings", "token_gaps"} <= taken
+    assert not os.path.exists(os.path.join(REPO, "benchmark", "model_build.py"))
+
+
+def test_a_family_is_added_by_files_alone(tmp_path):
+    """A later PR's way in for a new architecture: a directory of its own in
+    ``paths`` with ``families/<name>.py``, a configuration under that
+    family's own keys (none of them GPT-2's), a cell, and an entry each; no
+    file that is there is edited."""
+    before = {p: open(p, "rb").read() for p in glob.glob(
+        os.path.join(REPO, "benchmark", "**", "*.*"), recursive=True)
+        if os.path.isfile(p) and "__pycache__" not in p}
+    _copy_with_a_throwaway_family(tmp_path)
+    for path, content in before.items():
+        copy = os.path.join(str(tmp_path), os.path.relpath(path, REPO))
+        assert open(copy, "rb").read() == content, path
+    cell = spec.load_cell("throwaway.pretrain_2k", str(tmp_path))
+    assert cell["family"].__file__ == str(
+        tmp_path / "extra_bench/families/throwaway.py")
+    assert not set(tiny.THROWAWAY_KEYS) & set(cell["config"])
+    w = cell["family"].widths(cell["config"])
+    assert (w["vocab"], w["seq"], w["layers"], w["d"]) == (50257, 2048, 6, 2048)
+    assert cell["family"].param_count(w)["total"] == pytest.approx(512e6, rel=0.01)
+    # the committed cells' family is still found, and is another module
+    own = spec.load_cell("train_seq2048", str(tmp_path))["family"]
+    assert own.__file__ == str(tmp_path / "benchmark/families/gpt2.py")
+    assert {m["name"] for m in cell["per_layer"]} == {
+        m["name"] for m in spec.load_cell("train_seq2048", REPO)["per_layer"]}
+
+
+@pytest.mark.parametrize("edit,says", [
+    (lambda cfg: cfg.pop("family"), "names no family"),
+    (lambda cfg: cfg.update(family="no_such_family"), "no families/no_such_family.py"),
+    (lambda cfg: cfg.update(family="../configs/x"), "not a name"),
+], ids=["no_family", "unknown_family", "not_a_name"])
+def test_a_configuration_whose_family_cannot_be_found_is_an_error(
+        edit, says, tmp_path):
+    _copy_with_a_throwaway_family(tmp_path)
+    path = tmp_path / "extra_bench/configs/throwaway.json"
+    cfg = json.loads(path.read_text())
+    edit(cfg)
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(spec.SpecError, match=says):
+        spec.load_cell("throwaway.pretrain_2k", str(tmp_path))
+
+
+def test_a_family_without_a_count_leaves_that_metric_out_of_the_line(tmp_path):
+    """The throw-away family brings no ``flash_attention_train``: its cell's
+    traced run reports every other per-layer metric of the cell, leaves
+    ``flash_attn_roofline`` out, and ends as any run does."""
+    from benchmark import run
+
+    _copy_with_a_throwaway_family(tmp_path)
+    cell = spec.load_cell("throwaway.pretrain_2k", str(tmp_path))
+    assert "flash_attn_roofline" in {m["name"] for m in cell["per_layer"]}
+    out = _synthetic_run(cell)
+    metrics, operands = run.per_layer_metrics(
+        cell, out, {"kind": "TPU v5 lite"}, out["trace"])
+    harness.check_shares(metrics, operands)
+    assert set(metrics) == {m["name"] for m in cell["per_layer"]} - {
+        "flash_attn_roofline"}
+    assert metrics["train_mfu_pct"]["value"] == pytest.approx(
+        100 * 40000 * 2.58e9 / 197e12, rel=0.01)
