@@ -18,6 +18,7 @@ from distkeras_tpu.models.layers import (
     Conv2D,
     Dense,
     Dropout,
+    Embedding,
     Flatten,
     GlobalAvgPool2D,
     MaxPool2D,
@@ -354,6 +355,66 @@ def resnet18(
     ]
     head = [GlobalAvgPool2D(), Dense(num_classes, activation="softmax")]
     return Sequential(stem + body + head).build(input_shape, seed=seed)
+
+
+def mla_moe_lm(
+    vocab_size=256,
+    seq_len=128,
+    hidden_size=64,
+    num_heads=4,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    kv_lora_rank=32,
+    intermediate_size=128,
+    moe_intermediate_size=32,
+    n_routed_experts=8,
+    num_experts_per_tok=2,
+    n_shared_experts=1,
+    num_layers=3,
+    first_k_dense=1,
+    routed_scaling_factor=1.0,
+    rope_theta=10000.0,
+    rms_norm_eps=1e-6,
+    experts_held=None,
+    seed=0,
+):
+    """Causal language model of latent-attention blocks (the
+    ``deepseek_v3`` model type, under its published keys): Embedding
+    without a position table -> ``first_k_dense`` blocks with a gated MLP
+    of ``intermediate_size``, then blocks with ``n_routed_experts`` routed
+    experts (``num_experts_per_tok`` a token, sigmoid scores, a selection
+    bias) and ``n_shared_experts`` shared ones, each of
+    ``moe_intermediate_size`` -> RMSNorm -> an untied head without bias.
+    ``experts_held``: the routed experts every expert layer holds (None:
+    all); see ``models/mla_moe.py``. Serves through the paged
+    ``ServingEngine`` with a latent page in the KV pool."""
+    from distkeras_tpu.models.mla_moe import LatentMoEBlock, RMSNorm
+
+    def block(i):
+        moe = i >= first_k_dense
+        return LatentMoEBlock(
+            num_heads, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+            kv_lora_rank, ffn_width=0 if moe else intermediate_size,
+            n_experts=n_routed_experts if moe else 0,
+            top_k=num_experts_per_tok if moe else 0,
+            n_shared=n_shared_experts if moe else 0,
+            expert_width=moe_intermediate_size if moe else 0,
+            routed_scale=routed_scaling_factor, rope_theta=rope_theta,
+            epsilon=rms_norm_eps, experts_held=experts_held if moe else None,
+            out_scale=(2 * num_layers) ** -0.5,
+        )
+
+    model = Sequential(
+        [
+            Embedding(vocab_size, hidden_size, with_positions=False),
+            *[block(i) for i in range(num_layers)],
+            RMSNorm(rms_norm_eps),
+            Dense(vocab_size, use_bias=False),
+        ]
+    )
+    model.build((seq_len,), seed=seed)
+    return model
 
 
 ZOO = {

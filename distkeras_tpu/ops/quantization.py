@@ -139,8 +139,10 @@ def quantize_params(params, keys=DEFAULT_QUANT_KEYS, bits=8):
     one width is NOT re-quantized at another (round() already destroyed
     the master; re-quantize from the f32 original instead). Returns a new
     tree; the input is not mutated."""
+    if bits == 16:
+        return cast_params_bf16(params)
     if bits not in (8, 4):
-        raise ValueError(f"bits must be 8 or 4; got {bits}")
+        raise ValueError(f"bits must be 16, 8 or 4; got {bits}")
     quant = quantize_int8 if bits == 8 else quantize_int4
     if is_quantized(params):
         return params
@@ -162,6 +164,34 @@ def quantize_params(params, keys=DEFAULT_QUANT_KEYS, bits=8):
     return params
 
 
+def cast_params_bf16(params):
+    """The 16-bit serving tree (``bits=16``): every floating leaf of two or
+    more dimensions cast to bfloat16, which takes in every matrix that
+    ``bits=8`` would quantize, the embedding tables and expert weights
+    stacked ``(E, in, out)``; vectors (biases, norm gains) keep their
+    dtype, and a leaf that is quantized already passes through. Exact for
+    weights that are bfloat16-representable. Returns a new tree."""
+    if is_quantized(params):
+        return params
+    if isinstance(params, dict):
+        return {k: cast_params_bf16(v) for k, v in params.items()}
+    if isinstance(params, (list, tuple)):
+        return type(params)(cast_params_bf16(v) for v in params)
+    if getattr(params, "ndim", 0) >= 2 and jnp.issubdtype(
+            params.dtype, jnp.floating):
+        return params.astype(jnp.bfloat16)
+    return params
+
+
+def is_serving_tree(params) -> bool:
+    """A tree that went through ``quantize_model``: it holds a quantized
+    matrix, or a bfloat16 one (``bits=16``). Serve-only."""
+    return bool(count_quantized(params)) or any(
+        getattr(leaf, "ndim", 0) >= 2 and leaf.dtype == jnp.bfloat16
+        for leaf in jax.tree_util.tree_leaves(params)
+    )
+
+
 def count_quantized(params) -> int:
     """Number of quantized matrices in a tree (tests/reporting)."""
     if is_quantized(params):
@@ -177,7 +207,8 @@ def quantize_model(model, keys=DEFAULT_QUANT_KEYS, bits=8):
     """Switch a built model's params to the int8/int4 serving tree IN
     PLACE and return the model (chainable). Serve-only: trainers reject
     quantized trees (no gradients through round()); quantize a copy —
-    ``quantize_model(m.copy())`` — if the original must keep training."""
+    ``quantize_model(m.copy())`` — if the original must keep training.
+    ``bits=16`` casts to bfloat16 instead (``cast_params_bf16``)."""
     if getattr(model, "params", None) is None:
         raise ValueError("quantize_model needs a BUILT model (params set)")
     model.params = quantize_params(model.params, keys, bits)

@@ -435,6 +435,12 @@ class CachedSequenceGenerator(SequenceGenerator):
         from distkeras_tpu.parallel.expert_parallel import MoE
 
         layers = list(model.layers)
+        # which block the model is made of decides which programs the
+        # serving engine builds; it is read here, once, and never inside
+        # a traced function
+        self.block_kind = "kv"
+        if self._parse_latent(layers):
+            return
         shape_err = ValueError(
             "CachedSequenceGenerator supports Embedding -> causal "
             "TransformerBlock xN (each optionally followed by a MoE "
@@ -485,6 +491,32 @@ class CachedSequenceGenerator(SequenceGenerator):
         self._blocks = blocks
         self._final_ln = layers[-2]
         self._head = layers[-1]
+
+    def _parse_latent(self, layers) -> bool:
+        """Embedding -> ``LatentMoEBlock`` xN -> RMSNorm -> Dense
+        (``zoo.mla_moe_lm``): the latent-attention block, whose cache is
+        one latent row a token and layer and not keys and values. The
+        paged ``DecodeStepper`` serves it; the solo generators here keep a
+        dense (B, T, H, Dh) cache and refuse it (``_decode_prologue``)."""
+        from distkeras_tpu.models.layers import Dense, Embedding
+        from distkeras_tpu.models.mla_moe import LatentMoEBlock, RMSNorm
+
+        mid = layers[1:-2]
+        if not (
+            len(layers) >= 4
+            and isinstance(layers[0], Embedding)
+            and isinstance(layers[-2], RMSNorm)
+            and isinstance(layers[-1], Dense)
+            and all(isinstance(l, LatentMoEBlock) for l in mid)
+        ):
+            return False
+        self.block_kind = "latent"
+        self._emb = layers[0]
+        self._stages = [(blk, i + 1, None, None) for i, blk in enumerate(mid)]
+        self._blocks = mid
+        self._final_ln = layers[-2]
+        self._head = layers[-1]
+        return True
 
     def _stage_chunk(self, blk, moe, p, pm, x, cache_k, cache_v, pos,
                      qmask):
@@ -573,6 +605,14 @@ class CachedSequenceGenerator(SequenceGenerator):
         masked scratch). The embed closure clamps positions to the
         table — a no-op for every kept token; only speculative's
         discarded overrun drafts ever exceed it."""
+        if self.block_kind == "latent":
+            from distkeras_tpu.models.mla_moe import BlockUnsupportedError
+
+            raise BlockUnsupportedError(
+                "the solo cached generators keep a dense (B, T, H, Dh) "
+                "K/V cache; the latent-attention block decodes through "
+                "the paged ServingEngine only"
+            )
         n_layers = len(self.model.layers)
         if cache_len is None:
             cache_len = self.model.input_shape[0]

@@ -431,9 +431,13 @@ class _InflightStep:
         if self._toks is None:
             raise RuntimeError("decode step already collected")
         st, active = self._stepper, self.active
-        with _span("serving/collect"):
+        with _span("serving/collect") as sp:
             toks = np.asarray(self._toks)  # the one device->host fetch
             self._toks = None
+            if st._moe_layers:
+                # the expert layers' routing counters ride the tokens'
+                # fetch (no second device sync)
+                toks = st._note_routing(toks, int(active.sum()), sp)
             st._lens[active] = np.minimum(
                 st._lens[active] + 1, st._lens_cap
             )
@@ -566,6 +570,31 @@ class DecodeStepper:
             top_p=top_p, kv_dtype=kv_dtype,
         )
         self.model = model
+        # the block kind picks the page layout and which stage bodies
+        # the step / chunk programs are built from — decided here, when
+        # programs are built, never inside a traced function
+        latent = self._gen.block_kind == "latent"
+        self.layout = "latent" if latent else "kv"
+        self.prefix_caches_off = None
+        if latent:
+            from distkeras_tpu.ops.quantization import count_quantized
+
+            self._refuse_for_latent(
+                "the dense slot bank (paged=False)" if not paged else None,
+                "speculative decoding" if speculative else None,
+                "a tensor-parallel serving mesh" if mesh is not None
+                else None,
+                "int8 / int4 weights (quantize_model(bits=8|4))"
+                if count_quantized(model.params) else None,
+            )
+            # a host PrefixStore row is (p, H, Dh) keys and values and
+            # the device index was never exercised over latent pages:
+            # both are switched off for this layout, and stats() says so
+            prefix_cache = None
+            self.prefix_caches_off = (
+                "latent page layout: the host PrefixStore and the "
+                "DevicePrefixIndex hold (p, H, Dh) K/V rows only"
+            )
         self.num_slots = int(num_slots)
         if self.num_slots < 1:
             raise ValueError(f"num_slots must be >= 1; got {num_slots}")
@@ -600,12 +629,16 @@ class DecodeStepper:
         # (its programs belong to the drafter, not the serving path).
         self.ledger = None if _quiet else compile_ledger
         self._warming = False  # True inside warmup(): mints off-path
-        nh = self._gen._blocks[0].mhsa.num_heads
         from distkeras_tpu.ops.quantization import qshape
 
-        hd = qshape(
-            model.params[str(self._gen._stages[0][1])]["mhsa"]["wq"]
-        )[1] // nh
+        if latent:
+            # no (H, Dh) rows: one latent row a token and layer
+            nh, hd = self._gen._blocks[0].num_heads, None
+        else:
+            nh = self._gen._blocks[0].mhsa.num_heads
+            hd = qshape(
+                model.params[str(self._gen._stages[0][1])]["mhsa"]["wq"]
+            )[1] // nh
         b, t = self.num_slots, self._tp
         # -- serving mesh (tensor-parallel decode) ------------------------
         # Resolved FIRST (before any device allocation): a bad mesh must
@@ -676,21 +709,43 @@ class DecodeStepper:
                 1, 1 << (pages_per_slot - 1).bit_length()
             )
             self._caches = None
-            self._pools = [
-                (
-                    self._place_kv(jnp.zeros(
-                        (int(num_pages), self.page_size, nh, hd),
+            if latent:
+                # a latent page: (page_size, kv_rank + rope) a layer,
+                # ONE array and not a K and a V. The (num_pages,
+                # page_size, W) pool is held as its row-major flattening
+                # (num_pages x page_size, W), page p = rows [p * ps,
+                # (p + 1) * ps): the TPU's default layout of the 3-D
+                # array puts the page axis minor, and every program
+                # then copies the whole pool to rows and back, a layer
+                # (seen in the compiled step, PERF.md PR 28). For the
+                # same reason a row is padded with zeros to a multiple
+                # of 128 values, the TPU's lane width: 576 -> 640
+                self._pools = [
+                    jnp.zeros(
+                        (int(num_pages) * self.page_size,
+                         self._latent_row(blk)),
                         self._gen.kv_dtype,
-                    )),
-                    self._place_kv(jnp.zeros(
-                        (int(num_pages), self.page_size, nh, hd),
-                        self._gen.kv_dtype,
-                    )),
-                )
-                for _ in self._gen._stages
-            ]
+                    )
+                    for blk in self._gen._blocks
+                ]
+            else:
+                self._pools = [
+                    (
+                        self._place_kv(jnp.zeros(
+                            (int(num_pages), self.page_size, nh, hd),
+                            self._gen.kv_dtype,
+                        )),
+                        self._place_kv(jnp.zeros(
+                            (int(num_pages), self.page_size, nh, hd),
+                            self._gen.kv_dtype,
+                        )),
+                    )
+                    for _ in self._gen._stages
+                ]
             self._tables: list[list[int]] = [[] for _ in range(b)]
-            self.prefix_index = DevicePrefixIndex(self._kv_alloc)
+            self.prefix_index = (
+                None if latent else DevicePrefixIndex(self._kv_alloc)
+            )
             # paged program caches (separate families from the dense
             # ones: their keys carry the page-table bucket; the masked
             # flag selects the grammar-constrained variant)
@@ -715,6 +770,17 @@ class DecodeStepper:
                 for _ in self._gen._stages
             ]
         self._lens = np.ones((b,), np.int32)  # host mirror; >=1 always
+        # expert layers' routing counters: the step program of a model
+        # with routed experts returns them behind its tokens
+        self._moe_layers = sum(
+            1 for blk in self._gen._blocks if getattr(blk, "n_experts", 0)
+        )
+        self.moe_stats = {
+            "steps": 0, "experts_hit_sum": 0.0, "expert_load_max_sum": 0,
+            "experts_total": (
+                len(self._gen._blocks[-1].held) if self._moe_layers else 0
+            ),
+        }
         self.host_arg_bytes_step = 0  # of the last decode-step call
         self._step_fns = {}  # masked flag -> compiled decode step
         self._admit_fns = {}  # prefill-length bucket -> compiled admit
@@ -818,7 +884,10 @@ class DecodeStepper:
         engine's ``stats()`` (empty when dense)."""
         if not self.paged:
             return {"enabled": False}
-        out = {"enabled": True}
+        out = {"enabled": True, "layout": self.layout,
+               "bytes_per_token": self.kv_bytes_per_token()}
+        if self.prefix_caches_off:
+            out["prefix_caches"] = "off: " + self.prefix_caches_off
         out.update(self._kv_alloc.stats())
         # mesh geometry: the pool's TOTAL bytes are mesh-invariant;
         # what changes with tp:N is how many land per shard
@@ -950,11 +1019,72 @@ class DecodeStepper:
         """Total K/V bytes across all stages and shards (pool or dense
         bank) — constant across mesh sizes at a fixed config, which is
         what makes tp1/tp2/tp4 bench rows an equal-byte comparison."""
+        import jax
+
         arrs = self._pools if self.paged else self._caches
         return sum(
-            2 * int(np.prod(ck.shape)) * ck.dtype.itemsize
-            for ck, _ in arrs
+            int(np.prod(a.shape)) * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(arrs)
         )
+
+    def kv_bytes_per_token(self) -> int:
+        """Bytes one cached token takes over all layers: keys and values
+        of every head, or one latent row a layer."""
+        item = np.dtype(self._gen.kv_dtype).itemsize
+        if self.layout == "latent":
+            return item * sum(a.shape[-1] for a in self._pools)
+        return item * 2 * self._nh * self._hd * len(self._gen._stages)
+
+    # -- the latent-attention block ------------------------------------------
+
+    @staticmethod
+    def _refuse_for_latent(*features):
+        """Typed refusal of what the latent-attention block cannot run
+        yet (each named in PERF.md, "cannot run yet")."""
+        from distkeras_tpu.models.mla_moe import BlockUnsupportedError
+
+        for what in features:
+            if what:
+                raise BlockUnsupportedError(
+                    f"{what} cannot serve the latent-attention block "
+                    f"yet: its cache is one latent row a token and "
+                    f"layer, not (H, Dh) keys and values"
+                )
+
+    @staticmethod
+    def _latent_row(blk) -> int:
+        """Values a pool row holds: the block's latent width rounded up
+        to the TPU's lane width."""
+        return -(-blk.latent_width // 128) * 128
+
+    @staticmethod
+    def _pad_row(new, pool):
+        """Latent rows ``(n, latent_width)`` as the pool holds them."""
+        import jax.numpy as jnp
+
+        pad = pool.shape[-1] - new.shape[-1]
+        return jnp.pad(new.astype(pool.dtype), ((0, 0), (0, pad)))
+
+    def _note_routing(self, fetched, n_active, span):
+        """Split the step's fetch into its tokens and the expert layers'
+        two counters; sum them for ``stats()["moe"]`` and put them on
+        the ``serving/collect`` span. ``experts_hit`` is the distinct
+        routed experts that some active slot's token reached, a mean
+        over the expert layers; ``expert_load_max`` the largest token
+        count on one expert."""
+        toks, (hit_sum, load_max) = fetched[:-2], fetched[-2:]
+        hit = float(hit_sum) / self._moe_layers
+        m = self.moe_stats
+        self.moe_stats = {
+            **m, "steps": m["steps"] + 1,
+            "experts_hit_sum": m["experts_hit_sum"] + hit,
+            "expert_load_max_sum": m["expert_load_max_sum"] + int(load_max),
+        }
+        span.set_metadata(
+            experts_hit=hit, expert_load_max=int(load_max),
+            experts_total=m["experts_total"], routed_tokens=n_active,
+        )
+        return toks
 
     def kv_shard_bytes(self) -> int:
         """K/V bytes RESIDENT PER SHARD — the number a capacity planner
@@ -1044,8 +1174,9 @@ class DecodeStepper:
     @property
     def can_fork(self) -> bool:
         """Whether n-parallel completions can be scheduled here
-        (``fork_slot`` needs the paged CoW machinery)."""
-        return self.paged
+        (``fork_slot`` needs the paged CoW machinery, and its page copy
+        knows (p, H, Dh) pages only)."""
+        return self.paged and self.layout == "kv"
 
     def fork_pages_for(self, prompt_len: int, max_new: int) -> int:
         """FRESH pages one fork of a just-prefilled slot allocates
@@ -1305,6 +1436,8 @@ class DecodeStepper:
         completion)``, so its stream is exactly what an independent
         admission with that derived seed would produce (grammar mask
         state is CLONED: each completion walks the grammar alone)."""
+        if self.layout == "latent":
+            self._refuse_for_latent("fork / beam (copy-on-write page forks)")
         if not self.paged:
             raise ValueError("fork_slot requires paged=True")
         if src in self._pending or not self._tables[src]:
@@ -1410,6 +1543,8 @@ class DecodeStepper:
         leaves the victim decoding untouched. The returned dict rides
         the preempted request; dropping it (typed failure, stop) is
         the only cleanup."""
+        if self.layout == "latent":
+            self._refuse_for_latent("swap-out / preemption / K/V export")
         self._fire("kv.swap", slot=slot, direction="out")
         if slot in self._pending:
             raise ValueError(
@@ -1476,6 +1611,8 @@ class DecodeStepper:
         (garbage at positions >= len-1 is overwritten by that step's
         own K/V write before anything attends it, the standing
         restore argument)."""
+        if self.layout == "latent":
+            self._refuse_for_latent("swap-in / resume of exported K/V")
         self._fire("kv.swap", slot=slot, direction="in")
         ln = int(state["len"])
         remaining = (
@@ -2259,8 +2396,9 @@ class DecodeStepper:
         t = pbt * ps  # gathered (logical) attention extent
         tp = self._tp
 
-        def stage_step(blk, moe, p, pm, x, ck, cv, phys, off, table,
+        def stage_step(blk, moe, p, pm, x, pool, phys, off, table,
                        pos, active):
+            ck, cv = pool
             mh = p["mhsa"]
             nh = blk.mhsa.num_heads
             hd = qshape(mh["wq"])[1] // nh
@@ -2292,7 +2430,19 @@ class DecodeStepper:
             x = x + h_
             if moe is not None:
                 x = x + gen._moe_nodrop(pm, x)
-            return x, ck, cv
+            return x, (ck, cv), None
+
+        latent = self.layout == "latent"
+        if latent:  # stage body and head by block kind, at build time
+            from distkeras_tpu.models.mla_moe import matmul, routing_counts
+
+            stage_step = self._latent_stage_step(pbt)
+
+            def head(p_head, x):  # bf16 x bf16 -> f32
+                return matmul(x, p_head["kernel"])
+        else:
+            def head(p_head, x):
+                return gen._head.apply(p_head, {}, x)[0]
 
         def step(params, ctx, pools, lens, active, table, temps, topk,
                  topp, seeds, spos, *rest):
@@ -2303,17 +2453,19 @@ class DecodeStepper:
             x = self._embed(p_emb, tok, pos)
             phys = table[rows, jnp.clip(pos // ps, 0, pbt - 1)]
             off = pos % ps
-            new_pools = []
-            for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
+            new_pools, routed = [], []
+            for (blk, _, moe, _), (p, pm), pool in zip(
                 gen._stages, bp, pools
             ):
-                x, ck, cv = stage_step(
-                    blk, moe, p, pm, x, ck, cv, phys, off, table, pos,
+                x, pool, sizes = stage_step(
+                    blk, moe, p, pm, x, pool, phys, off, table, pos,
                     active,
                 )
-                new_pools.append((ck, cv))
+                new_pools.append(pool)
+                if sizes is not None:
+                    routed.append(sizes)
             x, _ = gen._final_ln.apply(p_ln, {}, x)
-            logit, _ = gen._head.apply(p_head, {}, x)  # (B, V)
+            logit = head(p_head, x)  # (B, V)
             if masked:
                 logit = logit + rest[0]  # grammar mask (0 / -inf rows)
             nxt = jax.lax.cond(
@@ -2327,6 +2479,11 @@ class DecodeStepper:
             cur = ctx[rows, wpos]
             write = active & (pos + 1 <= tp - 1)
             ctx = ctx.at[rows, wpos].set(jnp.where(write, nxt, cur))
+            if routed:
+                # the routing counters ride the tokens' fetch
+                nxt = jnp.concatenate(
+                    [nxt, routing_counts(routed).astype(nxt.dtype)]
+                )
             return ctx, new_pools, nxt
 
         return self._jit(
@@ -2347,6 +2504,11 @@ class DecodeStepper:
         gen = self._gen
         ps, nh, hd = self.page_size, self._nh, self._hd
         t = pbt * ps
+        if self.layout == "latent":
+            return self._jit(
+                self._latent_chunk_body(cb, pbt), donate=(1,), out="kv",
+                key=f"paged_chunk[{cb},{pbt}]",
+            )
 
         def chunk(params, pools, toks, trow, start):
             bp, p_emb, _, _ = self._unpack(params)
@@ -2386,6 +2548,82 @@ class DecodeStepper:
 
         return self._jit(chunk, donate=(1,), out="kv",
                          key=f"paged_chunk[{cb},{pbt}]")
+
+    def _latent_stage_step(self, pbt: int):
+        """The latent block's absorbed decode step for table bucket
+        ``pbt`` (its arithmetic is ``LatentMoEBlock.forward``): the
+        closure owns the page write and the gather of every slot's
+        latent pages, which stay in the pool's dtype (float32 is what
+        accumulates)."""
+        import jax.numpy as jnp
+
+        b, ps = self.num_slots, self.page_size
+        t = pbt * ps
+
+        def stage_step(blk, moe, p, pm, x, pool, phys, off, table, pos,
+                       active):
+            written = []
+
+            def exchange(new):  # (B, 1, latent_width) float32
+                at = phys * ps + off  # rows of the flat pool
+                row = jnp.where(
+                    active[:, None], self._pad_row(new[:, 0], pool),
+                    pool[at],
+                )
+                written.append(pool.at[at].set(row))
+                pages = written[0].reshape(-1, ps, pool.shape[-1])
+                return pages[table].reshape(b, t, -1)[..., :new.shape[-1]]
+
+            t_mask = (jnp.arange(t)[None, :] <= pos[:, None])[:, None]
+            x, sizes = blk.forward(
+                p, x[:, None], pos[:, None], t_mask, exchange,
+                absorbed=True, token_mask=active[:, None],
+            )
+            return x[:, 0], written[0], sizes
+
+        return stage_step
+
+    def _latent_chunk_body(self, cb: int, pbt: int):
+        """The latent block's prefill chunk: the slot's pages gathered
+        into its logical latent row, the chunk's own rows written into
+        it, expanded attention over the row (``LatentMoEBlock.forward``,
+        the same arithmetic as the step's and as ``apply``'s), and the
+        chunk's rows scattered back to their physical pages."""
+        import jax
+        import jax.numpy as jnp
+
+        gen = self._gen
+        ps = self.page_size
+        t = pbt * ps
+
+        def chunk(params, pools, toks, trow, start):
+            bp, p_emb, _, _ = self._unpack(params)
+            pos = start + jnp.arange(cb)  # (cb,) absolute positions
+            x = self._embed(p_emb, toks, pos)  # (1, cb, d)
+            qmask = (jnp.arange(t)[None, :] <= pos[:, None])[None]
+            fpos = (
+                trow[jnp.clip(pos // ps, 0, pbt - 1)] * ps + pos % ps
+            )  # (cb,) physical flat positions
+            out = []
+            for (blk, _, _, _), (p, _), pool in zip(gen._stages, bp, pools):
+                written = []
+
+                def exchange(new, pool=pool, written=written):
+                    rows = self._pad_row(new[0], pool)  # (cb, row width)
+                    written.append(pool.at[fpos].set(rows))
+                    row = pool.reshape(-1, ps, pool.shape[-1])[trow]
+                    return jax.lax.dynamic_update_slice(
+                        row.reshape(t, -1), rows, (start, 0)
+                    )[None, :, :new.shape[-1]]
+
+                x, _ = blk.forward(
+                    p, x, pos[None], qmask, exchange,
+                    n_keys=jnp.minimum(start + cb, t),
+                )
+                out.append(written[0])
+            return out
+
+        return chunk
 
     def _build_copy_fn_paged(self, pbk: int, pbt: int):
         """Compiled paged prefix restore: scatter the stacked per-stage
@@ -3247,6 +3485,14 @@ class ServingEngine:
         try:
             self._stepper = DecodeStepper(model, **self._stepper_cfg)
             self._stepper.on_compile = self._extend_grace
+            if self._stepper.layout == "latent":
+                if role != "unified":
+                    self._stepper._refuse_for_latent(
+                        f"role {role!r} (K/V export and resume)"
+                    )
+                # the stepper switched the prefix caches off for this
+                # page layout; stats()["paged"]["prefix_caches"] says so
+                store = None
             self.prefix_store = store
             if store is not None:
                 # fabric staleness at a glance: seconds since the
@@ -4613,6 +4859,9 @@ class ServingEngine:
                 self._stepper.prefix_fetch_failures
             )
             out["paged"] = self._stepper.paged_stats()
+            if self._stepper._moe_layers:
+                # the expert layers' routing, summed over decode steps
+                out["moe"] = dict(self._stepper.moe_stats)
         out["restarts"] = self._restarts
         out["watchdog_trips"] = self._watchdog_trips
         out["status"] = self.health()["status"]

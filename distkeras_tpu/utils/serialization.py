@@ -81,6 +81,11 @@ def _encode_node(obj, leaves: list) -> dict:
             "children": [_encode_node(c, leaves) for c in obj],
         }
     arr = np.asarray(obj)
+    if arr.dtype.name == "bfloat16":
+        # NumPy's own formats know no bfloat16: the 16 bits ride as
+        # uint16 (a view, no copy) and the node names the dtype
+        leaves.append(arr.view(np.uint16))
+        return {"t": "leaf", "i": len(leaves) - 1, "dt": "bfloat16"}
     if arr.dtype.kind not in "biufc":
         raise TypeError(f"non-numeric leaf of dtype {arr.dtype} is not serializable")
     leaves.append(arr)
@@ -116,7 +121,12 @@ def _decode_node(node: dict, leaves: list):
     if kind == "none":
         return None
     if kind == "leaf":
-        return leaves[node["i"]]
+        leaf = leaves[node["i"]]
+        if node.get("dt") == "bfloat16":
+            import ml_dtypes
+
+            leaf = leaf.view(ml_dtypes.bfloat16)
+        return leaf
     children = [_decode_node(c, leaves) for c in node["children"]]
     if kind == "int4":
         from distkeras_tpu.ops.quantization import Int4Weight
@@ -156,20 +166,82 @@ def unpack_frame(data: bytes) -> tuple[dict, bytes]:
 # ----------------------------------------------------------------- public API
 
 
-def serialize_params(params) -> bytes:
-    """Pytree of arrays -> bytes (typed structure header + npz, no pickle)."""
+class _Window:
+    """The rest of a seekable file from its current position on, as a file
+    of its own whose position 0 is there: what ``np.savez`` / ``np.load``
+    are handed so that the zip's offsets do not depend on the frame
+    headers written before it, and a multi-gigabyte bundle streams to and
+    from disk a leaf at a time instead of through ``bytes`` copies."""
+
+    def __init__(self, f):
+        self._f, self._base = f, f.tell()
+
+    def tell(self):
+        return self._f.tell() - self._base
+
+    def seek(self, off, whence=0):
+        self._f.seek(off + self._base if whence == 0 else off, whence)
+        return self.tell()
+
+    def read(self, n=-1):
+        return self._f.read(n)
+
+    def write(self, b):
+        return self._f.write(b)
+
+    def flush(self):
+        self._f.flush()
+
+    def seekable(self):
+        return True
+
+    def readable(self):
+        return True
+
+    def writable(self):
+        return True
+
+    def close(self):  # the owner closes the real file
+        pass
+
+
+def _write_frame_header(f, header: dict) -> None:
+    f.write(pack_frame(header))
+
+
+def _read_frame_header(f) -> dict:
+    head = f.read(len(_MAGIC) + _HLEN.size)
+    if head[: len(_MAGIC)] != _MAGIC:
+        raise ValueError("bad frame: missing DKT1 magic (refusing legacy pickle)")
+    (hlen,) = _HLEN.unpack_from(head, len(_MAGIC))
+    return json.loads(f.read(hlen).decode())
+
+
+def _write_params(f, params) -> None:
+    """The params frame (structure header + npz) written to ``f`` from its
+    current position on, a leaf at a time."""
     leaves: list = []
     tree = _encode_node(params, leaves)
+    _write_frame_header(f, {"tree": tree})
+    np.savez(_Window(f), **{f"a{i}": leaf for i, leaf in enumerate(leaves)})
+
+
+def _read_params(f):
+    header = _read_frame_header(f)
+    with np.load(_Window(f), allow_pickle=False) as z:
+        leaves = [z[f"a{i}"] for i in range(len(z.files))]
+    return _decode_node(header["tree"], leaves)
+
+
+def serialize_params(params) -> bytes:
+    """Pytree of arrays -> bytes (typed structure header + npz, no pickle)."""
     buf = io.BytesIO()
-    np.savez(buf, **{f"a{i}": leaf for i, leaf in enumerate(leaves)})
-    return pack_frame({"tree": tree}, buf.getvalue())
+    _write_params(buf, params)
+    return buf.getvalue()
 
 
 def deserialize_params(blob: bytes):
-    header, payload = unpack_frame(blob)
-    with np.load(io.BytesIO(payload), allow_pickle=False) as z:
-        leaves = [z[f"a{i}"] for i in range(len(z.files))]
-    return _decode_node(header["tree"], leaves)
+    return _read_params(io.BytesIO(blob))
 
 
 def serialize_model(model) -> bytes:
@@ -223,6 +295,25 @@ def load_params(path: str):
 # ------------------------------------------------------------ serving bundles
 
 
+def _write_serving_bundle(f, model) -> None:
+    from distkeras_tpu.ops.quantization import is_serving_tree
+
+    if getattr(model, "params", None) is None:
+        raise ValueError("serving bundle needs a BUILT model")
+    if not is_serving_tree(model.params):
+        raise ValueError(
+            "model is not quantized — a serving bundle stores the "
+            "quantized tree (ops.quantization.quantize_model first); "
+            "for the f32 master use serialize_model"
+        )
+    _write_frame_header(f, {
+        "spec": json.dumps(model.get_config()),
+        "input_shape": list(model.input_shape),
+        "serving": True,
+    })
+    _write_params(f, model.params)
+
+
 def serialize_serving_bundle(model) -> bytes:
     """Quantized model -> bytes, the DELIBERATE counterpart of
     ``serialize_model``'s quantized-tree rejection: that guard stops a
@@ -232,25 +323,11 @@ def serialize_serving_bundle(model) -> bytes:
     plus the quantized params tree (int8 dicts ride the leaf stream
     natively; ``Int4Weight`` has a structural node). Loads serve-only:
     trainers and ``serialize_model`` reject the result, exactly as they
-    reject any quantized tree."""
-    from distkeras_tpu.ops.quantization import count_quantized
-
-    if getattr(model, "params", None) is None:
-        raise ValueError("serving bundle needs a BUILT model")
-    if not count_quantized(model.params):
-        raise ValueError(
-            "model is not quantized — a serving bundle stores the "
-            "quantized tree (ops.quantization.quantize_model first); "
-            "for the f32 master use serialize_model"
-        )
-    return pack_frame(
-        {
-            "spec": json.dumps(model.get_config()),
-            "input_shape": list(model.input_shape),
-            "serving": True,
-        },
-        serialize_params(model.params),
-    )
+    reject any quantized tree. A tree cast to bfloat16
+    (``quantize_model(bits=16)``) is a serving tree too."""
+    buf = io.BytesIO()
+    _write_serving_bundle(buf, model)
+    return buf.getvalue()
 
 
 def deserialize_serving_bundle(blob: bytes):
@@ -258,17 +335,38 @@ def deserialize_serving_bundle(blob: bytes):
     params replaced by the stored quantized tree (validated structurally
     against the spec-built model — same tree paths, quantized leaves'
     logical shapes matching the f32 ones they replace)."""
+    return _read_serving_bundle(io.BytesIO(blob))
+
+
+def _build_shapes(model, input_shape):
+    """The spec-built model's params as shapes and dtypes: ``build`` under
+    ``jax.eval_shape``, so that a model of billions of parameters is not
+    initialised (in float32, on the device) only to be checked against and
+    thrown away. A model whose layers carry state (BatchNorm's moving
+    statistics, which a bundle does not hold) is built for real."""
+    import jax
+
+    shapes, state = jax.eval_shape(
+        lambda: (model.build(input_shape).params, model.state)
+    )
+    if jax.tree_util.tree_leaves(state):
+        return model.build(input_shape).params
+    model.state = state  # no leaves: plain containers, no tracer inside
+    return shapes
+
+
+def _read_serving_bundle(f):
     from distkeras_tpu.models.sequential import Sequential
     from distkeras_tpu.ops.quantization import is_quantized, qshape
 
-    header, payload = unpack_frame(blob)
+    header = _read_frame_header(f)
     if not header.get("serving"):
         raise ValueError(
             "not a serving bundle (use deserialize_model for f32 frames)"
         )
     model = Sequential.from_config(json.loads(header["spec"]))
-    model.build(tuple(header["input_shape"]))
-    loaded = deserialize_params(payload)
+    built = _build_shapes(model, tuple(header["input_shape"]))
+    loaded = _read_params(f)
 
     def check(path, built, got):
         if is_quantized(got):
@@ -279,7 +377,7 @@ def deserialize_serving_bundle(blob: bytes):
             # wrong-length s until the predictions are silently wrong)
             from distkeras_tpu.ops.quantization import Int4Weight
 
-            want = tuple(np.shape(built))
+            want = tuple(built.shape)
             if len(want) != 2:
                 # quantization only ever replaces 2-D matmul weights; a
                 # "quantized" leaf standing in for a bias/LN gain is a
@@ -343,32 +441,39 @@ def deserialize_serving_bundle(blob: bytes):
                 )
             for i, (b, g) in enumerate(zip(built, got)):
                 check(f"{path}[{i}]", b, g)
-        elif np.shape(built) != np.shape(got):
+        elif tuple(built.shape) != np.shape(got):
             raise ValueError(
                 f"serving bundle shape mismatch at {path}: "
-                f"{np.shape(built)} vs {np.shape(got)}"
+                f"{tuple(built.shape)} vs {np.shape(got)}"
             )
-        elif np.asarray(built).dtype != np.asarray(got).dtype:
+        elif built.dtype != np.asarray(got).dtype and not (
+            built.dtype == np.float32
+            and np.asarray(got).dtype.name == "bfloat16"
+        ):
             # shape alone would let a crafted bundle substitute e.g. a
             # float64 or int array for an f32 bias/LN gain and serve it
             # silently; non-quantized leaves must match the spec-built
-            # dtype exactly (the quantized branch pins its own dtypes)
+            # dtype exactly (the quantized branch pins its own dtypes),
+            # but for the 16-bit serving cast: bfloat16 where the spec
+            # builds float32 (``quantize_model(bits=16)``)
             raise ValueError(
                 f"serving bundle dtype mismatch at {path}: spec builds "
-                f"{np.asarray(built).dtype}, bundle holds "
+                f"{built.dtype}, bundle holds "
                 f"{np.asarray(got).dtype}"
             )
 
-    check("params", model.params, loaded)
+    check("params", built, loaded)
     model.params = loaded
     return model
 
 
 def save_serving_bundle(path: str, model) -> None:
+    """Streams to the file a leaf at a time: a bundle may be larger than
+    what the host could hold twice."""
     with open(path, "wb") as f:
-        f.write(serialize_serving_bundle(model))
+        _write_serving_bundle(f, model)
 
 
 def load_serving_bundle(path: str):
     with open(path, "rb") as f:
-        return deserialize_serving_bundle(f.read())
+        return _read_serving_bundle(f)
