@@ -687,6 +687,9 @@ class DecodeStepper:
         self.page_size = int(page_size)
         self.recorder = recorder
         if self.paged:
+            from distkeras_tpu.ops.paged_attention import (
+                decode_attention_path,
+            )
             from distkeras_tpu.serving.paging import PageAllocator
             from distkeras_tpu.serving.prefix_cache import (
                 DevicePrefixIndex,
@@ -707,6 +710,12 @@ class DecodeStepper:
             # full-capacity slot (every runtime bucket is <= this)
             self._max_pages_bucket = max(
                 1, 1 << (pages_per_slot - 1).bit_length()
+            )
+            # how the decode step attends, by what this stepper is:
+            # "kernel" (each slot's own pages, in place) or "gather:
+            # <why>" (the gathered extent of the longest table)
+            self.attention = decode_attention_path(
+                self.layout, hd, self._gen.kv_dtype, self.mesh
             )
             self._caches = None
             if latent:
@@ -885,6 +894,7 @@ class DecodeStepper:
         if not self.paged:
             return {"enabled": False}
         out = {"enabled": True, "layout": self.layout,
+               "attention": self.attention,
                "bytes_per_token": self.kv_bytes_per_token()}
         if self.prefix_caches_off:
             out["prefix_caches"] = "off: " + self.prefix_caches_off
@@ -1782,8 +1792,20 @@ class DecodeStepper:
         """Pow2 bucket covering every OCCUPIED slot's table — the step
         / verify program key. Occupied (not active) so blame-probe
         masks never change the program mid-blame."""
+        if self.attention == "kernel":
+            # the kernel reads a slot's own pages whatever the table's
+            # width: one step program, at the widest table
+            return self._max_pages_bucket
         m = max((len(t) for t in self._tables), default=0)
         return _bucket_pow2(max(1, m), self._max_pages_bucket)
+
+    def _step_table_buckets(self) -> list[int]:
+        """Every bucket ``_table_bucket`` can return: what the warm
+        methods compile the step program at."""
+        top = self._max_pages_bucket
+        if self.attention == "kernel":
+            return [top]
+        return [1 << i for i in range(top.bit_length())]
 
     def _table_row(self, slot, pbt) -> np.ndarray:
         row = np.zeros((pbt,), np.int32)
@@ -1952,17 +1974,19 @@ class DecodeStepper:
         active = np.zeros(self.num_slots, bool)
         sargs = self._sampling_args()  # parked slots = greedy defaults
         if self.paged:
-            # warm EVERY pow2 table bucket of the step program (the one
-            # paged family with a dynamic extent): the bucket tracks
-            # the longest occupied table at runtime, and a mid-serving
-            # bucket change must find its program compiled — a live-
-            # path step compile is exactly the stall paging must not
-            # reintroduce. O(log pages) programs, off the serving path.
-            # Only the UNMASKED variants warm here: grammar traffic is
-            # the rare case and its first mask may compile on-path
-            # (graced via on_compile, like a fresh prefill bucket).
-            pbt = 1
-            while True:
+            # warm EVERY table bucket of the step program (the one
+            # paged family with a dynamic extent): with the gather body
+            # the bucket tracks the longest occupied table at runtime,
+            # and a mid-serving bucket change must find its program
+            # compiled — a live-path step compile is exactly the stall
+            # paging must not reintroduce. O(log pages) programs, off
+            # the serving path; ONE where the kernel attends (the
+            # extent costs it nothing, so the table is always the
+            # widest). Only the UNMASKED variants warm here: grammar
+            # traffic is the rare case and its first mask may compile
+            # on-path (graced via on_compile, like a fresh prefill
+            # bucket).
+            for pbt in self._step_table_buckets():
                 fn = self._pstep_fns.get((pbt, False))
                 if fn is None:
                     fn = self._build_step_fn_paged(pbt)
@@ -1974,9 +1998,6 @@ class DecodeStepper:
                     self._params, self._ctx, self._pools,
                     self._lens.copy(), active, table, *sargs,
                 )
-                if pbt >= self._max_pages_bucket:
-                    break
-                pbt *= 2
             if self.drafter is not None:
                 key = (self._kb + 1, self._max_pages_bucket, False)
                 vfn = self._pverify_fns.get(key)
@@ -2142,12 +2163,12 @@ class DecodeStepper:
                     )
                 return
             # the masked STEP tracks the longest OCCUPIED table, so
-            # it needs every pow2 bucket; verify windows always run
-            # at the fixed _max_pages_bucket extent (the live call
-            # site pins it), so warming verify at the sub-max buckets
-            # would mint programs no iteration can ever key on
-            pbt = 1
-            while True:
+            # it needs every bucket the step can key on; verify
+            # windows always run at the fixed _max_pages_bucket extent
+            # (the live call site pins it), so warming verify at the
+            # sub-max buckets would mint programs no iteration can
+            # ever key on
+            for pbt in self._step_table_buckets():
                 table = np.zeros((self.num_slots, pbt), np.int32)
                 key = (pbt, True)
                 fn = self._pstep_fns.get(key)
@@ -2159,9 +2180,6 @@ class DecodeStepper:
                     self._lens.copy(), active, table, *sargs,
                     tmask,
                 )
-                if pbt >= self._max_pages_bucket:
-                    break
-                pbt *= 2
             if self.drafter is not None:
                 pbt = self._max_pages_bucket
                 table = np.zeros((self.num_slots, pbt), np.int32)
@@ -2361,33 +2379,57 @@ class DecodeStepper:
         return self._jit(copy, donate=(0,), out="kv",
                          key="restore")
 
-    # -- paged programs (gather-based attention over page pools) ------------
+    # -- paged programs (attention over page pools) -------------------------
     #
     # The paged family restates the dense programs over a ``(num_pages,
-    # page_size, H, Dh)`` pool per stage: each slot's logical K/V row
-    # is the GATHER of its page-table entries (``pool[table]`` ->
-    # (B, pages, page_size, H, Dh), reshaped to (B, T', H, Dh) with
-    # T' = bucket * page_size), and every K/V write scatters to the
-    # physical (page, offset) its logical position maps to. Program
-    # keys add the pow2-bucketed page count, so the attention extent
-    # tracks the ACTUAL longest table instead of the worst-case
-    # sequence — mixed-length traffic attends what it holds, and the
-    # compile count stays O(log T) per family. Attention math, masks,
-    # and the sampling tail are the dense bodies verbatim, which is
-    # what keeps paged greedy output pinned token-identical.
+    # page_size, H, Dh)`` pool per stage; every K/V write scatters to
+    # the physical (page, offset) its logical position maps to. How a
+    # program ATTENDS is one of two bodies over the same pool:
+    #
+    # - the decode step, where ``self.attention == "kernel"`` (an
+    #   unsharded stepper, the ``"kv"`` layout, heads a whole number of
+    #   128 lanes wide, a bfloat16 or float32 pool:
+    #   ``ops.paged_attention.decode_attention_path``): after its page
+    #   write, ``paged_decode_attention`` reads each slot's own pages
+    #   from the written pool where they lie, as many as the slot's
+    #   length needs; nothing is gathered, converted or padded in HBM,
+    #   a slot that is not decoding costs nothing, and the table's
+    #   width costs nothing either, so ONE step program is compiled,
+    #   at ``_max_pages_bucket``;
+    # - everywhere else (the step under a ``tp`` mesh, with heads of 16
+    #   or 64, or in the latent layout's own stage body; the chunk,
+    #   verify and restore programs of every stepper) the GATHER body:
+    #   each slot's logical K/V row is ``pool[table]`` -> (B, pages,
+    #   page_size, H, Dh), reshaped to (B, T', H, Dh) with T' = bucket
+    #   * page_size. The gather step's keys add the pow2-bucketed page
+    #   count, so its extent tracks the longest OCCUPIED table and the
+    #   compile count stays O(log T); chunk and verify run at the
+    #   fixed full-capacity extent.
+    #
+    # Masks, the softmax and the sampling tail are the dense bodies'
+    # (the kernel folds the same softmax over blocks of pages, in
+    # float32), which is what keeps paged greedy output pinned
+    # token-identical. ``stats()["paged"]["attention"]`` and the
+    # ``serving/step`` span say which body a stepper's step runs.
 
     def _build_step_fn_paged(self, pbt: int, masked=False):
         """Compiled paged decode step for table bucket ``pbt``: the
         dense ``_build_step_fn`` with the per-row cache write scattered
-        to ``table[row][pos // ps]`` and attention over the gathered
-        pages. Inactive / short rows pad their tables with the null
+        to ``table[row][pos // ps]`` and attention over the slot's
+        pages: read in place by ``paged_decode_attention`` where
+        ``self.attention == "kernel"``, gathered at the bucket's extent
+        otherwise. Inactive / short rows pad their tables with the null
         sentinel page (writes masked to read-back, reads masked by the
-        position mask), so one program serves every occupancy. Sampling
-        params are data (see ``_build_step_fn``); ``masked`` adds the
-        grammar-mask argument."""
+        position mask; the kernel never reads the pad), so one program
+        serves every occupancy. Sampling params are data (see
+        ``_build_step_fn``); ``masked`` adds the grammar-mask
+        argument."""
         import jax
         import jax.numpy as jnp
 
+        from distkeras_tpu.ops.paged_attention import (
+            paged_decode_attention,
+        )
         from distkeras_tpu.ops.quantization import qmatmul, qshape
         from distkeras_tpu.serving import sampling as _sp
 
@@ -2395,6 +2437,7 @@ class DecodeStepper:
         b, ps = self.num_slots, self.page_size
         t = pbt * ps  # gathered (logical) attention extent
         tp = self._tp
+        in_place = self.attention == "kernel"
 
         def stage_step(blk, moe, p, pm, x, pool, phys, off, table,
                        pos, active):
@@ -2413,14 +2456,22 @@ class DecodeStepper:
             cv = cv.at[phys, off].set(
                 jnp.where(keep, v_new.astype(cv.dtype), cv[phys, off])
             )
-            kg = ck[table].reshape(b, t, nh, hd)
-            vg = cv[table].reshape(b, t, nh, hd)
-            scores = jnp.einsum("bhd,bthd->bht", q, kg) / np.sqrt(hd)
-            t_mask = jnp.arange(t)[None, :] <= pos[:, None]  # (B, T')
-            scores = jnp.where(t_mask[:, None, :], scores, -jnp.inf)
-            w = jax.nn.softmax(scores, axis=-1)
-            o = jnp.einsum("bht,bthd->bhd", w, vg).reshape(b, nh * hd)
-            o = qmatmul(o, mh["wo"])
+            if in_place:
+                # positions <= pos of the slot's own pages, read from
+                # the written pool where they lie; nothing for a slot
+                # that is not decoding (its row is never used)
+                o = paged_decode_attention(
+                    q, ck, cv, table, jnp.where(active, pos + 1, 0)
+                )
+            else:
+                kg = ck[table].reshape(b, t, nh, hd)
+                vg = cv[table].reshape(b, t, nh, hd)
+                scores = jnp.einsum("bhd,bthd->bht", q, kg) / np.sqrt(hd)
+                t_mask = jnp.arange(t)[None, :] <= pos[:, None]  # (B, T')
+                scores = jnp.where(t_mask[:, None, :], scores, -jnp.inf)
+                w = jax.nn.softmax(scores, axis=-1)
+                o = jnp.einsum("bht,bthd->bhd", w, vg)
+            o = qmatmul(o.reshape(b, nh * hd), mh["wo"])
             if "bo" in mh:
                 o = o + mh["bo"]
             x = x + o
@@ -2816,8 +2867,11 @@ class DecodeStepper:
                 *self._sampling_args(), *((tmask,) if masked else ()),
             )
             self.host_arg_bytes_step = self._host_arg_bytes(host)
-        with _span("serving/step", host_arg_bytes=self.host_arg_bytes_step):
+        with _span(
+            "serving/step", host_arg_bytes=self.host_arg_bytes_step
+        ) as span:
             if self.paged:
+                span.set_metadata(attention=self.attention)
                 self._ctx, self._pools, toks = fn(
                     self._params, self._ctx, self._pools, *host
                 )

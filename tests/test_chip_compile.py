@@ -142,3 +142,48 @@ def test_momentum_leaf_compiles_for_v5e(one_chip):
         ),
         p, p, p,
     )
+
+
+# the serving cell's shapes (cerebras-gpt-1.3b: 32 slots, 16 heads of 128,
+# 1408 pages of 16 tokens, a table of 128 pages), and a float32 pool
+PAGED_SHAPES = [
+    pytest.param(32, 16, 128, 16, 1408, 128, jnp.bfloat16, id="gpt1.3b-bf16"),
+    pytest.param(8, 8, 128, 16, 256, 32, jnp.float32, id="f32-pool"),
+]
+
+
+@pytest.mark.parametrize("b,nh,hd,ps,pages,pbt,dtype", PAGED_SHAPES)
+def test_paged_decode_attention_compiles_for_v5e(
+    one_chip, b, nh, hd, ps, pages, pbt, dtype
+):
+    """The decode step's page write, then the kernel over the written
+    pool: the module holds the kernel and no copy of a pool (the pools
+    stay where they lie, an operand of the custom call)."""
+    from distkeras_tpu.ops.paged_attention import (
+        BLOCK_PAGES,
+        _paged_decode_attention,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(q, new, ck, cv, phys, off, table, lengths):
+        ck = ck.at[phys, off].set(new.astype(ck.dtype))
+        cv = cv.at[phys, off].set(new.astype(cv.dtype))
+        o = _paged_decode_attention(
+            q, ck, cv, table, lengths, block_pages=BLOCK_PAGES,
+            interpret=False,
+        )
+        return o, ck, cv
+
+    pool = s((pages, ps, nh, hd), dtype)
+    row = s((b, nh, hd), jnp.float32)
+    idx = s((b,), jnp.int32)
+    text = jax.jit(step, donate_argnums=(2, 3)).lower(
+        row, row, pool, pool, idx, idx, s((b, pbt), jnp.int32), idx
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    pool_shape = f"[{pages},{ps},{nh},{hd}]"
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and pool_shape in ln.split("=")[0]]
+    assert not copies, copies

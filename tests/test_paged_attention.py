@@ -1,0 +1,209 @@
+"""The paged decode-attention kernel against the gather body's arithmetic.
+
+``ops.paged_attention.paged_decode_attention`` (run here in the Pallas
+interpreter) must give what the engine's gather body gives: gather the
+slot's pages at the table's extent, scores over every position, the
+step's mask ``position < length``, softmax, weighted values — written
+out below in NumPy float32. The kernel's products are exact in ``q`` and
+the weights (three bfloat16 terms) and in the pool's values, so the two
+differ by the order of float32 accumulation alone; the tolerance is that
+bound and nothing wider: a ``q`` rounded to one bfloat16 term would miss
+it by two orders of magnitude.
+"""
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from distkeras_tpu.ops.paged_attention import (
+    BLOCK_PAGES,
+    decode_attention_path,
+    paged_decode_attention,
+)
+
+PS, HD = 16, 128
+EPS = float(np.finfo(np.float32).eps)
+
+
+def _gather_body(q, ck, cv, table, lengths):
+    """``_build_step_fn_paged``'s gather body (mask ``pos`` = length - 1),
+    float32 throughout; a slot of length 0 reads zeros."""
+    b, nh, hd = q.shape
+    t = table.shape[1] * PS
+    kg = np.asarray(ck, np.float32)[table].reshape(b, t, nh, hd)
+    vg = np.asarray(cv, np.float32)[table].reshape(b, t, nh, hd)
+    scores = np.einsum("bhd,bthd->bht", q, kg) / np.float32(np.sqrt(hd))
+    mask = np.arange(t)[None, :] < lengths[:, None]
+    scores = np.where(mask[:, None, :], scores, -np.inf)
+    top = scores.max(-1, keepdims=True)
+    w = np.exp(scores - np.where(np.isfinite(top), top, 0.0))
+    norm = w.sum(-1, keepdims=True)
+    w = w / np.where(norm == 0.0, 1.0, norm)
+    return np.einsum("bht,bthd->bhd", w, vg).astype(np.float32)
+
+
+def _pools(rng, num_pages, nh, dtype):
+    shape = (num_pages, PS, nh, HD)
+    return (jnp.asarray(rng.normal(size=shape), dtype),
+            jnp.asarray(rng.normal(size=shape), dtype))
+
+
+def _check(q, ck, cv, table, lengths, block_pages):
+    got = np.asarray(
+        paged_decode_attention(q, ck, cv, table, lengths,
+                               block_pages=block_pages)
+    )
+    want = _gather_body(q, ck, cv, table, lengths)
+    # a float32 sum of n terms is off by at most n eps sum|terms|: the
+    # longest sum here is the weighted values', weights summing to 1
+    tol = int(lengths.max()) * EPS * float(np.abs(np.asarray(
+        cv, np.float32)).max())
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=0, atol=tol)
+    return got
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("nh", [2, 4])
+def test_ragged_lengths_match_the_gather_body(nh, dtype):
+    """Lengths 1, 15, 16, 17 (a page's edges), one slot at the table's
+    full extent, and one slot that is not decoding: length 0 over a
+    null table, which copies nothing and returns zeros."""
+    rng = np.random.default_rng(nh)
+    pbt, num_pages = 8, 40
+    lengths = np.array([1, 15, 16, 17, pbt * PS, 0], np.int32)
+    table = np.zeros((len(lengths), pbt), np.int32)
+    free = iter(rng.permutation(np.arange(1, num_pages)))
+    for i, n in enumerate(-(-lengths // PS)):
+        table[i, :n] = [next(free) for _ in range(n)]
+    q = rng.normal(size=(len(lengths), nh, HD)).astype(np.float32)
+    ck, cv = _pools(rng, num_pages, nh, dtype)
+    got = _check(q, ck, cv, table, lengths, block_pages=4)
+    assert not got[-1].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_permuted_and_shared_pages(dtype):
+    """Two slots whose tables share their leading pages (a prefix hit or
+    a fork) in an order that is not the pool's, each with its own tail:
+    a slot reads its own table, whatever its neighbour holds."""
+    rng = np.random.default_rng(5)
+    nh, pbt, num_pages = 2, 8, 24
+    shared = [17, 3, 11]
+    table = np.zeros((3, pbt), np.int32)
+    table[0, :5] = shared + [9, 2]
+    table[1, :4] = shared + [20]
+    table[2, :2] = [2, 9]  # slot 0's tail, reversed
+    lengths = np.array([5 * PS - 3, 3 * PS + 1, 2 * PS], np.int32)
+    q = rng.normal(size=(3, nh, HD)).astype(np.float32)
+    ck, cv = _pools(rng, num_pages, nh, dtype)
+    _check(q, ck, cv, table, lengths, block_pages=2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("length", [4 * PS + 5, 6 * PS, 9 * PS + 16])
+def test_length_ending_inside_a_block_of_pages(length, dtype):
+    """Blocks of 4 pages: lengths of 5, 6 and 11 pages end one, two and
+    three pages into a block; the block's other pages are not copied and
+    what the buffer held before (the other slot's pages) is masked."""
+    rng = np.random.default_rng(length)
+    nh, pbt, num_pages = 4, 12, 32
+    lengths = np.array([12 * PS, length], np.int32)
+    table = np.zeros((2, pbt), np.int32)
+    pages = rng.permutation(np.arange(1, num_pages))
+    table[0] = pages[:pbt]
+    n = -(-length // PS)
+    table[1, :n] = pages[pbt:pbt + n]
+    q = rng.normal(size=(2, nh, HD)).astype(np.float32)
+    ck, cv = _pools(rng, num_pages, nh, dtype)
+    _check(q, ck, cv, table, lengths, block_pages=4)
+
+
+def test_a_table_narrower_than_a_block():
+    """The default block (8 pages) over a table of 3: the block clamps
+    to the table."""
+    rng = np.random.default_rng(9)
+    lengths = np.array([3 * PS - 1, 7], np.int32)
+    table = np.array([[4, 2, 6], [1, 0, 0]], np.int32)
+    q = rng.normal(size=(2, 2, HD)).astype(np.float32)
+    ck, cv = _pools(rng, 8, 2, jnp.bfloat16)
+    assert BLOCK_PAGES > table.shape[1]
+    _check(q, ck, cv, table, lengths, block_pages=BLOCK_PAGES)
+
+
+@pytest.mark.parametrize(
+    "layout,head_dim,kv_dtype,mesh,want",
+    [
+        ("kv", 128, jnp.bfloat16, None, "kernel"),
+        ("kv", 256, jnp.float32, None, "kernel"),
+        ("kv", 16, jnp.float32, None, "gather: heads of 16"),
+        ("kv", 64, jnp.bfloat16, None, "gather: heads of 64"),
+        ("kv", 128, jnp.float16, None, "gather: no kernel for a float16"),
+        ("latent", None, jnp.bfloat16, None, "gather: the latent page"),
+        ("kv", 128, jnp.bfloat16, object(), "gather: Mosaic kernels"),
+    ],
+)
+def test_where_the_kernel_engages(layout, head_dim, kv_dtype, mesh, want):
+    got = decode_attention_path(layout, head_dim, kv_dtype, mesh)
+    assert got.startswith(want), got
+
+
+# ------------------------------------------------------ through the engine
+
+
+def test_engine_with_128_wide_heads_decodes_through_the_kernel():
+    """A small ``transformer_lm`` with heads of 128 on a paged engine:
+    ``stats()["paged"]["attention"]`` says ``"kernel"``, one step
+    program is compiled (at the widest table), and concurrent greedy
+    requests decode the tokens of the solo ``CachedSequenceGenerator``,
+    chunked prefill and mixed lengths included."""
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.predictors import CachedSequenceGenerator
+    from distkeras_tpu.serving import ServingEngine
+
+    lm = zoo.transformer_lm(
+        vocab_size=61, seq_len=48, d_model=256, num_heads=2, depth=2,
+        seed=0,
+    )
+    ref = CachedSequenceGenerator(lm)
+    eng = ServingEngine(lm, num_slots=3, paged=True, page_size=4,
+                        prefill_chunk=4)
+    eng.start()
+    try:
+        assert eng.stats()["paged"]["attention"] == "kernel"
+        rng = np.random.default_rng(0)
+        prompts = [rng.integers(0, 61, n).astype(np.int32)
+                   for n in (5, 1, 19, 30)]
+        reqs = [eng.submit(p, 9) for p in prompts]
+        for p, r in zip(prompts, reqs):
+            want = ref.generate(p[None], steps=9)[0]
+            np.testing.assert_array_equal(np.asarray(r.result()), want)
+        paged = eng.stats()["paged"]
+        assert paged["attention"] == "kernel"
+        assert paged["compiled_step_buckets"] == [(16, False)]
+    finally:
+        eng.stop()
+
+
+def test_engine_with_16_wide_heads_keeps_the_gather_body():
+    """The suite's fixtures (heads of 16) stay on the gather body, with
+    its pow2 table buckets, and say why."""
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.serving.engine import DecodeStepper
+
+    lm = zoo.transformer_lm(
+        vocab_size=61, seq_len=32, d_model=32, num_heads=2, depth=2,
+        seed=0,
+    )
+    st = DecodeStepper(lm, num_slots=2, paged=True, page_size=4)
+    why = st.paged_stats()["attention"]
+    assert why.startswith("gather: heads of 16"), why
+    assert st._step_table_buckets() == [1, 2, 4, 8]
+    st.warmup()
+    assert st.paged_stats()["compiled_step_buckets"] == [
+        (1, False), (2, False), (4, False), (8, False)
+    ]

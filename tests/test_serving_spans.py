@@ -129,6 +129,20 @@ def test_pages_in_use_on_the_span_is_the_allocator_s(paged_engine, tmp_path):
     assert paged_engine.stats()["paged"]["pages_in_use"] <= max(held)
 
 
+def test_step_span_says_how_the_step_attended(paged_engine, tmp_path):
+    """``attention`` on every ``serving/step`` span is the word
+    ``stats()["paged"]["attention"]`` gives: the fixture's heads of 16
+    keep the gather body, and the span says why."""
+    def drive():
+        paged_engine.submit(np.arange(6, dtype=np.int32), 4).result(120)
+
+    _, plain = _traced(tmp_path, drive)
+    said = set(_program_spans.span_values(
+        _program_spans.iterations(plain), "serving/step", "attention"))
+    word = paged_engine.stats()["paged"]["attention"]
+    assert word.startswith("gather: heads of 16") and said == {word}
+
+
 def test_step_span_carries_the_host_bytes_of_the_call(tmp_path):
     """The stepper places a NumPy tree when it binds it (PR 26), so only
     the small per-step arrays ride a step's span; host arrays bound to
