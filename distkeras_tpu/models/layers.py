@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # ---------------------------------------------------------------- activations
@@ -424,6 +425,21 @@ class GlobalAvgPool1D(Layer):
         return jnp.mean(x, axis=1), state
 
 
+def cache_attention(q, keys, values, mask):
+    """Softmax attention of ``q`` over cached ``keys`` / ``values``
+    ``(B, T, H, Dh)``, in the cache's dtype with float32 accumulation:
+    THE masked softmax of every program that attends a cache it has
+    gathered or holds whole. ``q`` ``(B, H, Dh)`` is one token a row
+    under ``mask`` ``(B, T)``; ``q`` ``(B, C, H, Dh)`` a chunk under
+    ``(C, T)`` (every row alike) or ``(B, C, T)``. True = may attend."""
+    c = "c" if q.ndim == 4 else ""
+    scores = jnp.einsum(f"b{c}hd,bthd->bh{c}t", q, keys)
+    scores = scores / np.sqrt(q.shape[-1])
+    mask = mask[None, None] if mask.ndim < q.ndim - 1 else mask[:, None]
+    w = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    return jnp.einsum(f"bh{c}t,bthd->b{c}hd", w, values)
+
+
 @register_layer
 class MultiHeadSelfAttention(Layer):
     """Multi-head self-attention over (batch, seq, features).
@@ -471,24 +487,42 @@ class MultiHeadSelfAttention(Layer):
             params["bo"] = jnp.zeros((d,), jnp.float32)
         return params, {}, (*in_shape[:-1], d)
 
-    def apply(self, params, state, x, train=False, rng=None):
+    def forward(self, params, x, attend):
+        """``x`` ``(..., d)``: project q, k and v to ``(..., H, Dh)``,
+        ``attend(q, k_new, v_new) -> o (..., H, Dh)``, then ``wo`` and
+        the bias (a layer built with ``use_bias`` is one whose params
+        hold ``"bo"``). THE attention arithmetic of every program: what
+        the new keys and values are attended with and where they are
+        kept is the caller's ``attend``. Dtypes: the projections and
+        the ``wo`` product are in their input's dtype (``qmatmul``), so
+        the output is in ``attend``'s output dtype, and the bias is
+        cast to it: a float32 ``"bo"`` beside bfloat16 activations adds
+        in bfloat16 and never promotes the residual branch."""
         from distkeras_tpu.ops.quantization import qmatmul, qshape
-        from distkeras_tpu.parallel.ring_attention import dense_attention
 
-        b, t, d = x.shape
         h = self.num_heads
         hd = qshape(params["wq"])[1] // h
 
         def proj(w):
-            return qmatmul(x, w).reshape(b, t, h, hd)
+            return qmatmul(x, w).reshape(*x.shape[:-1], h, hd)
 
         q, k, v = proj(params["wq"]), proj(params["wk"]), proj(params["wv"])
+        o = attend(q, k, v)
+        o = qmatmul(o.reshape(*x.shape[:-1], h * hd), params["wo"])
+        if "bo" in params:
+            o = o + params["bo"].astype(o.dtype)
+        return o
+
+    def attend_self(self, q, k, v):
+        """The tokens' own keys and values, nothing cached: ``attend``
+        of a full forward (training, scoring)."""
+        from distkeras_tpu.parallel.ring_attention import dense_attention
+
         attn = self.attention_fn or dense_attention
-        o = attn(q, k, v, causal=self.causal)
-        o = qmatmul(o.reshape(b, t, h * hd), params["wo"])
-        if self.use_bias:
-            o = o + params["bo"].astype(x.dtype)
-        return o, state
+        return attn(q, k, v, causal=self.causal)
+
+    def apply(self, params, state, x, train=False, rng=None):
+        return self.forward(params, x, self.attend_self), state
 
     def get_config(self):
         if self.attention_fn is not None:
@@ -579,34 +613,43 @@ class TransformerBlock(Layer):
             return fn(params, state, x, rng)
         return self._apply(params, state, x, rng, train=train)
 
+    def forward(self, p, x, attend, branch=None):
+        """The block's arithmetic, once: ``x`` ``(..., d)`` through
+        ``ln1``, the q/k/v projections, ``attend(q, k_new, v_new) -> o``
+        (each ``(..., H, Dh)``), ``wo`` and its bias, the residual,
+        ``ln2``, ``fc1``, ``fc2``, the residual. ``attend`` owns what a
+        caller knows and the block does not: where the new keys and
+        values are written, which rows are frozen, what is gathered or
+        read in place, the mask, the softmax or the kernel; a caller
+        that keeps a cache gets it back by its closure's side.
+        ``branch(h, i)`` (training's dropout) maps the output of residual
+        branch ``i`` before it is added."""
+        h, _ = self.ln1.apply(p["ln1"], {}, x)
+        h = self.mhsa.forward(p["mhsa"], h, attend)
+        x = x + (h if branch is None else branch(h, 0))
+        h, _ = self.ln2.apply(p["ln2"], {}, x)
+        h, _ = self._fc1.apply(p["fc1"], {}, h)
+        h, _ = self._fc2.apply(p["fc2"], {}, h)
+        return x + (h if branch is None else branch(h, 1))
+
     def _apply(self, params, state, x, rng, train=False):
-        drop = train and self.dropout > 0.0
-        if drop:
+        branch = None
+        if train and self.dropout > 0.0:
             if rng is None:
                 raise ValueError(
                     "TransformerBlock(dropout>0).apply(train=True) "
                     "requires an rng"
                 )
-            r1, r2 = jax.random.split(rng)
-        # reuse the Dropout layer's mask logic (stateless, param-free) so
-        # the two inverted-dropout implementations cannot drift
-        _dropper = Dropout(self.dropout)
+            keys = tuple(jax.random.split(rng))
+            # reuse the Dropout layer's mask logic (stateless, param-free)
+            # so the two inverted-dropout implementations cannot drift
+            dropper = Dropout(self.dropout)
 
-        def residual_drop(h, r):
-            if not drop:
-                return h
-            return _dropper.apply({}, {}, h, train=True, rng=r)[0]
+            def branch(h, i):
+                return dropper.apply({}, {}, h, train=True, rng=keys[i])[0]
 
-        new_state = dict(state)
-        h, new_state["ln1"] = self.ln1.apply(params["ln1"], state["ln1"], x)
-        a, new_state["mhsa"] = self.mhsa.apply(
-            params["mhsa"], state["mhsa"], h, train, rng
-        )
-        x = x + residual_drop(a, r1 if drop else None)
-        h, new_state["ln2"] = self.ln2.apply(params["ln2"], state["ln2"], x)
-        h, new_state["fc1"] = self._fc1.apply(params["fc1"], state["fc1"], h)
-        h, new_state["fc2"] = self._fc2.apply(params["fc2"], state["fc2"], h)
-        return x + residual_drop(h, r2 if drop else None), new_state
+        y = self.forward(params, x, self.mhsa.attend_self, branch)
+        return y, dict(state)  # every sublayer is stateless
 
     def get_config(self):
         return {

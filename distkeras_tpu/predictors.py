@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu.data.dataset import Dataset
-from distkeras_tpu.ops.quantization import qmatmul, qshape
+from distkeras_tpu.models.layers import cache_attention
+from distkeras_tpu.ops.quantization import qshape
 
 
 class Predictor:
@@ -518,45 +519,36 @@ class CachedSequenceGenerator(SequenceGenerator):
         self._head = layers[-1]
         return True
 
-    def _stage_chunk(self, blk, moe, p, pm, x, cache_k, cache_v, pos,
-                     qmask):
-        """A C-token chunk through one (block, optional MoE) stage
-        against its cache — THE per-stage transformer body; single-token
-        decode is the C=1 case and the speculative verify passes C=k+1.
-        x: (B, C, d); caches: (B, T, H, Dh); pos: the chunk's first
-        position (K/V write offset); qmask: (C, T) bool, True where
-        chunk row c may attend cache position t."""
-        mh = p["mhsa"]
-        b, c, _ = x.shape
-        nh = blk.mhsa.num_heads
-        hd = qshape(mh["wq"])[1] // nh
-        h_, _ = blk.ln1.apply(p["ln1"], {}, x)
-        q = qmatmul(h_, mh["wq"]).reshape(b, c, nh, hd)
-        k_new = qmatmul(h_, mh["wk"]).reshape(b, c, nh, hd)
-        v_new = qmatmul(h_, mh["wv"]).reshape(b, c, nh, hd)
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype), (0, pos, 0, 0)
-        )
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype), (0, pos, 0, 0)
-        )
-        scores = jnp.einsum("bchd,bthd->bhct", q, cache_k) / np.sqrt(hd)
-        scores = jnp.where(qmask[None, None], scores, -jnp.inf)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhct,bthd->bchd", w, cache_v).reshape(
-            b, c, nh * hd
-        )
-        o = qmatmul(o, mh["wo"])
-        if "bo" in mh:
-            o = o + mh["bo"]
-        x = x + o
-        h_, _ = blk.ln2.apply(p["ln2"], {}, x)
-        h_, _ = blk._fc1.apply(p["fc1"], {}, h_)
-        h_, _ = blk._fc2.apply(p["fc2"], {}, h_)
-        x = x + h_
+    def _stage(self, blk, moe, p, pm, x, attend):
+        """One (block, optional MoE) stage, where every program walks
+        one: the block's arithmetic (``TransformerBlock.forward``) with
+        the caller's cache behind ``attend``, then the no-drop branch of
+        a switch-``MoE`` layer that follows the block in the model."""
+        x = blk.forward(p, x, attend)
         if moe is not None:
             x = x + self._moe_nodrop(pm, x)
-        return x, cache_k, cache_v
+        return x
+
+    def _stage_chunk(self, blk, moe, p, pm, x, cache_k, cache_v, pos,
+                     qmask):
+        """A C-token chunk through one stage against its cache; single-
+        token decode is the C=1 case and the speculative verify passes
+        C=k+1. x: (B, C, d); caches: (B, T, H, Dh); pos: the chunk's
+        first position (K/V write offset); qmask: (C, T) bool, True
+        where chunk row c may attend cache position t."""
+        kv = []
+
+        def attend(q, k_new, v_new):
+            kv.extend(
+                jax.lax.dynamic_update_slice(
+                    cache, new.astype(cache.dtype), (0, pos, 0, 0)
+                )
+                for cache, new in ((cache_k, k_new), (cache_v, v_new))
+            )
+            return cache_attention(q, *kv, qmask)
+
+        x = self._stage(blk, moe, p, pm, x, attend)
+        return x, *kv
 
     def _prefill(self, bp, caches, x):
         """Run ``x`` (B, PP, d) pre-embedded prompt prefix through every
@@ -565,32 +557,19 @@ class CachedSequenceGenerator(SequenceGenerator):
         steps, so prefill and per-token outputs agree."""
         from distkeras_tpu.parallel.ring_attention import dense_attention
 
-        bsz, pp, _ = x.shape
-        nh = self._blocks[0].mhsa.num_heads
+        pp = x.shape[1]
         new_caches = []
         for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
             self._stages, bp, caches
         ):
-            mh = p["mhsa"]
-            hd = qshape(mh["wq"])[1] // nh
-            h_, _ = blk.ln1.apply(p["ln1"], {}, x)
-            q = qmatmul(h_, mh["wq"]).reshape(bsz, pp, nh, hd)
-            k = qmatmul(h_, mh["wk"]).reshape(bsz, pp, nh, hd)
-            v = qmatmul(h_, mh["wv"]).reshape(bsz, pp, nh, hd)
-            ck = ck.at[:, :pp].set(k.astype(ck.dtype))
-            cv = cv.at[:, :pp].set(v.astype(cv.dtype))
-            o = dense_attention(q, k, v, causal=True)
-            o = qmatmul(o.reshape(bsz, pp, nh * hd), mh["wo"])
-            if "bo" in mh:
-                o = o + mh["bo"]
-            x = x + o
-            h_, _ = blk.ln2.apply(p["ln2"], {}, x)
-            h_, _ = blk._fc1.apply(p["fc1"], {}, h_)
-            h_, _ = blk._fc2.apply(p["fc2"], {}, h_)
-            x = x + h_
-            if moe is not None:
-                x = x + self._moe_nodrop(pm, x)
-            new_caches.append((ck, cv))
+            def attend(q, k, v, ck=ck, cv=cv):
+                new_caches.append((
+                    ck.at[:, :pp].set(k.astype(ck.dtype)),
+                    cv.at[:, :pp].set(v.astype(cv.dtype)),
+                ))
+                return dense_attention(q, k, v, causal=True)
+
+            x = self._stage(blk, moe, p, pm, x, attend)
         return x, new_caches
 
     def _decode_prologue(self, params, ctx, prompt_len, cache_len=None):
@@ -675,12 +654,9 @@ class CachedSequenceGenerator(SequenceGenerator):
         out = sel * gate[:, None].astype(x.dtype)
         return out.reshape(*lead, d)
 
-    def _stages_decode(self, bp, caches, x, pos, t_mask):
-        """One token through every (block, optional MoE) stage against
-        the caches — the C=1 face of ``_stage_chunk``, run by the
-        greedy/ragged scan, beam search, and the speculative draft."""
-        x = x[:, None]  # (B, d) -> (B, 1, d)
-        qmask = t_mask[None, :]
+    def _stages_chunk(self, bp, caches, x, pos, qmask):
+        """A (B, C, d) chunk at positions pos..pos+C-1 through every
+        stage against the caches (``_stage_chunk`` a stage)."""
         new_caches = []
         for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
             self._stages, bp, caches
@@ -689,7 +665,16 @@ class CachedSequenceGenerator(SequenceGenerator):
                 blk, moe, p, pm, x, ck, cv, pos, qmask
             )
             new_caches.append((ck, cv))
-        return x[:, 0], new_caches
+        return x, new_caches
+
+    def _stages_decode(self, bp, caches, x, pos, t_mask):
+        """One token through every stage: the C=1 face of
+        ``_stages_chunk``, run by the greedy/ragged scan, beam search,
+        and the speculative draft."""
+        x, caches = self._stages_chunk(
+            bp, caches, x[:, None], pos, t_mask[None, :]
+        )
+        return x[:, 0], caches
 
     def _decode_fn(self, min_len, n_scan, steps, temp):
         """THE cached decode builder (rectangular = uniform lens). The
@@ -1005,21 +990,12 @@ class SpeculativeGenerator:
     def _extend(self, gen, bp, caches, x, pos, t_pad):
         """Run a (1, C, d) token chunk at positions pos..pos+C-1 through
         ``gen``'s stages against full-length caches: the verify side of
-        a round — the same ``_stage_chunk`` body as every other decode
-        path, at C=k+1 with chunk-causal masking."""
+        a round, at C=k+1 with chunk-causal masking."""
         c = x.shape[1]
         qmask = (
             jnp.arange(t_pad)[None, :] <= (pos + jnp.arange(c))[:, None]
         )
-        new_caches = []
-        for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
-            gen._stages, bp, caches
-        ):
-            x, ck, cv = gen._stage_chunk(
-                blk, moe, p, pm, x, ck, cv, pos, qmask
-            )
-            new_caches.append((ck, cv))
-        return x, new_caches
+        return gen._stages_chunk(bp, caches, x, pos, qmask)
 
     def _spec_decode_fn(self, prompt_len, steps):
         k = self.k

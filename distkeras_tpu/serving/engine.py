@@ -16,12 +16,16 @@ chunk-length bucket (powers of two, like the ragged generator's
 bucketed scan keys) — so continuous batching churns the logical batch
 composition at zero recompiles.
 
-Per-slot positions are the one thing the generators' shared
-``_stage_chunk`` body cannot express (its K/V write offset and query
-mask are batch-wide), so the step body here re-states the same
-attention math with a per-row write index and a per-row (B, T) mask;
-everything else — model-family parsing, param-group unpacking, MoE
-no-drop routing, the prompt prefill — is reused from the generator.
+A block's arithmetic is written once, in ``models/``
+(``TransformerBlock.forward``, ``LatentMoEBlock.forward``); a program
+here hands it a cache callable (``attend`` / ``exchange``) and owns
+nothing else of the block: where the new rows are written (per-row
+positions, pages, frozen slots), what is gathered or read in place, the
+mask. One callable a program form (per-slot positions: step and
+verify; one slot's chunk) and cache (dense bank, ``"kv"`` pages, latent
+pages), one walk a form for all of them. Family parsing, param-group
+unpacking, the stage with MoE no-drop routing (``_stage``) and the
+prompt prefill are the generator's.
 
 ``ServingEngine`` wraps the stepper in a ``ContinuousBatcher`` driven
 by a dedicated scheduler thread, adds a ``WindowedBatcher`` over
@@ -34,6 +38,7 @@ around the device phases.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import threading
 import time
@@ -85,6 +90,22 @@ def _bucket_pow2(n: int, cap: int) -> int:
     if n <= 0:
         return 0
     return min(1 << (n - 1).bit_length(), cap)
+
+
+def _lead(a, n: int):
+    """``a`` with ``n`` trailing axes of one (a per-slot vector against
+    per-slot rows of ``n`` more axes)."""
+    return a[(slice(None),) + (None,) * n]
+
+
+def _write_rows(cache, at, new, keep):
+    """``cache[at] = new`` where ``keep``; a row that is not decoding
+    gets back what it held, so one scatter serves every occupancy."""
+    import jax.numpy as jnp
+
+    return cache.at[at].set(
+        jnp.where(keep, new.astype(cache.dtype), cache[at])
+    )
 
 
 class _MintScope(threading.local):
@@ -886,7 +907,9 @@ class DecodeStepper:
         arguments built in NumPy for this call, and the tree's share on
         the host, which is 0 unless host arrays were re-bound to
         ``_params`` after the constructor placed them (a fault)."""
-        return self._params_host_bytes + sum(a.nbytes for a in host)
+        return self._params_host_bytes + sum(
+            a.nbytes for a in host if a is not None
+        )
 
     def paged_stats(self) -> dict:
         """Pool / allocator / device-prefix-index observability for the
@@ -2019,7 +2042,7 @@ class DecodeStepper:
             self._step_fns = {**self._step_fns, False: fn}
         self._ctx, self._caches, _ = fn(
             self._params, self._ctx, self._caches,
-            self._lens.copy(), active, *sargs,
+            self._lens.copy(), active, None, *sargs,
         )
         if self.drafter is not None:
             # compile the verify (all writes masked: numerically a
@@ -2034,7 +2057,7 @@ class DecodeStepper:
                 self._params, self._ctx, self._caches,
                 self._lens.copy(), active,
                 np.zeros((self.num_slots, self._kb), np.int32),
-                np.zeros((self.num_slots,), np.int32), *sargs,
+                np.zeros((self.num_slots,), np.int32), None, *sargs,
             )
             self.drafter.warmup()
 
@@ -2146,7 +2169,7 @@ class DecodeStepper:
                     self._step_fns = {**self._step_fns, True: fn}
                 self._ctx, self._caches, _ = fn(
                     self._params, self._ctx, self._caches,
-                    self._lens.copy(), active, *sargs, tmask,
+                    self._lens.copy(), active, None, *sargs, tmask,
                 )
                 if self.drafter is not None:
                     key = (self._kb + 1, True)
@@ -2159,7 +2182,7 @@ class DecodeStepper:
                     self._ctx, self._caches, _, _ = vfn(
                         self._params, self._ctx, self._caches,
                         self._lens.copy(), active, cand, cnt,
-                        *sargs, tmask,
+                        None, *sargs, tmask,
                     )
                 return
             # the masked STEP tracks the longest OCCUPIED table, so
@@ -2306,53 +2329,6 @@ class DecodeStepper:
         return self._jit(admit, donate=(1,), out="kv",
                          key=f"admit[{pb}]")
 
-    def _build_chunk_fn(self, cb: int):
-        """Compiled mid-prompt prefill chunk for bucket ``cb``: run the
-        chunk's tokens at positions ``start..start+cb-1`` through every
-        stage against the SLOT'S existing cache row — the generators'
-        shared ``_stage_chunk`` body (K/V write at ``start``, (C, T)
-        query mask), sliced to one slot so neighbours are untouched.
-        ``start`` is traced: one program per chunk-length bucket serves
-        every position and every slot."""
-        import jax
-        import jax.numpy as jnp
-
-        gen = self._gen
-        t, nh, hd = self._tp, self._nh, self._hd
-
-        def chunk(params, caches, toks, slot, start):
-            bp, p_emb, _, _ = self._unpack(params)
-            pos = start + jnp.arange(cb)  # (cb,) absolute positions
-            x = self._embed(p_emb, toks, pos)  # (1, cb, d)
-            qmask = jnp.arange(t)[None, :] <= pos[:, None]  # (cb, T)
-            out = []
-            for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
-                gen._stages, bp, caches
-            ):
-                rk = jax.lax.dynamic_slice(
-                    ck, (slot, 0, 0, 0), (1, t, nh, hd)
-                )
-                rv = jax.lax.dynamic_slice(
-                    cv, (slot, 0, 0, 0), (1, t, nh, hd)
-                )
-                x, rk, rv = gen._stage_chunk(
-                    blk, moe, p, pm, x, rk, rv, start, qmask
-                )
-                out.append(
-                    (
-                        jax.lax.dynamic_update_slice(
-                            ck, rk, (slot, 0, 0, 0)
-                        ),
-                        jax.lax.dynamic_update_slice(
-                            cv, rv, (slot, 0, 0, 0)
-                        ),
-                    )
-                )
-            return out
-
-        return self._jit(chunk, donate=(1,), out="kv",
-                         key=f"chunk[{cb}]")
-
     def _build_copy_fn(self):
         """Compiled prefix-cache restore: write the stacked per-stage
         host K/V rows ``(n_stages, pb, H, Dh)`` into one slot's cache
@@ -2379,319 +2355,18 @@ class DecodeStepper:
         return self._jit(copy, donate=(0,), out="kv",
                          key="restore")
 
-    # -- paged programs (attention over page pools) -------------------------
-    #
-    # The paged family restates the dense programs over a ``(num_pages,
-    # page_size, H, Dh)`` pool per stage; every K/V write scatters to
-    # the physical (page, offset) its logical position maps to. How a
-    # program ATTENDS is one of two bodies over the same pool:
-    #
-    # - the decode step, where ``self.attention == "kernel"`` (an
-    #   unsharded stepper, the ``"kv"`` layout, heads a whole number of
-    #   128 lanes wide, a bfloat16 or float32 pool:
-    #   ``ops.paged_attention.decode_attention_path``): after its page
-    #   write, ``paged_decode_attention`` reads each slot's own pages
-    #   from the written pool where they lie, as many as the slot's
-    #   length needs; nothing is gathered, converted or padded in HBM,
-    #   a slot that is not decoding costs nothing, and the table's
-    #   width costs nothing either, so ONE step program is compiled,
-    #   at ``_max_pages_bucket``;
-    # - everywhere else (the step under a ``tp`` mesh, with heads of 16
-    #   or 64, or in the latent layout's own stage body; the chunk,
-    #   verify and restore programs of every stepper) the GATHER body:
-    #   each slot's logical K/V row is ``pool[table]`` -> (B, pages,
-    #   page_size, H, Dh), reshaped to (B, T', H, Dh) with T' = bucket
-    #   * page_size. The gather step's keys add the pow2-bucketed page
-    #   count, so its extent tracks the longest OCCUPIED table and the
-    #   compile count stays O(log T); chunk and verify run at the
-    #   fixed full-capacity extent.
-    #
-    # Masks, the softmax and the sampling tail are the dense bodies'
-    # (the kernel folds the same softmax over blocks of pages, in
-    # float32), which is what keeps paged greedy output pinned
-    # token-identical. ``stats()["paged"]["attention"]`` and the
-    # ``serving/step`` span say which body a stepper's step runs.
-
-    def _build_step_fn_paged(self, pbt: int, masked=False):
-        """Compiled paged decode step for table bucket ``pbt``: the
-        dense ``_build_step_fn`` with the per-row cache write scattered
-        to ``table[row][pos // ps]`` and attention over the slot's
-        pages: read in place by ``paged_decode_attention`` where
-        ``self.attention == "kernel"``, gathered at the bucket's extent
-        otherwise. Inactive / short rows pad their tables with the null
-        sentinel page (writes masked to read-back, reads masked by the
-        position mask; the kernel never reads the pad), so one program
-        serves every occupancy. Sampling params are data (see
-        ``_build_step_fn``); ``masked`` adds the grammar-mask
-        argument."""
-        import jax
-        import jax.numpy as jnp
-
-        from distkeras_tpu.ops.paged_attention import (
-            paged_decode_attention,
-        )
-        from distkeras_tpu.ops.quantization import qmatmul, qshape
-        from distkeras_tpu.serving import sampling as _sp
-
-        gen = self._gen
-        b, ps = self.num_slots, self.page_size
-        t = pbt * ps  # gathered (logical) attention extent
-        tp = self._tp
-        in_place = self.attention == "kernel"
-
-        def stage_step(blk, moe, p, pm, x, pool, phys, off, table,
-                       pos, active):
-            ck, cv = pool
-            mh = p["mhsa"]
-            nh = blk.mhsa.num_heads
-            hd = qshape(mh["wq"])[1] // nh
-            h_, _ = blk.ln1.apply(p["ln1"], {}, x)
-            q = qmatmul(h_, mh["wq"]).reshape(b, nh, hd)
-            k_new = qmatmul(h_, mh["wk"]).reshape(b, nh, hd)
-            v_new = qmatmul(h_, mh["wv"]).reshape(b, nh, hd)
-            keep = active[:, None, None]
-            ck = ck.at[phys, off].set(
-                jnp.where(keep, k_new.astype(ck.dtype), ck[phys, off])
-            )
-            cv = cv.at[phys, off].set(
-                jnp.where(keep, v_new.astype(cv.dtype), cv[phys, off])
-            )
-            if in_place:
-                # positions <= pos of the slot's own pages, read from
-                # the written pool where they lie; nothing for a slot
-                # that is not decoding (its row is never used)
-                o = paged_decode_attention(
-                    q, ck, cv, table, jnp.where(active, pos + 1, 0)
-                )
-            else:
-                kg = ck[table].reshape(b, t, nh, hd)
-                vg = cv[table].reshape(b, t, nh, hd)
-                scores = jnp.einsum("bhd,bthd->bht", q, kg) / np.sqrt(hd)
-                t_mask = jnp.arange(t)[None, :] <= pos[:, None]  # (B, T')
-                scores = jnp.where(t_mask[:, None, :], scores, -jnp.inf)
-                w = jax.nn.softmax(scores, axis=-1)
-                o = jnp.einsum("bht,bthd->bhd", w, vg)
-            o = qmatmul(o.reshape(b, nh * hd), mh["wo"])
-            if "bo" in mh:
-                o = o + mh["bo"]
-            x = x + o
-            h_, _ = blk.ln2.apply(p["ln2"], {}, x)
-            h_, _ = blk._fc1.apply(p["fc1"], {}, h_)
-            h_, _ = blk._fc2.apply(p["fc2"], {}, h_)
-            x = x + h_
-            if moe is not None:
-                x = x + gen._moe_nodrop(pm, x)
-            return x, (ck, cv), None
-
-        latent = self.layout == "latent"
-        if latent:  # stage body and head by block kind, at build time
-            from distkeras_tpu.models.mla_moe import matmul, routing_counts
-
-            stage_step = self._latent_stage_step(pbt)
-
-            def head(p_head, x):  # bf16 x bf16 -> f32
-                return matmul(x, p_head["kernel"])
-        else:
-            def head(p_head, x):
-                return gen._head.apply(p_head, {}, x)[0]
-
-        def step(params, ctx, pools, lens, active, table, temps, topk,
-                 topp, seeds, spos, *rest):
-            bp, p_emb, p_ln, p_head = self._unpack(params)
-            pos = jnp.clip(lens - 1, 0, tp - 1)  # (B,) per-slot position
-            rows = jnp.arange(b)
-            tok = jnp.take_along_axis(ctx, pos[:, None], axis=1)[:, 0]
-            x = self._embed(p_emb, tok, pos)
-            phys = table[rows, jnp.clip(pos // ps, 0, pbt - 1)]
-            off = pos % ps
-            new_pools, routed = [], []
-            for (blk, _, moe, _), (p, pm), pool in zip(
-                gen._stages, bp, pools
-            ):
-                x, pool, sizes = stage_step(
-                    blk, moe, p, pm, x, pool, phys, off, table, pos,
-                    active,
-                )
-                new_pools.append(pool)
-                if sizes is not None:
-                    routed.append(sizes)
-            x, _ = gen._final_ln.apply(p_ln, {}, x)
-            logit = head(p_head, x)  # (B, V)
-            if masked:
-                logit = logit + rest[0]  # grammar mask (0 / -inf rows)
-            nxt = jax.lax.cond(
-                jnp.any(temps > 0.0),
-                lambda: _sp.sample_tokens(
-                    logit, temps, topk, topp, seeds, spos
-                ),
-                lambda: jnp.argmax(logit, axis=-1).astype(jnp.int32),
-            ).astype(ctx.dtype)
-            wpos = jnp.clip(pos + 1, 0, tp - 1)
-            cur = ctx[rows, wpos]
-            write = active & (pos + 1 <= tp - 1)
-            ctx = ctx.at[rows, wpos].set(jnp.where(write, nxt, cur))
-            if routed:
-                # the routing counters ride the tokens' fetch
-                nxt = jnp.concatenate(
-                    [nxt, routing_counts(routed).astype(nxt.dtype)]
-                )
-            return ctx, new_pools, nxt
-
-        return self._jit(
-            step, donate=(1, 2), out="step",
-            key=f"paged_step[{pbt}{',masked' if masked else ''}]",
-        )
-
-    def _build_chunk_fn_paged(self, cb: int, pbt: int):
-        """Compiled paged prefill chunk for (chunk bucket ``cb``, table
-        bucket ``pbt``): gather the slot's pages into its logical row,
-        run the generators' shared ``_stage_chunk`` body against it
-        (identical math to the dense chunk program), then scatter the
-        chunk's updated K/V positions back to their physical pages.
-        ``start`` is traced, so one program serves every position."""
-        import jax
-        import jax.numpy as jnp
-
-        gen = self._gen
-        ps, nh, hd = self.page_size, self._nh, self._hd
-        t = pbt * ps
-        if self.layout == "latent":
-            return self._jit(
-                self._latent_chunk_body(cb, pbt), donate=(1,), out="kv",
-                key=f"paged_chunk[{cb},{pbt}]",
-            )
-
-        def chunk(params, pools, toks, trow, start):
-            bp, p_emb, _, _ = self._unpack(params)
-            pos = start + jnp.arange(cb)  # (cb,) absolute positions
-            x = self._embed(p_emb, toks, pos)  # (1, cb, d)
-            qmask = jnp.arange(t)[None, :] <= pos[:, None]  # (cb, T')
-            fpos = (
-                trow[jnp.clip(pos // ps, 0, pbt - 1)] * ps + pos % ps
-            )  # (cb,) physical flat positions
-            out = []
-            for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
-                gen._stages, bp, pools
-            ):
-                rk = ck[trow].reshape(t, nh, hd)[None]
-                rv = cv[trow].reshape(t, nh, hd)[None]
-                x, rk, rv = gen._stage_chunk(
-                    blk, moe, p, pm, x, rk, rv, start, qmask
-                )
-                ku = jax.lax.dynamic_slice(
-                    rk, (0, start, 0, 0), (1, cb, nh, hd)
-                )[0]
-                vu = jax.lax.dynamic_slice(
-                    rv, (0, start, 0, 0), (1, cb, nh, hd)
-                )[0]
-                ck = (
-                    ck.reshape(-1, nh, hd)
-                    .at[fpos].set(ku.astype(ck.dtype))
-                    .reshape(ck.shape)
-                )
-                cv = (
-                    cv.reshape(-1, nh, hd)
-                    .at[fpos].set(vu.astype(cv.dtype))
-                    .reshape(cv.shape)
-                )
-                out.append((ck, cv))
-            return out
-
-        return self._jit(chunk, donate=(1,), out="kv",
-                         key=f"paged_chunk[{cb},{pbt}]")
-
-    def _latent_stage_step(self, pbt: int):
-        """The latent block's absorbed decode step for table bucket
-        ``pbt`` (its arithmetic is ``LatentMoEBlock.forward``): the
-        closure owns the page write and the gather of every slot's
-        latent pages, which stay in the pool's dtype (float32 is what
-        accumulates)."""
-        import jax.numpy as jnp
-
-        b, ps = self.num_slots, self.page_size
-        t = pbt * ps
-
-        def stage_step(blk, moe, p, pm, x, pool, phys, off, table, pos,
-                       active):
-            written = []
-
-            def exchange(new):  # (B, 1, latent_width) float32
-                at = phys * ps + off  # rows of the flat pool
-                row = jnp.where(
-                    active[:, None], self._pad_row(new[:, 0], pool),
-                    pool[at],
-                )
-                written.append(pool.at[at].set(row))
-                pages = written[0].reshape(-1, ps, pool.shape[-1])
-                return pages[table].reshape(b, t, -1)[..., :new.shape[-1]]
-
-            t_mask = (jnp.arange(t)[None, :] <= pos[:, None])[:, None]
-            x, sizes = blk.forward(
-                p, x[:, None], pos[:, None], t_mask, exchange,
-                absorbed=True, token_mask=active[:, None],
-            )
-            return x[:, 0], written[0], sizes
-
-        return stage_step
-
-    def _latent_chunk_body(self, cb: int, pbt: int):
-        """The latent block's prefill chunk: the slot's pages gathered
-        into its logical latent row, the chunk's own rows written into
-        it, expanded attention over the row (``LatentMoEBlock.forward``,
-        the same arithmetic as the step's and as ``apply``'s), and the
-        chunk's rows scattered back to their physical pages."""
-        import jax
-        import jax.numpy as jnp
-
-        gen = self._gen
-        ps = self.page_size
-        t = pbt * ps
-
-        def chunk(params, pools, toks, trow, start):
-            bp, p_emb, _, _ = self._unpack(params)
-            pos = start + jnp.arange(cb)  # (cb,) absolute positions
-            x = self._embed(p_emb, toks, pos)  # (1, cb, d)
-            qmask = (jnp.arange(t)[None, :] <= pos[:, None])[None]
-            fpos = (
-                trow[jnp.clip(pos // ps, 0, pbt - 1)] * ps + pos % ps
-            )  # (cb,) physical flat positions
-            out = []
-            for (blk, _, _, _), (p, _), pool in zip(gen._stages, bp, pools):
-                written = []
-
-                def exchange(new, pool=pool, written=written):
-                    rows = self._pad_row(new[0], pool)  # (cb, row width)
-                    written.append(pool.at[fpos].set(rows))
-                    row = pool.reshape(-1, ps, pool.shape[-1])[trow]
-                    return jax.lax.dynamic_update_slice(
-                        row.reshape(t, -1), rows, (start, 0)
-                    )[None, :, :new.shape[-1]]
-
-                x, _ = blk.forward(
-                    p, x, pos[None], qmask, exchange,
-                    n_keys=jnp.minimum(start + cb, t),
-                )
-                out.append(written[0])
-            return out
-
-        return chunk
-
     def _build_copy_fn_paged(self, pbk: int, pbt: int):
         """Compiled paged prefix restore: scatter the stacked per-stage
         host K/V rows ``(n_stages, pbk, H, Dh)`` to the physical flat
         positions the slot's leading logical positions map to. Bucket
         padding past the real prefix lands at later reserved positions
         (clamped to the table), overwritten before anything attends it."""
-        import jax
         import jax.numpy as jnp
 
-        ps, nh, hd = self.page_size, self._nh, self._hd
+        nh, hd = self._nh, self._hd
 
         def copy(pools, ks, vs, trow):
-            pvec = jnp.arange(pbk)
-            fpos = (
-                trow[jnp.clip(pvec // ps, 0, pbt - 1)] * ps + pvec % ps
-            )
+            fpos = self._flat_positions(trow, jnp.arange(pbk), pbt)
             out = []
             for si, (ck, cv) in enumerate(pools):
                 out.append(
@@ -2709,65 +2384,163 @@ class DecodeStepper:
         return self._jit(copy, donate=(0,), out="kv",
                          key=f"paged_restore[{pbk},{pbt}]")
 
-    def _build_verify_fn_paged(self, c: int, pbt: int, masked=False):
-        """Compiled paged speculative verify for (``c`` candidates,
-        table bucket ``pbt``): the dense ``_build_verify_fn`` with the
-        (B, C) candidate K/V writes scattered to their physical pages
-        and attention over the gathered extent. Scratch overrun lands
-        in the slot's reserved scratch pages (``pages_for`` includes
-        the verify window), exactly as the dense pad absorbs it.
-        Sampling/acceptance and the ``masked`` grammar variant follow
-        ``_build_verify_fn``."""
+    # -- the programs -------------------------------------------------------
+    #
+    # A program form is one stage walk, whatever holds the cache
+    # (``_step_program``, ``_verify_program``, ``_chunk_program``), and a
+    # block's arithmetic is its ``forward`` in ``models/``. The dense
+    # bank, the ``"kv"`` page pool and the latent page pool each bring a
+    # CACHE CALLABLE a form and nothing else, picked by
+    # ``_cache_callable(form, pbt)`` when a program is built. A trace
+    # calls it once with what the stages share (step and verify:
+    # ``(table, rows, pos, active)``, the table None for the bank; chunk:
+    # ``(where, start, pos)``) and gets ``stage(blk, moe, p, pm, x,
+    # cache) -> (x, cache, group sizes or None)``, which owns where new
+    # rows go and what is attended:
+    #
+    # - the bank: each slot's ``(T, H, Dh)`` row, written at the row's
+    #   own positions and attended whole;
+    # - ``"kv"`` pages: a ``(num_pages, page_size, H, Dh)`` pool per
+    #   stage; a write scatters to the (page, offset) of its logical
+    #   position. Where ``self.attention == "kernel"`` (unsharded, heads
+    #   a whole number of 128 lanes, a bfloat16 or float32 pool:
+    #   ``ops.paged_attention.decode_attention_path``) the decode step
+    #   then reads each slot's own pages where they lie: nothing
+    #   gathered, converted or padded, a slot not decoding costs
+    #   nothing, nor does the table's width, so ONE step program, at
+    #   ``_max_pages_bucket``. Everything else GATHERS ``pool[table]``
+    #   -> (B, T' = bucket * page_size, H, Dh): the step under a ``tp``
+    #   mesh or with heads of 16 or 64 (keyed by the pow2-bucketed page
+    #   count: its extent tracks the longest OCCUPIED table at O(log T)
+    #   compiles), and chunk, verify and restore at the full extent;
+    # - latent pages: a ``(num_pages x page_size, row)`` pool per stage,
+    #   written and gathered by ``LatentMoEBlock.forward``'s ``exchange``.
+    #
+    # Masks, the softmax (``models.layers.cache_attention``; the kernel
+    # folds the same softmax over blocks of pages, in float32) and the
+    # sampling tail are shared: paged greedy output stays token-identical
+    # to the bank's and to solo decode. ``stats()["paged"]["attention"]``
+    # and the ``serving/step`` span say how a paged step attends.
+
+    def _cache_callable(self, form: str, pbt):
+        """What the bank or the page layout decides for a program of
+        ``form`` (``"step"``, ``"verify"``, ``"chunk"``) at table bucket
+        ``pbt`` (None: the bank), picked once when its builder runs:
+        the form's cache callable and how the head multiplies."""
+        def head(p_head, x):
+            return self._gen._head.apply(p_head, {}, x)[0]
+
+        chunk = form == "chunk"
+        if not self.paged:
+            return self._bank_chunk if chunk else self._bank_rows, head
+        if self.layout == "latent":
+            from distkeras_tpu.models.mla_moe import matmul
+
+            cache = self._latent_chunk if chunk else self._latent_rows
+            return (
+                functools.partial(cache, pbt),
+                lambda p_head, x: matmul(x, p_head["kernel"]),  # bf16
+            )
+        if chunk:
+            return functools.partial(self._kv_chunk, pbt), head
+        # the kernel attends one token a slot: the step, not the verify
+        in_place = form == "step" and self.attention == "kernel"
+        return functools.partial(self._kv_rows, pbt, in_place), head
+
+    def _step_program(self, pbt, masked, key):
+        """The decode step, dense (``pbt`` None) or paged at table
+        bucket ``pbt``: every active slot's last token at its own
+        position through the stages, then the sampling tail. Sampling
+        params are DATA (per-slot arrays), never part of the compile
+        key: one program serves greedy and sampled slots mixed, and an
+        all-greedy batch takes the argmax fast path (``lax.cond`` on
+        ``any(temps > 0)``), bit-identical to the pre-sampling program.
+        ``masked`` selects the grammar variant (an extra (B, V) additive
+        mask argument); unconstrained traffic never compiles or pays it.
+        Inactive / short rows pad their tables with the null sentinel
+        page (writes masked to read-back, reads masked by the position
+        mask; the kernel never reads the pad), so one program serves
+        every occupancy."""
         import jax
         import jax.numpy as jnp
 
-        from distkeras_tpu.ops.quantization import qmatmul, qshape
+        from distkeras_tpu.models.mla_moe import routing_counts
         from distkeras_tpu.serving import sampling as _sp
 
         gen = self._gen
-        b, tp, ml = self.num_slots, self._tp, self.max_len
-        ps = self.page_size
-        t = pbt * ps
+        b, tp = self.num_slots, self._tp
+        cache_of, head = self._cache_callable("step", pbt)
 
-        def stage_verify(blk, moe, p, pm, x, ck, cv, phys, offs, table,
-                         cpos, active):
-            mh = p["mhsa"]
-            nh = blk.mhsa.num_heads
-            hd = qshape(mh["wq"])[1] // nh
-            h_, _ = blk.ln1.apply(p["ln1"], {}, x)
-            q = qmatmul(h_, mh["wq"]).reshape(b, c, nh, hd)
-            k_new = qmatmul(h_, mh["wk"]).reshape(b, c, nh, hd)
-            v_new = qmatmul(h_, mh["wv"]).reshape(b, c, nh, hd)
-            keep = active[:, None, None, None]
-            ck = ck.at[phys, offs].set(
-                jnp.where(keep, k_new.astype(ck.dtype), ck[phys, offs])
-            )
-            cv = cv.at[phys, offs].set(
-                jnp.where(keep, v_new.astype(cv.dtype), cv[phys, offs])
-            )
-            kg = ck[table].reshape(b, t, nh, hd)
-            vg = cv[table].reshape(b, t, nh, hd)
-            scores = jnp.einsum("bchd,bthd->bhct", q, kg) / np.sqrt(hd)
-            t_mask = jnp.arange(t)[None, None, :] <= cpos[:, :, None]
-            scores = jnp.where(t_mask[:, None], scores, -jnp.inf)
-            w = jax.nn.softmax(scores, axis=-1)
-            o = jnp.einsum("bhct,bthd->bchd", w, vg).reshape(
-                b, c, nh * hd
-            )
-            o = qmatmul(o, mh["wo"])
-            if "bo" in mh:
-                o = o + mh["bo"]
-            x = x + o
-            h_, _ = blk.ln2.apply(p["ln2"], {}, x)
-            h_, _ = blk._fc1.apply(p["fc1"], {}, h_)
-            h_, _ = blk._fc2.apply(p["fc2"], {}, h_)
-            x = x + h_
-            if moe is not None:
-                x = x + gen._moe_nodrop(pm, x)
-            return x, ck, cv
+        def step(params, ctx, caches, lens, active, table,
+                 temps, topk, topp, seeds, spos, *mask):
+            bp, p_emb, p_ln, p_head = self._unpack(params)
+            pos = jnp.clip(lens - 1, 0, tp - 1)  # (B,) per-slot position
+            rows = jnp.arange(b)
+            tok = jnp.take_along_axis(ctx, pos[:, None], axis=1)[:, 0]
+            x = self._embed(p_emb, tok, pos)
+            stage = cache_of(table, rows, pos, active)
+            new_caches, routed = [], []
+            for (blk, _, moe, _), (p, pm), cache in zip(
+                gen._stages, bp, caches
+            ):
+                x, cache, sizes = stage(blk, moe, p, pm, x, cache)
+                new_caches.append(cache)
+                if sizes is not None:
+                    routed.append(sizes)
+            x, _ = gen._final_ln.apply(p_ln, {}, x)
+            logit = head(p_head, x)  # (B, V)
+            if masked:
+                logit = logit + mask[0]  # grammar mask (0 / -inf rows)
+            nxt = jax.lax.cond(
+                jnp.any(temps > 0.0),
+                lambda: _sp.sample_tokens(
+                    logit, temps, topk, topp, seeds, spos
+                ),
+                lambda: jnp.argmax(logit, axis=-1).astype(jnp.int32),
+            ).astype(ctx.dtype)
+            wpos = jnp.clip(pos + 1, 0, tp - 1)
+            cur = ctx[rows, wpos]
+            write = active & (pos + 1 <= tp - 1)
+            ctx = ctx.at[rows, wpos].set(jnp.where(write, nxt, cur))
+            if routed:
+                # the routing counters ride the tokens' fetch
+                nxt = jnp.concatenate(
+                    [nxt, routing_counts(routed).astype(nxt.dtype)]
+                )
+            return ctx, new_caches, nxt
 
-        def verify(params, ctx, pools, lens, active, dtoks, dcnt,
-                   table, temps, topk, topp, seeds, spos, *rest):
+        return self._jit(step, donate=(1, 2), out="step", key=key)
+
+    def _verify_program(self, c: int, pbt, masked, key):
+        """The speculative verify for ``c`` candidates per slot (the
+        slot's last real token plus ``c-1`` draft proposals; ``c`` is
+        the pow2 ``draft_k`` bucket + 1, the chunk-program discipline),
+        dense or paged at table bucket ``pbt``. One call scores every
+        candidate position of every active slot against the live cache
+        (the step's cache callable at (B, C) positions, gathered where
+        the step reads in place), computes the
+        accepted window (greedy rows by longest argmax agreement,
+        sampled rows by rejection sampling,
+        ``sampling.spec_window_tokens``) and writes the accepted tokens
+        into the context rows; the scheduler reads back only (tokens,
+        counts). K/V and context writes past the real sequence land in
+        the scratch pad (``_tp``; paged: the slot's reserved scratch
+        pages, ``pages_for`` includes the verify window); inactive
+        slots are frozen throughout. ``masked`` adds the grammar mask
+        argument, applied to candidate 0 only: constrained slots never
+        draft (``spec_step`` zeroes their proposals), so candidate 0 is
+        the single token they emit per window."""
+        import jax
+        import jax.numpy as jnp
+
+        from distkeras_tpu.serving import sampling as _sp
+
+        gen = self._gen
+        b, ml = self.num_slots, self.max_len
+        cache_of, head = self._cache_callable("verify", pbt)
+
+        def verify(params, ctx, caches, lens, active, dtoks, dcnt, table,
+                   temps, topk, topp, seeds, spos, *mask):
             bp, p_emb, p_ln, p_head = self._unpack(params)
             pos = jnp.clip(lens - 1, 0, ml - 1)  # (B,)
             rows = jnp.arange(b)
@@ -2775,23 +2548,17 @@ class DecodeStepper:
             chunk = jnp.concatenate([tok0[:, None], dtoks], axis=1)
             cpos = pos[:, None] + jnp.arange(c)[None, :]  # (B, C) < tp
             x = self._embed(p_emb, chunk, cpos)  # (B, C, d)
-            phys = table[
-                rows[:, None], jnp.clip(cpos // ps, 0, pbt - 1)
-            ]  # (B, C)
-            offs = cpos % ps
-            new_pools = []
-            for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
-                gen._stages, bp, pools
+            stage = cache_of(table, rows, cpos, active)
+            new_caches = []
+            for (blk, _, moe, _), (p, pm), cache in zip(
+                gen._stages, bp, caches
             ):
-                x, ck, cv = stage_verify(
-                    blk, moe, p, pm, x, ck, cv, phys, offs, table,
-                    cpos, active,
-                )
-                new_pools.append((ck, cv))
+                x, cache, _ = stage(blk, moe, p, pm, x, cache)
+                new_caches.append(cache)
             x, _ = gen._final_ln.apply(p_ln, {}, x)
-            logit, _ = gen._head.apply(p_head, {}, x)  # (B, C, V)
+            logit = head(p_head, x)  # (B, C, V)
             if masked:
-                logit = logit.at[:, 0].add(rest[0])
+                logit = logit.at[:, 0].add(mask[0])
             out, n_new = jax.lax.cond(
                 jnp.any(temps > 0.0),
                 lambda: _sp.spec_window_tokens(
@@ -2807,12 +2574,287 @@ class DecodeStepper:
             rows2 = rows[:, None]
             cur = ctx[rows2, wpos]
             ctx = ctx.at[rows2, wpos].set(jnp.where(keep, out, cur))
-            return ctx, new_pools, out, n_new
+            return ctx, new_caches, out, n_new
 
-        return self._jit(
-            verify, donate=(1, 2), out="verify",
-            key=f"paged_verify[{c},{pbt}{',masked' if masked else ''}]",
+        return self._jit(verify, donate=(1, 2), out="verify", key=key)
+
+    def _chunk_program(self, cb: int, pbt, key):
+        """The mid-prompt prefill chunk for chunk bucket ``cb``, dense
+        or paged at table bucket ``pbt``: the chunk's tokens at
+        positions ``start..start+cb-1`` through every stage against ONE
+        slot's cache (``where``: its slot, or its page-table row), so
+        neighbours are untouched. ``start`` is traced: one program per
+        bucket serves every position and every slot."""
+        import jax.numpy as jnp
+
+        gen = self._gen
+        cache_of, _ = self._cache_callable("chunk", pbt)
+
+        def chunk(params, caches, toks, where, start):
+            bp, p_emb, _, _ = self._unpack(params)
+            pos = start + jnp.arange(cb)  # (cb,) absolute positions
+            x = self._embed(p_emb, toks, pos)  # (1, cb, d)
+            stage = cache_of(where, start, pos)
+            out = []
+            for (blk, _, moe, _), (p, pm), cache in zip(
+                gen._stages, bp, caches
+            ):
+                x, cache, _ = stage(blk, moe, p, pm, x, cache)
+                out.append(cache)
+            return out
+
+        return self._jit(chunk, donate=(1,), out="kv", key=key)
+
+    # the builders' names and ``_jit`` keys, as the call sites, the
+    # compile ledger and ``compiled_step_buckets`` know them
+
+    def _build_step_fn(self, masked=False):
+        return self._step_program(
+            None, masked, f"step[{'masked' if masked else 'plain'}]"
         )
+
+    def _build_step_fn_paged(self, pbt: int, masked=False):
+        return self._step_program(
+            pbt, masked,
+            f"paged_step[{pbt}{',masked' if masked else ''}]",
+        )
+
+    def _build_verify_fn(self, c: int, masked=False):
+        return self._verify_program(
+            c, None, masked, f"verify[{c}{',masked' if masked else ''}]"
+        )
+
+    def _build_verify_fn_paged(self, c: int, pbt: int, masked=False):
+        return self._verify_program(
+            c, pbt, masked,
+            f"paged_verify[{c},{pbt}{',masked' if masked else ''}]",
+        )
+
+    def _build_chunk_fn(self, cb: int):
+        return self._chunk_program(cb, None, f"chunk[{cb}]")
+
+    def _build_chunk_fn_paged(self, cb: int, pbt: int):
+        return self._chunk_program(cb, pbt, f"paged_chunk[{cb},{pbt}]")
+
+    # -- the cache callables ------------------------------------------------
+
+    def _bank_rows(self, table, rows, pos, active):
+        """The dense bank (``table`` is None) at per-slot positions
+        ``pos``: (B,) for the step, (B, C) for the verify. K/V written
+        at each row's own positions, frozen where a slot is inactive; a
+        row attends itself up to its own position."""
+        import jax.numpy as jnp
+
+        from distkeras_tpu.models.layers import cache_attention
+
+        gen, t = self._gen, self._tp
+
+        def stage(blk, moe, p, pm, x, cache):
+            kv = []
+
+            def attend(q, k_new, v_new):
+                at = (_lead(rows, pos.ndim - 1), pos)
+                keep = _lead(active, pos.ndim + 1)
+                kv.extend(
+                    _write_rows(c, at, new, keep)
+                    for c, new in zip(cache, (k_new, v_new))
+                )
+                return cache_attention(
+                    q, *kv, jnp.arange(t) <= pos[..., None]
+                )
+
+            x = gen._stage(blk, moe, p, pm, x, attend)
+            return x, tuple(kv), None
+
+        return stage
+
+    def _bank_chunk(self, slot, start, pos):
+        """The dense bank, one slot's chunk: the slot's row sliced out,
+        the generators' ``_stage_chunk`` against it (K/V write at
+        ``start``, (C, T) query mask), the row written back."""
+        import jax
+        import jax.numpy as jnp
+
+        gen, t = self._gen, self._tp
+        row = (1, t, self._nh, self._hd)  # one slot's cache row
+        qmask = jnp.arange(t)[None, :] <= pos[:, None]  # (cb, T)
+
+        def stage(blk, moe, p, pm, x, cache):
+            x, *new = gen._stage_chunk(
+                blk, moe, p, pm, x,
+                *(jax.lax.dynamic_slice(c, (slot, 0, 0, 0), row)
+                  for c in cache),
+                start, qmask,
+            )
+            return x, tuple(
+                jax.lax.dynamic_update_slice(c, r, (slot, 0, 0, 0))
+                for c, r in zip(cache, new)
+            ), None
+
+        return stage
+
+    def _page_of(self, table, rows, pos, pbt: int):
+        """(page, offset) of each slot's logical positions ``pos``."""
+        import jax.numpy as jnp
+
+        ps = self.page_size
+        page = table[
+            _lead(rows, pos.ndim - 1), jnp.clip(pos // ps, 0, pbt - 1)
+        ]
+        return page, pos % ps
+
+    def _flat_positions(self, trow, pos, pbt: int):
+        """Rows of the flat pool that one slot's logical positions
+        ``pos`` map to through its page-table row ``trow``."""
+        import jax.numpy as jnp
+
+        ps = self.page_size
+        return trow[jnp.clip(pos // ps, 0, pbt - 1)] * ps + pos % ps
+
+    def _kv_rows(self, pbt: int, in_place: bool, table, rows, pos, active):
+        """``"kv"`` pages at per-slot positions ``pos`` ((B,) step, (B,
+        C) verify): the masked page write, then ``in_place`` (the step
+        where ``self.attention == "kernel"``) ``paged_decode_attention``
+        over the slot's own pages in the written pool (positions <= pos;
+        nothing for a slot that is not decoding, whose row is never
+        used), the pages gathered at the bucket's extent under the
+        position mask otherwise."""
+        import jax.numpy as jnp
+
+        from distkeras_tpu.models.layers import cache_attention
+        from distkeras_tpu.ops.paged_attention import (
+            paged_decode_attention,
+        )
+
+        gen = self._gen
+        b, t = self.num_slots, pbt * self.page_size
+        at = self._page_of(table, rows, pos, pbt)
+
+        def stage(blk, moe, p, pm, x, pool):
+            kv = []
+
+            def attend(q, k_new, v_new):
+                keep = _lead(active, pos.ndim + 1)
+                kv.extend(
+                    _write_rows(c, at, new, keep)
+                    for c, new in zip(pool, (k_new, v_new))
+                )
+                if in_place:
+                    return paged_decode_attention(
+                        q, *kv, table, jnp.where(active, pos + 1, 0)
+                    )
+                kg, vg = (
+                    c[table].reshape(b, t, *q.shape[-2:]) for c in kv
+                )
+                return cache_attention(
+                    q, kg, vg, jnp.arange(t) <= pos[..., None]
+                )
+
+            x = gen._stage(blk, moe, p, pm, x, attend)
+            return x, tuple(kv), None
+
+        return stage
+
+    def _kv_chunk(self, pbt: int, trow, start, pos):
+        """``"kv"`` pages, one slot's chunk: its pages gathered into its
+        logical row, the generators' ``_stage_chunk`` against the row
+        (as the bank's chunk), the chunk's updated positions scattered
+        back to their physical pages."""
+        import jax
+        import jax.numpy as jnp
+
+        gen = self._gen
+        ps, nh, hd = self.page_size, self._nh, self._hd
+        cb, t = pos.shape[0], pbt * ps
+        qmask = jnp.arange(t)[None, :] <= pos[:, None]  # (cb, T')
+        fpos = self._flat_positions(trow, pos, pbt)  # (cb,)
+
+        def stage(blk, moe, p, pm, x, pool):
+            x, *rows = gen._stage_chunk(
+                blk, moe, p, pm, x,
+                *(c[trow].reshape(t, nh, hd)[None] for c in pool),
+                start, qmask,
+            )
+            new = [
+                jax.lax.dynamic_slice(
+                    r, (0, start, 0, 0), (1, cb, nh, hd)
+                )[0]
+                for r in rows
+            ]
+            return x, tuple(
+                c.reshape(-1, nh, hd)
+                .at[fpos].set(u.astype(c.dtype))
+                .reshape(c.shape)
+                for c, u in zip(pool, new)
+            ), None
+
+        return stage
+
+    def _latent_rows(self, pbt: int, table, rows, pos, active):
+        """Latent pages, one token a slot (the absorbed form of
+        ``LatentMoEBlock.forward``): ``exchange`` owns the page write
+        and the gather of every slot's latent pages, which stay in the
+        pool's dtype (float32 is what accumulates)."""
+        import jax.numpy as jnp
+
+        b, ps = self.num_slots, self.page_size
+        t = pbt * ps
+        phys, off = self._page_of(table, rows, pos, pbt)
+
+        def stage(blk, moe, p, pm, x, pool):
+            written = []
+
+            def exchange(new):  # (B, 1, latent_width) float32
+                at = phys * ps + off  # rows of the flat pool
+                row = jnp.where(
+                    active[:, None], self._pad_row(new[:, 0], pool),
+                    pool[at],
+                )
+                written.append(pool.at[at].set(row))
+                pages = written[0].reshape(-1, ps, pool.shape[-1])
+                return pages[table].reshape(b, t, -1)[
+                    ..., :new.shape[-1]]
+
+            t_mask = (jnp.arange(t)[None, :] <= pos[:, None])[:, None]
+            x, sizes = blk.forward(
+                p, x[:, None], pos[:, None], t_mask, exchange,
+                absorbed=True, token_mask=active[:, None],
+            )
+            return x[:, 0], written[0], sizes
+
+        return stage
+
+    def _latent_chunk(self, pbt: int, trow, start, pos):
+        """Latent pages, one slot's chunk (the expanded form of
+        ``LatentMoEBlock.forward``): ``exchange`` scatters the chunk's
+        rows to their physical pages and returns the slot's gathered
+        logical row with the chunk's own rows written into it."""
+        import jax
+        import jax.numpy as jnp
+
+        ps = self.page_size
+        cb, t = pos.shape[0], pbt * ps
+        qmask = (jnp.arange(t)[None, :] <= pos[:, None])[None]
+        fpos = self._flat_positions(trow, pos, pbt)  # (cb,)
+
+        def stage(blk, moe, p, pm, x, pool):
+            written = []
+
+            def exchange(new):
+                rows = self._pad_row(new[0], pool)  # (cb, row width)
+                written.append(pool.at[fpos].set(rows))
+                row = pool.reshape(-1, ps, pool.shape[-1])[trow]
+                return jax.lax.dynamic_update_slice(
+                    row.reshape(t, -1), rows, (start, 0)
+                )[None, :, :new.shape[-1]]
+
+            x, sizes = blk.forward(
+                p, x, pos[None], qmask, exchange,
+                n_keys=jnp.minimum(start + cb, t),
+            )
+            return x, written[0], sizes
+
+        return stage
 
     # -- the decode step ----------------------------------------------------
 
@@ -2853,17 +2895,17 @@ class DecodeStepper:
                     self._compiling()
                     fn = self._build_step_fn_paged(pbt, masked)
                     self._pstep_fns = {**self._pstep_fns, key: fn}
-                tables = (self._tables_array(pbt),)
+                table = self._tables_array(pbt)
             else:
                 fn = self._step_fns.get(masked)
                 if fn is None:
                     self._compiling()
                     fn = self._build_step_fn(masked)
                     self._step_fns = {**self._step_fns, masked: fn}
-                tables = ()
+                table = None  # the bank has none
             # every argument of the step that is built on the host
             host = (
-                self._lens.copy(), active, *tables,
+                self._lens.copy(), active, table,
                 *self._sampling_args(), *((tmask,) if masked else ()),
             )
             self.host_arg_bytes_step = self._host_arg_bytes(host)
@@ -2880,97 +2922,6 @@ class DecodeStepper:
                     self._params, self._ctx, self._caches, *host
                 )
         return _InflightStep(self, active, toks)
-
-    def _build_step_fn(self, masked=False):
-        """Compiled dense decode step. Sampling params are DATA (per-
-        slot arrays), never part of the compile key: one program serves
-        greedy and sampled slots mixed, and an all-greedy batch takes
-        the argmax fast path (``lax.cond`` on ``any(temps > 0)``) —
-        output bit-identical to the pre-sampling program. ``masked``
-        selects the grammar variant (an extra (B, V) additive mask
-        argument); unconstrained traffic never compiles or pays it."""
-        import jax
-        import jax.numpy as jnp
-
-        from distkeras_tpu.ops.quantization import qmatmul, qshape
-        from distkeras_tpu.serving import sampling as _sp
-
-        gen = self._gen
-        b, t = self.num_slots, self._tp
-
-        def stage_step(blk, moe, p, pm, x, ck, cv, pos, active):
-            """One token per slot through one (block, optional MoE)
-            stage: the per-slot-position restatement of the generators'
-            ``_stage_chunk`` C=1 body — K/V write at each row's own
-            ``pos``, query mask per row, writes frozen where inactive."""
-            mh = p["mhsa"]
-            nh = blk.mhsa.num_heads
-            hd = qshape(mh["wq"])[1] // nh
-            h_, _ = blk.ln1.apply(p["ln1"], {}, x)
-            q = qmatmul(h_, mh["wq"]).reshape(b, nh, hd)
-            k_new = qmatmul(h_, mh["wk"]).reshape(b, nh, hd)
-            v_new = qmatmul(h_, mh["wv"]).reshape(b, nh, hd)
-            rows = jnp.arange(b)
-            keep = active[:, None, None]
-            ck = ck.at[rows, pos].set(
-                jnp.where(keep, k_new.astype(ck.dtype), ck[rows, pos])
-            )
-            cv = cv.at[rows, pos].set(
-                jnp.where(keep, v_new.astype(cv.dtype), cv[rows, pos])
-            )
-            scores = jnp.einsum("bhd,bthd->bht", q, ck) / np.sqrt(hd)
-            t_mask = jnp.arange(t)[None, :] <= pos[:, None]  # (B, T)
-            scores = jnp.where(t_mask[:, None, :], scores, -jnp.inf)
-            w = jax.nn.softmax(scores, axis=-1)
-            o = jnp.einsum("bht,bthd->bhd", w, cv).reshape(b, nh * hd)
-            o = qmatmul(o, mh["wo"])
-            if "bo" in mh:
-                o = o + mh["bo"]
-            x = x + o
-            h_, _ = blk.ln2.apply(p["ln2"], {}, x)
-            h_, _ = blk._fc1.apply(p["fc1"], {}, h_)
-            h_, _ = blk._fc2.apply(p["fc2"], {}, h_)
-            x = x + h_
-            if moe is not None:
-                x = x + gen._moe_nodrop(pm, x)
-            return x, ck, cv
-
-        def step(params, ctx, caches, lens, active, temps, topk, topp,
-                 seeds, spos, *rest):
-            bp, p_emb, p_ln, p_head = self._unpack(params)
-            pos = jnp.clip(lens - 1, 0, t - 1)  # (B,) per-slot position
-            tok = jnp.take_along_axis(ctx, pos[:, None], axis=1)[:, 0]
-            x = self._embed(p_emb, tok, pos)
-            new_caches = []
-            for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
-                gen._stages, bp, caches
-            ):
-                x, ck, cv = stage_step(
-                    blk, moe, p, pm, x, ck, cv, pos, active
-                )
-                new_caches.append((ck, cv))
-            x, _ = gen._final_ln.apply(p_ln, {}, x)
-            logit, _ = gen._head.apply(p_head, {}, x)  # (B, V)
-            if masked:
-                logit = logit + rest[0]  # grammar mask (0 / -inf rows)
-            nxt = jax.lax.cond(
-                jnp.any(temps > 0.0),
-                lambda: _sp.sample_tokens(
-                    logit, temps, topk, topp, seeds, spos
-                ),
-                lambda: jnp.argmax(logit, axis=-1).astype(jnp.int32),
-            ).astype(ctx.dtype)
-            wpos = jnp.clip(pos + 1, 0, t - 1)
-            rows = jnp.arange(b)
-            cur = ctx[rows, wpos]
-            write = active & (pos + 1 <= t - 1)
-            ctx = ctx.at[rows, wpos].set(jnp.where(write, nxt, cur))
-            return ctx, new_caches, nxt
-
-        return self._jit(
-            step, donate=(1, 2), out="step",
-            key=f"step[{'masked' if masked else 'plain'}]",
-        )
 
     # -- speculative decode (draft -> verify -> rollback) -------------------
 
@@ -3096,7 +3047,7 @@ class DecodeStepper:
                 self._ctx, self._caches, t_out, n_new = fn(
                     self._params, self._ctx, self._caches, lens0,
                     active, dtoks.astype(np.int32),
-                    dcnt.astype(np.int32), *sargs, *extra,
+                    dcnt.astype(np.int32), None, *sargs, *extra,
                 )
         t_out = np.asarray(t_out)
         counts = np.where(active, np.asarray(n_new), 0).astype(np.int64)
@@ -3141,116 +3092,6 @@ class DecodeStepper:
             lens0.astype(np.int32), counts.astype(np.int32),
             np.asarray(active, bool),
         )
-
-    def _build_verify_fn(self, c: int, masked=False):
-        """Compiled speculative verify for ``c`` candidates per slot
-        (the slot's last real token plus ``c-1`` draft proposals —
-        ``c`` is the pow2 ``draft_k`` bucket + 1, the chunk-program
-        discipline). One call scores every candidate position of every
-        active slot against the live caches (the generators'
-        ``_stage_chunk`` math restated with PER-ROW write offsets,
-        like the decode step), computes the accepted window — greedy
-        rows by longest argmax agreement, sampled rows by rejection
-        sampling (``sampling.spec_window_tokens``) — and writes the
-        accepted tokens into the context rows; the scheduler reads
-        back only (tokens, counts). K/V and context writes past the
-        real sequence land in the scratch pad (``_tp``); inactive
-        slots are frozen throughout. ``masked`` adds the grammar mask
-        argument, applied to candidate 0 only: constrained slots never
-        draft (``spec_step`` zeroes their proposals), so candidate 0
-        is the single token they emit per window."""
-        import jax
-        import jax.numpy as jnp
-
-        from distkeras_tpu.ops.quantization import qmatmul, qshape
-        from distkeras_tpu.serving import sampling as _sp
-
-        gen = self._gen
-        b, tp, ml = self.num_slots, self._tp, self.max_len
-
-        def stage_verify(blk, moe, p, pm, x, ck, cv, cpos, active):
-            """c tokens per slot through one (block, optional MoE)
-            stage: the C>1 sibling of the step's ``stage_step`` —
-            same per-row K/V scatter, (B, C, T) causal masks."""
-            mh = p["mhsa"]
-            nh = blk.mhsa.num_heads
-            hd = qshape(mh["wq"])[1] // nh
-            h_, _ = blk.ln1.apply(p["ln1"], {}, x)
-            q = qmatmul(h_, mh["wq"]).reshape(b, c, nh, hd)
-            k_new = qmatmul(h_, mh["wk"]).reshape(b, c, nh, hd)
-            v_new = qmatmul(h_, mh["wv"]).reshape(b, c, nh, hd)
-            rows = jnp.arange(b)[:, None]
-            keep = active[:, None, None, None]
-            ck = ck.at[rows, cpos].set(
-                jnp.where(keep, k_new.astype(ck.dtype), ck[rows, cpos])
-            )
-            cv = cv.at[rows, cpos].set(
-                jnp.where(keep, v_new.astype(cv.dtype), cv[rows, cpos])
-            )
-            scores = jnp.einsum("bchd,bthd->bhct", q, ck) / np.sqrt(hd)
-            t_mask = jnp.arange(tp)[None, None, :] <= cpos[:, :, None]
-            scores = jnp.where(t_mask[:, None], scores, -jnp.inf)
-            w = jax.nn.softmax(scores, axis=-1)
-            o = jnp.einsum("bhct,bthd->bchd", w, cv).reshape(
-                b, c, nh * hd
-            )
-            o = qmatmul(o, mh["wo"])
-            if "bo" in mh:
-                o = o + mh["bo"]
-            x = x + o
-            h_, _ = blk.ln2.apply(p["ln2"], {}, x)
-            h_, _ = blk._fc1.apply(p["fc1"], {}, h_)
-            h_, _ = blk._fc2.apply(p["fc2"], {}, h_)
-            x = x + h_
-            if moe is not None:
-                x = x + gen._moe_nodrop(pm, x)
-            return x, ck, cv
-
-        def verify(params, ctx, caches, lens, active, dtoks, dcnt,
-                   temps, topk, topp, seeds, spos, *rest):
-            bp, p_emb, p_ln, p_head = self._unpack(params)
-            pos = jnp.clip(lens - 1, 0, ml - 1)  # (B,)
-            rows = jnp.arange(b)
-            tok0 = ctx[rows, pos]
-            chunk = jnp.concatenate([tok0[:, None], dtoks], axis=1)
-            cpos = pos[:, None] + jnp.arange(c)[None, :]  # (B, C) < tp
-            x = self._embed(p_emb, chunk, cpos)  # (B, C, d)
-            new_caches = []
-            for (blk, _, moe, _), (p, pm), (ck, cv) in zip(
-                gen._stages, bp, caches
-            ):
-                x, ck, cv = stage_verify(
-                    blk, moe, p, pm, x, ck, cv, cpos, active
-                )
-                new_caches.append((ck, cv))
-            x, _ = gen._final_ln.apply(p_ln, {}, x)
-            logit, _ = gen._head.apply(p_head, {}, x)  # (B, C, V)
-            if masked:
-                # constrained slots never draft: candidate 0 is their
-                # one emission, so the mask applies there alone
-                logit = logit.at[:, 0].add(rest[0])
-            out, n_new = jax.lax.cond(
-                jnp.any(temps > 0.0),
-                lambda: _sp.spec_window_tokens(
-                    logit, dtoks, dcnt, temps, topk, topp, seeds, spos
-                ),
-                lambda: _sp.greedy_window_tokens(logit, dtoks, dcnt),
-            )
-            out = out.astype(ctx.dtype)
-            wpos = cpos + 1  # <= ml-1 + c < tp: scratch absorbs overrun
-            keep = active[:, None] & (
-                jnp.arange(c)[None, :] < n_new[:, None]
-            )
-            rows2 = rows[:, None]
-            cur = ctx[rows2, wpos]
-            ctx = ctx.at[rows2, wpos].set(jnp.where(keep, out, cur))
-            return ctx, new_caches, out, n_new
-
-        return self._jit(
-            verify, donate=(1, 2), out="verify",
-            key=f"verify[{c}{',masked' if masked else ''}]",
-        )
-
 
 class ServingEngine:
     """The in-process serving runtime: continuous-batching decode plus

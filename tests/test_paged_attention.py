@@ -27,7 +27,7 @@ EPS = float(np.finfo(np.float32).eps)
 
 
 def _gather_body(q, ck, cv, table, lengths):
-    """``_build_step_fn_paged``'s gather body (mask ``pos`` = length - 1),
+    """``DecodeStepper._kv_rows``'s gather (mask ``pos`` = length - 1),
     float32 throughout; a slot of length 0 reads zeros."""
     b, nh, hd = q.shape
     t = table.shape[1] * PS

@@ -102,3 +102,175 @@ def test_synthetic_sequences_learnable_structure():
     for i in range(10):
         marker = y[i] + 1
         assert (x[i] == marker).sum() >= 2  # the class marker is planted
+
+# ------------------------------------------------ the block is written once
+
+
+class _Seam:
+    """Counts ``TransformerBlock.forward`` calls and the ``LayerNorm``
+    applications inside and outside them while a program is traced."""
+
+    def __init__(self, monkeypatch):
+        self.forwards = self.inside = self.outside = self.depth = 0
+        forward, norm = TransformerBlock.forward, LayerNorm.apply
+
+        def counted_forward(blk, *a, **kw):
+            self.forwards += 1
+            self.depth += 1
+            try:
+                return forward(blk, *a, **kw)
+            finally:
+                self.depth -= 1
+
+        def counted_norm(ln, *a, **kw):
+            if self.depth:
+                self.inside += 1
+            else:
+                self.outside += 1
+            return norm(ln, *a, **kw)
+
+        monkeypatch.setattr(TransformerBlock, "forward", counted_forward)
+        monkeypatch.setattr(LayerNorm, "apply", counted_norm)
+
+    def reset(self):
+        self.forwards = self.inside = self.outside = 0
+
+
+def _seam_lm(d_model=32, seq_len=32):
+    return zoo.transformer_lm(vocab_size=61, seq_len=seq_len, d_model=d_model,
+                              num_heads=2, depth=2, seed=0)
+
+
+def _trace_apply(seam):
+    lm = _seam_lm()
+    x = jnp.zeros((2, 32), jnp.int32)
+    seam.reset()
+    jax.make_jaxpr(
+        lambda p: lm.apply(p, lm.state, x, train=True,
+                           rng=jax.random.PRNGKey(0))[0]
+    )(lm.params)
+
+
+def _solo(seam):
+    from distkeras_tpu.predictors import CachedSequenceGenerator
+
+    lm = _seam_lm()
+    gen = CachedSequenceGenerator(lm)
+    bp = [(lm.params[str(bi)], None) for (_, bi, _, _) in gen._stages]
+    caches = [(jnp.zeros((2, 32, 2, 16)), jnp.zeros((2, 32, 2, 16)))] * 2
+    seam.reset()
+    return gen, bp, caches
+
+
+def _trace_solo_prefill(seam):
+    gen, bp, caches = _solo(seam)
+    jax.make_jaxpr(lambda x: gen._prefill(bp, caches, x)[0])(
+        jnp.zeros((2, 5, 32)))
+
+
+def _trace_solo_decode(seam):
+    gen, bp, caches = _solo(seam)
+    jax.make_jaxpr(
+        lambda x: gen._stages_decode(bp, caches, x, 5, jnp.arange(32) <= 5)[0]
+    )(jnp.zeros((2, 32)))
+
+
+def _stepper(seam, kernel=False, spec=False, **kw):
+    from distkeras_tpu.serving.engine import DecodeStepper, NgramDrafter
+
+    lm = _seam_lm(256, 48) if kernel else _seam_lm()
+    if spec:
+        kw.update(speculative=NgramDrafter(), draft_k=3)
+    st = DecodeStepper(lm, num_slots=2, **kw)
+    if kw.get("paged"):
+        want = "kernel" if kernel else "gather"
+        assert st.attention.startswith(want), st.attention
+    prompt = np.tile(np.array([3, 5, 7], np.int32), 3)  # 9 tokens
+    return st, prompt
+
+
+def _trace_step(seam, **kw):
+    st, prompt = _stepper(seam, **kw)
+    st.admit(0, prompt, max_new=4)
+    seam.reset()
+    st.step(np.array([True, False]))
+
+
+def _trace_chunk(seam, **kw):
+    st, prompt = _stepper(seam, **kw)
+    assert st.begin_admit(0, prompt, max_new=4) == 8
+    seam.reset()
+    assert st.prefill_chunk(0, 4) == 4  # one call of the 4-token bucket
+
+
+def _trace_verify(seam, **kw):
+    st, prompt = _stepper(seam, spec=True, **kw)
+    st.admit(0, prompt, max_new=6)
+    seam.reset()
+    _, _, used_verify = st.spec_step(np.array([True, False]), [(prompt, []), None])
+    assert used_verify
+
+
+PAGED = dict(paged=True, page_size=4)
+PROGRAMS = {
+    "apply": _trace_apply,
+    "solo_prefill": _trace_solo_prefill,
+    "solo_decode_step": _trace_solo_decode,
+    "dense_step": _trace_step,
+    "dense_chunk": _trace_chunk,
+    "dense_verify": _trace_verify,
+    "paged_step_gather": lambda s: _trace_step(s, **PAGED),
+    "paged_step_kernel": lambda s: _trace_step(s, kernel=True, **PAGED),
+    "paged_chunk": lambda s: _trace_chunk(s, **PAGED),
+    "paged_verify": lambda s: _trace_verify(s, **PAGED),
+}
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_every_program_runs_the_one_block_body(program, monkeypatch):
+    """Tracing a program family calls ``TransformerBlock.forward`` once a
+    block, and no block norm is applied outside it: there is no second
+    body. (What the arithmetic gives is pinned by the identity tests:
+    slot to solo, paged to dense, kernel to gather, ``tp:2`` to solo.)"""
+    seam = _Seam(monkeypatch)
+    PROGRAMS[program](seam)
+    assert seam.forwards == 2, "once a block"
+    assert seam.inside == 4, "ln1 and ln2 of each block, inside forward"
+    assert seam.outside <= 1, "the final norm alone is applied outside"
+
+
+@pytest.mark.parametrize("bits", [None, 8, 16],
+                         ids=["float32", "int8", "bfloat16"])
+def test_forward_with_a_plain_causal_attend_is_apply(bits):
+    """``forward`` under an ``attend`` written out here (causal softmax
+    over the tokens' own keys, accumulated in float32) equals ``apply``
+    to the bit. ``bfloat16``: the weights as ``quantize_model(bits=16)``
+    leaves them (``"bo"`` and the norms stay float32) under bfloat16
+    activations; the bias is cast to the product's dtype, so the
+    attention branch stays bfloat16."""
+    from distkeras_tpu.ops.quantization import quantize_model
+
+    lm = _seam_lm()
+    if bits:
+        lm = quantize_model(lm, bits=bits)
+    blk, p = lm.layers[1], lm.params["1"]
+    dtype = jnp.bfloat16 if bits == 16 else jnp.float32
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32), dtype)
+
+    def attend(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                       preferred_element_type=jnp.float32)
+        s = s * (1.0 / q.shape[-1] ** 0.5)
+        s = jnp.where(jnp.tril(jnp.ones((32, 32), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+    want, _ = blk.apply(p, lm.state["1"], x)
+    got = blk.forward(p, x, attend)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32))
+    if bits == 16:
+        assert p["mhsa"]["bo"].dtype == jnp.float32
+        o = blk.mhsa.forward(p["mhsa"], x, attend)
+        assert o.dtype == jnp.bfloat16
