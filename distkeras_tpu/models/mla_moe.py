@@ -10,13 +10,15 @@ all three of its uses:
 - the serving engine's prefill-chunk program: a chunk of one sequence's
   tokens against that slot's gathered latent row, expanded attention;
 - the serving engine's decode-step program: one token a slot against the
-  slot's gathered latent pages, attention in its *absorbed* form.
+  slot's latent pages, attention in its *absorbed* form.
 
 What differs between them is who holds the cache: ``forward`` hands the
 new latent rows of its tokens to ``exchange`` and attends over what that
 returns. ``apply`` passes the identity; the stepper's closures pass a
-function that scatters the rows into the page pool and gathers the slot's
-pages (page and slot bookkeeping stay in ``serving/engine.py``).
+function that scatters the rows into the page pool and returns the slot's
+gathered pages, or (the decode step, ``ops/paged_attention.py``) a
+callable that attends the pages where they lie (page and slot bookkeeping
+stay in ``serving/engine.py``).
 
 Block: ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; FFN is
 a gated SiLU MLP, or an expert layer (sigmoid scores over all routed
@@ -223,18 +225,25 @@ def attend_expanded(p, q, latent, mask, nh, nope, vd, n_keys=None,
 def attend_absorbed(p, q, latent, mask, nh, nope, vd):
     """The same attention with ``Wkvb`` absorbed into the query and the
     output: scores ``([q_nope Wuk^T | q_pe] . [cn | k_pe]) / sqrt(dq)``,
-    ``o = (sum w cn) Wuv``. The latents are read as they are cached
-    (bfloat16 as served), accumulation is float32."""
+    ``o = (sum w cn) Wuv``. ``latent`` is the cached rows ``(B, t,
+    rank+rope)``, read as they are cached (bfloat16 as served) under
+    ``mask`` and accumulated in float32; or, where the cache's holder
+    attends its rows where they lie, a callable ``(qc (B, n, H,
+    rank+rope) f32, scale) -> (B, n, H, rank) f32`` that is the softmax
+    and both products (``mask`` is then its own business)."""
     b, n = q.shape[:2]
     rank = p["wkvb"].shape[0]
     wkvb = p["wkvb"].reshape(rank, nh, nope + vd)
     wuk, wuv = wkvb[..., :nope], wkvb[..., nope:]
     q_lat = _einsum("bnhd,chd->bnhc", q[..., :nope], wuk)
     qc = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
-    s = _einsum("bnhc,btc->bhnt", qc, latent) / np.sqrt(q.shape[-1])
-    s = jnp.where(mask[:, None], s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
-    o_lat = _einsum("bhnt,btc->bnhc", w, latent[..., :rank])
+    if callable(latent):
+        o_lat = latent(qc, 1.0 / np.sqrt(q.shape[-1]))
+    else:
+        s = _einsum("bnhc,btc->bhnt", qc, latent) / np.sqrt(q.shape[-1])
+        s = jnp.where(mask[:, None], s, -jnp.inf)
+        w = jax.nn.softmax(s, axis=-1)
+        o_lat = _einsum("bhnt,btc->bnhc", w, latent[..., :rank])
     o = _einsum("bnhc,chv->bnhv", o_lat, wuv)
     return o.reshape(b, n, nh * vd)
 
@@ -387,7 +396,9 @@ class LatentMoEBlock(Layer):
         """``x`` ``(B, n, d)`` float32 at positions ``pos`` ``(B, n)``;
         ``exchange(new (B, n, latent_width) f32) -> (B, t, latent_width)``
         stores the tokens' latent rows and returns what they attend over
-        (None: their own); ``mask`` ``(B|1, n, t)``; ``n_keys``: how many
+        (None: their own; for the absorbed form it may return a callable
+        that attends in place, see ``attend_absorbed``); ``mask`` ``(B|1,
+        n, t)`` (None with such a callable); ``n_keys``: how many
         of the ``t`` positions can be attended at all (a traced scalar, the
         chunk's form; see ``attend_expanded``). Returns ``(y, group_sizes |
         None)``."""

@@ -736,7 +736,8 @@ class DecodeStepper:
             # "kernel" (each slot's own pages, in place) or "gather:
             # <why>" (the gathered extent of the longest table)
             self.attention = decode_attention_path(
-                self.layout, hd, self._gen.kv_dtype, self.mesh
+                self.layout, hd, self._gen.kv_dtype, self.mesh,
+                self.page_size,
             )
             self._caches = None
             if latent:
@@ -2414,7 +2415,14 @@ class DecodeStepper:
     #   count: its extent tracks the longest OCCUPIED table at O(log T)
     #   compiles), and chunk, verify and restore at the full extent;
     # - latent pages: a ``(num_pages x page_size, row)`` pool per stage,
-    #   written and gathered by ``LatentMoEBlock.forward``'s ``exchange``.
+    #   ONE array that is keys and values, written by
+    #   ``LatentMoEBlock.forward``'s ``exchange``. The decode step under
+    #   ``"kernel"`` (unsharded, pages that are whole tiles of 8 rows, a
+    #   bfloat16 or float32 pool) hands ``forward`` a callable that
+    #   attends each slot's own pages where they lie
+    #   (``ops.paged_attention.paged_latent_attention``): ONE step
+    #   program here too. The step otherwise, and the chunk always,
+    #   gather the table's pages into rows.
     #
     # Masks, the softmax (``models.layers.cache_attention``; the kernel
     # folds the same softmax over blocks of pages, in float32) and the
@@ -2792,14 +2800,28 @@ class DecodeStepper:
 
     def _latent_rows(self, pbt: int, table, rows, pos, active):
         """Latent pages, one token a slot (the absorbed form of
-        ``LatentMoEBlock.forward``): ``exchange`` owns the page write
-        and the gather of every slot's latent pages, which stay in the
-        pool's dtype (float32 is what accumulates)."""
+        ``LatentMoEBlock.forward``): ``exchange`` owns the masked page
+        write and hands back how the written pool is attended. Where
+        ``self.attention == "kernel"`` that is
+        ``paged_latent_attention`` over each slot's own pages where
+        they lie (positions <= pos; nothing for a slot that is not
+        decoding); otherwise every slot's pages gathered at the
+        bucket's extent, in the pool's dtype, under the position mask
+        (float32 is what accumulates either way)."""
         import jax.numpy as jnp
+
+        from distkeras_tpu.ops.paged_attention import (
+            paged_latent_attention,
+        )
 
         b, ps = self.num_slots, self.page_size
         t = pbt * ps
+        in_place = self.attention == "kernel"
         phys, off = self._page_of(table, rows, pos, pbt)
+        if in_place:
+            lengths, t_mask = jnp.where(active, pos + 1, 0), None
+        else:
+            t_mask = (jnp.arange(t)[None, :] <= pos[:, None])[:, None]
 
         def stage(blk, moe, p, pm, x, pool):
             written = []
@@ -2811,11 +2833,15 @@ class DecodeStepper:
                     pool[at],
                 )
                 written.append(pool.at[at].set(row))
+                if in_place:
+                    return lambda qc, scale: paged_latent_attention(
+                        qc[:, 0], written[0], table, lengths, ps,
+                        blk.kv_rank, scale,
+                    )[:, None]
                 pages = written[0].reshape(-1, ps, pool.shape[-1])
                 return pages[table].reshape(b, t, -1)[
                     ..., :new.shape[-1]]
 
-            t_mask = (jnp.arange(t)[None, :] <= pos[:, None])[:, None]
             x, sizes = blk.forward(
                 p, x[:, None], pos[:, None], t_mask, exchange,
                 absorbed=True, token_mask=active[:, None],

@@ -187,3 +187,70 @@ def test_paged_decode_attention_compiles_for_v5e(
     copies = [ln for ln in text.splitlines()
               if " copy(" in ln and pool_shape in ln.split("=")[0]]
     assert not copies, copies
+
+
+# the latent cell's shapes (kanana-2-30b-a3b-8l: 64 slots, 32 heads, rows of
+# 512 + 64 values padded to 640, 8,960 pages of 16 tokens, a table of 512
+# pages: 128 KB of scalar-prefetched table), and a float32 pool
+LATENT_SHAPES = [
+    pytest.param(64, 32, 576, 512, 16, 8960, 512, jnp.bfloat16,
+                 id="kanana-bf16"),
+    pytest.param(8, 4, 40, 32, 8, 64, 16, jnp.float32, id="f32-pool"),
+]
+
+
+def _latent_step(one_chip, b, nh, width, rank, ps, pages, pbt, dtype):
+    from distkeras_tpu.ops.paged_attention import (
+        LATENT_BLOCK_PAGES,
+        _paged_latent_attention,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(qc, new, pool, at, table, lengths):
+        pool = pool.at[at].set(new.astype(pool.dtype))
+        with jax.named_scope("mla"):
+            o = _paged_latent_attention(
+                qc, pool, table, lengths, page_size=ps, rank=rank,
+                scale=0.07, block_pages=LATENT_BLOCK_PAGES, interpret=False,
+            )
+        return o, pool
+
+    row = -(-width // 128) * 128
+    idx = s((b,), jnp.int32)
+    return jax.jit(step, donate_argnums=(2,)).lower(
+        s((b, nh, width), jnp.float32), s((b, row), jnp.float32),
+        s((pages * ps, row), dtype), idx, s((b, pbt), jnp.int32), idx,
+    )
+
+
+@pytest.mark.parametrize("b,nh,width,rank,ps,pages,pbt,dtype", LATENT_SHAPES)
+def test_paged_latent_attention_compiles_for_v5e(
+    one_chip, b, nh, width, rank, ps, pages, pbt, dtype
+):
+    """The latent step's page write, then the kernel over the written
+    pool: the module holds the kernel, no copy of the pool, and the
+    custom call carries the ``mla`` scope that ``mla_decode_roofline``
+    reads its operations by."""
+    text = _latent_step(
+        one_chip, b, nh, width, rank, ps, pages, pbt, dtype
+    ).compile().as_text()
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert "/mla/" in call.split('op_name="')[1].split('"')[0], call
+    pool_shape = f"[{pages * ps},{-(-width // 128) * 128}]"
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and pool_shape in ln.split("=")[0]]
+    assert not copies, copies
+
+
+def test_latent_pages_of_four_rows_are_refused_by_mosaic(one_chip):
+    """Why ``decode_attention_path`` keeps latent pages that are not
+    whole tiles of the pool on the gather body."""
+    from distkeras_tpu.ops.paged_attention import decode_attention_path
+
+    assert decode_attention_path(
+        "latent", None, jnp.bfloat16, None, 4).startswith("gather")
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _latent_step(one_chip, 8, 4, 40, 32, 4, 64, 16,
+                     jnp.bfloat16).compile()

@@ -42,8 +42,8 @@ CONFIG = {
     "rms_norm_eps": 1e-6,
     "assumed": {"initializer_range": 0.02, "router_bias_std": 0.01},
     "serving": {"weight_bits": 16, "weight_bytes": 2, "kv_dtype": "bfloat16",
-                "kv_bytes": 2, "num_slots": 4, "page_size": 4,
-                "num_pages": 160, "queue_capacity": 64,
+                "kv_bytes": 2, "num_slots": 4, "page_size": 8,
+                "num_pages": 80, "queue_capacity": 64,
                 # bfloat16 operands and a bfloat16 cache against the
                 # float32 reference: the sound runs of this tiny cell read
                 # 0 to 0.004; a head the reference never saw reads 0.05
@@ -101,13 +101,16 @@ def test_the_zoo_model_s_apply_is_the_reference_s_forward(fam, tiny):
     assert fam.param_count(w)["total"] == model.num_params()
 
 
-def _stepper_logits(model, prompt, n_new, kv_dtype, chunk=16, num_pages=80):
+def _stepper_logits(model, prompt, n_new, kv_dtype, chunk=16, num_pages=80,
+                    page_size=4, attention="gather"):
     """Prefill ``prompt`` in chunks of ``chunk`` and decode ``n_new`` tokens
     through the paged stepper; the logits of every decode step, read off the
     step program itself (the final norm's output as the program computed
-    it, times the head)."""
-    st = DecodeStepper(model, num_slots=3, paged=True, page_size=4,
+    it, times the head). Pages of 4 rows keep the step on the gather body,
+    pages of 8 take it through ``paged_latent_attention``."""
+    st = DecodeStepper(model, num_slots=3, paged=True, page_size=page_size,
                        num_pages=num_pages, kv_dtype=kv_dtype)
+    assert st.attention.startswith(attention), st.attention
     seen = []
     norm, real = st._gen._final_ln, st._gen._final_ln.apply
 
@@ -158,6 +161,61 @@ def test_chunked_prefill_then_paged_decode_gives_the_reference_s_logits(
     seq16 = np.concatenate([prompt, toks16])
     ref16 = _reference_logits(fam, w, weights, seq16)[len(prompt) - 1:-1]
     assert np.abs(got16 - ref16).max() > 4 * LOGIT_TOL
+
+
+def test_the_kernel_s_decode_step_gives_the_reference_s_logits(fam, tiny):
+    """(ii) with pages of 8 rows: the step attends each slot's pages in
+    place (``ops.paged_attention.paged_latent_attention``, interpreted
+    here), and its logits are the reference's to the same tolerance, two
+    slots idle beside the one that decodes."""
+    w, weights, f32 = tiny
+    prompt = np.random.default_rng(1).integers(0, w["vocab"], 53)
+    with jax.default_matmul_precision("highest"):
+        _, toks, got = _stepper_logits(
+            _model(fam, w, f32), prompt, 12, None, num_pages=60,
+            page_size=8, attention="kernel")
+    seq = np.concatenate([prompt, toks])
+    ref = _reference_logits(fam, w, weights, seq)[len(prompt) - 1:-1]
+    np.testing.assert_allclose(got, ref, atol=LOGIT_TOL, rtol=0)
+    assert toks == list(ref.argmax(axis=-1))
+
+
+def test_a_paged_engine_decodes_the_latent_block_through_the_kernel():
+    """A tiny ``zoo.mla_moe_lm`` on a paged engine with pages of 8 rows:
+    ``stats()["paged"]["attention"]`` says ``"kernel"``, one step program
+    is compiled (at the widest table), and concurrent greedy requests of
+    unequal length, one slot idle beside them, decode the tokens of the
+    un-paged forward (``model.apply`` over the growing sequence)."""
+    from distkeras_tpu.models import zoo
+
+    lm = zoo.mla_moe_lm(
+        vocab_size=61, seq_len=48, hidden_size=32, num_heads=2,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+        kv_lora_rank=16, intermediate_size=32, moe_intermediate_size=16,
+        n_routed_experts=4, num_experts_per_tok=2, num_layers=2, seed=0)
+    eng = ServingEngine(lm, num_slots=3, paged=True, page_size=8,
+                        prefill_chunk=8)
+    eng.start()
+    try:
+        assert eng.stats()["paged"]["attention"] == "kernel"
+        rng = np.random.default_rng(0)
+        prompts = [rng.integers(0, 61, n).astype(np.int32) for n in (5, 19)]
+        reqs = [eng.submit(p, 9) for p in prompts]
+        got = [np.asarray(r.result()) for r in reqs]
+        paged = eng.stats()["paged"]
+    finally:
+        eng.stop()
+    assert paged["attention"] == "kernel"
+    assert paged["compiled_step_buckets"] == [(8, False)]
+    with jax.default_matmul_precision("highest"):
+        for p, served in zip(prompts, got):
+            seq = list(p)
+            for _ in range(9):
+                x = np.zeros((1, 48), np.int32)
+                x[0, :len(seq)] = seq
+                logits = lm.apply(lm.params, lm.state, x)[0]
+                seq.append(int(np.asarray(logits)[0, len(seq) - 1].argmax()))
+            np.testing.assert_array_equal(served, seq)
 
 
 def test_the_serving_engine_serves_the_reference_s_tokens(fam, tiny, tmp_path):

@@ -1,4 +1,4 @@
-"""The paged decode-attention kernel against the gather body's arithmetic.
+"""The paged decode-attention kernels against the gather bodies' arithmetic.
 
 ``ops.paged_attention.paged_decode_attention`` (run here in the Pallas
 interpreter) must give what the engine's gather body gives: gather the
@@ -9,6 +9,14 @@ the weights (three bfloat16 terms) and in the pool's values, so the two
 differ by the order of float32 accumulation alone; the tolerance is that
 bound and nothing wider: a ``q`` rounded to one bfloat16 term would miss
 it by two orders of magnitude.
+
+``paged_latent_attention`` (the latent layout: one array of rows that
+are keys and values, no head axis) is held the same way to the absorbed
+attention over the gathered pages, ``models.mla_moe.attend_absorbed``'s
+middle written out in NumPy float64: to the order of summation over a
+float32 pool, and to the rounding of its two bfloat16 operands (the
+query, the softmax weights) over a bfloat16 pool, which is what that
+block's products take everywhere.
 """
 
 import numpy as np
@@ -18,8 +26,10 @@ import jax.numpy as jnp
 
 from distkeras_tpu.ops.paged_attention import (
     BLOCK_PAGES,
+    LATENT_BLOCK_PAGES,
     decode_attention_path,
     paged_decode_attention,
+    paged_latent_attention,
 )
 
 PS, HD = 16, 128
@@ -135,6 +145,108 @@ def test_a_table_narrower_than_a_block():
     _check(q, ck, cv, table, lengths, block_pages=BLOCK_PAGES)
 
 
+# ------------------------------------------------------- the latent layout
+
+RANK, WIDTH, ROW = 128, 160, 256  # cn | k_pe | zeros to whole lanes
+SCALE = 1.0 / np.sqrt(48.0)
+
+
+def _latent_pool(rng, num_pages, dtype):
+    rows = np.zeros((num_pages * PS, ROW), np.float32)
+    rows[:, :WIDTH] = rng.normal(size=(num_pages * PS, WIDTH))
+    return jnp.asarray(rows, dtype)
+
+
+def _absorbed_over_gathered_pages(qc, pool, table, lengths):
+    """``attend_absorbed``'s scores, softmax and weighted latents over a
+    slot's gathered pages, float64; a slot of length 0 reads zeros."""
+    pages = np.asarray(jnp.asarray(pool, jnp.float32), np.float64).reshape(
+        -1, PS, ROW)
+    out = np.zeros((*qc.shape[:2], RANK))
+    for i, n in enumerate(lengths):
+        if n:
+            rows = pages[table[i]].reshape(-1, ROW)[:n]
+            s = qc[i].astype(np.float64) @ rows[:, :WIDTH].T * SCALE
+            w = np.exp(s - s.max(-1, keepdims=True))
+            out[i] = (w / w.sum(-1, keepdims=True)) @ rows[:, :RANK]
+    return out
+
+
+def _latent_tables(rng, lengths, pbt, num_pages):
+    table = np.zeros((len(lengths), pbt), np.int32)
+    free = iter(rng.permutation(np.arange(1, num_pages)))
+    for i, n in enumerate(-(-lengths // PS)):
+        table[i, :n] = [next(free) for _ in range(n)]
+    return table
+
+
+def _check_latent(qc, pool, table, lengths, block_pages):
+    got = np.asarray(paged_latent_attention(
+        qc, pool, table, lengths, PS, RANK, SCALE, block_pages=block_pages))
+    values = float(np.nanmax(np.abs(np.asarray(pool, np.float32))))
+    # float32 sums of exact products, as ``_check``
+    tol = max(1, int(lengths.max())) * EPS * values
+    if pool.dtype == jnp.bfloat16:
+        # both operands bfloat16, as the latent block's products are
+        # everywhere: the query rounded as the kernel rounds it, and
+        # each softmax weight within half a bfloat16 step (2^-9) of its
+        # float32 value, the weights summing to 1
+        qc = np.asarray(jnp.asarray(qc, jnp.bfloat16), np.float32)
+        tol += 2.0 ** -9 * values
+    want = _absorbed_over_gathered_pages(qc, pool, table, lengths)
+    assert got.shape == want.shape and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=0, atol=tol)
+    return got
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("lengths,pbt,block_pages", [
+    # a page's edges, a slot that is not decoding, the table's full extent
+    ([1, 15, 16, 17, 0, 8 * PS], 8, 4),
+    # a block's edges (4 pages), and ragged slots ending inside a block
+    ([4 * PS - 1, 4 * PS, 4 * PS + 1, 9 * PS + 5, 6 * PS, 3], 12, 4),
+    # a table far wider than the longest slot, not a whole number of blocks
+    ([2 * PS + 3, 7, 0, PS], 37, 8),
+    # a table narrower than the default block: the block clamps to it
+    ([3 * PS - 1, 7], 3, LATENT_BLOCK_PAGES),
+], ids=["page-edges", "block-edges", "wide-table", "narrow-table"])
+def test_latent_kernel_is_absorbed_attention_over_the_gathered_pages(
+        lengths, pbt, block_pages, dtype):
+    rng = np.random.default_rng(len(lengths) + pbt)
+    lengths = np.array(lengths, np.int32)
+    num_pages = 48
+    table = _latent_tables(rng, lengths, pbt, num_pages)
+    qc = rng.normal(size=(len(lengths), 4, WIDTH)).astype(np.float32)
+    pool = _latent_pool(rng, num_pages, dtype)
+    got = _check_latent(qc, pool, table, lengths, block_pages)
+    assert not got[lengths == 0].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_latent_kernel_never_reads_pages_past_a_slot_s_length(dtype):
+    """Every page that no slot holds within its length is NaN, the null
+    page and the pages that table entries past a slot's own name among
+    them: none is copied, so none reaches the output."""
+    rng = np.random.default_rng(11)
+    lengths = np.array([5 * PS + 2, PS, 0, 2 * PS + 9], np.int32)
+    num_pages, pbt = 32, 8
+    table = _latent_tables(rng, lengths, pbt, num_pages)
+    held = np.unique(np.concatenate(
+        [table[i, :-(-n // PS)] for i, n in enumerate(lengths)]))
+    rows = np.asarray(_latent_pool(rng, num_pages, jnp.float32)).reshape(
+        num_pages, PS, ROW).copy()
+    rows[np.setdiff1d(np.arange(num_pages), held)] = np.nan
+    pool = jnp.asarray(rows.reshape(-1, ROW), dtype)
+    # entries past a slot's own pages point at garbage, not at the null page
+    for i, n in enumerate(-(-lengths // PS)):
+        table[i, n:] = np.setdiff1d(np.arange(num_pages), held)[0]
+    qc = rng.normal(size=(len(lengths), 2, WIDTH)).astype(np.float32)
+    _check_latent(qc, pool, table, lengths, block_pages=4)
+
+
 @pytest.mark.parametrize(
     "layout,head_dim,kv_dtype,mesh,want",
     [
@@ -143,13 +255,34 @@ def test_a_table_narrower_than_a_block():
         ("kv", 16, jnp.float32, None, "gather: heads of 16"),
         ("kv", 64, jnp.bfloat16, None, "gather: heads of 64"),
         ("kv", 128, jnp.float16, None, "gather: no kernel for a float16"),
-        ("latent", None, jnp.bfloat16, None, "gather: the latent page"),
+        ("latent", None, jnp.bfloat16, None, "kernel"),
         ("kv", 128, jnp.bfloat16, object(), "gather: Mosaic kernels"),
+        ("latent", None, jnp.float32, None, "kernel"),
+        ("latent", None, jnp.float16, None, "gather: no kernel for a float16"),
+        ("latent", None, jnp.float8_e4m3fn, None,
+         "gather: no kernel for a float8_e4m3fn"),
+        ("latent", None, jnp.bfloat16, object(), "gather: Mosaic kernels"),
+        ("window", 128, jnp.bfloat16, None, "gather: the window page"),
     ],
 )
 def test_where_the_kernel_engages(layout, head_dim, kv_dtype, mesh, want):
     got = decode_attention_path(layout, head_dim, kv_dtype, mesh)
     assert got.startswith(want), got
+
+
+@pytest.mark.parametrize("page_size,want", [
+    (16, "kernel"), (8, "kernel"),
+    (4, "gather: latent pages of 4 rows"),
+    (12, "gather: latent pages of 12 rows"),
+])
+def test_a_latent_page_is_whole_tiles_of_the_pool(page_size, want):
+    """A latent page is ``page_size`` rows of the flat pool and a copy
+    covers whole tiles (Mosaic refuses less, ``test_chip_compile``); the
+    ``"kv"`` layout's page is a leading index and takes any size."""
+    got = decode_attention_path("latent", None, jnp.bfloat16, None, page_size)
+    assert got.startswith(want), got
+    assert decode_attention_path(
+        "kv", 128, jnp.bfloat16, None, page_size) == "kernel"
 
 
 # ------------------------------------------------------ through the engine
