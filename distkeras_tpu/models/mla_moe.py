@@ -1,8 +1,11 @@
-"""The latent-attention / routed-expert block (the ``deepseek_v3`` model
-type), written once.
+"""The blocks of latent attentions and routed experts, each written once:
+``LatentMoEBlock`` (the ``deepseek_v3`` model type: one attention, one
+FFN) and ``ShortcutMoEBlock`` (the LongCat-Flash layer: two attentions
+and two dense MLPs beside a shortcut-connected expert layer with
+zero-compute experts). They share every function of the arithmetic below.
 
-One function, ``LatentMoEBlock.forward``, is the layer's arithmetic for
-all three of its uses:
+One function, a block's ``forward``, is the layer's arithmetic for all
+three of its uses:
 
 - ``apply`` (``Sequential.apply``, ``eval_shape``, training-side code and
   the CPU tests): the full causal forward, attention in its *expanded*
@@ -20,12 +23,15 @@ gathered pages, or (the decode step, ``ops/paged_attention.py``) a
 callable that attends the pages where they lie (page and slot bookkeeping
 stay in ``serving/engine.py``).
 
-Block: ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; FFN is
-a gated SiLU MLP, or an expert layer (sigmoid scores over all routed
-experts, top-k of score + selection bias, chosen scores normalised and
-scaled; plus shared experts on every token). The cache holds, a token and
-a layer, the normalised latent ``cn`` (``kv_rank``) and the rotated
-shared key ``k_pe`` (``rope_dim``), and nothing else.
+``LatentMoEBlock``: ``h = x + Attn(RMSNorm(x))``, ``y = h +
+FFN(RMSNorm(h))``; FFN is a gated SiLU MLP, or an expert layer (sigmoid
+scores over all routed experts, top-k of score + selection bias, chosen
+scores normalised and scaled; plus shared experts on every token).
+``ShortcutMoEBlock``: its docstring. The cache holds, a token and an
+attention, the normalised latent ``cn`` (``kv_rank``) and the rotated
+shared key ``k_pe`` (``rope_dim``), and nothing else; a block says how
+many such rows a token it caches (``cached_rows``) and that its kind is
+``"latent"``: the serving engine asks those, never the class.
 
 Matrix products take their operands in the weights' dtype (float32 as
 initialised, bfloat16 as served) and accumulate in float32; norms,
@@ -33,6 +39,8 @@ softmax, the router and the residual stream are float32.
 """
 
 from __future__ import annotations
+
+import typing
 
 import numpy as np
 
@@ -43,11 +51,20 @@ from distkeras_tpu.models.layers import Layer, register_layer
 
 
 class BlockUnsupportedError(NotImplementedError):
-    """A serving feature that the latent-attention block cannot run yet
+    """A serving feature that a block which caches latent rows cannot run yet
     (the dense slot bank, speculation, fork/beam, ``tp`` meshes, K/V
     export, swap-out). Not a ``ValueError``: ``ServingEngine`` demotes a
     model whose stepper raises one to predict-only, and a refused feature
     must fail the boot instead."""
+
+
+class Picks(typing.NamedTuple):
+    """What an expert layer's tokens chose, for the step's counters:
+    ``sizes`` ``(E_held,)`` tokens on each held routed expert, ``zero``
+    how many picks took an identity (zero-compute) expert."""
+
+    sizes: jax.Array
+    zero: jax.Array | int
 
 
 # --------------------------------------------------------------- arithmetic
@@ -111,19 +128,24 @@ def gated_mlp(p, x):
     return matmul(jax.nn.silu(matmul(x, p["wg"])) * matmul(x, p["wu"]), p["wd"])
 
 
-def route(p, x, top_k, scale):
-    """Sigmoid scores over ALL routed experts in float32; the top ``k`` of
-    score + selection bias; weights = chosen scores over their sum, times
-    ``scale``. Returns ``(chosen (n, k) int32, weights (n, k) f32)``."""
+def route(p, x, top_k, scale, softmax=False):
+    """Scores over ALL the router's outputs in float32; the top ``k`` of
+    score + selection bias. Sigmoid scores: weights = chosen scores over
+    their sum, times ``scale``; ``softmax``: scores a softmax over the
+    outputs, weights = chosen scores times ``scale``, not normalised.
+    Returns ``(chosen (n, k) int32, weights (n, k) f32)``."""
     with jax.named_scope("moe/route"):
-        s = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             x.astype(jnp.float32), p["wr"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
-        ))
+        )
+        s = jax.nn.softmax(logits, axis=-1) if softmax else jax.nn.sigmoid(
+            logits)
         _, chosen = jax.lax.top_k(s + p["bias"].astype(jnp.float32), top_k)
         w = jnp.take_along_axis(s, chosen, axis=-1)
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
-        return chosen.astype(jnp.int32), w
+        if not softmax:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return chosen.astype(jnp.int32), w * scale
 
 
 def routed_experts(p, x, chosen, weights, held, n_experts, token_mask=None):
@@ -248,6 +270,51 @@ def attend_absorbed(p, q, latent, mask, nh, nope, vd):
     return o.reshape(b, n, nh * vd)
 
 
+def latent_attention(blk, p, x, pos, mask, exchange=None, absorbed=False,
+                     n_keys=None):
+    """``x + Attn(RMSNorm(x))`` for one latent attention of ``blk`` (its
+    sizes, ``rope_theta``, ``epsilon``) with the parameters ``p`` =
+    ``{"ln1", "attn"}``; ``x`` ``(B, n, d)`` float32; the other arguments
+    as ``LatentMoEBlock.forward`` has them. The query is one projection
+    (``wq``) or low-rank (``wqa`` -> RMSNorm ``q_norm`` -> ``wqb``); with
+    ``blk.scale_q`` / ``blk.scale_kv`` the query is scaled by ``sqrt(d /
+    q_rank)`` after ``wqb`` and the normalised latent by ``sqrt(d /
+    kv_rank)`` before ``wkvb``, so the cached row is the scaled one."""
+    a = p["attn"]
+    b, n, d = x.shape
+    nh, nope, vd = blk.num_heads, blk.qk_nope_dim, blk.v_dim
+    with jax.named_scope("mla"):
+        h = rms_norm(x, p["ln1"]["gamma"], blk.epsilon)
+        if "wqa" in a:
+            cq = rms_norm(matmul(h, a["wqa"]), a["q_norm"]["gamma"],
+                          blk.epsilon)
+            q = matmul(cq, a["wqb"])
+            if blk.scale_q:
+                q = q * np.sqrt(d / a["wqa"].shape[-1])
+        else:
+            q = matmul(h, a["wq"])
+        q = q.reshape(b, n, nh, nope + blk.qk_rope_dim)
+        q = jnp.concatenate([
+            q[..., :nope],
+            rope(q[..., nope:], pos[..., None], blk.rope_theta),
+        ], axis=-1)
+        ckv = matmul(h, a["wkva"])
+        cn = rms_norm(ckv[..., :blk.kv_rank], a["kv_norm"]["gamma"],
+                      blk.epsilon)
+        if blk.scale_kv:
+            cn = cn * np.sqrt(d / blk.kv_rank)
+        new = jnp.concatenate([
+            cn, rope(ckv[..., blk.kv_rank:], pos, blk.rope_theta),
+        ], axis=-1)
+        latent = new if exchange is None else exchange(new)
+        if absorbed:
+            o = attend_absorbed(a, q, latent, mask, nh, nope, vd)
+        else:
+            o = attend_expanded(a, q, latent, mask, nh, nope, vd, n_keys,
+                                blk.key_block)
+        return x + matmul(o, a["wo"])
+
+
 # -------------------------------------------------------------------- layers
 
 
@@ -272,8 +339,91 @@ class RMSNorm(Layer):
         return {"layer": "RMSNorm", "epsilon": self.epsilon}
 
 
+class _LatentBlock(Layer):
+    """What the blocks of latent attentions share: the kind the serving
+    engine reads (never the class), the held experts, ``apply``."""
+
+    kind = "latent"
+    causal = True
+    cached_rows = 1  # latent rows a token caches: one an attention
+    scale_q = scale_kv = False  # see ``latent_attention``
+    key_block = 512  # cache positions a prefill chunk attends at once
+
+    @property
+    def held(self) -> list:
+        if self.experts_held is None:
+            return list(range(self.n_experts))
+        return self.experts_held
+
+    @property
+    def latent_width(self) -> int:
+        """Values an attention caches a token: the latent and the shared
+        rotary key."""
+        return self.kv_rank + self.qk_rope_dim
+
+    def _check_experts(self, n_outputs):
+        held = self.held
+        if not 1 <= self.top_k <= n_outputs or (
+                len(set(held)) != len(held)
+                or not all(0 <= e < self.n_experts for e in held)):
+            raise ValueError(
+                f"expert layer: top_k {self.top_k} of {n_outputs} router "
+                f"outputs, {self.n_experts} routed experts, held {held}"
+            )
+
+    def apply(self, params, state, x, train=False, rng=None):
+        b, n, _ = x.shape
+        pos = jnp.broadcast_to(jnp.arange(n), (b, n))
+        mask = jnp.tril(jnp.ones((n, n), bool))[None]
+        y, _ = self.forward(params, x, pos, mask)
+        return y, state
+
+    # -- parameters: N(0, 0.02), output projections scaled by out_scale -----
+
+    _std = 0.02
+
+    def _mlp_init(self, ks, d, width, lead=()):
+        std, dt = self._std, jnp.float32
+        return {"wg": _normal(next(ks), (*lead, d, width), std, dt),
+                "wu": _normal(next(ks), (*lead, d, width), std, dt),
+                "wd": _normal(next(ks), (*lead, width, d),
+                              std * self.out_scale, dt)}
+
+    def _router_init(self, ks, d, n_outputs):
+        return {"wr": _normal(next(ks), (d, n_outputs), self._std,
+                              jnp.float32),
+                "bias": jnp.zeros((n_outputs,), jnp.float32)}
+
+    def _attention_init(self, ks, d, q_rank=None):
+        """One latent attention's ``{"ln1", "attn"}`` and the ``ln2`` that
+        follows it; the query one projection, or low-rank with ``q_rank``."""
+        std, dt = self._std, jnp.float32
+        nh, dq = self.num_heads, self.qk_nope_dim + self.qk_rope_dim
+        if q_rank is None:
+            query = {"wq": _normal(next(ks), (d, nh * dq), std, dt)}
+        else:
+            query = {"wqa": _normal(next(ks), (d, q_rank), std, dt),
+                     "q_norm": {"gamma": jnp.ones((q_rank,), dt)},
+                     "wqb": _normal(next(ks), (q_rank, nh * dq), std, dt)}
+        return {
+            "ln1": {"gamma": jnp.ones((d,), dt)},
+            "attn": {
+                **query,
+                "wkva": _normal(next(ks), (d, self.latent_width), std, dt),
+                "kv_norm": {"gamma": jnp.ones((self.kv_rank,), dt)},
+                "wkvb": _normal(
+                    next(ks),
+                    (self.kv_rank, nh * (self.qk_nope_dim + self.v_dim)),
+                    std, dt),
+                "wo": _normal(next(ks), (nh * self.v_dim, d),
+                              std * self.out_scale, dt),
+            },
+            "ln2": {"gamma": jnp.ones((d,), dt)},
+        }
+
+
 @register_layer
-class LatentMoEBlock(Layer):
+class LatentMoEBlock(_LatentBlock):
     """One pre-RMSNorm block of latent attention and a gated MLP
     (``n_experts=0``: width ``ffn_width``) or an expert layer
     (``n_experts`` routed experts of width ``expert_width``, ``top_k`` a
@@ -286,10 +436,6 @@ class LatentMoEBlock(Layer):
     experts. The stacked expert weights are ``(len(held), d, width)`` x2
     and ``(len(held), width, d)``. Nothing stands in for absent experts.
     """
-
-    kind = "latent"
-    causal = True
-    key_block = 512  # cache positions a prefill chunk attends at once
 
     def __init__(self, num_heads, qk_nope_dim, qk_rope_dim, v_dim, kv_rank,
                  ffn_width=0, n_experts=0, top_k=0, n_shared=0,
@@ -313,68 +459,24 @@ class LatentMoEBlock(Layer):
         )
         self.out_scale = float(out_scale)
         if self.n_experts:
-            held = self.held
-            if not 1 <= self.top_k <= self.n_experts or (
-                    len(set(held)) != len(held)
-                    or not all(0 <= e < self.n_experts for e in held)):
-                raise ValueError(
-                    f"expert layer: top_k {self.top_k} of {self.n_experts} "
-                    f"experts, held {held}"
-                )
+            self._check_experts(self.n_experts)
         elif self.ffn_width < 1:
             raise ValueError("a dense block needs ffn_width >= 1")
 
-    @property
-    def held(self) -> list:
-        if self.experts_held is None:
-            return list(range(self.n_experts))
-        return self.experts_held
-
-    @property
-    def latent_width(self) -> int:
-        """Values cached a token: the latent and the shared rotary key."""
-        return self.kv_rank + self.qk_rope_dim
-
-    # -- parameters ---------------------------------------------------------
-
     def init(self, rng, in_shape):
         d = in_shape[-1]
-        dt = jnp.float32
-        nh, dq = self.num_heads, self.qk_nope_dim + self.qk_rope_dim
         ks = iter(jax.random.split(rng, 16))
-        std = 0.02  # N(0, 0.02); output projections scaled by out_scale
-        out = std * self.out_scale
-
-        def mlp(width, lead=()):
-            return {"wg": _normal(next(ks), (*lead, d, width), std, dt),
-                    "wu": _normal(next(ks), (*lead, d, width), std, dt),
-                    "wd": _normal(next(ks), (*lead, width, d), out, dt)}
-
-        params = {
-            "ln1": {"gamma": jnp.ones((d,), dt)},
-            "attn": {
-                "wq": _normal(next(ks), (d, nh * dq), std, dt),
-                "wkva": _normal(next(ks), (d, self.latent_width), std, dt),
-                "kv_norm": {"gamma": jnp.ones((self.kv_rank,), dt)},
-                "wkvb": _normal(
-                    next(ks),
-                    (self.kv_rank, nh * (self.qk_nope_dim + self.v_dim)),
-                    std, dt),
-                "wo": _normal(next(ks), (nh * self.v_dim, d), out, dt),
-            },
-            "ln2": {"gamma": jnp.ones((d,), dt)},
-        }
+        params = self._attention_init(ks, d)
         if self.n_experts:
             params["ffn"] = {
-                "router": {
-                    "wr": _normal(next(ks), (d, self.n_experts), std, dt),
-                    "bias": jnp.zeros((self.n_experts,), dt),
-                },
-                "experts": mlp(self.expert_width, (len(self.held),)),
-                "shared": mlp(self.n_shared * self.expert_width),
+                "router": self._router_init(ks, d, self.n_experts),
+                "experts": self._mlp_init(ks, d, self.expert_width,
+                                          (len(self.held),)),
+                "shared": self._mlp_init(
+                    ks, d, self.n_shared * self.expert_width),
             }
         else:
-            params["ffn"] = mlp(self.ffn_width)
+            params["ffn"] = self._mlp_init(ks, d, self.ffn_width)
         return params, {}, in_shape
 
     # -- the arithmetic, once -----------------------------------------------
@@ -400,44 +502,18 @@ class LatentMoEBlock(Layer):
         that attends in place, see ``attend_absorbed``); ``mask`` ``(B|1,
         n, t)`` (None with such a callable); ``n_keys``: how many
         of the ``t`` positions can be attended at all (a traced scalar, the
-        chunk's form; see ``attend_expanded``). Returns ``(y, group_sizes |
+        chunk's form; see ``attend_expanded``). Returns ``(y, Picks |
         None)``."""
-        a = p["attn"]
         x = x.astype(jnp.float32)
         b, n, d = x.shape
-        nh, nope, vd = self.num_heads, self.qk_nope_dim, self.v_dim
-        with jax.named_scope("mla"):
-            h = rms_norm(x, p["ln1"]["gamma"], self.epsilon)
-            q = matmul(h, a["wq"]).reshape(b, n, nh, nope + self.qk_rope_dim)
-            q = jnp.concatenate([
-                q[..., :nope],
-                rope(q[..., nope:], pos[..., None], self.rope_theta),
-            ], axis=-1)
-            ckv = matmul(h, a["wkva"])
-            new = jnp.concatenate([
-                rms_norm(ckv[..., :self.kv_rank], a["kv_norm"]["gamma"],
-                         self.epsilon),
-                rope(ckv[..., self.kv_rank:], pos, self.rope_theta),
-            ], axis=-1)
-            latent = new if exchange is None else exchange(new)
-            if absorbed:
-                o = attend_absorbed(a, q, latent, mask, nh, nope, vd)
-            else:
-                o = attend_expanded(a, q, latent, mask, nh, nope, vd, n_keys,
-                                    self.key_block)
-            x = x + matmul(o, a["wo"])
+        x = latent_attention(self, p, x, pos, mask, exchange, absorbed,
+                             n_keys)
         h = rms_norm(x, p["ln2"]["gamma"], self.epsilon)
         if token_mask is not None:
             token_mask = jnp.broadcast_to(token_mask, (b, n)).reshape(-1)
         y, sizes = self.ffn(p["ffn"], h.reshape(b * n, d), token_mask)
-        return x + y.reshape(b, n, d), sizes
-
-    def apply(self, params, state, x, train=False, rng=None):
-        b, n, _ = x.shape
-        pos = jnp.broadcast_to(jnp.arange(n), (b, n))
-        mask = jnp.tril(jnp.ones((n, n), bool))[None]
-        y, _ = self.forward(params, x, pos, mask)
-        return y, state
+        return (x + y.reshape(b, n, d),
+                None if sizes is None else Picks(sizes, 0))
 
     def get_config(self):
         return {
@@ -453,10 +529,154 @@ class LatentMoEBlock(Layer):
         }
 
 
-def routing_counts(sizes_by_layer):
-    """The step's two routing counters from the expert layers' group
-    sizes: ``[sum over layers of the experts that got a token, the
-    largest token count on one expert]`` as int32."""
-    hit = sum(jnp.sum(s > 0) for s in sizes_by_layer)
-    load = jnp.max(jnp.stack([jnp.max(s) for s in sizes_by_layer]))
-    return jnp.stack([hit, load]).astype(jnp.int32)
+@register_layer
+class ShortcutMoEBlock(_LatentBlock):
+    """One layer of the shortcut-connected form: two latent attentions
+    and two gated MLPs of ``ffn_width`` in a row, and an expert layer that
+    reads the hidden state after the first attention and whose result is
+    added at the layer's end (so it can run beside everything between)::
+
+        h1 = x  + MLA_0(RMSNorm(x));   u = RMSNorm(h1);   m = MoE(u)
+        h2 = h1 + MLP_0(u)
+        h3 = h2 + MLA_1(RMSNorm(h2))
+        h4 = h3 + MLP_1(RMSNorm(h3));  y = h4 + m
+
+    Each attention has a low-rank query (``q_rank``) and, with
+    ``scale_q`` / ``scale_kv``, the two factors of ``latent_attention``.
+    The router has ``n_experts + n_zero`` outputs: softmax scores, the top
+    ``top_k`` of score + selection bias, weights ``routed_scale`` x score,
+    not normalised. A pick below ``n_experts`` is a routed expert of width
+    ``expert_width`` (``experts_held``: as ``LatentMoEBlock``); a pick at
+    or above it is a zero-compute expert, the identity: it adds ``weight x
+    u``, every one of them computed here, as one weighted sum that costs no
+    product. The block caches two latent rows a token, one an attention,
+    and hands ``exchange`` each attention's new rows in turn."""
+
+    cached_rows = 2
+    token_block = 1024  # tokens whose picks the expert layer sorts at once
+
+    def __init__(self, num_heads, qk_nope_dim, qk_rope_dim, v_dim, kv_rank,
+                 q_rank, ffn_width, n_experts, n_zero, top_k, expert_width,
+                 routed_scale=1.0, rope_theta=10000.0, epsilon=1e-5,
+                 scale_q=True, scale_kv=True, experts_held=None,
+                 out_scale=1.0):
+        self.num_heads = int(num_heads)
+        self.qk_nope_dim = int(qk_nope_dim)
+        self.qk_rope_dim = int(qk_rope_dim)
+        self.v_dim = int(v_dim)
+        self.kv_rank = int(kv_rank)
+        self.q_rank = int(q_rank)
+        self.ffn_width = int(ffn_width)
+        self.n_experts = int(n_experts)
+        self.n_zero = int(n_zero)
+        self.top_k = int(top_k)
+        self.expert_width = int(expert_width)
+        self.routed_scale = float(routed_scale)
+        self.rope_theta = float(rope_theta)
+        self.epsilon = float(epsilon)
+        self.scale_q, self.scale_kv = bool(scale_q), bool(scale_kv)
+        self.experts_held = (
+            None if experts_held is None else [int(e) for e in experts_held]
+        )
+        self.out_scale = float(out_scale)
+        self._check_experts(self.n_experts + self.n_zero)
+
+    def init(self, rng, in_shape):
+        d = in_shape[-1]
+        ks = iter(jax.random.split(rng, 32))
+
+        def half():
+            return {**self._attention_init(ks, d, self.q_rank),
+                    "mlp": self._mlp_init(ks, d, self.ffn_width)}
+
+        params = {
+            "0": half(), "1": half(),
+            "moe": {
+                "router": self._router_init(
+                    ks, d, self.n_experts + self.n_zero),
+                "experts": self._mlp_init(ks, d, self.expert_width,
+                                          (len(self.held),)),
+            },
+        }
+        return params, {}, in_shape
+
+    def moe(self, p, u, token_mask=None):
+        """``u`` ``(n, d)`` -> ``(m, Picks)``: the held routed experts'
+        part and every identity pick's. More than ``token_block`` tokens
+        (a long prefill chunk) go ``token_block`` at a time: the sort holds
+        a row for every (token, pick) pair, ``top_k`` rows of ``d`` a
+        token, of which a chip's share of the experts takes few."""
+        n, tb = u.shape[0], self.token_block
+        if n <= tb or n % tb:
+            return self._moe(p, u, token_mask)
+        if token_mask is None:
+            token_mask = jnp.ones((n,), bool)
+        y, picks = jax.lax.map(
+            lambda block: self._moe(p, *block),
+            (u.reshape(-1, tb, u.shape[-1]), token_mask.reshape(-1, tb)),
+        )
+        return y.reshape(u.shape), Picks(picks.sizes.sum(0), picks.zero.sum())
+
+    def _moe(self, p, u, token_mask):
+        chosen, w = route(p["router"], u, self.top_k, self.routed_scale,
+                          softmax=True)
+        y, sizes = routed_experts(
+            p["experts"], u, chosen, w, self.held,
+            self.n_experts + self.n_zero, token_mask,
+        )
+        with jax.named_scope("moe/zero"):
+            zero = chosen >= self.n_experts
+            y = y + jnp.sum(jnp.where(zero, w, 0.0), axis=-1,
+                            keepdims=True) * u
+            if token_mask is not None:
+                zero = zero & token_mask[:, None]
+            return y, Picks(sizes, jnp.sum(zero))
+
+    def forward(self, p, x, pos, mask, exchange=None, absorbed=False,
+                token_mask=None, n_keys=None):
+        """As ``LatentMoEBlock.forward``; ``exchange`` is called twice,
+        with the first attention's new rows and then the second's."""
+        x = x.astype(jnp.float32)
+        b, n, d = x.shape
+        h = latent_attention(self, p["0"], x, pos, mask, exchange, absorbed,
+                             n_keys)
+        u = rms_norm(h, p["0"]["ln2"]["gamma"], self.epsilon)
+        if token_mask is not None:
+            token_mask = jnp.broadcast_to(token_mask, (b, n)).reshape(-1)
+        m, picks = self.moe(p["moe"], u.reshape(b * n, d), token_mask)
+        with jax.named_scope("ffn/dense"):
+            h = h + gated_mlp(p["0"]["mlp"], u)
+        h = latent_attention(self, p["1"], h, pos, mask, exchange, absorbed,
+                             n_keys)
+        with jax.named_scope("ffn/dense"):
+            h = h + gated_mlp(
+                p["1"]["mlp"],
+                rms_norm(h, p["1"]["ln2"]["gamma"], self.epsilon))
+        return h + m.reshape(b, n, d), picks
+
+    def get_config(self):
+        return {
+            "layer": "ShortcutMoEBlock", "num_heads": self.num_heads,
+            "qk_nope_dim": self.qk_nope_dim, "qk_rope_dim": self.qk_rope_dim,
+            "v_dim": self.v_dim, "kv_rank": self.kv_rank,
+            "q_rank": self.q_rank, "ffn_width": self.ffn_width,
+            "n_experts": self.n_experts, "n_zero": self.n_zero,
+            "top_k": self.top_k, "expert_width": self.expert_width,
+            "routed_scale": self.routed_scale, "rope_theta": self.rope_theta,
+            "epsilon": self.epsilon, "scale_q": self.scale_q,
+            "scale_kv": self.scale_kv, "experts_held": self.experts_held,
+            "out_scale": self.out_scale,
+        }
+
+
+def routing_counts(picks_by_layer):
+    """The step's four routing counters from the expert layers' ``Picks``:
+    ``[sum over layers of the held experts that got a token, the largest
+    token count on one expert, the picks of an identity expert, the picks
+    of a held routed expert]`` as int32."""
+    sizes = [p.sizes for p in picks_by_layer]
+    hit = sum(jnp.sum(s > 0) for s in sizes)
+    load = jnp.max(jnp.stack([jnp.max(s) for s in sizes]))
+    zero = sum(p.zero for p in picks_by_layer)
+    held = sum(jnp.sum(s) for s in sizes)
+    return jnp.stack([hit, load, zero, held]).astype(jnp.int32)

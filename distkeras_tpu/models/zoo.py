@@ -417,6 +417,67 @@ def mla_moe_lm(
     return model
 
 
+def longcat_flash_lm(
+    vocab_size=256,
+    seq_len=128,
+    hidden_size=64,
+    num_attention_heads=4,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    kv_lora_rank=32,
+    q_lora_rank=48,
+    ffn_hidden_size=128,
+    expert_ffn_hidden_size=32,
+    n_routed_experts=8,
+    zero_expert_num=4,
+    moe_topk=3,
+    num_layers=2,
+    routed_scaling_factor=1.0,
+    rope_theta=10000.0,
+    rms_norm_eps=1e-5,
+    mla_scale_q_lora=True,
+    mla_scale_kv_lora=True,
+    experts_held=None,
+    seed=0,
+):
+    """Causal language model of shortcut-connected expert layers (the
+    LongCat-Flash layer, under its published keys): Embedding without a
+    position table -> ``num_layers`` x ``ShortcutMoEBlock`` (two latent
+    attentions with a low-rank query, two gated MLPs of
+    ``ffn_hidden_size``, and ``n_routed_experts`` routed experts of
+    ``expert_ffn_hidden_size`` beside ``zero_expert_num`` identity experts,
+    ``moe_topk`` a token by softmax scores and a selection bias) -> RMSNorm
+    -> an untied head without bias. ``experts_held``: the routed experts
+    every layer holds (None: all); see ``models/mla_moe.py``. Serves through
+    the paged ``ServingEngine`` with two latent pages a layer in the pool."""
+    from distkeras_tpu.models.mla_moe import RMSNorm, ShortcutMoEBlock
+
+    model = Sequential(
+        [
+            Embedding(vocab_size, hidden_size, with_positions=False),
+            *[
+                ShortcutMoEBlock(
+                    num_attention_heads, qk_nope_head_dim, qk_rope_head_dim,
+                    v_head_dim, kv_lora_rank, q_lora_rank, ffn_hidden_size,
+                    n_routed_experts, zero_expert_num, moe_topk,
+                    expert_ffn_hidden_size,
+                    routed_scale=routed_scaling_factor,
+                    rope_theta=rope_theta, epsilon=rms_norm_eps,
+                    scale_q=mla_scale_q_lora, scale_kv=mla_scale_kv_lora,
+                    experts_held=experts_held,
+                    out_scale=(2 * num_layers) ** -0.5,
+                )
+                for _ in range(num_layers)
+            ],
+            RMSNorm(rms_norm_eps),
+            Dense(vocab_size, use_bias=False),
+        ]
+    )
+    model.build((seq_len,), seed=seed)
+    return model
+
+
 ZOO = {
     "mnist_mlp": mnist_mlp,
     "mnist_cnn": mnist_cnn,

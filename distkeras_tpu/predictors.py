@@ -494,13 +494,15 @@ class CachedSequenceGenerator(SequenceGenerator):
         self._head = layers[-1]
 
     def _parse_latent(self, layers) -> bool:
-        """Embedding -> ``LatentMoEBlock`` xN -> RMSNorm -> Dense
-        (``zoo.mla_moe_lm``): the latent-attention block, whose cache is
-        one latent row a token and layer and not keys and values. The
-        paged ``DecodeStepper`` serves it; the solo generators here keep a
-        dense (B, T, H, Dh) cache and refuse it (``_decode_prologue``)."""
+        """Embedding -> blocks of kind ``"latent"`` xN -> RMSNorm ->
+        Dense (``zoo.mla_moe_lm``, ``zoo.longcat_flash_lm``): blocks of
+        latent attentions, whose cache is ``cached_rows`` latent rows a
+        token and layer and not keys and values. The block says its kind;
+        its class is not asked. The paged ``DecodeStepper`` serves it; the
+        solo generators here keep a dense (B, T, H, Dh) cache and refuse it
+        (``_decode_prologue``)."""
         from distkeras_tpu.models.layers import Dense, Embedding
-        from distkeras_tpu.models.mla_moe import LatentMoEBlock, RMSNorm
+        from distkeras_tpu.models.mla_moe import RMSNorm
 
         mid = layers[1:-2]
         if not (
@@ -508,7 +510,7 @@ class CachedSequenceGenerator(SequenceGenerator):
             and isinstance(layers[0], Embedding)
             and isinstance(layers[-2], RMSNorm)
             and isinstance(layers[-1], Dense)
-            and all(isinstance(l, LatentMoEBlock) for l in mid)
+            and all(getattr(l, "kind", None) == "latent" for l in mid)
         ):
             return False
         self.block_kind = "latent"
@@ -589,8 +591,8 @@ class CachedSequenceGenerator(SequenceGenerator):
 
             raise BlockUnsupportedError(
                 "the solo cached generators keep a dense (B, T, H, Dh) "
-                "K/V cache; the latent-attention block decodes through "
-                "the paged ServingEngine only"
+                "K/V cache; a block that caches latent rows decodes "
+                "through the paged ServingEngine only"
             )
         n_layers = len(self.model.layers)
         if cache_len is None:

@@ -653,7 +653,7 @@ class DecodeStepper:
         from distkeras_tpu.ops.quantization import qshape
 
         if latent:
-            # no (H, Dh) rows: one latent row a token and layer
+            # no (H, Dh) rows: a latent row a token and attention
             nh, hd = self._gen._blocks[0].num_heads, None
         else:
             nh = self._gen._blocks[0].mhsa.num_heads
@@ -741,7 +741,8 @@ class DecodeStepper:
             )
             self._caches = None
             if latent:
-                # a latent page: (page_size, kv_rank + rope) a layer,
+                # a latent page: (page_size, kv_rank + rope) an attention
+                # (a block says how many it has: ``cached_rows``),
                 # ONE array and not a K and a V. The (num_pages,
                 # page_size, W) pool is held as its row-major flattening
                 # (num_pages x page_size, W), page p = rows [p * ps,
@@ -752,10 +753,13 @@ class DecodeStepper:
                 # same reason a row is padded with zeros to a multiple
                 # of 128 values, the TPU's lane width: 576 -> 640
                 self._pools = [
-                    jnp.zeros(
-                        (int(num_pages) * self.page_size,
-                         self._latent_row(blk)),
-                        self._gen.kv_dtype,
+                    tuple(
+                        jnp.zeros(
+                            (int(num_pages) * self.page_size,
+                             self._latent_row(blk)),
+                            self._gen.kv_dtype,
+                        )
+                        for _ in range(blk.cached_rows)
                     )
                     for blk in self._gen._blocks
                 ]
@@ -811,6 +815,9 @@ class DecodeStepper:
             "experts_total": (
                 len(self._gen._blocks[-1].held) if self._moe_layers else 0
             ),
+            # of the active slots' tokens x top_k x expert layers picks:
+            # those of an identity expert and of a held routed expert
+            "routed_tokens": 0, "zero_picks": 0, "held_picks": 0,
         }
         self.host_arg_bytes_step = 0  # of the last decode-step call
         self._step_fns = {}  # masked flag -> compiled decode step
@@ -1063,26 +1070,28 @@ class DecodeStepper:
 
     def kv_bytes_per_token(self) -> int:
         """Bytes one cached token takes over all layers: keys and values
-        of every head, or one latent row a layer."""
+        of every head, or one latent row an attention."""
         item = np.dtype(self._gen.kv_dtype).itemsize
         if self.layout == "latent":
-            return item * sum(a.shape[-1] for a in self._pools)
+            return item * sum(
+                a.shape[-1] for rows in self._pools for a in rows
+            )
         return item * 2 * self._nh * self._hd * len(self._gen._stages)
 
     # -- the latent-attention block ------------------------------------------
 
     @staticmethod
     def _refuse_for_latent(*features):
-        """Typed refusal of what the latent-attention block cannot run
-        yet (each named in PERF.md, "cannot run yet")."""
+        """Typed refusal of what a block that caches latent rows cannot
+        run yet (each named in PERF.md, "cannot run yet")."""
         from distkeras_tpu.models.mla_moe import BlockUnsupportedError
 
         for what in features:
             if what:
                 raise BlockUnsupportedError(
-                    f"{what} cannot serve the latent-attention block "
-                    f"yet: its cache is one latent row a token and "
-                    f"layer, not (H, Dh) keys and values"
+                    f"{what} cannot serve a block that caches latent "
+                    f"rows yet: its cache is a latent row a token and "
+                    f"attention, not (H, Dh) keys and values"
                 )
 
     @staticmethod
@@ -1101,22 +1110,32 @@ class DecodeStepper:
 
     def _note_routing(self, fetched, n_active, span):
         """Split the step's fetch into its tokens and the expert layers'
-        two counters; sum them for ``stats()["moe"]`` and put them on
-        the ``serving/collect`` span. ``experts_hit`` is the distinct
-        routed experts that some active slot's token reached, a mean
-        over the expert layers; ``expert_load_max`` the largest token
-        count on one expert."""
-        toks, (hit_sum, load_max) = fetched[:-2], fetched[-2:]
-        hit = float(hit_sum) / self._moe_layers
+        four counters (``models.mla_moe.routing_counts``); sum them for
+        ``stats()["moe"]`` and put them on the ``serving/collect`` span.
+        ``experts_hit`` is the distinct held routed experts that some
+        active slot's token reached, a mean over the expert layers;
+        ``expert_load_max`` the largest token count on one expert;
+        ``zero_picks`` and ``held_picks`` how many of the active slots'
+        ``picks`` (tokens x top_k x expert layers) took an identity
+        expert and a held routed expert."""
+        toks, counts = fetched[:-4], fetched[-4:]
+        hit_sum, load_max, zero, held = (int(c) for c in counts)
+        hit = hit_sum / self._moe_layers
         m = self.moe_stats
         self.moe_stats = {
             **m, "steps": m["steps"] + 1,
             "experts_hit_sum": m["experts_hit_sum"] + hit,
-            "expert_load_max_sum": m["expert_load_max_sum"] + int(load_max),
+            "expert_load_max_sum": m["expert_load_max_sum"] + load_max,
+            "routed_tokens": m["routed_tokens"] + n_active,
+            "zero_picks": m["zero_picks"] + zero,
+            "held_picks": m["held_picks"] + held,
         }
         span.set_metadata(
-            experts_hit=hit, expert_load_max=int(load_max),
+            experts_hit=hit, expert_load_max=load_max,
             experts_total=m["experts_total"], routed_tokens=n_active,
+            zero_picks=zero, held_picks=held,
+            picks=n_active * self._moe_layers
+            * self._gen._blocks[-1].top_k,
         )
         return toks
 
@@ -2414,9 +2433,11 @@ class DecodeStepper:
     #   mesh or with heads of 16 or 64 (keyed by the pow2-bucketed page
     #   count: its extent tracks the longest OCCUPIED table at O(log T)
     #   compiles), and chunk, verify and restore at the full extent;
-    # - latent pages: a ``(num_pages x page_size, row)`` pool per stage,
-    #   ONE array that is keys and values, written by
-    #   ``LatentMoEBlock.forward``'s ``exchange``. The decode step under
+    # - latent pages: per stage, a ``(num_pages x page_size, row)`` pool
+    #   for each latent attention the block has (its ``cached_rows``: one
+    #   for ``LatentMoEBlock``, two for ``ShortcutMoEBlock``), ONE array
+    #   that is keys and values, written by the block's ``forward``
+    #   through ``exchange``, an attention a call. The decode step under
     #   ``"kernel"`` (unsharded, pages that are whole tiles of 8 rows, a
     #   bfloat16 or float32 pool) hands ``forward`` a callable that
     #   attends each slot's own pages where they lie
@@ -2799,9 +2820,10 @@ class DecodeStepper:
         return stage
 
     def _latent_rows(self, pbt: int, table, rows, pos, active):
-        """Latent pages, one token a slot (the absorbed form of
-        ``LatentMoEBlock.forward``): ``exchange`` owns the masked page
-        write and hands back how the written pool is attended. Where
+        """Latent pages, one token a slot (the absorbed form of a latent
+        block's ``forward``): ``exchange`` owns the masked page write of
+        the attention it is called for (the block's pools in its order)
+        and hands back how the written pool is attended. Where
         ``self.attention == "kernel"`` that is
         ``paged_latent_attention`` over each slot's own pages where
         they lie (positions <= pos; nothing for a slot that is not
@@ -2823,38 +2845,41 @@ class DecodeStepper:
         else:
             t_mask = (jnp.arange(t)[None, :] <= pos[:, None])[:, None]
 
-        def stage(blk, moe, p, pm, x, pool):
-            written = []
+        def stage(blk, moe, p, pm, x, pools):
+            written = []  # an attention's pool, in the block's order
 
             def exchange(new):  # (B, 1, latent_width) float32
+                pool = pools[len(written)]
                 at = phys * ps + off  # rows of the flat pool
                 row = jnp.where(
                     active[:, None], self._pad_row(new[:, 0], pool),
                     pool[at],
                 )
-                written.append(pool.at[at].set(row))
+                mine = pool.at[at].set(row)
+                written.append(mine)
                 if in_place:
                     return lambda qc, scale: paged_latent_attention(
-                        qc[:, 0], written[0], table, lengths, ps,
+                        qc[:, 0], mine, table, lengths, ps,
                         blk.kv_rank, scale,
                     )[:, None]
-                pages = written[0].reshape(-1, ps, pool.shape[-1])
+                pages = mine.reshape(-1, ps, pool.shape[-1])
                 return pages[table].reshape(b, t, -1)[
                     ..., :new.shape[-1]]
 
-            x, sizes = blk.forward(
+            x, picks = blk.forward(
                 p, x[:, None], pos[:, None], t_mask, exchange,
                 absorbed=True, token_mask=active[:, None],
             )
-            return x[:, 0], written[0], sizes
+            return x[:, 0], tuple(written), picks
 
         return stage
 
     def _latent_chunk(self, pbt: int, trow, start, pos):
-        """Latent pages, one slot's chunk (the expanded form of
-        ``LatentMoEBlock.forward``): ``exchange`` scatters the chunk's
-        rows to their physical pages and returns the slot's gathered
-        logical row with the chunk's own rows written into it."""
+        """Latent pages, one slot's chunk (the expanded form of a latent
+        block's ``forward``): ``exchange`` scatters the chunk's rows to
+        their physical pages in the pool of the attention it is called
+        for and returns the slot's gathered logical row with the chunk's
+        own rows written into it."""
         import jax
         import jax.numpy as jnp
 
@@ -2863,10 +2888,11 @@ class DecodeStepper:
         qmask = (jnp.arange(t)[None, :] <= pos[:, None])[None]
         fpos = self._flat_positions(trow, pos, pbt)  # (cb,)
 
-        def stage(blk, moe, p, pm, x, pool):
-            written = []
+        def stage(blk, moe, p, pm, x, pools):
+            written = []  # an attention's pool, in the block's order
 
             def exchange(new):
+                pool = pools[len(written)]
                 rows = self._pad_row(new[0], pool)  # (cb, row width)
                 written.append(pool.at[fpos].set(rows))
                 row = pool.reshape(-1, ps, pool.shape[-1])[trow]
@@ -2874,11 +2900,11 @@ class DecodeStepper:
                     row.reshape(t, -1), rows, (start, 0)
                 )[None, :, :new.shape[-1]]
 
-            x, sizes = blk.forward(
+            x, picks = blk.forward(
                 p, x, pos[None], qmask, exchange,
                 n_keys=jnp.minimum(start + cb, t),
             )
-            return x, written[0], sizes
+            return x, tuple(written), picks
 
         return stage
 

@@ -191,10 +191,14 @@ def test_paged_decode_attention_compiles_for_v5e(
 
 # the latent cell's shapes (kanana-2-30b-a3b-8l: 64 slots, 32 heads, rows of
 # 512 + 64 values padded to 640, 8,960 pages of 16 tokens, a table of 512
-# pages: 128 KB of scalar-prefetched table), and a float32 pool
+# pages: 128 KB of scalar-prefetched table), the shortcut layer's cell
+# (longcat-flash-chat-4l-ep32: 128 slots, 64 heads, 15,360 pages: 256 KB of
+# table, 64 query rows a block), and a float32 pool
 LATENT_SHAPES = [
     pytest.param(64, 32, 576, 512, 16, 8960, 512, jnp.bfloat16,
                  id="kanana-bf16"),
+    pytest.param(128, 64, 576, 512, 16, 15360, 512, jnp.bfloat16,
+                 id="longcat-bf16"),
     pytest.param(8, 4, 40, 32, 8, 64, 16, jnp.float32, id="f32-pool"),
 ]
 
