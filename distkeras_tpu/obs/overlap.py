@@ -17,24 +17,35 @@ time-series-visible metric instead of a one-off bench printout:
   wall-clock the device was actually computing (1.0 = zero bubble).
   ``1 - efficiency`` is the bubble fraction ``dkt_top`` renders.
 
-The batcher stamps three instants per iteration through this ledger:
-``note_dispatch()`` when the compiled call is issued,
+The batcher stamps three instants per step through this ledger:
+``note_dispatch()`` when the compiled call has RETURNED (the device
+starts once the host has handed the program over; the call itself is
+host time, and a stamp taken before it counted 8.8 ms of an idle chip
+as device time in every ``serve_backlog`` iteration),
 ``note_ready()`` when device completion is first *observed* (an
 opportunistic poll between host phases, or implicitly at collect),
-and ``note_collect()`` when the tokens are materialized. Device wall
-is measured, not inferred: if readiness was never observed before the
-blocking collect, the device ran right up to the collect and the
-bubble for that interval is honestly zero. The clock is injectable so
-the arithmetic is unit-testable without sleeping.
+and ``note_collect()`` when the tokens are materialized. Steps close
+in dispatch order and two can be open at once: the overlapped loop
+dispatches step n+1 before it collects step n. The device is serial,
+so a step's device wall starts at the later of its own dispatch stamp
+and the previous step's ready stamp, and ends at its ready stamp; the
+iteration wall is collect-to-collect. Device wall is measured, not
+inferred: if readiness was never observed before the blocking
+collect, the device ran right up to the collect and the bubble for
+that interval is honestly zero. The clock is injectable so the
+arithmetic is unit-testable without sleeping.
 
-Both loop modes feed the same ledger — the sequential control stamps
-dispatch/ready/collect back-to-back around its blocking step, so the
-committed overlapped-vs-sequential A/B reads the bubble from the same
-instrument on both sides.
+Both loop modes feed the same ledger. The sequential control (and a
+stepper without an async face, whose device call runs inside the
+dispatch) has one blocking call that is dispatch and wait at once, so
+it stamps dispatch BEFORE that call: its device wall holds the call's
+host part, which is the bubble the overlapped loop hides and the trace
+(``device_idle_pct``) prices exactly.
 """
 
 from __future__ import annotations
 
+import collections
 import time
 
 
@@ -62,39 +73,52 @@ class OverlapLedger:
         self.iterations = 0
         self.device_seconds = 0.0
         self.iteration_seconds = 0.0
-        self._dispatched_at = None
-        self._ready_at = None
+        # [dispatched_at, ready_at] of every dispatched, uncollected
+        # step, oldest first
+        self._open: collections.deque[list] = collections.deque()
+        self._last_ready = None  # when the device ended the last step
         self._last_collect = None
 
     # -- the three stamps (scheduler thread only) ---------------------------
 
     def note_dispatch(self) -> None:
-        """The compiled step for this iteration was just issued."""
-        self._dispatched_at = self._clock()
-        self._ready_at = None
+        """A compiled step was just handed to the device (stamp this
+        when the call returns): one more open step."""
+        self._open.append([self._clock(), None])
 
     def note_ready(self) -> None:
-        """Device completion observed (first observation wins — later
-        polls and the implicit collect stamp never move it back)."""
-        if self._ready_at is None and self._dispatched_at is not None:
-            self._ready_at = self._clock()
+        """Device completion of the OLDEST open step observed (first
+        observation wins — later polls and the implicit collect stamp
+        never move it back)."""
+        try:
+            oldest = self._open[0]
+        except IndexError:  # nothing open (or stop() just discarded it)
+            return
+        if oldest[1] is None:
+            oldest[1] = self._clock()
 
     def note_collect(self) -> None:
-        """Tokens materialized: close this iteration's ledger entry.
+        """Tokens materialized: close the oldest open step's entry.
         No-op when nothing was dispatched (idle scheduler passes)."""
         now = self._clock()
-        if self._dispatched_at is None:
+        try:
+            dispatched, ready = self._open.popleft()
+        except IndexError:
             return
-        ready = self._ready_at if self._ready_at is not None else now
-        device = min(max(0.0, ready - self._dispatched_at),
-                     max(0.0, now - self._dispatched_at))
+        if ready is None:
+            ready = now
+        # the device is serial: a step dispatched behind another one
+        # starts when that one ends, not when the host let go of it
+        start = (
+            dispatched if self._last_ready is None
+            else max(dispatched, self._last_ready)
+        )
+        device = max(0.0, min(ready, now) - start)
         # iteration wall: collect-to-collect once steady, else
         # dispatch-to-collect (the first iteration has no predecessor)
         base = (
-            self._last_collect
-            if self._last_collect is not None
-            and self._last_collect <= self._dispatched_at
-            else self._dispatched_at
+            self._last_collect if self._last_collect is not None
+            else dispatched
         )
         iter_wall = max(0.0, now - base)
         device = min(device, iter_wall)
@@ -102,15 +126,14 @@ class OverlapLedger:
         self.iterations += 1
         self.device_seconds += device
         self.iteration_seconds += iter_wall
-        self._dispatched_at = None
-        self._ready_at = None
+        self._last_ready = ready
         self._last_collect = now
 
     def discard(self) -> None:
-        """Drop an in-flight entry without closing it (the step was
-        abandoned — scheduler stop with a handle still in the air)."""
-        self._dispatched_at = None
-        self._ready_at = None
+        """Drop every open entry without closing it (the steps were
+        abandoned — scheduler stop with a handle still in the air, or
+        the step dispatched behind one whose collect raised)."""
+        self._open.clear()
 
     # -- read side ----------------------------------------------------------
 

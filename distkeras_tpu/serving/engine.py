@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import logging
 import os
 import threading
 import time
@@ -59,6 +60,8 @@ from distkeras_tpu.serving.scheduler import (
     WrongRoleError,
 )
 from distkeras_tpu.utils.profiling import annotate
+
+logger = logging.getLogger(__name__)
 
 
 def _span(name, **args):
@@ -414,19 +417,25 @@ class ModelDrafter:
 class _InflightStep:
     """One dispatched-but-uncollected decode step (the zero-bubble
     handle): holds the stepper, the active mask the step was issued
-    with, and the UN-MATERIALIZED device token array. ``ready()`` is a
-    non-blocking poll; ``collect()`` is the single host sync point —
-    it fetches the tokens AND applies the host bookkeeping a
-    successful step implies (length/RNG-position advance, grammar
-    cursors), so nothing advances until the step is known good.
-    Single-consumer, collect-once (the scheduler thread)."""
+    with, each slot's tenancy count at that moment, and the
+    UN-MATERIALIZED device token array. ``ready()`` is a non-blocking
+    poll; ``collect()`` is the single host sync point — it fetches the
+    tokens AND applies the host bookkeeping a successful step implies
+    (length/RNG-position advance, grammar cursors), so nothing advances
+    until the step is known good. A handle is open (in the stepper's
+    ``_air``, in dispatch order) from its dispatch to its ``collect()``
+    or ``discard()``: a step dispatched meanwhile counts the advance
+    this one still owes (``DecodeStepper._owed``). Single-consumer,
+    collect-once, in dispatch order (the scheduler thread)."""
 
-    __slots__ = ("_stepper", "active", "_toks")
+    __slots__ = ("_stepper", "active", "_toks", "_tenancy")
 
     def __init__(self, stepper, active, toks):
         self._stepper = stepper
         self.active = active
         self._toks = toks
+        self._tenancy = stepper._tenancy.copy()
+        stepper._air.append(self)
 
     def ready(self) -> bool:
         """True when the device result is available (collect would not
@@ -443,6 +452,27 @@ class _InflightStep:
         except Exception:  # noqa: BLE001 — a poll must never crash
             return True
 
+    def owed(self) -> np.ndarray:
+        """The slots whose bookkeeping this step advances at its
+        collect: those of its mask whose tenant has not left since the
+        dispatch. A slot released meanwhile (evicted at the collect of
+        the step before this one, and perhaps admitted anew since)
+        keeps the state its release and its new admission gave it: this
+        step's token for it is discarded, as the scheduler discards it
+        (``ContinuousBatcher._emit``)."""
+        return self.active & (self._tenancy == self._stepper._tenancy)
+
+    def discard(self) -> None:
+        """The step is dropped un-collected: the stepper stops counting
+        it as in the air, and nothing of it advances. (A scheduler
+        thread that ``stop()`` from another thread overtook inside a
+        call may still collect it: every slot was released meanwhile,
+        so it owes nothing then either.)"""
+        try:
+            self._stepper._air.remove(self)
+        except ValueError:  # collected, or discarded before
+            pass
+
     def collect(self) -> np.ndarray:
         """Materialize the step's tokens (THE host sync point) and
         advance the host bookkeeping. Raises whatever the device call
@@ -453,23 +483,26 @@ class _InflightStep:
             raise RuntimeError("decode step already collected")
         st, active = self._stepper, self.active
         with _span("serving/collect") as sp:
-            toks = np.asarray(self._toks)  # the one device->host fetch
-            self._toks = None
+            try:
+                # the one device->host fetch
+                toks = np.asarray(self._toks)
+            finally:
+                self._toks = None
+                self.discard()  # collected or failed: in the air no more
             if st._moe_layers:
                 # the expert layers' routing counters ride the tokens'
                 # fetch (no second device sync)
                 toks = st._note_routing(toks, int(active.sum()), sp)
-            st._lens[active] = np.minimum(
-                st._lens[active] + 1, st._lens_cap
-            )
+            owed = self.owed()
+            st._lens[owed] = np.minimum(st._lens[owed] + 1, st._lens_cap)
             # the RNG counter mirrors the length discipline exactly: a
             # failed call advanced nothing, a successful one advanced
-            # each active slot once — replay through blame probes is
-            # this line
-            st._spos[active] += 1
+            # each slot it still owes once — replay through blame
+            # probes is this line
+            st._spos[owed] += 1
             if st._grammar:
                 st._advance_grammar(
-                    toks.reshape(-1, 1), np.where(active, 1, 0)
+                    toks.reshape(-1, 1), np.where(owed, 1, 0)
                 )
         return toks
 
@@ -850,6 +883,12 @@ class DecodeStepper:
         self._topp = np.ones((b,), np.float32)  # 1.0 = disabled
         self._seeds = np.zeros((b,), np.int32)
         self._spos = np.zeros((b,), np.int32)  # emitted-token counter
+        # zero-bubble decode: the dispatched, uncollected steps in
+        # dispatch order (``_InflightStep``), and how often each slot's
+        # tenant has left (``release``): a step in the air advances a
+        # slot at its collect only if the count is what it was
+        self._air: list[_InflightStep] = []
+        self._tenancy = np.zeros((b,), np.int64)
         self._slot_params = [None] * b  # SamplingParams per slot
         self._grammar = {}  # slot -> incremental grammar mask state
         self._mask_compiler = TokenMaskCompiler(
@@ -1215,14 +1254,32 @@ class DecodeStepper:
             for j in range(int(counts[i])):
                 st.advance(int(toks[i, j]))
 
-    def _sampling_args(self):
+    def _sampling_args(self, owed=0):
         """The per-slot sampler arrays every step/verify call passes
         (fresh copies: the device call must see this iteration's
-        snapshot even if host bookkeeping advances meanwhile)."""
+        snapshot even if host bookkeeping advances meanwhile).
+        ``owed``: what the steps still in the air will add to the
+        sample positions (``_owed``)."""
         return (
             self._temps.copy(), self._topk.copy(), self._topp.copy(),
-            self._seeds.copy(), self._spos.copy(),
+            self._seeds.copy(), self._spos + owed,
         )
+
+    def _owed(self):
+        """Per slot, the advance that the steps in the air still owe
+        (each adds one to length and sample position at its collect):
+        a step dispatched behind them passes lengths and positions as
+        they WILL be. 0 (the scalar) with nothing in the air."""
+        if not self._air:
+            return 0
+        return sum(h.owed().astype(np.int32) for h in self._air)
+
+    @property
+    def constrained_slots(self):
+        """The slots whose next token mask is built from the token
+        before it: the scheduler collects a step before it dispatches
+        the next while one of them decodes."""
+        return self._grammar.keys()
 
     @property
     def can_fork(self) -> bool:
@@ -1959,6 +2016,7 @@ class DecodeStepper:
             )
 
     def release(self, slot: int) -> None:
+        self._tenancy[slot] += 1  # steps in the air owe it nothing now
         self._lens[slot] = 1  # keep pos = lens-1 in range while parked
         self._pending.pop(slot, None)  # eviction mid-prefill
         self._prefill_pos.pop(slot, None)
@@ -2930,7 +2988,19 @@ class DecodeStepper:
         implies (``_lens``/``_spos`` advance, grammar cursors) is
         DEFERRED to ``collect()`` so a failed call still advances
         nothing — the blame-retry discipline is unchanged, it just
-        surfaces at the collect of the step's own iteration."""
+        surfaces at the collect of the step's own iteration.
+
+        May be called with a step still in the air (the overlapped
+        loop dispatches step n+1 before it collects step n): the
+        lengths and sample positions passed are then the host's plus
+        what the open steps owe (``_owed``) — the values the host will
+        hold once they are collected, in dispatch order. Everything
+        else the call passes is known before n's tokens are: each
+        slot's last token is in ``self._ctx`` on the device, the page
+        table is complete from admission, the sampler's parameters are
+        the request's. Host state still advances only at ``collect()``.
+        No slot with a grammar may be in ``active`` then
+        (``constrained_slots``): its mask needs the token."""
         active = np.asarray(active, bool)
         # the injection seam fires BEFORE any device work or host
         # bookkeeping: a failed step leaves the slot bank exactly as it
@@ -2956,9 +3026,11 @@ class DecodeStepper:
                     self._step_fns = {**self._step_fns, masked: fn}
                 table = None  # the bank has none
             # every argument of the step that is built on the host
+            owed = self._owed()
             host = (
-                self._lens.copy(), active, table,
-                *self._sampling_args(), *((tmask,) if masked else ()),
+                np.minimum(self._lens + owed, self._lens_cap), active,
+                table, *self._sampling_args(owed),
+                *((tmask,) if masked else ()),
             )
             self.host_arg_bytes_step = self._host_arg_bytes(host)
         with _span(
@@ -3277,19 +3349,26 @@ class ServingEngine:
         the autoscaler can see per-replica geometry.
 
         Loop-structure knob: ``overlap`` (True — the default) runs the
-        scheduler's ZERO-BUBBLE loop: the compiled decode step for
-        iteration N is dispatched asynchronously and iteration N+1's
-        host work (admission, chunked prefill, stream pushes, deadline
-        sweeps) executes while the device runs, with the host
-        synchronizing on N's tokens only at emission time. Emitted
-        token ORDER is unchanged — the overlap moves wall-clock, not
+        scheduler's ZERO-BUBBLE loop, as deep as is legal and at most
+        two steps: with step N-1 on the device a scheduler call does
+        its host work (admission, chunked prefill, deadline sweeps),
+        dispatches step N behind it (``DecodeStepper.step_async`` with
+        a step in the air), and only then collects and emits N-1 — the
+        step's host call runs while the device steps. Where N needs
+        N-1's tokens on the host (a grammar, a drafter), before a
+        preemption and after a failure, the call collects first
+        (``ContinuousBatcher._lookahead_refusal``). Emitted token
+        ORDER is unchanged — the overlap moves wall-clock, not
         semantics — and a step that fails surfaces at the collect of
         its own iteration with blame/quarantine behavior identical to
         the sequential loop. ``overlap=False`` is the bit-identical
         sequential control (the bench A/B's baseline side). The bubble
         is measured either way: ``serving_step_bubble_seconds`` /
         ``serving_overlap_efficiency`` in the registry and an
-        ``overlap`` block on ``health()``.
+        ``overlap`` block on ``health()`` and ``stats()``, which also
+        says how deep the loop ran (``ahead_steps`` of ``steps``,
+        ``drained`` by reason, ``discarded_slot_steps``); ``stop()``
+        logs the block at INFO.
 
         ``shed``: adaptive load shedding at the admission door. False
         (the default) keeps the door exactly as it was. True builds a
@@ -4000,6 +4079,11 @@ class ServingEngine:
             # fail anything the loop left behind (hard stop, or a drain
             # whose scheduler thread was already dead)
             batcher.stop()
+        if batcher is not None:
+            logger.info(
+                "serving engine stopped: overlap %s",
+                batcher.overlap_stats(),
+            )
         self._predict_batcher.close()
         self.peer_fabric.close()  # pooled peer sockets do not leak
         if self.recorder is not None:
@@ -4758,10 +4842,7 @@ class ServingEngine:
             # the zero-bubble ledger: how much of decode wall-clock the
             # device actually computed (overlap mode or the sequential
             # control — the instrument reads the same either way)
-            out["overlap"] = {
-                "enabled": batcher.overlap,
-                **batcher.overlap_ledger.snapshot(),
-            }
+            out["overlap"] = batcher.overlap_stats()
         if self.shed_gate is not None:
             # overload-gate state for routers and dkt_top: the current
             # brownout rung, whether the CoDel side is shedding, and

@@ -29,15 +29,23 @@ pure-Python fake in the unit tests) with::
     step(active) -> (num_slots,) int array, the token appended per slot
 
 A stepper MAY additionally expose ``step_async(active)`` returning a
-handle with ``ready() -> bool`` and ``collect() -> tokens``: with
-``overlap=True`` the batcher then dispatches iteration N's device
-step and runs iteration N+1's host work (admission, emission,
-deferred preemption) UNDER it, syncing on N's tokens only at the
-next call's collect — the zero-bubble loop. Steppers without the
-async face still work under ``overlap=True`` (the device call runs
-synchronously at dispatch; the loop shape and outputs are
-unchanged), and ``overlap=False`` keeps the strict one-call-emits
-sequential control. Both modes stamp the same ``OverlapLedger``
+handle with ``ready() -> bool`` and ``collect() -> tokens`` (and,
+optionally, ``discard()`` for a handle that is dropped un-collected):
+with ``overlap=True`` the batcher then keeps the device busy through
+its own host work — the zero-bubble loop. One call admits and prefills
+under step N-1, DISPATCHES step N behind it (``step_async`` with a step
+still in the air: the stepper builds N's arguments as they will be once
+N-1 is collected), and only then collects and emits N-1, so the jitted
+call of N runs while the device steps and not after it. Handles are
+collected in dispatch order; at most two are open, and only inside a
+call. Where step N needs N-1's tokens on the host (a grammar mask, a
+drafter's sequences), where nothing may be in the air (a preemption),
+or after a failure, the call collects first and dispatches after, one
+step deep (``_lookahead_refusal``). Steppers without the async face
+still work under ``overlap=True`` (the device call runs synchronously
+at dispatch; the loop shape and outputs are unchanged), and
+``overlap=False`` keeps the strict one-call-emits sequential control.
+Both modes stamp the same ``OverlapLedger``
 (``serving_step_bubble_seconds`` / ``serving_overlap_efficiency``),
 so the bubble is one instrument read either way.
 
@@ -102,24 +110,45 @@ def _no_span(name, **args):
     return _NO_SPAN
 
 
-class _Inflight:
-    """One dispatched-but-uncollected device step, scheduler-side: the
-    active mask / sequences it was issued against, the wall/mint
-    stamps its collect needs for attribution, and exactly one of — an
-    engine ``step_async`` handle (async dispatch), a held synchronous
-    result tuple (steppers without an async face: speculative
-    drafters materialize host-side mid-call, unit-test fakes), or a
-    stashed dispatch exception (a failure at dispatch surfaces at the
-    COLLECT of this step's own iteration, where the blame machinery
-    runs)."""
+# why a call of the overlapped loop did not dispatch ahead of the step
+# in the air (``ContinuousBatcher._lookahead_refusal``); each has a
+# ``drained_<reason>`` counter
+_DRAIN_REASONS = (
+    "sync_stepper", "wants_sequences", "grammar", "preempt", "failed_step",
+)
 
-    __slots__ = (
-        "active", "seqs", "t0", "mints0", "handle", "result", "exc",
+
+def _still_held(active, reqs, slots) -> np.ndarray:
+    """The slots of a dispatched step's mask that still hold the
+    request they held at its dispatch (``reqs``)."""
+    return np.array(
+        [a and r is s for a, r, s in zip(active, reqs, slots)], bool
     )
 
-    def __init__(self, active, seqs, t0, mints0):
+
+class _Inflight:
+    """One dispatched-but-uncollected device step, scheduler-side: the
+    active mask / sequences it was issued against, the request each
+    slot held at that moment (a slot of the mask may be evicted, and
+    even given to a new tenant, before this step is collected: the
+    step's result counts only where the slot still holds that
+    request), the wall/mint stamps its collect needs for attribution,
+    and exactly one of — an engine ``step_async`` handle (async
+    dispatch), a held synchronous result tuple (steppers without an
+    async face: speculative drafters materialize host-side mid-call,
+    unit-test fakes), or a stashed dispatch exception (a failure at
+    dispatch surfaces at the COLLECT of this step's own iteration,
+    where the blame machinery runs)."""
+
+    __slots__ = (
+        "active", "seqs", "reqs", "t0", "mints0", "handle", "result",
+        "exc",
+    )
+
+    def __init__(self, active, seqs, reqs, t0, mints0):
         self.active = active
         self.seqs = seqs
+        self.reqs = reqs
         self.t0 = t0
         self.mints0 = mints0
         self.handle = None
@@ -130,6 +159,16 @@ class _Inflight:
         if self.handle is not None:
             return self.handle.ready()
         return True  # held result / stashed exception: nothing to wait on
+
+    def held(self, slots) -> np.ndarray:
+        return _still_held(self.active, self.reqs, slots)
+
+    def drop(self) -> None:
+        """Abandon the step un-collected, and say so to a handle that
+        wants to know (the stepper stops counting it as in the air)."""
+        discard = getattr(self.handle, "discard", None)
+        if discard is not None:
+            discard()
 
 
 class ServingError(RuntimeError):
@@ -478,22 +517,27 @@ class ContinuousBatcher:
         across the boundary. ``max_preemptions`` bounds displacement
         per request so nothing livelocks.
 
-        ``overlap``: True runs the ZERO-BUBBLE loop — each ``step()``
-        call first does the host scheduling work (admission, chunked
-        prefill, exports, forks, deadline sweeps) while the PREVIOUS
-        iteration's device step runs, then collects that step's tokens
-        (emission/eviction — the only host sync point), then dispatches
-        the next step asynchronously. Token order per request is
-        UNCHANGED; a step that fails surfaces at the collect of its own
-        iteration with the same blame/quarantine semantics. False (the
-        default here; the ``ServingEngine`` defaults to True) is the
-        strictly sequential dispatch-and-wait loop — the bit-identical
-        control side of the bench A/B, and what raw-batcher unit tests
-        drive so one ``step()`` call emits its own tokens. Steppers
-        without a ``step_async`` face (fakes, speculative draft/verify
-        — the drafter materializes host state mid-call) run their
-        device call synchronously at dispatch; the loop structure and
-        failure surfacing stay identical.
+        ``overlap``: True runs the ZERO-BUBBLE loop, as deep as is
+        legal and at most two steps — each ``step()`` call first does
+        the host scheduling work (admission, chunked prefill, exports,
+        forks, deadline sweeps) while the PREVIOUS iteration's device
+        step runs, then dispatches the NEXT step behind it, then
+        collects the previous step's tokens (emission/eviction — the
+        only host sync point) and returns with one step in the air.
+        Where the next step cannot be built before the previous one's
+        tokens are on the host (``_lookahead_refusal``) the call
+        collects first and dispatches after. Token order per request
+        is UNCHANGED; a step that fails surfaces at the collect of its
+        own iteration with the same blame/quarantine semantics. False
+        (the default here; the ``ServingEngine`` defaults to True) is
+        the strictly sequential dispatch-and-wait loop — the
+        bit-identical control side of the bench A/B, and what
+        raw-batcher unit tests drive so one ``step()`` call emits its
+        own tokens. Steppers without a ``step_async`` face (fakes,
+        speculative draft/verify — the drafter materializes host
+        state mid-call) run their device call synchronously at
+        dispatch; the loop structure and failure surfacing stay
+        identical.
 
         ``shed_gate``: an optional
         ``resilience.AdmissionController``. None (the default) keeps
@@ -559,11 +603,14 @@ class ContinuousBatcher:
         self._admit_order = [0] * stepper.num_slots
         self._quarantined: dict[int, int] = {}
         self._sched_iters = 0  # step() calls (not device steps)
-        # zero-bubble decode: the dispatched-but-uncollected step (at
-        # most one — the loop collects before it dispatches again).
-        # Only the scheduler thread touches it outside stop().
+        # zero-bubble decode: the dispatched-but-uncollected step
+        # BETWEEN calls (at most one; inside a call the loop holds a
+        # second while it collects the first). Only the scheduler
+        # thread touches it outside stop().
         self.overlap = bool(overlap)
         self._inflight: _Inflight | None = None
+        # the last collect raised: the next call runs one step deep
+        self._step_failed = False
         self._lock = threading.Lock()
         self._work = threading.Event()  # signals the engine loop
         self._draining = False
@@ -634,6 +681,15 @@ class ContinuousBatcher:
                 "exports",  # prefill-only slots serialized + completed
                 "export_failures",  # swap-out at export raised; typed
                 "streamed_chunks",  # per-iteration token chunks pushed
+                # the overlapped loop's depth (0 under overlap=False)
+                "ahead_steps",  # steps dispatched with a step in the air
+                # slot-steps whose slot no longer held the request it
+                # held at dispatch when the step was collected (an EOS,
+                # deadline or blame eviction one step earlier)
+                "discarded_slot_steps",
+                # calls that had a step in the air and did not
+                # dispatch ahead of it, by reason
+                *(f"drained_{r}" for r in _DRAIN_REASONS),
             ),
         )
         # occupancy gauges, computed at scrape time from state the
@@ -843,10 +899,10 @@ class ContinuousBatcher:
         Two loop shapes, one contract: sequential mode runs host-work
         -> dispatch+wait -> emit in one pass; overlapped mode
         (``overlap=True``) runs host-work (the PREVIOUS step still on
-        the device) -> collect+emit that step -> preemption -> dispatch
-        the next step and return without waiting on it. Emitted token
-        order per request is identical — only where the wall-clock goes
-        differs."""
+        the device) -> dispatch the next step behind it -> collect+emit
+        the previous step, and returns without waiting on the one it
+        dispatched. Emitted token order per request is identical —
+        only where the wall-clock goes differs."""
         run = self._step_overlapped if self.overlap else self._step_sequential
         if self.idle:
             return run()  # a pass over an idle bank: no span for it
@@ -877,10 +933,34 @@ class ContinuousBatcher:
         )
 
     def _step_overlapped(self) -> bool:
-        """The zero-bubble iteration: iteration N+1's host scheduling
-        work executes while step N runs on the device; the host syncs
-        on N's tokens at the last moment it needs them (emission /
-        eviction), then dispatches N+1 and returns.
+        """The zero-bubble iteration. With step N-1 in the air, call N:
+
+            admit / prefill chunk   (N-1 on the device; the chunk's
+                                     call chains behind it)
+            look-ahead mask         (today's mask, less the slots whose
+                                     budget N-1 exhausts)
+            dispatch step N         (lengths and sample positions as
+                                     they WILL be once N-1 is
+                                     collected; chains behind N-1 and
+                                     the chunk)
+            collect step N-1        (usually no wait left)
+            emit step N-1
+            return                  (one step in the air between
+                                     calls; two only inside a call)
+
+        so the jitted call of N — as long as the device step itself
+        where the step is fast — runs while the device steps.
+
+        Why step N need not wait for N-1's tokens: everything its call
+        passes from the host is known beforehand. The last token of
+        every slot lives in the stepper's context ON THE DEVICE (N-1
+        writes it there, N reads it there); lengths and sample
+        positions advance by exactly one for every slot of N-1's mask;
+        the page table is complete from admission; sampling params are
+        per request. A slot whose budget N-1 exhausts is known
+        (``len(comp) + 1 >= max_new_tokens``) and left out of N's mask.
+        Only a finish by EOS, deadline or blame is not known: it costs
+        one DISCARDED slot-step (``_Inflight.held``, ``_emit``).
 
         Why this is loop structure, not semantics:
 
@@ -892,44 +972,115 @@ class ContinuousBatcher:
         - Slots freed by this call's collect admit on the NEXT call
           (one device-step later than the sequential loop under slot
           contention); each request's own token stream is unchanged.
-        - QoS preemption picks its victim AFTER collect — swapping a
-          slot out from under an in-flight step would fetch post-step
-          KV against pre-step host token lists.
+        - QoS preemption needs NOTHING in the air — swapping a slot
+          out from under an in-flight step would fetch post-step KV
+          against pre-step host token lists — so a call that may
+          preempt drains first (``_lookahead_refusal``), picks its
+          victim after collect, and goes on.
         - A step that raises (at dispatch or inside the device call)
           surfaces at the COLLECT of its own iteration, where the
           blame probes run synchronously against unadvanced state —
-          identical containment to the sequential loop.
+          identical containment to the sequential loop. With two in
+          the air the later step is dropped un-collected first
+          (``_collect_with_blame``): nothing of either has advanced.
         """
-        inflight = self._inflight
-        if inflight is not None and inflight.ready():
+        prev = self._inflight
+        after_failure, self._step_failed = self._step_failed, False
+        if prev is not None and prev.ready():
             # opportunistic poll: the device finished while the host
             # was away — stamp it so the ledger's device wall is
             # measured, not inferred from the blocking collect
             self.overlap_ledger.note_ready()
         progressed, blocked = self._admit_phase(preempt_now=False)
-        if inflight is not None:
-            self._inflight = None
-            if inflight.ready():
+        if prev is not None:
+            why_not = self._lookahead_refusal(prev, blocked, after_failure)
+            ahead = None
+            if why_not is None:
+                ahead = self._dispatch_phase(ahead_of=prev)
+            else:
+                with self._lock:
+                    self.counters[f"drained_{why_not}"] += 1
+            self._inflight = ahead
+            if prev.ready():
                 self.overlap_ledger.note_ready()
-            toks, counts, blamed, used_verify = (
-                self._collect_with_blame(inflight)
-            )
-            self.overlap_ledger.note_collect()
-            self._finish_step(
-                inflight.active, inflight.t0, inflight.mints0,
-                toks, counts, blamed, used_verify,
-            )
-            progressed = True
+            failed = self._collect_phase(prev, ahead)
+            if why_not is None and not failed:
+                return True  # step N, if any slot takes it, is in the air
         if blocked is not None and self._preempt_phase(blocked):
             progressed = True
-        active, seqs = self._mask_phase()
+        self._inflight = self._dispatch_phase()
+        return progressed or prev is not None or self._inflight is not None
+
+    def _lookahead_refusal(self, prev: _Inflight, blocked,
+                           after_failure: bool) -> str | None:
+        """Why this call must collect ``prev`` before it dispatches the
+        next step (a ``_DRAIN_REASONS`` name), or None where the next
+        step can be dispatched behind it. Decided per call from what
+        the loop can observe: one algorithm whose depth depends on its
+        input."""
+        st = self.stepper
+        if not self._steps_async():
+            return "sync_stepper"  # its device call is synchronous
+        if getattr(st, "wants_sequences", False):
+            return "wants_sequences"  # ... with prev's tokens in them
+        if prev.exc is not None or after_failure:
+            # prev failed at dispatch, or the last call's collect
+            # raised: the state a step ahead would assume is not the
+            # one to come
+            return "failed_step"
+        constrained = getattr(st, "constrained_slots", ())
+        with self._lock:
+            if any(
+                self._slots[i] is not None and i not in self._prefill_left
+                for i in constrained
+            ):
+                return "grammar"  # the token mask is built from the token
+            if (
+                blocked is not None and self._preemptible
+                and self._pick_victim_locked(blocked) is not None
+            ):
+                return "preempt"  # swap-out needs nothing in the air
+        return None
+
+    def _steps_async(self) -> bool:
+        """Whether the stepper's device call can be issued and left in
+        the air: it has the ``step_async`` face and is not speculative
+        (the draft->verify path materializes host state mid-call)."""
+        st = self.stepper
+        return not getattr(st, "speculative", False) and hasattr(
+            st, "step_async"
+        )
+
+    def _dispatch_phase(self, ahead_of: _Inflight | None = None):
+        """Mask + dispatch: the next step, issued and not waited for;
+        None when no slot takes it. ``ahead_of``: the step still in
+        the air that this one is dispatched behind."""
+        active, seqs = self._mask_phase(ahead_of)
         if not active.any():
-            return progressed
-        t0 = time.monotonic()
-        mints0 = self._led_total()
-        self.overlap_ledger.note_dispatch()
-        self._inflight = self._dispatch(active, seqs, t0, mints0)
-        return True
+            return None
+        if ahead_of is not None:
+            with self._lock:
+                self.counters["ahead_steps"] += 1
+            self._iter_counts["ahead"] = 1
+        return self._dispatch(
+            active, seqs, time.monotonic(), self._led_total()
+        )
+
+    def _collect_phase(self, inf: _Inflight, later=None) -> bool:
+        """Collect + emit ``inf``, the oldest step in the air; True
+        when its collect raised (``later``, the step dispatched behind
+        it, is then dropped)."""
+        toks, counts, blamed, used_verify, failed = (
+            self._collect_with_blame(inf, later)
+        )
+        self.overlap_ledger.note_collect()
+        if failed:
+            self.overlap_ledger.discard()  # the dropped step's stamp
+        self._finish_step(
+            inf.active, inf.t0, inf.mints0, toks, counts, blamed,
+            used_verify, reqs=inf.reqs,
+        )
+        return failed
 
     def _dispatch(self, active, seqs, t0, mints0) -> _Inflight:
         """Issue the device step for ``active`` without waiting on it.
@@ -938,28 +1089,40 @@ class ContinuousBatcher:
         mid-call); otherwise the device call runs synchronously HERE
         and its result — or exception — rides the handle to this
         iteration's collect, so loop structure and failure surfacing
-        are stepper-independent."""
-        inf = _Inflight(active, seqs, t0, mints0)
+        are stepper-independent. The ledger's dispatch stamp is taken
+        when an async call RETURNS (the device starts once it has the
+        program; the call is the host's time), and before a
+        synchronous one (which is dispatch and wait at once)."""
+        with self._lock:
+            reqs = list(self._slots)
+        inf = _Inflight(active, seqs, reqs, t0, mints0)
         st = self.stepper
+        is_async = self._steps_async()
+        if not is_async:
+            self.overlap_ledger.note_dispatch()
         try:
-            if (
-                not getattr(st, "speculative", False)
-                and hasattr(st, "step_async")
-            ):
+            if is_async:
                 inf.handle = st.step_async(active)
             else:
                 inf.result = self._device_step(active, seqs)
         except Exception as e:  # noqa: BLE001 — device crash boundary
             inf.exc = e
+        if is_async:
+            self.overlap_ledger.note_dispatch()
         return inf
 
-    def _collect_with_blame(self, inf: _Inflight):
+    def _collect_with_blame(self, inf: _Inflight, later=None):
         """The overlapped loop's sync point: materialize the in-flight
         step's tokens (or re-raise its deferred failure) and assign
         blame exactly like ``_step_with_blame`` — a failed call
         advanced nothing, so the synchronous probes retry from the
-        same state the failed dispatch saw. Returns ``(toks, counts,
-        blamed, used_verify)`` in the variable-advance shape."""
+        same state the failed dispatch saw. ``later``: the step
+        dispatched behind ``inf`` and still in the air; when ``inf``
+        fails it is dropped un-collected BEFORE the probes (it assumed
+        an advance that did not happen, and nothing of it has reached
+        the host), and the probes run against the slots of ``inf``'s
+        mask that still hold their request. Returns ``(toks, counts,
+        blamed, used_verify, failed)`` in the variable-advance shape."""
         active = inf.active
         try:
             if inf.exc is not None:
@@ -973,13 +1136,19 @@ class ContinuousBatcher:
                     np.where(active, 1, 0).astype(np.int64),
                     [],
                     np.zeros(len(active), bool),
+                    False,
                 )
             toks, counts, used = inf.result
-            return toks, counts, [], used
+            return toks, counts, [], used, False
         except Exception:  # noqa: BLE001 — device crash boundary
             with self._lock:
                 self.counters["step_failures"] += 1
-        return self._assign_blame(active, inf.seqs)
+                held = inf.held(self._slots)
+        self._step_failed = True
+        if later is not None:
+            later.drop()
+            self._inflight = None
+        return (*self._assign_blame(held, inf.seqs), True)
 
     def _preempt_phase(self, blocked) -> bool:
         """The overlapped loop's deferred preemption: decided AFTER
@@ -1139,6 +1308,9 @@ class ContinuousBatcher:
             "iter": self._sched_iters, "active": 0,
             "prefilling": len(self._prefill_left),
             "queue_depth": len(self._queue),
+            # the overlapped loop's: 1 if this call dispatched its step
+            # behind one in the air; slot-steps its collect discarded
+            "ahead": 0, "discarded": 0,
         }
         if paged:
             total = self.stepper.total_pages
@@ -1148,17 +1320,20 @@ class ContinuousBatcher:
         self._iter_counts = counts
         return progressed, blocked, len(admitted)
 
-    def _mask_phase(self):
+    def _mask_phase(self, ahead_of: _Inflight | None = None):
         """Deadline-sweep slots that produce no tokens (mid-prefill,
         awaiting-fork) and compute the decode active mask + optional
         per-slot host sequences. Runs immediately before dispatch in
-        both loop modes."""
+        both loop modes. ``ahead_of``: a step still in the air — the
+        mask is then the one its collect WILL leave: a slot whose
+        budget that step exhausts (it emits one token a slot) sits
+        this one out, as it would once evicted."""
         with self._span("serving/mask"):
-            active, seqs = self._mask()
+            active, seqs = self._mask(ahead_of)
             self._iter_counts["active"] = int(active.sum())
         return active, seqs
 
-    def _mask(self):
+    def _mask(self, ahead_of=None):
         now = time.monotonic()
         with self._lock:
             # deadline sweep for slots still mid-prefill AND groups
@@ -1198,6 +1373,14 @@ class ContinuousBatcher:
                 ],
                 bool,
             )
+            if ahead_of is not None:
+                for i in np.flatnonzero(
+                    active & ahead_of.held(self._slots)
+                ):
+                    req = self._slots[i]
+                    done = len(req.completions[self._slot_comp[i]]) + 1
+                    if done >= req.max_new_tokens:
+                        active[i] = False
             seqs = None
             if active.any() and getattr(
                 self.stepper, "wants_sequences", False
@@ -1216,7 +1399,7 @@ class ContinuousBatcher:
         return active, seqs
 
     def _finish_step(self, active, step_t0, mints0, toks, counts,
-                     blamed, used_verify) -> bool:
+                     blamed, used_verify, reqs=None) -> bool:
         """Emission/eviction for one collected device step (the former
         tail of the monolithic ``step``): decode-phase mint
         attribution, blame eviction + quarantine, per-token budget /
@@ -1225,14 +1408,17 @@ class ContinuousBatcher:
         acceptance counters, and the recorder's iteration line."""
         with self._span("serving/emit") as sp:
             emitted = self._emit(
-                active, step_t0, mints0, toks, counts, blamed, used_verify
+                active, step_t0, mints0, toks, counts, blamed,
+                used_verify, reqs,
             )
             sp.set_metadata(emitted=emitted)
         return True
 
     def _emit(self, active, step_t0, mints0, toks, counts, blamed,
-              used_verify) -> int:
-        """``_finish_step``'s body; returns the tokens emitted."""
+              used_verify, reqs=None) -> int:
+        """``_finish_step``'s body; returns the tokens emitted.
+        ``reqs``: the request each slot held when the step was
+        dispatched (the overlapped loop's; None = as they are now)."""
         now = time.monotonic()
         if self._led_total() > mints0:
             # a mint landed inside the decode phase: every traced
@@ -1245,7 +1431,6 @@ class ContinuousBatcher:
                 noted.add(id(r))
                 self._note_mints(r, mints0, step_t0, now)
         emitted_total = 0
-        n_active = int(active.sum())
         # the queue and the pool as this iteration's admission left
         # them, on the tape beside what the step emitted
         c = self._iter_counts
@@ -1255,6 +1440,27 @@ class ContinuousBatcher:
             "page_waits": c.get("page_waits"),
         }
         with self._lock:
+            if reqs is not None:
+                # THE GUARD of the two-deep loop: a slot of this step's
+                # mask may have been evicted at the collect of the step
+                # before it (EOS, deadline, blame), after this step was
+                # dispatched, and even given to a new tenant since. The
+                # step's token for it is discarded, here and in the
+                # stepper's collect (lengths and sample positions stay
+                # the new tenant's). What the discarded slot-step wrote
+                # on the device is harmless by device order: its page
+                # write lands inside the old tenant's reservation (a
+                # slot that ends by EOS or deadline is short of
+                # max_new), pages freed at the eviction are written
+                # again only by programs dispatched AFTER this step
+                # (and no position is read before its tenant has
+                # written it), and the context row likewise.
+                held = _still_held(active, reqs, self._slots)
+                discarded = int(active.sum()) - int(held.sum())
+                self.counters["discarded_slot_steps"] += discarded
+                self._iter_counts["discarded"] = discarded
+                active = held
+            n_active = int(active.sum())
             self.counters["steps"] += 1
             self.counters["occupancy_sum"] += n_active
             for i in blamed:
@@ -1620,8 +1826,9 @@ class ContinuousBatcher:
         synchronous steps): newest-admission masked retry, then
         bisection. Same return shape as ``_step_with_blame``."""
         idxs = [int(i) for i in np.flatnonzero(active)]
-        if len(idxs) == 1:
-            # alone in the batch = culpable by elimination
+        if len(idxs) <= 1:
+            # alone in the batch = culpable by elimination (none: every
+            # slot of the failed step's mask has left since)
             return None, None, idxs, np.zeros(len(active), bool)
         with self._lock:
             suspect = max(idxs, key=lambda i: self._admit_order[i])
@@ -1927,7 +2134,9 @@ class ContinuousBatcher:
             # handle is dropped UNCOLLECTED (every slot is released
             # below, re-admission re-initializes per-slot state, and a
             # supervisor restart rebuilds the stepper outright)
-            self._inflight = None
+            inflight, self._inflight = self._inflight, None
+            if inflight is not None:
+                inflight.drop()
             self.overlap_ledger.discard()
             while self._queue:
                 req = self._queue.popleft()
@@ -2042,10 +2251,7 @@ class ContinuousBatcher:
             }
         else:
             out["qos"] = {"enabled": False}
-        out["overlap"] = {
-            "enabled": self.overlap,
-            **self.overlap_ledger.snapshot(),
-        }
+        out["overlap"] = self.overlap_stats()
         st = self.stepper
         if getattr(st, "speculative", False):
             drafted = int(getattr(st, "spec_drafted_tokens", 0))
@@ -2077,6 +2283,27 @@ class ContinuousBatcher:
         else:
             out["speculative"] = {"enabled": False}
         return out
+
+    def overlap_stats(self) -> dict:
+        """The ``overlap`` block of ``stats()`` and ``health()``: the
+        bubble ledger, and how deep the overlapped loop ran —
+        ``ahead_steps`` of ``steps`` were dispatched with a step in
+        the air, ``drained`` counts the calls that collected first by
+        reason (``_lookahead_refusal``), ``discarded_slot_steps`` the
+        slot-steps thrown away because the slot's tenant left between
+        dispatch and collect."""
+        c = self.counters
+        return {
+            "enabled": self.overlap,
+            **self.overlap_ledger.snapshot(),
+            "steps": c["steps"],
+            "ahead_steps": c["ahead_steps"],
+            "drained": {
+                r: c[f"drained_{r}"] for r in _DRAIN_REASONS
+                if c[f"drained_{r}"]
+            },
+            "discarded_slot_steps": c["discarded_slot_steps"],
+        }
 
     def wait_for_work(self, timeout=0.05):
         """Engine-loop helper: park until a submit/drain signal."""

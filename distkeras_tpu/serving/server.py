@@ -225,7 +225,10 @@ class ServingServer:
                 ]
                 self._conn_threads.append(th)
                 self._conns.add(conn)
-            th.start()
+                # started under the lock: shutdown() joins what it finds
+                # in the list, and a thread cannot be joined before it
+                # has been started
+                th.start()
 
     def _serve_conn(self, conn: socket.socket):
         try:

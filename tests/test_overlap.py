@@ -357,3 +357,398 @@ def test_sequential_mode_is_unchanged_one_call_emits():
     assert r.result().tolist() == [1, 2, 3, 1001]
     # the sequential control stamps the same ledger
     assert b.overlap_ledger.iterations == 1
+
+
+# ------------------------------------------------- two steps in the air
+
+
+class PositionStepper:
+    """A fake with the real stepper's discipline, so that a wrong
+    look-ahead shows in the tokens: slot ``i`` at host length ``L``
+    emits ``base + 100 * i + L`` (a pure function of the position, as
+    a greedy decode's token is of its context), host lengths and sample
+    positions advance only at ``collect()``, in dispatch order, a step
+    dispatched with others in the air passes lengths as they WILL be,
+    and a released slot owes the steps in the air nothing. Every
+    dispatch / collect / discard is logged with its step number."""
+
+    def __init__(self, num_slots=2, max_len=64, base=1000, poison=None):
+        self.num_slots, self.max_len, self.base = num_slots, max_len, base
+        self.poison = poison  # a step with this slot fails at collect
+        self.lens = np.ones(num_slots, int)
+        self.spos = np.zeros(num_slots, int)
+        self.tenancy = np.zeros(num_slots, int)
+        self.air = []
+        self.log = []  # (event, step number)
+        self.seen = []  # (lens, spos) each dispatch passed, by step
+        self.admitted = []
+        self.constrained_slots = set()
+
+    def begin_admit(self, slot, prompt, **kw):
+        self.admitted.append(slot)
+        self.lens[slot] = len(np.asarray(prompt))
+        self.spos[slot] = 0
+        return 0
+
+    def release(self, slot):
+        self.tenancy[slot] += 1
+        self.lens[slot] = 1
+        self.spos[slot] = 0
+
+    def step(self, active):
+        return self.step_async(active).collect()
+
+    def step_async(self, active):
+        st = self
+        active = np.asarray(active, bool)
+        owed = sum((h.owed().astype(int) for h in self.air),
+                   np.zeros(self.num_slots, int))
+        lens = self.lens + owed
+        number = len(self.seen)
+        self.seen.append((lens.copy(), self.spos + owed))
+        toks = np.where(
+            active, self.base + 100 * np.arange(self.num_slots) + lens, -1
+        )
+        boom = self.poison is not None and active[self.poison]
+
+        class Handle:
+            tenancy = self.tenancy.copy()
+
+            def ready(self):
+                return True
+
+            def owed(self):
+                return active & (self.tenancy == st.tenancy)
+
+            def discard(self):
+                st.log.append(("discard", number))
+                st.air.remove(self)
+
+            def collect(self):
+                st.log.append(("collect", number))
+                st.air.remove(self)
+                if boom:
+                    raise RuntimeError("poison slot in batch")
+                owed = self.owed()
+                st.lens[owed] += 1
+                st.spos[owed] += 1
+                return toks
+
+        self.log.append(("dispatch", number))
+        handle = Handle()
+        self.air.append(handle)
+        return handle
+
+
+def _run(b, reqs=(), n=200):
+    """Drive the batcher until it is idle; each request's chunks."""
+    for _ in range(n):
+        if b.idle:
+            break
+        b.step()
+    assert b.idle
+    out = []
+    for r in reqs:
+        chunks = []
+        while r.stream:
+            c = r.next_chunk(timeout=0.1)
+            if c is None:
+                break
+            chunks.append(list(c))
+        out.append(chunks)
+    return out
+
+
+def test_lookahead_dispatches_step_k_before_it_collects_step_k_minus_1():
+    st = PositionStepper(num_slots=2)
+    b = ContinuousBatcher(st, overlap=True)
+    reqs = [b.submit(_req(max_new=4)) for _ in range(2)]
+    _run(b)
+    assert st.log == [
+        ("dispatch", 0),
+        ("dispatch", 1), ("collect", 0),
+        ("dispatch", 2), ("collect", 1),
+        # step 2's token leaves one of the budget: step 3 is the last,
+        # and the look-ahead mask behind it is empty
+        ("dispatch", 3), ("collect", 2),
+        ("collect", 3),
+    ]
+    # lengths and sample positions as they WILL be: one more a step
+    # although the host's advanced one collect later
+    assert [tuple(l) for l, _ in st.seen] == [(3, 3), (4, 4), (5, 5), (6, 6)]
+    assert [tuple(p) for _, p in st.seen] == [(0, 0), (1, 1), (2, 2), (3, 3)]
+    for i, r in enumerate(reqs):
+        assert r.result().tolist() == [1, 2, 3] + [
+            1000 + 100 * i + n for n in (3, 4, 5, 6)
+        ]
+    ov = b.stats()["overlap"]
+    assert ov["steps"] == 4 and ov["ahead_steps"] == 3
+    assert ov["drained"] == {} and ov["discarded_slot_steps"] == 0
+    assert not st.air
+
+
+def _preemptible():
+    from distkeras_tpu.serving.qos import QosPolicy
+
+    class Swappable(PositionStepper):
+        def swap_out(self, slot):
+            return {"len": int(self.lens[slot]), "spos": int(self.spos[slot])}
+
+        def swap_in(self, slot, state, max_new=None):
+            self.lens[slot], self.spos[slot] = state["len"], state["spos"]
+
+    st = Swappable(num_slots=1)
+    b = ContinuousBatcher(
+        st, overlap=True, qos=QosPolicy(preempt=True, max_preemptions=1),
+    )
+    lo = b.submit(_req(max_new=6, tenant="a", priority=0))
+    b.step()
+    b.step()
+    hi = _req(max_new=2, tenant="b", priority=2)
+    return st, b, [lo], hi
+
+
+def _constrained_by_stepper():
+    st = PositionStepper(num_slots=1)
+    st.constrained_slots = {0}
+    return st, ContinuousBatcher(st, overlap=True), [], _req(max_new=4)
+
+
+def _wants_sequences():
+    st = PositionStepper(num_slots=1)
+    st.wants_sequences = True
+    return st, ContinuousBatcher(st, overlap=True), [], _req(max_new=4)
+
+
+@pytest.mark.parametrize("reason,build", [
+    ("wants_sequences", _wants_sequences),
+    ("grammar", _constrained_by_stepper),
+    ("preempt", _preemptible),
+])
+def test_each_fallback_reason_runs_one_step_deep_and_is_counted(
+    reason, build
+):
+    st, b, before, req = build()
+    b.submit(req)
+    _run(b)
+    assert req.done and all(r.done for r in before)
+    assert b.stats()["overlap"]["drained"].get(reason, 0) >= 1
+    if reason == "preempt":
+        # only the call that preempts drains; the step after the
+        # swap-out is dispatched with nothing in the air
+        assert b.counters["preemptions"] == b.counters["resumes"] == 1
+        first = st.log.index(("collect", 1))
+        assert st.log[first + 1] == ("dispatch", 2)
+    else:
+        # every call collects before it dispatches
+        events = [e for e, _ in st.log]
+        assert events == ["dispatch", "collect"] * (len(events) // 2)
+        assert b.stats()["overlap"]["ahead_steps"] == 0
+    # a stream is its positions in order, whatever the depth
+    assert req.result().tolist()[3:] == [
+        1000 + n for n in range(3, 3 + req.max_new_tokens)
+    ]
+    for r in before:
+        assert r.result().tolist()[3:] == [1000 + n for n in range(3, 9)]
+    assert not st.air
+
+
+@pytest.mark.parametrize("stepper", [FakeStepper, "speculative"])
+def test_sync_steppers_never_look_ahead(stepper):
+    from test_serving import FakeSpecStepper
+
+    st = (
+        FakeSpecStepper(num_slots=1) if stepper == "speculative"
+        else stepper(num_slots=1)
+    )
+    b = ContinuousBatcher(st, overlap=True)
+    r = b.submit(_req(max_new=6))
+    _run(b)
+    assert r.done
+    ov = b.stats()["overlap"]
+    assert ov["ahead_steps"] == 0
+    # every call that found a step in the air collected it first
+    assert ov["drained"] == {"sync_stepper": ov["steps"]}
+
+
+def _finish_kinds(overlap):
+    """Four streams on three slots: one ends by its budget, one by EOS
+    (and a queued request takes its slot while the discarded step is
+    in the air), one by its deadline; the late one by its budget."""
+    st = PositionStepper(num_slots=3)
+    b = ContinuousBatcher(st, overlap=overlap)
+    budget = b.submit(_req(max_new=5, stream=True))
+    eos = b.submit(_req(max_new=9, eos_id=1100 + 5, stream=True))
+    dead = b.submit(_req(max_new=9, stream=True))
+    late = b.submit(_req(plen=5, max_new=3, stream=True))
+    for _ in range(50):
+        if len(dead.tokens) >= 4:
+            break
+        b.step()
+    dead.deadline = 0.0  # expires at its next emission
+    reqs = [budget, eos, dead, late]
+    return st, b, reqs, _run(b, reqs)
+
+
+def test_streams_equal_the_sequential_controls_over_every_finish():
+    _, seq_b, seq_reqs, seq_chunks = _finish_kinds(False)
+    st, b, reqs, chunks = _finish_kinds(True)
+    assert chunks == seq_chunks  # chunk for chunk
+    for r, sr in zip(reqs, seq_reqs):
+        assert r.tokens == sr.tokens  # token for token
+        assert type(r.error) is type(sr.error)
+    budget, eos, dead, late = reqs
+    assert budget.tokens == [1003, 1004, 1005, 1006, 1007]
+    assert eos.tokens == [1103, 1104, 1105] and eos.error is None
+    assert dead.tokens == [1203, 1204, 1205, 1206, 1207]
+    assert late.tokens == [1105, 1106, 1107]  # slot 1 again, from ITS length
+    ov, seq_ov = b.stats()["overlap"], seq_b.stats()["overlap"]
+    # the EOS and the deadline each cost one slot-step; the budget none
+    assert ov["discarded_slot_steps"] == 2
+    assert seq_ov["discarded_slot_steps"] == seq_ov["ahead_steps"] == 0
+    assert ov["ahead_steps"] >= ov["steps"] - 2 and not ov["drained"]
+    assert b.counters["tokens_generated"] == 16
+    assert b.counters["occupancy_sum"] == seq_b.counters["occupancy_sum"]
+
+
+def test_new_tenant_admitted_under_the_old_tenants_discarded_step():
+    st = PositionStepper(num_slots=1)
+    b = ContinuousBatcher(st, overlap=True)
+    old = b.submit(_req(max_new=9, eos_id=1004))
+    new = b.submit(_req(plen=6, max_new=3))
+    _run(b)
+    assert old.result().tolist() == [1, 2, 3, 1003, 1004]
+    # step 2 held the old tenant at length 5 and was in the air when
+    # the new tenant took the slot: dispatched behind it, step 3 passed
+    # the NEW tenant's own length and sample position, and step 2's
+    # collect left them alone
+    assert st.log[:7] == [
+        ("dispatch", 0), ("dispatch", 1), ("collect", 0),
+        ("dispatch", 2), ("collect", 1), ("dispatch", 3), ("collect", 2),
+    ]
+    assert st.seen[2][0][0] == 5 and st.seen[3] == ([6], [0])
+    assert new.result().tolist()[6:] == [1006, 1007, 1008]
+    assert b.stats()["overlap"]["discarded_slot_steps"] == 1
+
+
+def test_stop_with_a_lookahead_engaged_drops_the_handle():
+    st = PositionStepper(num_slots=1)
+    b = ContinuousBatcher(st, overlap=True)
+    r = b.submit(_req(max_new=9))
+    b.step()
+    b.step()  # step 1 dispatched behind step 0, step 0 collected
+    assert st.log[-2:] == [("dispatch", 1), ("collect", 0)]
+    b.stop()  # the request is cancelled with step 1 in the air
+    assert st.log[-1] == ("discard", 1) and not st.air and b.idle
+    assert r.done and r.tokens == [1003]
+    with pytest.raises(Exception):
+        r.result()
+
+
+def test_collect_raises_with_two_in_the_air():
+    st = PositionStepper(num_slots=3, poison=2)
+    b = ContinuousBatcher(st, overlap=True, quarantine_steps=100)
+    good = [b.submit(_req(max_new=6)) for _ in range(2)]  # slots 0, 1
+    b.step()  # step 0 in the air
+    b.step()
+    bad = b.submit(_req(plen=4, max_new=4))  # -> slot 2
+    b.step()  # admits the poison; step 2 holds it, step 1 collected
+    before = st.lens.copy(), st.spos.copy()
+    n_log = len(st.log)
+    b.step()  # step 3 behind step 2; step 2's collect raises
+    events = st.log[n_log:]
+    # the later step is dropped un-collected BEFORE the probes run
+    assert events[:3] == [("dispatch", 3), ("collect", 2), ("discard", 3)]
+    # the probes passed the lengths and sample positions as they were
+    # when step 2 was dispatched: nothing of either step had advanced
+    probe = events[3][1]
+    assert st.seen[probe][0].tolist() == before[0].tolist()
+    assert st.seen[probe][1].tolist() == before[1].tolist()
+    with pytest.raises(InternalError, match="blamed"):
+        bad.result()
+    ov = b.stats()["overlap"]
+    assert b.counters["step_failures"] == 1
+    assert b.counters["blame_probes"] >= 1
+    # the survivors advanced exactly one position in the failed call
+    assert st.lens.tolist() == [before[0][0] + 1, before[0][1] + 1, 1]
+    # the call after the failure runs one step deep, then it looks
+    # ahead again
+    n_log = len(st.log)
+    b.step()
+    assert [e for e, _ in st.log[n_log:]] == ["collect", "dispatch"]
+    assert b.stats()["overlap"]["drained"] == {"failed_step": 1}
+    _run(b)
+    for slot, r in enumerate(good):
+        assert r.result().tolist()[3:] == [
+            1000 + 100 * slot + n for n in (3, 4, 5, 6, 7, 8)
+        ]
+    assert b.stats()["overlap"]["ahead_steps"] > ov["ahead_steps"]
+    assert not st.air
+
+
+def test_dispatch_raise_with_a_step_in_the_air_rides_its_own_record():
+    class DispatchBoom(PositionStepper):
+        boom_at = 2
+
+        def step_async(self, active):
+            if len(self.seen) == self.boom_at and self.air:
+                self.boom_at = None
+                raise RuntimeError("injected dispatch crash")
+            return super().step_async(active)
+
+    st = DispatchBoom(num_slots=2)
+    b = ContinuousBatcher(st, overlap=True, quarantine_steps=100)
+    a = b.submit(_req(max_new=5))
+    c = b.submit(_req(max_new=5))
+    b.step()
+    b.step()
+    b.step()  # step 2's dispatch raises behind step 1; step 1 collected
+    assert b.counters["step_failures"] == 0 and len(a.tokens) == 2
+    b.step()  # its own collect: the probes run one step deep
+    assert b.counters["step_failures"] == 1
+    assert b.stats()["overlap"]["drained"] == {"failed_step": 1}
+    _run(b)
+    # the newest admission is the prime suspect of a failure that names
+    # no slot; the other stream is whole
+    assert a.result().tolist()[3:] == [1003, 1004, 1005, 1006, 1007]
+    with pytest.raises(InternalError, match="blamed"):
+        c.result()
+
+
+def test_ledger_with_two_open_stamps():
+    led, reg, clock = _ledger()
+    # step 0: handed over @1 (its call began @0: host time, not the
+    # device's), ready @4
+    clock.t = 1.0
+    led.note_dispatch()
+    # step 1's call runs while step 0 does: handed over @3
+    clock.t = 3.0
+    led.note_dispatch()
+    clock.t = 4.0
+    led.note_ready()  # the OLDEST open step's
+    clock.t = 4.5
+    led.note_collect()  # closes step 0: device 4 - 1, wall 4.5 - 1
+    assert led.iterations == 1
+    assert led.device_seconds == pytest.approx(3.0)
+    assert led.iteration_seconds == pytest.approx(3.5)
+    # step 1 started when the device ended step 0 (@4), not at its
+    # stamp (@3); never polled, collected @7: device 7 - 4, wall
+    # collect-to-collect 7 - 4.5
+    clock.t = 7.0
+    led.note_collect()
+    assert led.iterations == 2
+    assert led.device_seconds == pytest.approx(3.0 + 2.5)  # clipped to wall
+    assert led.iteration_seconds == pytest.approx(3.5 + 2.5)
+    # two open, the older one's collect raises: close it, drop the other
+    clock.t = 8.0
+    led.note_dispatch()
+    clock.t = 8.5
+    led.note_dispatch()
+    clock.t = 9.0
+    led.note_collect()
+    led.discard()
+    assert led.iterations == 3
+    led.note_collect()  # nothing open: no-op
+    assert led.iterations == 3
+    assert led.device_seconds == pytest.approx(5.5 + 1.0)
+    assert led.iteration_seconds == pytest.approx(6.0 + 2.0)

@@ -1252,3 +1252,123 @@ def test_engine_from_bundle_and_non_lm_predict_only(tmp_path):
             eng.generate(np.arange(3), 4)
     finally:
         eng.stop()
+
+
+# ------------------------------- two steps deep on the real DecodeStepper
+
+
+def _lookahead_model(layout):
+    from distkeras_tpu.models import zoo
+
+    if layout == "latent":
+        lm = zoo.mla_moe_lm(
+            vocab_size=61, seq_len=48, hidden_size=32, num_heads=2,
+            qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+            kv_lora_rank=16, intermediate_size=32,
+            moe_intermediate_size=16, n_routed_experts=4,
+            num_experts_per_tok=2, num_layers=2, seed=0)
+        return lm, dict(paged=True, page_size=8, prefill_chunk=8)
+    lm = zoo.transformer_lm(
+        vocab_size=61, seq_len=48, d_model=32, num_heads=2, depth=2,
+        seed=0)
+    if layout == "kv":
+        return lm, dict(paged=True, page_size=4, prefill_chunk=4)
+    return lm, dict(prefill_chunk=4)  # the dense slot bank
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("layout", ["kv", "latent", "bank"])
+def test_replies_under_lookahead_equal_the_sequential_engines(
+    layout, sampled
+):
+    """The overlapped engine dispatches step n+1 before it collects
+    step n; its replies are token for token ``overlap=False``'s, over
+    budget and EOS finishes and slots that change tenant under a
+    discarded step, and it compiles the programs the sequential engine
+    compiles: the same keys at the same argument signatures."""
+    from distkeras_tpu.serving import ServingEngine
+    from distkeras_tpu.serving.sampling import SamplingParams
+
+    lm, kw = _lookahead_model(layout)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 61, n).astype(np.int32)
+               for n in (5, 2, 13, 7, 3)]
+    budgets = (9, 4, 6, 11, 8)
+
+    def serve(eng, eos=None):
+        reqs = [
+            eng.submit(
+                p, n, eos_id=eos,
+                sampling=SamplingParams(temperature=0.9, top_k=12, seed=7 + i)
+                if sampled else None,
+            )
+            for i, (p, n) in enumerate(zip(prompts, budgets))
+        ]
+        return [np.asarray(r.result(timeout=120)).tolist() for r in reqs]
+
+    def run(overlap, eos=None):
+        eng = ServingEngine(lm, num_slots=2, overlap=overlap, **kw)
+        eng.start()
+        try:
+            plain = serve(eng)
+            if eos is None:  # the third token the first request is given
+                eos = plain[0][len(prompts[0]) + 2]
+            ended = serve(eng, eos)
+            st = eng._stepper
+            return plain, ended, eos, eng.stats(), dict(
+                programs=sorted(eng.compile_ledger._seen, key=repr),
+                step_keys=sorted(
+                    st._pstep_fns if kw.get("paged") else st._step_fns),
+                lens=st._lens.tolist(), spos=st._spos.tolist(),
+                in_the_air=len(st._air),
+            )
+        finally:
+            eng.stop()
+
+    want_plain, want_ended, eos, seq_stats, seq = run(False)
+    plain, ended, _, stats, ov = run(True, eos)
+    assert plain == want_plain and ended == want_ended
+    # it did end by EOS, short of its budget
+    assert want_ended[0][-1] == eos and len(want_ended[0]) < len(plain[0])
+    assert ov == seq  # no program the sequential engine has not; all parked
+    look = stats["overlap"]
+    assert look["ahead_steps"] > 0.5 * look["steps"] and not look["drained"]
+    assert look["discarded_slot_steps"] >= 1
+    seq_look = seq_stats["overlap"]
+    assert seq_look["ahead_steps"] == seq_look["discarded_slot_steps"] == 0
+    if kw.get("paged"):
+        assert (stats["paged"]["compiled_step_buckets"]
+                == seq_stats["paged"]["compiled_step_buckets"])
+
+
+def test_a_slot_with_a_grammar_keeps_the_real_engine_one_step_deep():
+    """The stepper's ``constrained_slots`` is what the loop observes:
+    while a constrained slot decodes every call collects first (its
+    token mask is built from the token), and replies are the
+    sequential engine's."""
+    from distkeras_tpu.serving import ServingEngine
+    from distkeras_tpu.serving.sampling import SamplingParams
+
+    lm, kw = _lookahead_model("kv")
+    allow = SamplingParams(
+        grammar={"kind": "allow", "tokens": [3, 5, 8, 13]})
+    prompts = [np.arange(1, 6, dtype=np.int32), np.arange(7, 10, dtype=np.int32)]
+
+    def run(overlap):
+        eng = ServingEngine(lm, num_slots=2, overlap=overlap, **kw)
+        eng.start()
+        try:
+            reqs = [eng.submit(prompts[0], 6, sampling=allow),
+                    eng.submit(prompts[1], 12)]
+            out = [np.asarray(r.result(timeout=120)).tolist() for r in reqs]
+            return out, eng.stats()["overlap"]
+        finally:
+            eng.stop()
+
+    want, _ = run(False)
+    got, look = run(True)
+    assert got == want
+    assert set(got[0][5:]) <= {3, 5, 8, 13}
+    assert look["drained"].get("grammar", 0) >= 3
+    # once the constrained request has left, the loop looks ahead again
+    assert look["ahead_steps"] >= 1
