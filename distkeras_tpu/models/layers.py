@@ -65,6 +65,7 @@ def layer_from_config(cfg: dict):
     if name not in _LAYER_REGISTRY:
         # the blocks that live beside this module register when imported:
         # a process that only loads a bundle has not imported them yet
+        import distkeras_tpu.models.gqa_moe  # noqa: F401
         import distkeras_tpu.models.mla_moe  # noqa: F401
         import distkeras_tpu.parallel.expert_parallel  # noqa: F401
     return _LAYER_REGISTRY[name](**cfg)

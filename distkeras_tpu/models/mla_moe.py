@@ -108,14 +108,19 @@ def rms_norm(x, gamma, eps):
     return y * gamma.astype(jnp.float32)
 
 
-def rope(x, pos, theta):
+def rope(x, pos, theta, freq=None, factor=1.0):
     """Rotate the pairs ``(2i, 2i+1)`` of the last axis by ``pos *
     theta^(-2i/n)`` (the interleaved pairing; ``n`` the axis' size).
-    ``pos`` broadcasts against ``x``'s leading axes."""
+    ``pos`` broadcasts against ``x``'s leading axes. ``freq`` ``(n/2,)``
+    gives the pairs' frequencies in ``theta``'s place (scaled rotary
+    positions blend them), ``factor`` multiplies cosine and sine."""
     n = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, n, 2, dtype=jnp.float32) / n)
+    if freq is None:
+        freq = theta ** (-jnp.arange(0, n, 2, dtype=jnp.float32) / n)
     ang = pos[..., None].astype(jnp.float32) * freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x = x.astype(jnp.float32)
     x0, x1 = x[..., 0::2], x[..., 1::2]
     return jnp.stack(
@@ -128,12 +133,16 @@ def gated_mlp(p, x):
     return matmul(jax.nn.silu(matmul(x, p["wg"])) * matmul(x, p["wu"]), p["wd"])
 
 
-def route(p, x, top_k, scale, softmax=False):
-    """Scores over ALL the router's outputs in float32; the top ``k`` of
-    score + selection bias. Sigmoid scores: weights = chosen scores over
-    their sum, times ``scale``; ``softmax``: scores a softmax over the
-    outputs, weights = chosen scores times ``scale``, not normalised.
+def route(p, x, top_k, scale, softmax=False, normalise=None):
+    """Scores over ALL the router's outputs in float32 (sigmoid, or with
+    ``softmax`` a softmax over the outputs); the top ``k`` of score +
+    selection bias (a router without a ``bias`` selects by score);
+    weights = the chosen scores times ``scale``, with ``normalise``
+    first divided by their sum (None: sigmoid scores are, softmax
+    scores are not, the two forms the latent blocks use).
     Returns ``(chosen (n, k) int32, weights (n, k) f32)``."""
+    if normalise is None:
+        normalise = not softmax
     with jax.named_scope("moe/route"):
         logits = jnp.dot(
             x.astype(jnp.float32), p["wr"].astype(jnp.float32),
@@ -141,9 +150,10 @@ def route(p, x, top_k, scale, softmax=False):
         )
         s = jax.nn.softmax(logits, axis=-1) if softmax else jax.nn.sigmoid(
             logits)
-        _, chosen = jax.lax.top_k(s + p["bias"].astype(jnp.float32), top_k)
+        biased = s + p["bias"].astype(jnp.float32) if "bias" in p else s
+        _, chosen = jax.lax.top_k(biased, top_k)
         w = jnp.take_along_axis(s, chosen, axis=-1)
-        if not softmax:
+        if normalise:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return chosen.astype(jnp.int32), w * scale
 

@@ -478,6 +478,114 @@ def longcat_flash_lm(
     return model
 
 
+def laguna_lm(
+    vocab_size=256,
+    seq_len=128,
+    hidden_size=64,
+    num_key_value_heads=2,
+    head_dim=16,
+    intermediate_size=128,
+    moe_intermediate_size=32,
+    shared_expert_intermediate_size=32,
+    num_experts=8,
+    num_experts_per_tok=3,
+    layer_types=("full_attention", "sliding_attention",
+                 "sliding_attention", "sliding_attention",
+                 "full_attention"),
+    num_attention_heads_per_layer=(4, 6, 6, 6, 4),
+    mlp_layer_types=("dense", "sparse", "sparse", "sparse", "sparse"),
+    gating_types=("per_head",) * 5,
+    sliding_window=8,
+    rope_parameters=None,
+    moe_routed_scaling_factor=2.5,
+    norm_topk_prob=True,
+    rms_norm_eps=1e-6,
+    experts_held=None,
+    seed=0,
+):
+    """Causal language model of grouped-query blocks (the ``laguna`` model
+    type, under its published keys): Embedding without a position table ->
+    one ``GroupedQueryMoEBlock`` a layer -> RMSNorm -> an untied head
+    without bias. Layer ``l`` has ``num_attention_heads_per_layer[l]``
+    query heads over ``num_key_value_heads`` K/V heads of ``head_dim``, a
+    gate a head (``gating_types[l]``: ``"per_head"``), attends everything
+    (``layer_types[l]`` ``"full_attention"``) or the last ``sliding_window``
+    positions (``"sliding_attention"``) with that kind's entry of
+    ``rope_parameters`` (``{kind: {"rope_theta", "partial_rotary_factor",
+    "rope_type": "default" | "yarn", and for YaRN "factor",
+    "original_max_position_embeddings", "beta_fast", "beta_slow",
+    "attention_factor"}}``; None: plain rotary, theta 1e4, whole heads),
+    and a gated MLP of ``intermediate_size`` (``mlp_layer_types[l]``
+    ``"dense"``) or ``num_experts`` routed experts of
+    ``moe_intermediate_size`` (``num_experts_per_tok`` a token, softmax
+    scores normalised over the picks with ``norm_topk_prob``, times
+    ``moe_routed_scaling_factor``) beside a shared expert of
+    ``shared_expert_intermediate_size``. ``experts_held``: the routed
+    experts every expert layer holds (None: all); see
+    ``models/gqa_moe.py``. Serves through the paged ``ServingEngine``
+    with one page budget for the full layers and a ring a slot for the
+    window layers."""
+    from distkeras_tpu.models.gqa_moe import GroupedQueryMoEBlock
+    from distkeras_tpu.models.mla_moe import RMSNorm
+
+    n = len(layer_types)
+    if not (len(num_attention_heads_per_layer) == len(mlp_layer_types)
+            == len(gating_types) == n):
+        raise ValueError("the per-layer lists differ in length")
+    rope_parameters = rope_parameters or {}
+
+    def rope_of(kind):
+        r = rope_parameters.get(kind, {})
+        rope_type = r.get("rope_type", "default")
+        out = {"theta": float(r.get("rope_theta", 10000.0)),
+               "partial": float(r.get("partial_rotary_factor", 1))}
+        if rope_type == "yarn":
+            out.update(
+                factor=float(r["factor"]),
+                original=float(r["original_max_position_embeddings"]),
+                beta_fast=float(r["beta_fast"]),
+                beta_slow=float(r["beta_slow"]),
+                attention_factor=float(r.get("attention_factor", 1.0)))
+        elif rope_type != "default":
+            raise ValueError(f"rope_type {rope_type!r}")
+        return out
+
+    def block(i):
+        kind, mlp = layer_types[i], mlp_layer_types[i]
+        if kind not in ("full_attention", "sliding_attention") or \
+                mlp not in ("dense", "sparse") or \
+                gating_types[i] not in ("per_head", None):
+            raise ValueError(
+                f"layer {i}: {kind!r}, {mlp!r}, {gating_types[i]!r}")
+        moe = mlp == "sparse"
+        return GroupedQueryMoEBlock(
+            num_attention_heads_per_layer[i], num_key_value_heads, head_dim,
+            rope_of(kind),
+            window=sliding_window if kind == "sliding_attention" else None,
+            gate=gating_types[i],
+            ffn_width=0 if moe else intermediate_size,
+            n_experts=num_experts if moe else 0,
+            top_k=num_experts_per_tok if moe else 0,
+            expert_width=moe_intermediate_size if moe else 0,
+            shared_width=shared_expert_intermediate_size if moe else 0,
+            routed_scale=moe_routed_scaling_factor,
+            norm_topk=norm_topk_prob, epsilon=rms_norm_eps,
+            experts_held=experts_held if moe else None,
+            out_scale=(2 * n) ** -0.5,
+        )
+
+    model = Sequential(
+        [
+            Embedding(vocab_size, hidden_size, with_positions=False),
+            *[block(i) for i in range(n)],
+            RMSNorm(rms_norm_eps),
+            Dense(vocab_size, use_bias=False),
+        ]
+    )
+    model.build((seq_len,), seed=seed)
+    return model
+
+
 ZOO = {
     "mnist_mlp": mnist_mlp,
     "mnist_cnn": mnist_cnn,

@@ -63,6 +63,43 @@ kernel on the chip (PERF.md §6, PR 31). Against a float32 pool both
 products are float32 at ``HIGHEST``. Scale, mask, running maximum,
 normaliser and output are float32.
 
+**Layout ``"gqa"``** (``paged_decode_attention`` with fewer K/V heads than
+query heads, a first position, or a ring; ``_grouped_kernel``; PR 35): pools
+``(num_pages x page_size, Hkv x Dh)``, the row-major flattening of
+``(num_pages, page_size, Hkv, Dh)``, held flat for the reason the latent
+pool is (a 4-D bfloat16 pool of 8 heads tiles its two minor axes ``(8, 128)``
+in pairs of rows, and no reshape of it is free). A page is ``page_size``
+rows of the pool and a K/V head is a lane-aligned slice ``[h Dh, (h + 1) Dh)``
+of a row, so a copied block of pages gives each K/V head's keys as ``(tokens,
+Dh)`` with no relayout.
+
+*Products grouped by K/V head.* A K/V head's ``G = Hq / Hkv`` queries (padded
+to whole sublane tiles: 6 -> 8, 9 -> 16) against that head's keys: ``(G, Dh)
+@ (tokens, Dh)^T``, every entry one the attention needs, and the weights times
+the same head's slice of the values' block. PR 29's trick (every query against
+every head's keys, the diagonal kept) would cost ``Hkv`` = 8 times that. *What
+a block costs* at blocks of 16 pages of 16 tokens, 8 K/V heads of 128, a
+bfloat16 pool: 1 MiB of keys and values (1.28 us at the v5e's 819 GB/s) and
+``2 x 2 x Hq x Dh x 256`` operations: 6.3e6 at ``Hq`` 48, 9.4e6 at ``Hq`` 72
+(0.03 and 0.05 us at the MXU's peak; but an operand of 8 or 16 rows fills a
+sixteenth or an eighth of a pass, and each head's 128 x 128 tiles of keys and
+of values are loaded for it: 32 tile loads a block, the same number a byte as
+PR 29's body, which reads 72% of the bandwidth). A window layer's slot copies
+the pages from ``first // page_size`` on and no others: at most ``window /
+page_size + 1`` = 33, in three blocks of 11.
+
+*A first position and a ring.* ``first`` ``(B,)`` is the first position a slot
+attends (a window layer: ``max(0, length - window)``); the loop starts at its
+page and masks the positions before it. With ``ring`` the table has ``ring``
+columns and logical page ``p`` lies in column ``p % ring``: a window layer's
+pages are overwritten in place once they are behind the window
+(``serving/engine.py`` ``_build_grouped_pools``).
+
+*Precision.* As the latent body: the query and the softmax weights enter as
+ONE bfloat16 term against a bfloat16 pool (what ``models.mla_moe._operands``
+gives every product of that block), float32 at ``HIGHEST`` against a float32
+pool; scale, mask, running maximum, normaliser and output are float32.
+
 ``decode_attention_path`` is the one place that says whether a kernel
 serves a shape (as ``flash_attention.effective_path`` does for the
 trainer's kernel); the engine reads it when it builds its step program.
@@ -94,7 +131,8 @@ def decode_attention_path(layout, head_dim, kv_dtype, mesh=None,
                           page_size=None):
     """``"kernel"`` where a kernel of this module serves the paged decode
     step (:func:`paged_decode_attention` for layout ``"kv"``,
-    :func:`paged_latent_attention` for ``"latent"``), else ``"gather:
+    :func:`paged_latent_attention` for ``"latent"``, the grouped body of
+    :func:`paged_decode_attention` for ``"gqa"``), else ``"gather:
     <why>"`` — read from what the stepper can see of itself, never from
     a knob or a model's name."""
     if mesh is not None:
@@ -108,6 +146,16 @@ def decode_attention_path(layout, head_dim, kv_dtype, mesh=None,
         # copy starts and ends on a tile of the pool (Mosaic refuses it)
         if page_size is not None and page_size % _SUBLANES:
             return (f"gather: latent pages of {page_size} rows are not "
+                    f"whole tiles of {_SUBLANES} rows")
+    elif layout == "gqa":
+        # a grouped page is ``page_size`` rows of ``Hkv x Dh`` values in
+        # the flat pool: a head is a lane-aligned slice of a row, and a
+        # copy starts and ends on a tile of the pool, as for the latent
+        if head_dim % _LANES:
+            return (f"gather: heads of {head_dim} are not a whole number "
+                    f"of {_LANES} lanes")
+        if page_size is not None and page_size % _SUBLANES:
+            return (f"gather: grouped pages of {page_size} rows are not "
                     f"whole tiles of {_SUBLANES} rows")
     else:
         return f"gather: the {layout} page layout has its own stage body"
@@ -266,23 +314,207 @@ def _paged_decode_attention(q, ck, cv, table, lengths, *, block_pages,
     )
 
 
-def paged_decode_attention(q, ck, cv, table, lengths,
-                           block_pages=BLOCK_PAGES):
+def paged_decode_attention(q, ck, cv, table, lengths, first=None, *,
+                           page_size=None, ring=0, block_pages=None):
     """Single-query attention of ``B`` slots over a paged pool.
 
-    ``q``: ``(B, H, Dh)``; ``ck``, ``cv``: the pools as the engine holds
-    them, ``(num_pages, page_size, H, Dh)`` bfloat16 or float32;
-    ``table``: ``(B, pages)`` int32, a slot's pages in logical order
-    (entries past ``ceil(length / page_size)`` are never read);
-    ``lengths``: ``(B,)`` int32, the positions a slot attends (its own
-    newest included), 0 for a slot that is not decoding. Returns ``(B,
-    H, Dh)`` float32: ``softmax(q . k / sqrt(Dh)) . v`` over positions
-    ``< length``, zeros where the length is 0."""
-    return _paged_decode_attention(
-        q, ck, cv, table, lengths,
-        block_pages=min(int(block_pages), max(1, table.shape[1])),
+    ``q``: ``(B, Hq, Dh)``; ``ck``, ``cv``: the pools of keys and of
+    values, bfloat16 or float32, ``(num_pages, page_size, Hkv, Dh)`` with
+    ``Hq`` a multiple of ``Hkv``, as the engine holds them: 4-D where
+    ``Hkv == Hq`` (the GPT-2 block), and for fewer K/V heads than query
+    heads as that array's row-major flattening ``(num_pages x page_size,
+    Hkv x Dh)`` with ``page_size`` given (a 4-D pool of fewer K/V heads
+    is accepted and flattened here, which on a TPU is a copy of the
+    pool: for tests, not for a step); ``table``: ``(B, pages)`` int32, a
+    slot's pages in logical order (entries past ``ceil(length /
+    page_size)`` are never read), or with ``ring`` ``(B, ring)``: the
+    slot's ring, logical page ``p`` in column ``p % ring``; ``lengths``:
+    ``(B,)`` int32, the positions a slot attends (its own newest
+    included), 0 for a slot that is not decoding; ``first``: ``(B,)``
+    int32, the first position a slot attends (None: 0; a window layer:
+    ``max(0, length - window)``): pages wholly before it are not copied.
+    Returns ``(B, Hq, Dh)`` float32: ``softmax(q . k / sqrt(Dh)) . v``
+    over positions ``first <= s < length`` with query head ``j`` on K/V
+    head ``j // (Hq / Hkv)``, zeros where the length is 0.
+
+    With ``Hkv == Hq``, a 4-D pool, no first position and no ring this is
+    ``_kernel``, the program it was before K/V heads could be fewer.
+    Everything else is ``_grouped_kernel``."""
+    if ck.ndim == 4 and ck.shape[2] == q.shape[1] and first is None \
+            and not ring:
+        return _paged_decode_attention(
+            q, ck, cv, table, lengths,
+            block_pages=min(int(block_pages or BLOCK_PAGES),
+                            max(1, table.shape[1])),
+            interpret=pallas_interpret(),
+        )
+    if ck.ndim == 4:
+        page_size = ck.shape[1]
+        ck, cv = (c.reshape(c.shape[0] * c.shape[1], -1) for c in (ck, cv))
+    bp = int(block_pages or GROUPED_BLOCK_PAGES)
+    if ring:
+        # equal blocks that cover the ring: 33 pages go 11 at a time
+        bp = -(-int(ring) // -(-int(ring) // bp))
+    if first is None:
+        first = jnp.zeros_like(lengths)
+    return _paged_grouped_attention(
+        q, ck, cv, table, lengths, first, page_size=int(page_size),
+        ring=int(ring), block_pages=min(bp, max(1, table.shape[1])),
         interpret=pallas_interpret(),
     )
+
+
+# ------------------------------------------- fewer K/V heads, and a window
+
+# pages a block of the grouped body (16 tokens x 8 heads x 128 a page at
+# the grouped cell's widths: 256 tokens, 1 MiB of keys and values)
+GROUPED_BLOCK_PAGES = 16
+# the query and the softmax weights as ONE bfloat16 term: what the
+# grouped block's products take everywhere (``models.mla_moe._operands``)
+_GROUPED_TERMS = 1
+
+
+def _grouped_kernel(block_pages, pbt, ps, kvh, ring, lens_ref, first_ref,
+                    table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems):
+    b = pl.program_id(0)
+    rows, hd = q_ref.shape[1], q_ref.shape[2]  # Hkv x padded group, Dh
+    gp = rows // kvh
+    toks = block_pages * ps  # a block's rows: one a token, heads in lanes
+    scale = 1.0 / (hd ** 0.5)
+    length = lens_ref[b]
+    first = jnp.minimum(first_ref[b], jnp.maximum(length - 1, 0))
+    page0 = first // ps  # the first page with a position to attend
+    npages = (length + ps - 1) // ps - page0
+    nblocks = (npages + block_pages - 1) // block_pages
+
+    @pl.when(b == 0)
+    def _():
+        # as in ``_kernel``: what a short block does not copy is masked,
+        # and must be finite for the values' product
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def copies(i, slot, j):
+        page = page0 + i * block_pages + j  # logical
+        if ring:
+            page = page % ring
+        at = pl.multiple_of(table_ref[b * pbt + page] * ps, ps)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[pl.ds(at, ps)], kbuf.at[slot, pl.ds(j * ps, ps)],
+                sems.at[0, slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[pl.ds(at, ps)], vbuf.at[slot, pl.ds(j * ps, ps)],
+                sems.at[1, slot]),
+        )
+
+    def for_block(i, slot, act):
+        """Start (or wait for) the copies of block ``i``'s own pages."""
+        for j in range(block_pages):
+            @pl.when(i * block_pages + j < npages)
+            def _():
+                for c in copies(i, slot, j):
+                    act(c)
+
+    @pl.when(nblocks > 0)
+    def _():
+        for_block(0, 0, lambda c: c.start())
+
+    tok = jax.lax.broadcasted_iota(jnp.int32, (rows, toks), 1)
+    q = q_ref[0]  # (Hkv x gp, Dh) float32, a K/V head's group in a row
+
+    def block(i, carry):
+        acc, m, l = carry
+        slot = i % 2
+
+        @pl.when(i + 1 < nblocks)
+        def _():
+            for_block(i + 1, 1 - slot, lambda c: c.start())
+
+        for_block(i, slot, lambda c: c.wait())
+        k, v = kbuf[slot], vbuf[slot]  # (tokens, Hkv x Dh)
+        # a K/V head's keys are a lane-aligned slice of the rows, and its
+        # group of queries against them is every product the head needs
+        s = jnp.concatenate([
+            _product(q[h * gp:(h + 1) * gp], k[:, h * hd:(h + 1) * hd], 1,
+                     _GROUPED_TERMS)
+            for h in range(kvh)
+        ], axis=0) * scale  # (rows, tokens)
+        pos = (page0 + i * block_pages) * ps + tok
+        s = jnp.where((pos >= first) & (pos < length), s, -jnp.inf)
+        # block 0 holds position ``first``: every row's maximum is finite
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)  # 0 where masked
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        pv = jnp.concatenate([
+            _product(p[h * gp:(h + 1) * gp], v[:, h * hd:(h + 1) * hd], 0,
+                     _GROUPED_TERMS)
+            for h in range(kvh)
+        ], axis=0)  # (rows, Dh)
+        return acc * corr + pv, m_new, l_new
+
+    acc, _, l = jax.lax.fori_loop(
+        0, nblocks, block,
+        (
+            jnp.zeros((rows, hd), jnp.float32),
+            jnp.full((rows, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+        ),
+    )
+    o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("page_size", "ring", "block_pages", "interpret"),
+)
+def _paged_grouped_attention(q, ck, cv, table, lengths, first, *, page_size,
+                             ring, block_pages, interpret):
+    b, nh, hd = q.shape
+    kvh = ck.shape[1] // hd
+    g = nh // kvh
+    # a K/V head's group of queries as whole sublane tiles: 6 -> 8, 9 -> 16
+    gp = -(-g // _SUBLANES) * _SUBLANES
+    qg = jnp.pad(q.astype(jnp.float32).reshape(b, kvh, g, hd),
+                 ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    pbt = table.shape[1]
+    pad = 0 if ring else -pbt % block_pages  # never read, as above
+    if pad:
+        table = jnp.pad(table, ((0, 0), (0, pad)))
+    kernel = functools.partial(
+        _grouped_kernel, block_pages, pbt + pad, page_size, kvh, ring)
+    buf = (2, block_pages * page_size, kvh * hd)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, kvh * gp, hd), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, kvh * gp, hd),
+                                   lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM(buf, ck.dtype),
+                pltpu.VMEM(buf, cv.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, kvh * gp, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(
+        lengths.astype(jnp.int32), first.astype(jnp.int32),
+        table.astype(jnp.int32).reshape(-1),
+        qg.reshape(b, kvh * gp, hd), ck, cv,
+    )
+    return out.reshape(b, kvh, gp, hd)[:, :, :g].reshape(b, nh, hd)
 
 
 # ------------------------------------------------------- the latent layout

@@ -440,7 +440,7 @@ class CachedSequenceGenerator(SequenceGenerator):
         # serving engine builds; it is read here, once, and never inside
         # a traced function
         self.block_kind = "kv"
-        if self._parse_latent(layers):
+        if self._parse_paged_only(layers):
             return
         shape_err = ValueError(
             "CachedSequenceGenerator supports Embedding -> causal "
@@ -493,27 +493,29 @@ class CachedSequenceGenerator(SequenceGenerator):
         self._final_ln = layers[-2]
         self._head = layers[-1]
 
-    def _parse_latent(self, layers) -> bool:
-        """Embedding -> blocks of kind ``"latent"`` xN -> RMSNorm ->
-        Dense (``zoo.mla_moe_lm``, ``zoo.longcat_flash_lm``): blocks of
-        latent attentions, whose cache is ``cached_rows`` latent rows a
-        token and layer and not keys and values. The block says its kind;
-        its class is not asked. The paged ``DecodeStepper`` serves it; the
-        solo generators here keep a dense (B, T, H, Dh) cache and refuse it
-        (``_decode_prologue``)."""
+    def _parse_paged_only(self, layers) -> bool:
+        """Embedding -> blocks that say one ``kind`` xN -> RMSNorm ->
+        Dense (``zoo.mla_moe_lm``, ``zoo.longcat_flash_lm``: kind
+        ``"latent"``, whose cache is ``cached_rows`` latent rows a token
+        and layer; ``zoo.laguna_lm``: kind ``"gqa"``, grouped-query keys
+        and values with a window by layer). The block says its kind; its
+        class is not asked. The paged ``DecodeStepper`` serves them; the
+        solo generators here keep a dense (B, T, H, Dh) cache and refuse
+        them (``_decode_prologue``)."""
         from distkeras_tpu.models.layers import Dense, Embedding
         from distkeras_tpu.models.mla_moe import RMSNorm
 
         mid = layers[1:-2]
+        kinds = {getattr(l, "kind", None) for l in mid}
         if not (
             len(layers) >= 4
             and isinstance(layers[0], Embedding)
             and isinstance(layers[-2], RMSNorm)
             and isinstance(layers[-1], Dense)
-            and all(getattr(l, "kind", None) == "latent" for l in mid)
+            and len(kinds) == 1 and kinds <= {"latent", "gqa"}
         ):
             return False
-        self.block_kind = "latent"
+        self.block_kind = kinds.pop()
         self._emb = layers[0]
         self._stages = [(blk, i + 1, None, None) for i, blk in enumerate(mid)]
         self._blocks = mid
@@ -586,13 +588,15 @@ class CachedSequenceGenerator(SequenceGenerator):
         masked scratch). The embed closure clamps positions to the
         table — a no-op for every kept token; only speculative's
         discarded overrun drafts ever exceed it."""
-        if self.block_kind == "latent":
+        if self.block_kind != "kv":
             from distkeras_tpu.models.mla_moe import BlockUnsupportedError
 
             raise BlockUnsupportedError(
                 "the solo cached generators keep a dense (B, T, H, Dh) "
-                "K/V cache; a block that caches latent rows decodes "
-                "through the paged ServingEngine only"
+                "K/V cache of one head count; a block that caches latent "
+                "rows, or grouped keys and values with a window by layer "
+                f"(kind {self.block_kind!r}), decodes through the paged "
+                "ServingEngine only"
             )
         n_layers = len(self.model.layers)
         if cache_len is None:

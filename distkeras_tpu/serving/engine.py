@@ -624,16 +624,21 @@ class DecodeStepper:
             top_p=top_p, kv_dtype=kv_dtype,
         )
         self.model = model
-        # the block kind picks the page layout and which stage bodies
-        # the step / chunk programs are built from — decided here, when
-        # programs are built, never inside a traced function
-        latent = self._gen.block_kind == "latent"
-        self.layout = "latent" if latent else "kv"
+        # the block kind IS the page layout: "kv" (keys and values of
+        # every head, one budget), "latent" (a latent row an attention),
+        # "gqa" (grouped keys and values, a budget a layer kind). It picks
+        # what the step / chunk programs are built from (``_LAYOUTS``) and
+        # what is refused and why (``_PAGED_ONLY``) — decided here, when
+        # programs are built, never inside a traced function, and never
+        # by a block's class
+        self.layout = self._gen.block_kind
+        self._face = self._LAYOUTS[self.layout]
+        self._paged_only = self._PAGED_ONLY.get(self.layout)
         self.prefix_caches_off = None
-        if latent:
+        if self._paged_only:
             from distkeras_tpu.ops.quantization import count_quantized
 
-            self._refuse_for_latent(
+            self._refuse_unsupported(
                 "the dense slot bank (paged=False)" if not paged else None,
                 "speculative decoding" if speculative else None,
                 "a tensor-parallel serving mesh" if mesh is not None
@@ -641,14 +646,13 @@ class DecodeStepper:
                 "int8 / int4 weights (quantize_model(bits=8|4))"
                 if count_quantized(model.params) else None,
             )
-            # a host PrefixStore row is (p, H, Dh) keys and values and
-            # the device index was never exercised over latent pages:
-            # both are switched off for this layout, and stats() says so
+            # a host PrefixStore row is (p, H, Dh) keys and values of one
+            # head count, and the device index shares a prompt's early
+            # pages, which a latent pool never had exercised and a window
+            # layer's ring has overwritten: both are switched off for
+            # these layouts, and stats() says so
             prefix_cache = None
-            self.prefix_caches_off = (
-                "latent page layout: the host PrefixStore and the "
-                "DevicePrefixIndex hold (p, H, Dh) K/V rows only"
-            )
+            self.prefix_caches_off = self._paged_only["prefix_caches"]
         self.num_slots = int(num_slots)
         if self.num_slots < 1:
             raise ValueError(f"num_slots must be >= 1; got {num_slots}")
@@ -683,16 +687,11 @@ class DecodeStepper:
         # (its programs belong to the drafter, not the serving path).
         self.ledger = None if _quiet else compile_ledger
         self._warming = False  # True inside warmup(): mints off-path
-        from distkeras_tpu.ops.quantization import qshape
-
-        if latent:
-            # no (H, Dh) rows: a latent row a token and attention
-            nh, hd = self._gen._blocks[0].num_heads, None
-        else:
-            nh = self._gen._blocks[0].mhsa.num_heads
-            hd = qshape(
-                model.params[str(self._gen._stages[0][1])]["mhsa"]["wq"]
-            )[1] // nh
+        # what a block caches is what it says: the heads of a cached row
+        # (K/V heads) and their size, and the window layers' window;
+        # everything a layout decides is picked from ``_LAYOUTS``, once
+        nh, hd, self._window = getattr(self, self._face["shape"])()
+        self._ring = 0  # pages of a slot's ring in a window layer
         b, t = self.num_slots, self._tp
         # -- serving mesh (tensor-parallel decode) ------------------------
         # Resolved FIRST (before any device allocation): a bad mesh must
@@ -713,11 +712,15 @@ class DecodeStepper:
             self.mesh = serving_mesh(mesh)
             tp_ways = int(self.mesh.shape["model"])
             if nh % tp_ways:
+                # the cache is sharded by K/V head, so K/V heads are what
+                # the model axis must divide (for this block they are the
+                # query heads too)
                 raise ValueError(
-                    f"cannot shard {nh} attention heads over mesh "
-                    f"'tp:{tp_ways}': the model axis must divide "
-                    f"num_heads — pick a mesh that divides the head "
-                    f"count or serve this bundle solo"
+                    f"cannot shard {nh} K/V heads over mesh "
+                    f"'tp:{tp_ways}': the model axis must divide the "
+                    f"K/V heads (num_heads where every query head has "
+                    f"its own) — pick a mesh that divides them or serve "
+                    f"this bundle solo"
                 )
             self._kv_sh = NamedSharding(
                 self.mesh, PartitionSpec(None, None, "model")
@@ -773,46 +776,14 @@ class DecodeStepper:
                 self.page_size,
             )
             self._caches = None
-            if latent:
-                # a latent page: (page_size, kv_rank + rope) an attention
-                # (a block says how many it has: ``cached_rows``),
-                # ONE array and not a K and a V. The (num_pages,
-                # page_size, W) pool is held as its row-major flattening
-                # (num_pages x page_size, W), page p = rows [p * ps,
-                # (p + 1) * ps): the TPU's default layout of the 3-D
-                # array puts the page axis minor, and every program
-                # then copies the whole pool to rows and back, a layer
-                # (seen in the compiled step, PERF.md PR 28). For the
-                # same reason a row is padded with zeros to a multiple
-                # of 128 values, the TPU's lane width: 576 -> 640
-                self._pools = [
-                    tuple(
-                        jnp.zeros(
-                            (int(num_pages) * self.page_size,
-                             self._latent_row(blk)),
-                            self._gen.kv_dtype,
-                        )
-                        for _ in range(blk.cached_rows)
-                    )
-                    for blk in self._gen._blocks
-                ]
-            else:
-                self._pools = [
-                    (
-                        self._place_kv(jnp.zeros(
-                            (int(num_pages), self.page_size, nh, hd),
-                            self._gen.kv_dtype,
-                        )),
-                        self._place_kv(jnp.zeros(
-                            (int(num_pages), self.page_size, nh, hd),
-                            self._gen.kv_dtype,
-                        )),
-                    )
-                    for _ in self._gen._stages
-                ]
+            self._window_alloc = None
+            self._pools = getattr(self, self._face["pools"])(
+                int(num_pages), nh, hd
+            )
             self._tables: list[list[int]] = [[] for _ in range(b)]
             self.prefix_index = (
-                None if latent else DevicePrefixIndex(self._kv_alloc)
+                None if self._paged_only
+                else DevicePrefixIndex(self._kv_alloc)
             )
             # paged program caches (separate families from the dense
             # ones: their keys carry the page-table bucket; the masked
@@ -954,8 +925,10 @@ class DecodeStepper:
         arguments built in NumPy for this call, and the tree's share on
         the host, which is 0 unless host arrays were re-bound to
         ``_params`` after the constructor placed them (a fault)."""
+        import jax
+
         return self._params_host_bytes + sum(
-            a.nbytes for a in host if a is not None
+            a.nbytes for a in jax.tree_util.tree_leaves(host)
         )
 
     def paged_stats(self) -> dict:
@@ -969,6 +942,16 @@ class DecodeStepper:
         if self.prefix_caches_off:
             out["prefix_caches"] = "off: " + self.prefix_caches_off
         out.update(self._kv_alloc.stats())
+        if self._window_alloc is not None:
+            # the second budget: what a token costs by layer kind, and
+            # what a slot's window layers hold at most, whatever its length
+            out["bytes_per_token_by_kind"] = {
+                kind: self.kv_bytes_per_token(kind)
+                for kind in ("full", "window")
+            }
+            out["window_positions_max"] = self._ring * self.page_size
+            out["window_pages_a_slot"] = self._ring
+            out["window"] = self._window_alloc.stats()
         # mesh geometry: the pool's TOTAL bytes are mesh-invariant;
         # what changes with tp:N is how many land per shard
         out["mesh"] = self.mesh_spec
@@ -1107,31 +1090,180 @@ class DecodeStepper:
             for a in jax.tree_util.tree_leaves(arrs)
         )
 
-    def kv_bytes_per_token(self) -> int:
+    def kv_bytes_per_token(self, kind=None) -> int:
         """Bytes one cached token takes over all layers: keys and values
-        of every head, or one latent row an attention."""
-        item = np.dtype(self._gen.kv_dtype).itemsize
-        if self.layout == "latent":
-            return item * sum(
-                a.shape[-1] for rows in self._pools for a in rows
-            )
-        return item * 2 * self._nh * self._hd * len(self._gen._stages)
+        of every K/V head, or one latent row an attention, read off the
+        pools' own shapes. ``kind`` (``"full"`` | ``"window"``): over the
+        layers of that kind alone, where layers differ (a window layer
+        holds a token only while it is inside the window)."""
+        if not self.paged:
+            return (np.dtype(self._gen.kv_dtype).itemsize * 2 * self._nh
+                    * self._hd * len(self._gen._stages))
+        total = 0
+        for blk, arrs in zip(self._gen._blocks, self._pools):
+            windowed = getattr(blk, "window", None) is not None
+            if kind is None or windowed == (kind == "window"):
+                total += sum(
+                    int(np.prod(a.shape[1:] if a.ndim == 2 else a.shape[2:]))
+                    * a.dtype.itemsize for a in arrs
+                )
+        return total
 
-    # -- the latent-attention block ------------------------------------------
+    # -- the blocks that only the paged engine serves --------------------------
 
-    @staticmethod
-    def _refuse_for_latent(*features):
-        """Typed refusal of what a block that caches latent rows cannot
-        run yet (each named in PERF.md, "cannot run yet")."""
+    # by block kind: why, and why the prefix caches are off for it
+    _PAGED_ONLY = {
+        "latent": {
+            "what": "a block that caches latent rows",
+            "why": "its cache is a latent row a token and attention, not "
+                   "(H, Dh) keys and values",
+            "prefix_caches": (
+                "latent page layout: the host PrefixStore and the "
+                "DevicePrefixIndex hold (p, H, Dh) K/V rows only"),
+        },
+        "gqa": {
+            "what": "a grouped-query block with window layers",
+            "why": "its cache is a page budget a layer kind, a window "
+                   "layer's pages a ring a slot that is overwritten "
+                   "behind the window, and its K/V heads are fewer than "
+                   "its query heads, which differ by layer",
+            "prefix_caches": (
+                "grouped page layout: a window layer's ring has "
+                "overwritten a prompt's early pages, and the host "
+                "PrefixStore holds (p, H, Dh) rows of one head count"),
+        },
+    }
+
+    def _refuse_unsupported(self, *features):
+        """Typed refusal of what a block that only the paged engine
+        serves cannot run yet (each named in PERF.md, "cannot run
+        yet"); nothing for the GPT-2 block."""
         from distkeras_tpu.models.mla_moe import BlockUnsupportedError
 
+        why = self._paged_only
         for what in features:
-            if what:
+            if what and why:
                 raise BlockUnsupportedError(
-                    f"{what} cannot serve a block that caches latent "
-                    f"rows yet: its cache is a latent row a token and "
-                    f"attention, not (H, Dh) keys and values"
+                    f"{what} cannot serve {why['what']} yet: "
+                    f"{why['why']}"
                 )
+
+    # what each page layout (= block kind) brings: the shape of a cached
+    # row, its pools, and the two cache callables of ``_cache_callable``
+    _LAYOUTS = {
+        "kv": {"shape": "_kv_cache_shape", "pools": "_build_kv_pools",
+               "rows": "_kv_rows", "chunk": "_kv_chunk"},
+        "latent": {"shape": "_latent_cache_shape",
+                   "pools": "_build_latent_pools",
+                   "rows": "_latent_rows", "chunk": "_latent_chunk"},
+        "gqa": {"shape": "_grouped_cache_shape",
+                "pools": "_build_grouped_pools",
+                "rows": "_gqa_rows", "chunk": "_gqa_chunk"},
+    }
+
+    def _kv_cache_shape(self):
+        from distkeras_tpu.ops.quantization import qshape
+
+        nh = self._gen._blocks[0].mhsa.num_heads
+        wq = self.model.params[str(self._gen._stages[0][1])]["mhsa"]["wq"]
+        return nh, qshape(wq)[1] // nh, None
+
+    def _latent_cache_shape(self):
+        # no (H, Dh) rows: a latent row a token and attention
+        return self._gen._blocks[0].num_heads, None, None
+
+    def _build_kv_pools(self, num_pages, nh, hd):
+        import jax.numpy as jnp
+
+        return [
+            tuple(
+                self._place_kv(jnp.zeros(
+                    (num_pages, self.page_size, nh, hd), self._gen.kv_dtype,
+                ))
+                for _ in range(2)
+            )
+            for _ in self._gen._stages
+        ]
+
+    def _build_latent_pools(self, num_pages, nh, hd):
+        """A latent page: (page_size, kv_rank + rope) an attention (a
+        block says how many it has: ``cached_rows``), ONE array and not
+        a K and a V. The (num_pages, page_size, W) pool is held as its
+        row-major flattening (num_pages x page_size, W), page p = rows
+        [p * ps, (p + 1) * ps): the TPU's default layout of the 3-D
+        array puts the page axis minor, and every program then copies
+        the whole pool to rows and back, a layer (seen in the compiled
+        step, PERF.md PR 28). For the same reason a row is padded with
+        zeros to a multiple of 128 values, the TPU's lane width: 576 ->
+        640."""
+        import jax.numpy as jnp
+
+        return [
+            tuple(
+                jnp.zeros(
+                    (num_pages * self.page_size, self._latent_row(blk)),
+                    self._gen.kv_dtype,
+                )
+                for _ in range(blk.cached_rows)
+            )
+            for blk in self._gen._blocks
+        ]
+
+    def _grouped_cache_shape(self):
+        """``(K/V heads, head size, window)`` that the grouped blocks
+        say: the first two alike in every layer (a slot's table serves
+        every layer of a kind), one window size among the window layers
+        (None: no window layer)."""
+        blocks = self._gen._blocks
+        shapes = {(b.kv_heads, b.head_dim) for b in blocks}
+        windows = {b.window for b in blocks} - {None}
+        if len(shapes) != 1 or len(windows) > 1:
+            raise ValueError(
+                f"a paged pool has one (K/V heads, head size) and one "
+                f"window size a model; got {sorted(shapes)} and windows "
+                f"{sorted(windows)}"
+            )
+        return (*shapes.pop(), windows.pop() if windows else None)
+
+    def _build_grouped_pools(self, num_pages, kvh, hd):
+        """The grouped layout's pools, a budget a layer kind. A full
+        layer's pages come from ``num_pages`` (``_kv_alloc``: the budget
+        that grows with a request). A window layer reads the last
+        ``window`` positions and nothing else, so a slot holds a RING of
+        ``ceil(window / page_size) + 1`` pages there (the window, plus
+        the page of the write frontier), logical page ``p`` in column
+        ``p % ring`` of the slot's window table, overwritten in place
+        once it lies behind the window; the window pool is sized here to
+        ``num_slots`` rings, so whenever a slot is free its ring is too.
+        Every pool is ``(pages x page_size, Hkv x Dh)``, the row-major
+        flattening of ``(pages, page_size, Hkv, Dh)``: a head is a
+        lane-aligned slice of a row, which is how the kernel takes a K/V
+        head's keys out of a copied page (``ops/paged_attention.py``)."""
+        import jax.numpy as jnp
+
+        from distkeras_tpu.serving.paging import PageAllocator
+
+        ps = self.page_size
+        window_pages = 0
+        if self._window is not None:
+            self._ring = -(-self._window // ps) + 1
+            window_pages = self.num_slots * self._ring + 1
+            self._window_alloc = PageAllocator(
+                window_pages, ps, recorder=self.recorder)
+            self._window_tables = [[] for _ in range(self.num_slots)]
+        return [
+            tuple(
+                jnp.zeros(
+                    ((num_pages if blk.window is None else window_pages)
+                     * ps, kvh * hd),
+                    self._gen.kv_dtype,
+                )
+                for _ in range(2)
+            )
+            for blk in self._gen._blocks
+        ]
+
+    # -- the latent-attention block ------------------------------------------
 
     @staticmethod
     def _latent_row(blk) -> int:
@@ -1286,7 +1418,7 @@ class DecodeStepper:
         """Whether n-parallel completions can be scheduled here
         (``fork_slot`` needs the paged CoW machinery, and its page copy
         knows (p, H, Dh) pages only)."""
-        return self.paged and self.layout == "kv"
+        return self.paged and not self._paged_only
 
     def fork_pages_for(self, prompt_len: int, max_new: int) -> int:
         """FRESH pages one fork of a just-prefilled slot allocates
@@ -1515,6 +1647,17 @@ class DecodeStepper:
             if shared:
                 self._kv_alloc.free(shared, reason="admit_abort")
             raise
+        if self._window_alloc is not None:
+            # the second budget: a ring that does not grow with the
+            # request. All or nothing over both: a ring that cannot be
+            # had gives the growing pages back
+            try:
+                self._window_tables[slot] = self._window_alloc.alloc(
+                    min(self._ring, need), reason="admit"
+                )
+            except Exception:
+                self._kv_alloc.free(shared + fresh, reason="admit_abort")
+                raise
         self._tables[slot] = shared + fresh
         return start, host_hit
 
@@ -1523,6 +1666,20 @@ class DecodeStepper:
         self._tables[slot] = []
         if pages:
             self._kv_alloc.free(pages, reason="release")
+        if self._window_alloc is not None:
+            pages = self._window_tables[slot]
+            self._window_tables[slot] = []
+            if pages:
+                self._window_alloc.free(pages, reason="release")
+
+    @property
+    def window_pages(self):
+        """``(in use, total)`` of the window layers' pool; None where no
+        layer has a window."""
+        if not self.paged or self._window_alloc is None:
+            return None
+        a = self._window_alloc
+        return a.pages_in_use, a.total_pages
 
     def fork_slot(self, src: int, dst: int, max_new=None,
                   completion=1) -> None:
@@ -1546,8 +1703,7 @@ class DecodeStepper:
         completion)``, so its stream is exactly what an independent
         admission with that derived seed would produce (grammar mask
         state is CLONED: each completion walks the grammar alone)."""
-        if self.layout == "latent":
-            self._refuse_for_latent("fork / beam (copy-on-write page forks)")
+        self._refuse_unsupported("fork / beam (copy-on-write page forks)")
         if not self.paged:
             raise ValueError("fork_slot requires paged=True")
         if src in self._pending or not self._tables[src]:
@@ -1653,8 +1809,7 @@ class DecodeStepper:
         leaves the victim decoding untouched. The returned dict rides
         the preempted request; dropping it (typed failure, stop) is
         the only cleanup."""
-        if self.layout == "latent":
-            self._refuse_for_latent("swap-out / preemption / K/V export")
+        self._refuse_unsupported("swap-out / preemption / K/V export")
         self._fire("kv.swap", slot=slot, direction="out")
         if slot in self._pending:
             raise ValueError(
@@ -1721,8 +1876,7 @@ class DecodeStepper:
         (garbage at positions >= len-1 is overwritten by that step's
         own K/V write before anything attends it, the standing
         restore argument)."""
-        if self.layout == "latent":
-            self._refuse_for_latent("swap-in / resume of exported K/V")
+        self._refuse_unsupported("swap-in / resume of exported K/V")
         self._fire("kv.swap", slot=slot, direction="in")
         ln = int(state["len"])
         remaining = (
@@ -1868,7 +2022,7 @@ class DecodeStepper:
                 self._compiling()
                 fn = self._build_chunk_fn_paged(cb, pbt)
                 self._pchunk_fns = {**self._pchunk_fns, key: fn}
-            host = (toks, self._table_row(slot, pbt), np.int32(pos))
+            host = (toks, self._chunk_where(slot, pbt, n), np.int32(pos))
             with _span(
                 "serving/prefill_chunk",
                 host_arg_bytes=self._host_arg_bytes(host),
@@ -1907,19 +2061,42 @@ class DecodeStepper:
             return [top]
         return [1 << i for i in range(top.bit_length())]
 
-    def _table_row(self, slot, pbt) -> np.ndarray:
-        row = np.zeros((pbt,), np.int32)
-        pages = self._tables[slot]
+    @staticmethod
+    def _padded(pages, width) -> np.ndarray:
+        """``pages`` as a table row of ``width``, the null page behind."""
+        row = np.zeros((width,), np.int32)
         row[: len(pages)] = pages
         return row
 
-    def _tables_array(self, pbt) -> np.ndarray:
+    def _table_row(self, slot, pbt) -> np.ndarray:
+        return self._padded(self._tables[slot], pbt)
+
+    def _chunk_where(self, slot, pbt, n):
+        """The chunk program's ``where``: the slot's page-table row; with
+        a window budget ``(row, ring row, n)``, a table a layer kind and
+        the chunk's count of real tokens (a ring takes no write that is
+        not a real token's: behind it lies what the window still reads)."""
+        row = self._table_row(slot, pbt)
+        if self._window_alloc is None:
+            return row
+        ring = self._padded(self._window_tables[slot], self._ring)
+        return row, ring, np.int32(n)
+
+    def _tables_array(self, pbt):
         """The (B, pbt) page-table argument of the step / verify
-        programs; rows pad with the null sentinel page 0 (masked)."""
-        arr = np.zeros((self.num_slots, pbt), np.int32)
-        for i, pages in enumerate(self._tables):
-            arr[i, : len(pages)] = pages
-        return arr
+        programs; rows pad with the null sentinel page 0 (masked). With
+        a window budget ``(tables, rings (B, ring))``: a table a layer
+        kind."""
+        def filled(tables, width):
+            arr = np.zeros((self.num_slots, width), np.int32)
+            for i, pages in enumerate(tables):
+                arr[i, : len(pages)] = pages
+            return arr
+
+        arr = filled(self._tables, pbt)
+        if self._window_alloc is None:
+            return arr
+        return arr, filled(self._window_tables, self._ring)
 
     def _finish_admit(self, slot):
         """Admission complete: drop the pending state and publish the
@@ -2094,7 +2271,7 @@ class DecodeStepper:
                     self._pstep_fns = {
                         **self._pstep_fns, (pbt, False): fn
                     }
-                table = np.zeros((self.num_slots, pbt), np.int32)
+                table = self._tables_array(pbt)  # an idle bank: zeros
                 self._ctx, self._pools, _ = fn(
                     self._params, self._ctx, self._pools,
                     self._lens.copy(), active, table, *sargs,
@@ -2183,7 +2360,7 @@ class DecodeStepper:
                     # empty table row -> null sentinel page
                     self._pools = fn(
                         self._params, self._pools, toks,
-                        self._table_row(0, pbt), np.int32(0),
+                        self._chunk_where(0, pbt, 0), np.int32(0),
                     )
                 else:
                     fn = self._chunk_fns.get(cbb)
@@ -2270,7 +2447,7 @@ class DecodeStepper:
             # sub-max buckets would mint programs no iteration can
             # ever key on
             for pbt in self._step_table_buckets():
-                table = np.zeros((self.num_slots, pbt), np.int32)
+                table = self._tables_array(pbt)  # all slots inactive
                 key = (pbt, True)
                 fn = self._pstep_fns.get(key)
                 if fn is None:
@@ -2520,19 +2697,19 @@ class DecodeStepper:
         chunk = form == "chunk"
         if not self.paged:
             return self._bank_chunk if chunk else self._bank_rows, head
-        if self.layout == "latent":
+        cache = getattr(self, self._face["chunk" if chunk else "rows"])
+        if self._paged_only:
             from distkeras_tpu.models.mla_moe import matmul
 
-            cache = self._latent_chunk if chunk else self._latent_rows
             return (
                 functools.partial(cache, pbt),
                 lambda p_head, x: matmul(x, p_head["kernel"]),  # bf16
             )
         if chunk:
-            return functools.partial(self._kv_chunk, pbt), head
+            return functools.partial(cache, pbt), head
         # the kernel attends one token a slot: the step, not the verify
         in_place = form == "step" and self.attention == "kernel"
-        return functools.partial(self._kv_rows, pbt, in_place), head
+        return functools.partial(cache, pbt, in_place), head
 
     def _step_program(self, pbt, masked, key):
         """The decode step, dense (``pbt`` None) or paged at table
@@ -2963,6 +3140,138 @@ class DecodeStepper:
                 n_keys=jnp.minimum(start + cb, t),
             )
             return x, tuple(written), picks
+
+        return stage
+
+    def _gqa_rows(self, pbt: int, tables, rows, pos, active):
+        """Grouped pages, one token a slot: ``attend`` owns the masked
+        write of the token's key and value into the layer's own kind of
+        page (a full layer: column ``pos // page_size`` of the slot's
+        table; a window layer: column ``(pos // page_size) % ring`` of
+        its ring) and how the written pools are attended. Where
+        ``self.attention == "kernel"`` that is ``paged_decode_attention``
+        over the slot's own pages where they lie, from the window's first
+        position in a window layer; otherwise the positions a slot may
+        see gathered (a full layer: the table's extent; a window layer:
+        the ring's) under the position mask."""
+        import jax.numpy as jnp
+
+        from distkeras_tpu.models.gqa_moe import attend_dense
+        from distkeras_tpu.ops.paged_attention import (
+            paged_decode_attention,
+        )
+
+        b, ps, ring = self.num_slots, self.page_size, self._ring
+        kvh, hd = self._nh, self._hd
+        in_place = self.attention == "kernel"
+        full, rings = tables if ring else (tables, None)
+        lengths = jnp.where(active, pos + 1, 0)
+
+        def stage(blk, moe, p, pm, x, pool):
+            w = blk.window
+            table = full if w is None else rings
+            col = (jnp.clip(pos // ps, 0, pbt - 1) if w is None
+                   else (pos // ps) % ring)
+            at = table[rows, col] * ps + pos % ps  # rows of the flat pool
+            kv = []
+
+            def attend(q, k_new, v_new):
+                # a slot that is not decoding writes nothing: its row's
+                # index is out of range, and dropped
+                kv.extend(
+                    c.at[jnp.where(active, at, c.shape[0])].set(
+                        new.reshape(b, kvh * hd).astype(c.dtype),
+                        mode="drop",
+                    )
+                    for c, new in zip(pool, (k_new, v_new))
+                )
+                if in_place:
+                    return paged_decode_attention(
+                        q, *kv, table, lengths,
+                        None if w is None else jnp.maximum(pos + 1 - w, 0),
+                        page_size=ps, ring=0 if w is None else ring,
+                    )
+                if w is None:
+                    kpos = jnp.arange(pbt * ps)[None, :]  # (1, T')
+                    idx = (table[:, :, None] * ps
+                           + jnp.arange(ps)).reshape(b, -1)
+                    see = kpos <= pos[:, None]
+                else:
+                    n = ring * ps  # what a ring can hold
+                    kpos = pos[:, None] - (n - 1) + jnp.arange(n)[None, :]
+                    idx = (table[rows[:, None], (kpos // ps) % ring] * ps
+                           + kpos % ps)
+                    see = (kpos >= 0) & (kpos > pos[:, None] - w)
+                kg, vg = (c[idx].reshape(b, -1, kvh, hd) for c in kv)
+                return attend_dense(q[:, None], kg, vg, see[:, None])[:, 0]
+
+            x, picks = blk.forward(
+                p, x, pos, None, attend, token_mask=active
+            )
+            return x, tuple(kv), picks
+
+        return stage
+
+    def _gqa_chunk(self, pbt: int, where, start, pos):
+        """Grouped pages, one slot's chunk (``attend_blocked``: the keys
+        a query can see and no others). A full layer: the chunk's keys
+        and values scattered to their pages, the slot's pages gathered
+        into its row and attended up to the chunk's end. A window layer
+        attends ``window + chunk`` keys: the ``window`` positions before
+        the chunk out of the ring, then the chunk's own, which never
+        pass through the ring; the ring then takes the chunk's last real
+        positions (at most what it holds, so no two land on one row)."""
+        import jax
+        import jax.numpy as jnp
+
+        from distkeras_tpu.models.gqa_moe import attend_blocked
+
+        ps, ring = self.page_size, self._ring
+        kvh, hd = self._nh, self._hd
+        cb = pos.shape[0]
+        trow, rrow, n = where if ring else (where, None, None)
+        fpos = self._flat_positions(trow, pos, pbt)  # (cb,)
+        ridx = (trow[:, None] * ps + jnp.arange(ps)).reshape(-1)
+
+        def stage(blk, moe, p, pm, x, pool):
+            w = blk.window
+            kv = []
+
+            def rows_of(new, c):  # (1, cb, Hkv, Dh) as the pool holds it
+                return new[0].reshape(cb, kvh * hd).astype(c.dtype)
+
+            def attend(q, k_new, v_new):
+                if w is None:
+                    kv.extend(c.at[fpos].set(rows_of(new, c))
+                              for c, new in zip(pool, (k_new, v_new)))
+                    kg, vg = (c[ridx].reshape(-1, kvh, hd) for c in kv)
+                    return attend_blocked(
+                        q[0], kg, vg, pos, 0, None, blk.key_block)[None]
+                # the window before the chunk, out of the ring as it is
+                prev = start - w + jnp.arange(w)  # negative: no such key
+                pidx = rrow[(prev // ps) % ring] * ps + prev % ps
+                # the ring takes the last m positions of the real chunk
+                m = min(cb, ring * ps)
+                off = jnp.clip(n - m, 0, cb - m)
+                last = start + off + jnp.arange(m)
+                widx = jnp.where(
+                    last < start + n,
+                    rrow[(last // ps) % ring] * ps + last % ps,
+                    pool[0].shape[0],  # out of range: dropped
+                )
+                keys = []
+                for c, new in zip(pool, (k_new, v_new)):
+                    mine = rows_of(new, c)
+                    keys.append(jnp.concatenate(
+                        [c[pidx], mine]).reshape(-1, kvh, hd))
+                    kv.append(c.at[widx].set(
+                        jax.lax.dynamic_slice_in_dim(mine, off, m, 0),
+                        mode="drop"))
+                return attend_blocked(
+                    q[0], *keys, pos, start - w, w, blk.key_block)[None]
+
+            x, picks = blk.forward(p, x, pos[None], None, attend)
+            return x, tuple(kv), picks
 
         return stage
 
@@ -3511,11 +3820,15 @@ class ServingEngine:
         try:
             self._stepper = DecodeStepper(model, **self._stepper_cfg)
             self._stepper.on_compile = self._extend_grace
-            if self._stepper.layout == "latent":
-                if role != "unified":
-                    self._stepper._refuse_for_latent(
-                        f"role {role!r} (K/V export and resume)"
-                    )
+            if self._stepper.prefix_caches_off:
+                from distkeras_tpu.serving.prefix_cache import PrefixStore
+
+                self._stepper._refuse_unsupported(
+                    f"role {role!r} (K/V export and resume)"
+                    if role != "unified" else None,
+                    "a shared PrefixStore (prefix caches over its pages)"
+                    if isinstance(prefix_cache, PrefixStore) else None,
+                )
                 # the stepper switched the prefix caches off for this
                 # page layout; stats()["paged"]["prefix_caches"] says so
                 store = None

@@ -1317,6 +1317,12 @@ class ContinuousBatcher:
             counts["pages_in_use"] = total - self.stepper.free_pages
             counts["pages_total"] = total
             counts["page_waits"] = int(page_wait)
+            # a second budget, where window layers have one: the pool of
+            # the slots' rings (``pages_*`` stay the budget that grows)
+            window = getattr(self.stepper, "window_pages", None)
+            if window is not None:
+                counts["window_pages_in_use"] = window[0]
+                counts["window_pages_total"] = window[1]
         self._iter_counts = counts
         return progressed, blocked, len(admitted)
 

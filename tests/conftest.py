@@ -84,15 +84,20 @@ def _synthetic_run_has_scoped_ops(request, monkeypatch):
         import json
         import os
 
-        from benchmark.layer_metrics import _scoped_ops, _shortcut_ops
+        from benchmark.layer_metrics import (
+            _gqa_ops, _scoped_ops, _shortcut_ops)
 
         # the shortcut layer's two metrics (``_shortcut_ops.py``) read the
         # dense path's scope and the identity picks' counters: a small cut
         # of a traced run of their cell too
         fixtures = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "benchmark", "fixtures")
+        # the grouped-query block's three metrics (``_gqa_ops.py``) read the
+        # attention scopes, the paged kernel's calls and the window pool's
+        # counters: a small cut of a traced run of their cell too
         for module, name in ((_scoped_ops, "scoped_ops_small.json"),
-                             (_shortcut_ops, "shortcut_ops_small.json")):
+                             (_shortcut_ops, "shortcut_ops_small.json"),
+                             (_gqa_ops, "gqa_ops_small.json")):
             with open(os.path.join(fixtures, name)) as f:
                 plain = json.load(f)["plain"]
             monkeypatch.setattr(module, "run_profile",

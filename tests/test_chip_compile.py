@@ -189,6 +189,126 @@ def test_paged_decode_attention_compiles_for_v5e(
     assert not copies, copies
 
 
+# the grouped cell's shapes (laguna-s-2.1-5l-ep4: 96 slots, 8 K/V heads of
+# 128, pages of 16 tokens): a full layer's 48 query heads over a table of
+# 1,024 pages, a window layer's 72 over a ring of 33 from a first position
+GROUPED_SHAPES = [
+    pytest.param(96, 48, 43008, 1024, 0, id="laguna-full"),
+    pytest.param(96, 72, 96 * 33 + 1, 33, 33, id="laguna-window"),
+]
+
+
+@pytest.mark.parametrize("b,nh,pages,pbt,ring", GROUPED_SHAPES)
+def test_grouped_paged_attention_compiles_for_v5e(
+    one_chip, b, nh, pages, pbt, ring
+):
+    """The decode step's row write into the flat pool, then the grouped
+    kernel over the written pool: Mosaic takes a K/V head's lane-aligned
+    slice of a copied page and a group of 6 or 9 queries padded to whole
+    tiles; the module holds the kernel and no copy of a pool."""
+    from distkeras_tpu.ops.paged_attention import (
+        GROUPED_BLOCK_PAGES,
+        _paged_grouped_attention,
+    )
+
+    kvh, hd, ps = 8, 128, 16
+    bp = GROUPED_BLOCK_PAGES
+    if ring:
+        bp = -(-ring // -(-ring // bp))
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(q, new, ck, cv, at, table, lengths, first):
+        ck = ck.at[at].set(new.astype(ck.dtype))
+        cv = cv.at[at].set(new.astype(cv.dtype))
+        o = _paged_grouped_attention(
+            q, ck, cv, table, lengths, first, page_size=ps, ring=ring,
+            block_pages=bp, interpret=False,
+        )
+        return o, ck, cv
+
+    pool = s((pages * ps, kvh * hd), jnp.bfloat16)
+    idx = s((b,), jnp.int32)
+    text = jax.jit(step, donate_argnums=(2, 3)).lower(
+        s((b, nh, hd), jnp.float32), s((b, kvh * hd), jnp.float32), pool,
+        pool, idx, s((b, pbt), jnp.int32), idx, idx,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    pool_shape = f"[{pages * ps},{kvh * hd}]"
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and pool_shape in ln.split("=")[0]]
+    assert not copies, copies
+
+
+def test_the_grouped_step_and_chunk_programs_compile_for_v5e(one_chip):
+    """The stepper's own step program and a 2,048-token chunk program at
+    the configuration's widths (hidden 3072, 48 / 72 query heads over 8
+    K/V heads of 128, window 512, dense MLP 12288, experts of 1024, top 10
+    of 256 router outputs, YaRN and plain rotary, 96 slots, pages of 16, a
+    table of 1,024 pages and a ring of 33), with what is no width cut so
+    that the CPU holds it: 2 experts held a layer, 512 rows of vocabulary,
+    2,048 pages. Both hold the kernel or gather no more than they say, and
+    neither copies a pool."""
+    import numpy as np
+
+    import distkeras_tpu.ops.paged_attention as pa
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.serving.engine import DecodeStepper
+
+    rope = {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+            "original_max_position_embeddings": 8192, "beta_slow": 1,
+            "beta_fast": 32, "attention_factor": 1.4852030263919618,
+            "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                              "partial_rotary_factor": 1},
+    }
+    model = zoo.laguna_lm(
+        vocab_size=512, seq_len=16384, hidden_size=3072,
+        num_key_value_heads=8, head_dim=128, intermediate_size=12288,
+        moe_intermediate_size=1024, shared_expert_intermediate_size=1024,
+        num_experts=256, num_experts_per_tok=10,
+        num_attention_heads_per_layer=(48, 72, 72, 72, 48),
+        sliding_window=512, rope_parameters=rope, experts_held=[0, 1])
+    model.params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                                model.params)
+    st = DecodeStepper(model, num_slots=96, paged=True, page_size=16,
+                       num_pages=2048, kv_dtype=jnp.bfloat16)
+    assert st.attention == "kernel" and st._ring == 33
+    pbt = st._max_pages_bucket
+    assert pbt == 1024
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one_chip), tree)
+
+    real = pa.pallas_interpret
+    pa.pallas_interpret = lambda: False  # compile the kernel, as the chip
+    try:
+        step = st._build_step_fn_paged(pbt).lower(*shapes((
+            st._params, st._ctx, st._pools, st._lens.copy(),
+            np.zeros(96, bool), st._tables_array(pbt),
+            *st._sampling_args()))).compile()
+        chunk = st._build_chunk_fn_paged(2048, pbt).lower(*shapes((
+            st._params, st._pools, np.zeros((1, 2048), np.int32),
+            st._chunk_where(0, pbt, 0), np.int32(0)))).compile()
+    finally:
+        pa.pallas_interpret = real
+    text = step.as_text()
+    assert text.count("tpu_custom_call") >= 5  # a kernel call a layer
+    for compiled in (step, chunk):
+        for rows in (2048 * 16, (96 * 33 + 1) * 16):
+            copies = [ln for ln in compiled.as_text().splitlines()
+                      if " copy(" in ln and f"[{rows},1024]" in ln.split("=")[0]]
+            assert not copies, copies[:3]
+    # the chunk's transients beside 96 slots' pools fit the chip
+    assert chunk.memory_analysis().temp_size_in_bytes < 2.0e9
+    assert step.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 # the latent cell's shapes (kanana-2-30b-a3b-8l: 64 slots, 32 heads, rows of
 # 512 + 64 values padded to 640, 8,960 pages of 16 tokens, a table of 512
 # pages: 128 KB of scalar-prefetched table), the shortcut layer's cell

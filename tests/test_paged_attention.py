@@ -247,6 +247,122 @@ def test_latent_kernel_never_reads_pages_past_a_slot_s_length(dtype):
     _check_latent(qc, pool, table, lengths, block_pages=4)
 
 
+# ------------------------------------------- fewer K/V heads, and a window
+
+
+def _grouped_oracle(q, ck, cv, table, lengths, first, ring, ps):
+    """``models.layers.cache_attention`` a K/V head's group at a time: the
+    positions ``first <= s < length`` of a slot gathered through its table
+    (a ring: logical page ``p`` in column ``p % ring``), float32."""
+    from distkeras_tpu.models.layers import cache_attention
+
+    b, hq, hd = q.shape
+    kvh = ck.shape[1] // hd
+    out = np.zeros((b, hq, hd), np.float32)
+    for i in range(b):
+        pos = np.arange(first[i], lengths[i])
+        if not len(pos):
+            continue
+        col = (pos // ps) % ring if ring else pos // ps
+        rows = table[i, col] * ps + pos % ps
+        k = np.asarray(ck, np.float32)[rows].reshape(1, -1, kvh, hd)
+        v = np.asarray(cv, np.float32)[rows].reshape(1, -1, kvh, hd)
+        g = hq // kvh
+        for h in range(kvh):
+            out[i, h * g:(h + 1) * g] = np.asarray(cache_attention(
+                jnp.asarray(q[i:i + 1, h * g:(h + 1) * g]),
+                jnp.repeat(k[:, :, h:h + 1], g, axis=2),
+                jnp.repeat(v[:, :, h:h + 1], g, axis=2),
+                jnp.ones((1, len(pos)), bool)))[0]
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("hq", [48, 72])
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+def test_grouped_queries_over_eight_kv_heads(hq, windowed, dtype):
+    """The grouped body at the grouped cell's head counts (48 and 72 query
+    heads over 8 K/V heads of 128), interpreted, against
+    ``cache_attention`` a group at a time: without a first position over a
+    growing table, and from ``max(0, length - window)`` over a ring of
+    ``window / page + 1`` pages, at lengths below, at and beyond the window
+    (a window of two pages: 32), a page's edges, and a slot of length 0.
+    Against a float32 pool the products are float32; against a bfloat16 pool
+    the query and the weights enter as one bfloat16 term, as everywhere in
+    that block, and the tolerance is that rounding's."""
+    rng = np.random.default_rng(hq)
+    kvh, window = 8, 2 * PS
+    ring = window // PS + 1 if windowed else 0
+    lengths = np.array([0, 1, 15, 31, 32, 33, 48, 49, 130], np.int32)
+    first = np.maximum(lengths - window, 0) if windowed else np.zeros_like(
+        lengths)
+    b, num_pages = len(lengths), 64
+    pbt = ring or 12
+    table = np.zeros((b, pbt), np.int32)
+    free = iter(rng.permutation(np.arange(1, num_pages)))
+    for i, n in enumerate(np.minimum(-(-lengths // PS), pbt)):
+        table[i, :n] = [next(free) for _ in range(n)]
+    q = rng.normal(size=(b, hq, HD)).astype(np.float32)
+    ck, cv = (jnp.asarray(rng.normal(size=(num_pages * PS, kvh * HD)), dtype)
+              for _ in range(2))
+    got = np.asarray(paged_decode_attention(
+        q, ck, cv, table, lengths, first if windowed else None,
+        page_size=PS, ring=ring, block_pages=2))
+    want = _grouped_oracle(q, ck, cv, table, lengths, first, ring, PS)
+    big = float(np.abs(np.asarray(cv, np.float32)).max())
+    tol = (130 * EPS * big if dtype == jnp.float32
+           # one bfloat16 term: the scores move by 2^-9 |q . k| / sqrt(Dh)
+           # (about 0.01 here) and the weights by 2^-9 of themselves
+           else 0.03 * big)
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=0, atol=tol)
+    assert not got[0].any()
+
+
+def test_a_4d_pool_of_fewer_kv_heads_is_the_flat_pool():
+    """``(pages, page, Hkv, Dh)`` as the docstring gives the pool: accepted,
+    and the same numbers as its row-major flattening."""
+    rng = np.random.default_rng(3)
+    lengths = np.array([20, 0, 40], np.int32)
+    table = np.array([[3, 5, 0], [0, 0, 0], [2, 7, 1]], np.int32)
+    q = rng.normal(size=(3, 12, HD)).astype(np.float32)
+    ck, cv = (jnp.asarray(rng.normal(size=(8, PS, 2, HD)), jnp.float32)
+              for _ in range(2))
+    flat = [c.reshape(8 * PS, 2 * HD) for c in (ck, cv)]
+    np.testing.assert_array_equal(
+        paged_decode_attention(q, ck, cv, table, lengths),
+        paged_decode_attention(q, *flat, table, lengths, page_size=PS))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_equal_heads_without_a_first_position_is_the_kernel_it_was(
+        dtype, monkeypatch):
+    """``Hq == Hkv``, a 4-D pool, no first position, no ring, at
+    ``serve_backlog``'s shapes (32 slots, 16 heads of 128): the body PR 29
+    wrote is the one that runs (the grouped one is not entered), and it
+    matches the gather body to the tolerance this file has held since."""
+    from distkeras_tpu.ops import paged_attention as pa
+
+    def never(*a, **kw):
+        raise AssertionError("the grouped body ran for equal heads")
+
+    monkeypatch.setattr(pa, "_paged_grouped_attention", never)
+    rng = np.random.default_rng(29)
+    b, nh, pbt, num_pages = 32, 16, 8, 160
+    lengths = rng.integers(1, pbt * PS + 1, b).astype(np.int32)
+    lengths[5] = 0
+    table = np.zeros((b, pbt), np.int32)
+    free = iter(rng.permutation(np.arange(1, num_pages)))
+    for i, n in enumerate(-(-lengths // PS)):
+        table[i, :n] = [next(free) for _ in range(n)]
+    q = rng.normal(size=(b, nh, HD)).astype(np.float32)
+    ck, cv = _pools(rng, num_pages, nh, dtype)
+    got = _check(q, ck, cv, table, lengths, block_pages=BLOCK_PAGES)
+    assert not got[5].any()
+
+
 @pytest.mark.parametrize(
     "layout,head_dim,kv_dtype,mesh,want",
     [
@@ -263,6 +379,11 @@ def test_latent_kernel_never_reads_pages_past_a_slot_s_length(dtype):
          "gather: no kernel for a float8_e4m3fn"),
         ("latent", None, jnp.bfloat16, object(), "gather: Mosaic kernels"),
         ("window", 128, jnp.bfloat16, None, "gather: the window page"),
+        ("gqa", 128, jnp.bfloat16, None, "kernel"),
+        ("gqa", 128, jnp.float32, None, "kernel"),
+        ("gqa", 64, jnp.bfloat16, None, "gather: heads of 64"),
+        ("gqa", 128, jnp.float16, None, "gather: no kernel for a float16"),
+        ("gqa", 128, jnp.bfloat16, object(), "gather: Mosaic kernels"),
     ],
 )
 def test_where_the_kernel_engages(layout, head_dim, kv_dtype, mesh, want):
@@ -281,6 +402,8 @@ def test_a_latent_page_is_whole_tiles_of_the_pool(page_size, want):
     ``"kv"`` layout's page is a leading index and takes any size."""
     got = decode_attention_path("latent", None, jnp.bfloat16, None, page_size)
     assert got.startswith(want), got
+    grouped = decode_attention_path("gqa", 128, jnp.bfloat16, None, page_size)
+    assert grouped.startswith(want.replace("latent", "grouped")), grouped
     assert decode_attention_path(
         "kv", 128, jnp.bfloat16, None, page_size) == "kernel"
 

@@ -1372,3 +1372,145 @@ def test_a_slot_with_a_grammar_keeps_the_real_engine_one_step_deep():
     assert look["drained"].get("grammar", 0) >= 3
     # once the constrained request has left, the loop looks ahead again
     assert look["ahead_steps"] >= 1
+
+
+# ------------------------- a page budget a layer kind (the grouped block)
+
+
+def _laguna(**kw):
+    from distkeras_tpu.models import zoo
+
+    return zoo.laguna_lm(vocab_size=61, seq_len=256, hidden_size=32, **kw)
+
+
+def _grouped_stepper(num_slots=3, num_pages=60, **kw):
+    from distkeras_tpu.serving.engine import DecodeStepper
+
+    return DecodeStepper(_laguna(), num_slots=num_slots, paged=True,
+                         page_size=4, num_pages=num_pages, **kw)
+
+
+def test_a_request_holds_two_budgets_and_release_frees_both():
+    """Admission reserves the growing budget (``pages_for(prompt +
+    max_new)``, the full layers') and a ring of the window pool (the window
+    of 8 over pages of 4: 3 pages) that does not grow with the request: 20
+    times the window holds no more window pages than 2 times; release frees
+    both."""
+    st = _grouped_stepper()
+    assert st.window_pages == (0, 9) and st._ring == 3
+    st.begin_admit(0, np.arange(1, 13) % 61, max_new=4)     # 2 x the window
+    assert st._kv_alloc.pages_in_use == 4 and st.window_pages[0] == 3
+    st.begin_admit(1, np.arange(1, 121) % 61, max_new=40)   # 20 x
+    assert st._kv_alloc.pages_in_use == 4 + 40 and st.window_pages[0] == 6
+    st.begin_admit(2, np.arange(1, 4), max_new=2)           # under a window
+    assert st.window_pages[0] == 6 + 2  # pages_for(5) = 2 < the ring
+    stats = st.paged_stats()
+    assert stats["window"]["pages_in_use"] == 8
+    assert stats["window_positions_max"] == 12
+    for slot in range(3):
+        st.release(slot)
+    assert st._kv_alloc.pages_in_use == 0 and st.window_pages == (0, 9)
+    assert st._tables == [[], [], []] and st._window_tables == [[], [], []]
+
+
+@pytest.mark.parametrize("short", ["growing", "window"])
+def test_exhaustion_of_either_budget_is_typed_and_holds_nothing(short):
+    """All or nothing over both: where either pool cannot cover the
+    request, ``PoolExhaustedError`` is raised with no page of the other
+    held and the slot as it was."""
+    from distkeras_tpu.serving.scheduler import PoolExhaustedError
+
+    st = _grouped_stepper(num_pages=12 if short == "growing" else 60)
+    if short == "window":  # someone else holds all but two of the rings' pages
+        held = st._window_alloc.alloc(7)
+    with pytest.raises(PoolExhaustedError):
+        st.begin_admit(0, np.arange(1, 41) % 61, max_new=20)  # 15 + 3 pages
+    assert st._kv_alloc.pages_in_use == 0
+    assert st.window_pages[0] == (7 if short == "window" else 0)
+    assert st._tables[0] == [] and st._window_tables[0] == []
+    assert 0 not in st._pending
+    if short == "window":
+        st._window_alloc.free(held)
+    st.begin_admit(0, np.arange(1, 9), max_new=4)  # and the slot still admits
+    assert st.window_pages[0] == 3
+
+
+def test_the_scheduler_s_iteration_carries_the_window_pool():
+    """``serving/iter``'s counters: ``pages_*`` stay the growing budget,
+    ``window_pages_*`` join them where a layer has a window."""
+    st = _grouped_stepper()
+    b = ContinuousBatcher(st, queue_capacity=8, prefill_chunk=16)
+    req = b.submit(ServeRequest(np.arange(1, 30) % 61, 3))
+    for _ in range(6):
+        b.step()
+        if req.done:
+            break
+        counts = dict(b._iter_counts)
+    assert counts["pages_total"] == 59 and counts["pages_in_use"] == 8
+    assert counts["window_pages_total"] == 9
+    assert counts["window_pages_in_use"] == 3
+    assert req.done and st.window_pages[0] == 0
+
+
+@pytest.mark.parametrize("feature", [
+    "dense_bank", "speculative", "mesh", "int8", "prefix_store", "fork",
+    "swap_out", "swap_in", "role", "solo_generator"])
+def test_what_the_engine_cannot_do_for_the_grouped_block_is_refused_typed(
+        feature, tp_mesh):
+    """Each thing the grouped-query block with window layers cannot do yet
+    is a ``BlockUnsupportedError`` that names it, at construction where a
+    construction argument asks for it."""
+    from distkeras_tpu.models.mla_moe import BlockUnsupportedError
+    from distkeras_tpu.ops.quantization import quantize_model
+    from distkeras_tpu.predictors import CachedSequenceGenerator
+    from distkeras_tpu.serving import ServingEngine
+    from distkeras_tpu.serving.engine import DecodeStepper, NgramDrafter
+    from distkeras_tpu.serving.prefix_cache import PrefixStore
+
+    names = {
+        "dense_bank": "dense slot bank", "speculative": "speculative",
+        "mesh": "tensor-parallel", "int8": "int8 / int4",
+        "prefix_store": "PrefixStore", "fork": "fork / beam",
+        "swap_out": "swap-out", "swap_in": "swap-in", "role": "role",
+        "solo_generator": "solo cached generators",
+    }
+    model = _laguna()
+    paged = dict(num_slots=2, paged=True, page_size=4, num_pages=40)
+    with pytest.raises(BlockUnsupportedError, match=names[feature]) as err:
+        if feature == "dense_bank":
+            ServingEngine(model, num_slots=2, paged=False)
+        elif feature == "speculative":
+            DecodeStepper(model, speculative=NgramDrafter(), **paged)
+        elif feature == "mesh":
+            ServingEngine(model, mesh=tp_mesh(2), **paged)
+        elif feature == "int8":
+            ServingEngine(quantize_model(model, bits=8), **paged)
+        elif feature == "prefix_store":
+            ServingEngine(model, prefix_cache=PrefixStore(max_bytes=1 << 20),
+                          **paged)
+        elif feature == "role":
+            ServingEngine(model, role="prefill", **paged)
+        elif feature == "solo_generator":
+            CachedSequenceGenerator(model).generate(np.ones((1, 4), np.int32), 2)
+        else:
+            st = DecodeStepper(model, **paged)
+            assert st.can_fork is False and st.prefix_index is None
+            st.admit(0, np.arange(6), max_new=4)
+            if feature == "fork":
+                st.fork_slot(0, 1)
+            elif feature == "swap_out":
+                st.swap_out(0)
+            else:
+                st.swap_in(1, {"len": 3})
+    if feature != "solo_generator":
+        assert "window layers" in str(err.value)
+
+
+def test_the_default_prefix_cache_is_switched_off_and_says_so():
+    from distkeras_tpu.serving import ServingEngine
+
+    eng = ServingEngine(_laguna(), num_slots=2, paged=True, page_size=4,
+                        num_pages=40)  # prefix_cache=True is the default
+    assert eng.prefix_store is None
+    assert eng.stats()["paged"]["prefix_caches"].startswith(
+        "off: grouped page layout")

@@ -139,10 +139,10 @@ def test_decode_param_specs_quantized(lm, tp_mesh):
 
 
 def test_heads_divisibility_is_loud_at_load(lm):
-    with pytest.raises(ValueError, match="cannot shard 2 attention"):
+    with pytest.raises(ValueError, match="cannot shard 2 K/V heads"):
         DecodeStepper(lm, num_slots=2, mesh="tp:4")
     # the ENGINE must fail the boot too, never demote to predict-only
-    with pytest.raises(ValueError, match="cannot shard 2 attention"):
+    with pytest.raises(ValueError, match="cannot shard 2 K/V heads"):
         ServingEngine(lm, num_slots=2, mesh="tp:4")
     with pytest.raises(ValueError, match="needs 16 devices"):
         ServingEngine(lm, num_slots=2, mesh="tp:16")
