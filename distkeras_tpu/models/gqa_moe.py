@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from distkeras_tpu.models.layers import Layer, register_layer
 from distkeras_tpu.models.mla_moe import (
-    Picks, _einsum, _normal, gated_mlp, matmul, rms_norm, rope, route,
+    _einsum, _normal, gated_mlp, matmul, rms_norm, rope, route,
     routed_experts)
 
 
@@ -312,7 +312,7 @@ class GroupedQueryMoEBlock(Layer):
             return x + matmul(o.reshape(*lead, nh * hd), a["wo"])
 
     def ffn(self, p, u, token_mask=None):
-        """``u`` ``(n, d)`` -> ``(y, group sizes | None)``; more than
+        """``u`` ``(n, d)`` -> ``(y, Picks | None)``; more than
         ``token_block`` tokens (a long prefill chunk) go ``token_block``
         at a time, since the expert layer sorts ``top_k`` rows of ``d`` a
         token and the dense MLP is 4 x ``d`` wide."""
@@ -321,11 +321,11 @@ class GroupedQueryMoEBlock(Layer):
             return self._ffn(p, u, token_mask)
         if token_mask is None:
             token_mask = jnp.ones((n,), bool)
-        y, sizes = jax.lax.map(
+        y, picks = jax.lax.map(
             lambda block: self._ffn(p, *block),
             (u.reshape(-1, tb, u.shape[-1]), token_mask.reshape(-1, tb)),
         )
-        return y.reshape(u.shape), None if sizes is None else sizes.sum(0)
+        return y.reshape(u.shape), jax.tree.map(lambda a: a.sum(0), picks)
 
     def _ffn(self, p, u, token_mask):
         if not self.n_experts:
@@ -333,12 +333,12 @@ class GroupedQueryMoEBlock(Layer):
                 return gated_mlp(p, u), None
         chosen, w = route(p["router"], u, self.top_k, self.routed_scale,
                           softmax=True, normalise=self.norm_topk)
-        y, sizes = routed_experts(
+        y, picks = routed_experts(
             p["experts"], u, chosen, w, self.held, self.n_experts,
             token_mask)
         with jax.named_scope("moe/shared"):
             y = y + gated_mlp(p["shared"], u)
-        return y, sizes
+        return y, picks
 
     def forward(self, p, x, pos, mask, attend=None, token_mask=None):
         """``x`` ``(..., d)`` at positions ``pos`` ``(...)``; ``attend``
@@ -351,9 +351,8 @@ class GroupedQueryMoEBlock(Layer):
         u = rms_norm(x, p["ln2"]["gamma"], self.epsilon)
         if token_mask is not None:
             token_mask = jnp.broadcast_to(token_mask, lead).reshape(-1)
-        y, sizes = self.ffn(p["ffn"], u.reshape(-1, d), token_mask)
-        return (x + y.reshape(*lead, d),
-                None if sizes is None else Picks(sizes, 0))
+        y, picks = self.ffn(p["ffn"], u.reshape(-1, d), token_mask)
+        return x + y.reshape(*lead, d), picks
 
     def apply(self, params, state, x, train=False, rng=None):
         b, n, _ = x.shape

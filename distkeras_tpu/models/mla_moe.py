@@ -61,10 +61,13 @@ class BlockUnsupportedError(NotImplementedError):
 class Picks(typing.NamedTuple):
     """What an expert layer's tokens chose, for the step's counters:
     ``sizes`` ``(E_held,)`` tokens on each held routed expert, ``zero``
-    how many picks took an identity (zero-compute) expert."""
+    how many picks took an identity (zero-compute) expert, ``overflow``
+    how many of the layer's calls held more rows than one pass of
+    ``routed_experts`` takes (None: the layer is not compacted)."""
 
     sizes: jax.Array
     zero: jax.Array | int
+    overflow: jax.Array | None = None
 
 
 # --------------------------------------------------------------- arithmetic
@@ -100,6 +103,14 @@ def _grouped_mm(x, w, sizes):
     weights' dtype, float32 out."""
     return jax.lax.ragged_dot(*_operands(x, w), sizes,
                               preferred_element_type=jnp.float32)
+
+
+def _grouped_mlp(p, xs, sizes):
+    """``gated_mlp`` over rows sorted by expert, each group of ``sizes``
+    rows through its own expert of the stacked weights ``p``."""
+    h = jax.nn.silu(_grouped_mm(xs, p["wg"], sizes)) * _grouped_mm(
+        xs, p["wu"], sizes)
+    return _grouped_mm(h, p["wd"], sizes)
 
 
 def rms_norm(x, gamma, eps):
@@ -158,6 +169,24 @@ def route(p, x, top_k, scale, softmax=False, normalise=None):
         return chosen.astype(jnp.int32), w * scale
 
 
+def held_capacity(rows, e_held, n_outputs):
+    """Rows one pass of ``routed_experts`` takes, from what it can read
+    off its arguments: of ``rows`` (token, pick) pairs a share of
+    ``e_held / n_outputs`` belong to a held expert under even routing;
+    a quarter more and 128 rows of room, in tiles of 128 rows, and an odd
+    number of them: the TPU's grouped product takes the largest power of
+    two that divides its rows (up to 512) as its row tile and multiplies
+    a whole tile for every expert that has a row in it, so 128-row tiles
+    keep an expert's few rows bound by its weights' bytes, where a tile
+    of 512 is bound by the tile's products. None where the pass would be
+    more than half of ``rows`` (every expert held, a handful of tokens, a
+    quarter of the experts under a decode step's rows): compacting saves
+    less there than its own sort and scatter cost."""
+    tiles = -(-int(1.25 * rows * e_held / n_outputs + 128) // 128)
+    cap = 128 * (tiles | 1)
+    return cap if 2 * cap <= rows else None
+
+
 def routed_experts(p, x, chosen, weights, held, n_experts, token_mask=None):
     """The held experts' part of the routed output, no token dropped:
     the (token, choice) pairs are sorted by expert and the three products
@@ -166,11 +195,26 @@ def routed_experts(p, x, chosen, weights, held, n_experts, token_mask=None):
     routed to it. A pair whose expert is not held here (or whose token
     ``token_mask`` switches off) adds nothing and costs no product.
 
-    Returns ``(y (n, d) f32, group_sizes (E_held,) int32)``."""
+    Where this chip holds a share of the experts, it costs no row
+    either. The stable sort puts the held pairs first, so only the first
+    ``cap`` sorted pairs (``held_capacity``: a function of the shapes and
+    of ``len(held)``, nothing a caller sets) are gathered, multiplied and
+    weighted, and each row is added to its token's sum. Routing that
+    holds more than ``cap`` pairs here (skew, a batch that picks held
+    experts alone) is never cut: the pairs past ``cap`` go through the
+    same pass, ``cap`` at a time, in a loop that makes no trip under even
+    routing, and ``Picks.overflow`` counts the calls that needed it. Where
+    a pass would be more than half the rows (every expert held, a few
+    tokens) ``cap`` is None and all rows go at once, through the gather
+    back over every pair.
+
+    Returns ``(y (n, d) f32, Picks)``; ``Picks.sizes`` ``(E_held,)`` int32
+    are the group sizes."""
     n, k = chosen.shape
     e_held = p["wg"].shape[0]
     local = np.full((n_experts + 1,), e_held, np.int32)  # sentinel: absent
     local[np.asarray(held, np.int64)] = np.arange(e_held, dtype=np.int32)
+    cap = held_capacity(n * k, e_held, n_experts)
     with jax.named_scope("moe/experts"):
         flat = jnp.asarray(local)[chosen.reshape(-1)]  # (n k,) local ids
         if token_mask is not None:
@@ -179,17 +223,39 @@ def routed_experts(p, x, chosen, weights, held, n_experts, token_mask=None):
         sizes = jnp.bincount(flat, length=e_held + 1)[:e_held].astype(
             jnp.int32
         )
-        xs = x[order // k]
-        h = jax.nn.silu(_grouped_mm(xs, p["wg"], sizes)) * _grouped_mm(
-            xs, p["wu"], sizes)
-        y = _grouped_mm(h, p["wd"], sizes)  # (n k, d), sorted by expert
-        # rows past the held groups belong to no expert: weight 0
-        wsorted = jnp.where(
-            flat[order] < e_held, weights.reshape(-1)[order], 0.0
-        )
-        y = jnp.where(wsorted[:, None] != 0.0, y * wsorted[:, None], 0.0)
-        back = jnp.argsort(order)  # sorted row of each (token, choice)
-        return y[back].reshape(n, k, -1).sum(axis=1), sizes
+        if cap is None:
+            y = _grouped_mlp(p, x[order // k], sizes)  # (n k, d), sorted
+            # rows past the held groups belong to no expert: weight 0
+            wsorted = jnp.where(
+                flat[order] < e_held, weights.reshape(-1)[order], 0.0
+            )
+            y = jnp.where(wsorted[:, None] != 0.0, y * wsorted[:, None], 0.0)
+            back = jnp.argsort(order)  # sorted row of each (token, choice)
+            return y[back].reshape(n, k, -1).sum(axis=1), Picks(sizes, 0)
+
+        ends = jnp.cumsum(sizes)
+        n_held = ends[-1]
+        # whole passes: a slice past the last pair reads pairs of weight 0
+        order = jnp.pad(order, (0, -(n * k) % cap))
+        wflat = weights.reshape(-1)
+
+        def add_pass(lo, out):
+            """Sorted pairs ``lo .. lo + cap``: each expert's rows among
+            them through its three products, added to their tokens."""
+            pairs = jax.lax.dynamic_slice_in_dim(order, lo, cap)
+            span = jnp.clip(ends, lo, lo + cap) - jnp.clip(
+                ends - sizes, lo, lo + cap)
+            y = _grouped_mlp(p, x[pairs // k], span)  # (cap, d), sorted
+            # rows past the held pairs belong to no expert: weight 0
+            w = jnp.where(lo + jnp.arange(cap) < n_held, wflat[pairs], 0.0)
+            y = jnp.where(w[:, None] != 0.0, y * w[:, None], 0.0)
+            return out.at[pairs // k].add(y)
+
+        out = add_pass(0, jnp.zeros((n, x.shape[-1]), jnp.float32))
+        out = jax.lax.fori_loop(
+            1, (n_held + cap - 1) // cap,
+            lambda j, out: add_pass(j * cap, out), out)
+        return out, Picks(sizes, 0, (n_held > cap).astype(jnp.int32))
 
 
 def attend_expanded(p, q, latent, mask, nh, nope, vd, n_keys=None,
@@ -492,16 +558,16 @@ class LatentMoEBlock(_LatentBlock):
     # -- the arithmetic, once -----------------------------------------------
 
     def ffn(self, p, x, token_mask=None):
-        """``x`` ``(n, d)`` -> ``(y, group_sizes | None)``."""
+        """``x`` ``(n, d)`` -> ``(y, Picks | None)``."""
         if not self.n_experts:
             return gated_mlp(p, x), None
         chosen, w = route(p["router"], x, self.top_k, self.routed_scale)
-        y, sizes = routed_experts(
+        y, picks = routed_experts(
             p["experts"], x, chosen, w, self.held, self.n_experts, token_mask
         )
         with jax.named_scope("moe/shared"):
             y = y + gated_mlp(p["shared"], x)
-        return y, sizes
+        return y, picks
 
     def forward(self, p, x, pos, mask, exchange=None, absorbed=False,
                 token_mask=None, n_keys=None):
@@ -521,9 +587,8 @@ class LatentMoEBlock(_LatentBlock):
         h = rms_norm(x, p["ln2"]["gamma"], self.epsilon)
         if token_mask is not None:
             token_mask = jnp.broadcast_to(token_mask, (b, n)).reshape(-1)
-        y, sizes = self.ffn(p["ffn"], h.reshape(b * n, d), token_mask)
-        return (x + y.reshape(b, n, d),
-                None if sizes is None else Picks(sizes, 0))
+        y, picks = self.ffn(p["ffn"], h.reshape(b * n, d), token_mask)
+        return x + y.reshape(b, n, d), picks
 
     def get_config(self):
         return {
@@ -613,9 +678,10 @@ class ShortcutMoEBlock(_LatentBlock):
     def moe(self, p, u, token_mask=None):
         """``u`` ``(n, d)`` -> ``(m, Picks)``: the held routed experts'
         part and every identity pick's. More than ``token_block`` tokens
-        (a long prefill chunk) go ``token_block`` at a time: the sort holds
-        a row for every (token, pick) pair, ``top_k`` rows of ``d`` a
-        token, of which a chip's share of the experts takes few."""
+        (a long prefill chunk) go ``token_block`` at a time: a layer that
+        holds every expert moves a row of ``d`` for every (token, pick)
+        pair, ``top_k`` a token (a chip's share moves its own pairs'
+        rows alone, ``routed_experts``)."""
         n, tb = u.shape[0], self.token_block
         if n <= tb or n % tb:
             return self._moe(p, u, token_mask)
@@ -625,12 +691,12 @@ class ShortcutMoEBlock(_LatentBlock):
             lambda block: self._moe(p, *block),
             (u.reshape(-1, tb, u.shape[-1]), token_mask.reshape(-1, tb)),
         )
-        return y.reshape(u.shape), Picks(picks.sizes.sum(0), picks.zero.sum())
+        return y.reshape(u.shape), jax.tree.map(lambda a: a.sum(0), picks)
 
     def _moe(self, p, u, token_mask):
         chosen, w = route(p["router"], u, self.top_k, self.routed_scale,
                           softmax=True)
-        y, sizes = routed_experts(
+        y, picks = routed_experts(
             p["experts"], u, chosen, w, self.held,
             self.n_experts + self.n_zero, token_mask,
         )
@@ -640,7 +706,7 @@ class ShortcutMoEBlock(_LatentBlock):
                             keepdims=True) * u
             if token_mask is not None:
                 zero = zero & token_mask[:, None]
-            return y, Picks(sizes, jnp.sum(zero))
+            return y, picks._replace(zero=jnp.sum(zero))
 
     def forward(self, p, x, pos, mask, exchange=None, absorbed=False,
                 token_mask=None, n_keys=None):
@@ -680,13 +746,17 @@ class ShortcutMoEBlock(_LatentBlock):
 
 
 def routing_counts(picks_by_layer):
-    """The step's four routing counters from the expert layers' ``Picks``:
+    """The step's routing counters from the expert layers' ``Picks``:
     ``[sum over layers of the held experts that got a token, the largest
     token count on one expert, the picks of an identity expert, the picks
-    of a held routed expert]`` as int32."""
+    of a held routed expert]`` as int32, and behind them, where a layer
+    compacts its held rows, how many layers needed more than one pass."""
     sizes = [p.sizes for p in picks_by_layer]
     hit = sum(jnp.sum(s > 0) for s in sizes)
     load = jnp.max(jnp.stack([jnp.max(s) for s in sizes]))
     zero = sum(p.zero for p in picks_by_layer)
     held = sum(jnp.sum(s) for s in sizes)
-    return jnp.stack([hit, load, zero, held]).astype(jnp.int32)
+    over = [p.overflow for p in picks_by_layer if p.overflow is not None]
+    return jnp.stack(
+        [hit, load, zero, held] + ([sum(over)] if over else [])
+    ).astype(jnp.int32)

@@ -822,6 +822,8 @@ class DecodeStepper:
             # of the active slots' tokens x top_k x expert layers picks:
             # those of an identity expert and of a held routed expert
             "routed_tokens": 0, "zero_picks": 0, "held_picks": 0,
+            # layer-steps whose held rows took more than one pass
+            "overflow_passes": 0,
         }
         self.host_arg_bytes_step = 0  # of the last decode-step call
         self._step_fns = {}  # masked flag -> compiled decode step
@@ -1281,16 +1283,20 @@ class DecodeStepper:
 
     def _note_routing(self, fetched, n_active, span):
         """Split the step's fetch into its tokens and the expert layers'
-        four counters (``models.mla_moe.routing_counts``); sum them for
+        counters (``models.mla_moe.routing_counts``); sum them for
         ``stats()["moe"]`` and put them on the ``serving/collect`` span.
         ``experts_hit`` is the distinct held routed experts that some
         active slot's token reached, a mean over the expert layers;
         ``expert_load_max`` the largest token count on one expert;
         ``zero_picks`` and ``held_picks`` how many of the active slots'
         ``picks`` (tokens x top_k x expert layers) took an identity
-        expert and a held routed expert."""
-        toks, counts = fetched[:-4], fetched[-4:]
-        hit_sum, load_max, zero, held = (int(c) for c in counts)
+        expert and a held routed expert; ``overflow_passes`` how many
+        expert layers held more rows than one pass of
+        ``routed_experts`` takes (a fifth counter, which a program whose
+        layers hold every expert does not send: 0)."""
+        toks, counts = fetched[:self.num_slots], fetched[self.num_slots:]
+        hit_sum, load_max, zero, held, *over = (int(c) for c in counts)
+        over = sum(over)
         hit = hit_sum / self._moe_layers
         m = self.moe_stats
         self.moe_stats = {
@@ -1300,11 +1306,12 @@ class DecodeStepper:
             "routed_tokens": m["routed_tokens"] + n_active,
             "zero_picks": m["zero_picks"] + zero,
             "held_picks": m["held_picks"] + held,
+            "overflow_passes": m["overflow_passes"] + over,
         }
         span.set_metadata(
             experts_hit=hit, expert_load_max=load_max,
             experts_total=m["experts_total"], routed_tokens=n_active,
-            zero_picks=zero, held_picks=held,
+            zero_picks=zero, held_picks=held, overflow_passes=over,
             picks=n_active * self._moe_layers
             * self._gen._blocks[-1].top_k,
         )

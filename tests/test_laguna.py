@@ -379,9 +379,9 @@ def test_the_experts_shares_add_up_to_the_whole_layer(fam, tiny):
             k: v[np.asarray(held)] for k, v in p["ffn"]["experts"].items()}}}
         with jax.default_matmul_precision("highest"):
             y, _ = blk.apply(part, {}, x[None])
-            mine, sizes = blk.ffn(part["ffn"], u)
+            mine, picks = blk.ffn(part["ffn"], u)
             ref = fam.expert_layer(p["ffn"], u, w, dot_highest, held=held)[0]
-        assert sizes.shape == (2,)
+        assert picks.sizes.shape == (2,)
         np.testing.assert_allclose(mine, ref, atol=2e-6, rtol=0)
         total += np.asarray(y)[0] - alike  # this share's routed part
     np.testing.assert_allclose(total + alike, whole, atol=5e-6, rtol=0)

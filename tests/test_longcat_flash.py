@@ -380,6 +380,9 @@ def test_the_step_s_counters_come_from_the_group_sizes_and_picks():
     picks = [Picks(jnp.asarray([2, 0, 5]), jnp.asarray(4)),
              Picks(jnp.asarray([0, 0, 1]), 0)]
     assert list(np.asarray(mla_moe.routing_counts(picks))) == [3, 5, 4, 8]
+    # layers that compact their held rows send a fifth: those that overflowed
+    picks = [Picks(p.sizes, p.zero, jnp.asarray(i)) for i, p in enumerate(picks)]
+    assert list(np.asarray(mla_moe.routing_counts(picks))) == [3, 5, 4, 8, 1]
 
     class Span:
         def set_metadata(self, **kw):
@@ -395,10 +398,62 @@ def test_the_step_s_counters_come_from_the_group_sizes_and_picks():
     # 2 tokens x 3 picks x 2 layers = 12 picks, 4 identity, 8 held
     assert span.kw == {
         "experts_hit": 3.0, "expert_load_max": 5, "experts_total": 8,
-        "routed_tokens": 2, "zero_picks": 4, "held_picks": 8, "picks": 12}
+        "routed_tokens": 2, "zero_picks": 4, "held_picks": 8, "picks": 12,
+        "overflow_passes": 0}
     assert {k: st.moe_stats[k] for k in (
-        "steps", "routed_tokens", "zero_picks", "held_picks")} == {
-        "steps": 1, "routed_tokens": 2, "zero_picks": 4, "held_picks": 8}
+        "steps", "routed_tokens", "zero_picks", "held_picks",
+        "overflow_passes")} == {
+        "steps": 1, "routed_tokens": 2, "zero_picks": 4, "held_picks": 8,
+        "overflow_passes": 0}
+    # a program whose layers compact their rows sends the fifth counter
+    st._note_routing(np.asarray([7, 8, 9, 6, 5, 4, 8, 2]), 2, span)
+    assert span.kw["overflow_passes"] == 2 and span.kw["held_picks"] == 8
+    assert st.moe_stats["overflow_passes"] == 2 and st.moe_stats["steps"] == 2
+
+
+def _decode_all_slots(model, steps=3, slots=256):
+    """Every slot of a paged stepper prefilled with its own three tokens,
+    then ``steps`` decode steps: the tokens and ``moe_stats``."""
+    st = DecodeStepper(model, num_slots=slots, paged=True, page_size=8,
+                       num_pages=slots + 8)
+    rng = np.random.default_rng(5)
+    for slot in range(slots):
+        left = st.begin_admit(slot, rng.integers(0, 256, 3), max_new=steps)
+        while left:
+            left = st.prefill_chunk(slot, 8)
+    active = np.ones(slots, bool)
+    return np.stack([st.step(active) for _ in range(steps)]), st.moe_stats
+
+
+@pytest.mark.parametrize("biased", [False, True], ids=["even", "skewed"])
+def test_the_step_counts_the_layers_whose_held_rows_overflowed_a_pass(
+        biased, monkeypatch):
+    """A step of 256 slots x top 3 over three held experts of twelve router
+    outputs compacts its 768 rows to a pass of 384. Under the seeded
+    weights' even routing no layer-step needs a second pass; with a
+    selection bias that sends every pick to the held experts every one
+    does, and none is dropped: tokens and every other counter are the
+    uncompacted body's."""
+    from distkeras_tpu.models import zoo
+
+    model = zoo.longcat_flash_lm(experts_held=[0, 1, 2])
+    assert mla_moe.held_capacity(256 * 3, 3, 12) == 384
+    if biased:
+        model.params = jax.tree_util.tree_map_with_path(
+            lambda path, a: a.at[:3].set(10.0) if "bias" in str(path) else a,
+            model.params)
+    toks, moe = _decode_all_slots(model)
+    monkeypatch.setattr(mla_moe, "held_capacity", lambda *a: None)
+    toks_all_rows, moe_all_rows = _decode_all_slots(model)
+    np.testing.assert_array_equal(toks, toks_all_rows)
+    assert moe_all_rows.pop("overflow_passes") == 0
+    # 3 steps x 2 expert layers
+    assert moe.pop("overflow_passes") == (6 if biased else 0)
+    assert moe == moe_all_rows and moe["steps"] == 3
+    if biased:
+        assert moe["held_picks"] == 3 * 2 * 768 and moe["zero_picks"] == 0
+    else:
+        assert 0 < moe["held_picks"] < 3 * 2 * 384 and moe["zero_picks"] > 0
 
 
 def test_the_16_bit_tree_and_its_bundle_keep_every_leaf_bit_for_bit(

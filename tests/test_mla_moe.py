@@ -401,8 +401,8 @@ def test_the_experts_shares_add_up_to_the_whole_layer(fam, tiny):
         part = {**p, "experts": {k: v[np.asarray(held)]
                                  for k, v in p["experts"].items()}}
         with jax.default_matmul_precision("highest"):
-            y, sizes = blk.ffn(part, x)
-        assert sizes.shape == (2,)
+            y, picks = blk.ffn(part, x)
+        assert picks.sizes.shape == (2,)
         total += np.asarray(y) - shared  # this share's routed part
         # and each share is the reference's for the same experts
         ref = _reference_expert_layer(fam, w, p, x, held=held,
@@ -422,10 +422,134 @@ def test_no_token_is_dropped_when_all_route_to_one_expert(fam, tiny):
     blk, _ = _one_block()
     x = jax.random.normal(jax.random.PRNGKey(6), (96, 64))
     with jax.default_matmul_precision("highest"):
-        y, sizes = blk.ffn(p, x)
-    assert list(np.asarray(sizes)) == [0, 0, 0, 96, 0, 96, 0, 0]
+        y, picks = blk.ffn(p, x)
+    assert list(np.asarray(picks.sizes)) == [0, 0, 0, 96, 0, 96, 0, 0]
     np.testing.assert_allclose(
         y, _reference_expert_layer(fam, w, p, x), atol=5e-6, rtol=0)
+
+
+def _plain_experts(p, x, chosen, weights, held, token_mask):
+    """The held experts' routed sum, a token and a pick at a time, in
+    float32: the reference the grouped passes are held to."""
+    local = {e: i for i, e in enumerate(held)}
+    y = np.zeros(x.shape, np.float32)
+    sizes = np.zeros(len(held), np.int32)
+    for t in range(x.shape[0]):
+        if token_mask is not None and not token_mask[t]:
+            continue
+        for e, weight in zip(chosen[t], weights[t]):
+            if int(e) not in local:
+                continue
+            i = local[int(e)]
+            sizes[i] += 1
+            gate = x[t] @ p["wg"][i]
+            h = gate / (1.0 + np.exp(-gate)) * (x[t] @ p["wu"][i])
+            y[t] += weight * (h @ p["wd"][i])
+    return y, sizes
+
+
+def _expert_inputs(n, held, outputs=48, top_k=4, d=16, width=8, biased=False):
+    """Seeded stacked experts, hidden states and a router over ``outputs``;
+    ``biased``: a selection bias that sends every pick to a held expert."""
+    rng = np.random.default_rng(n + len(held))
+
+    def normal(*shape):
+        return (0.1 * rng.normal(size=shape)).astype(np.float32)
+
+    p = {"wg": normal(len(held), d, width), "wu": normal(len(held), d, width),
+         "wd": normal(len(held), width, d)}
+    router = {"wr": normal(d, outputs), "bias": np.zeros(outputs, np.float32)}
+    if biased:
+        router["bias"][np.asarray(held)] = 10.0
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    with jax.default_matmul_precision("highest"):
+        chosen, weights = mla_moe.route(router, jnp.asarray(x), top_k, 2.5)
+    return p, x, np.asarray(chosen), np.asarray(weights)
+
+
+def _routed(p, x, chosen, weights, held, outputs, token_mask):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(
+            lambda *a: mla_moe.routed_experts(*a[:4], held, outputs, a[4])
+        )(p, x, chosen, weights, token_mask)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("n", [16, 600], ids=["64rows", "2400rows"])
+@pytest.mark.parametrize("held", [
+    list(range(48)), list(range(3, 48, 4)), [17]],
+    ids=["all_held", "1_in_4", "1_in_48"])
+def test_routed_experts_is_the_plain_sum_over_held_picks(held, n, masked):
+    """(v-b) the grouped passes against a loop over tokens and picks, for
+    every held share of the router's width, below and above the rows at
+    which a share is compacted; the same bits from call to call."""
+    p, x, chosen, weights = _expert_inputs(n, held)
+    mask = (np.arange(n) % 5 != 0) if masked else None
+    want, sizes = _plain_experts(p, x, chosen, weights, held, mask)
+    y, picks = _routed(p, x, chosen, weights, held, 48, mask)
+    again, _ = _routed(p, x, chosen, weights, held, 48, mask)
+    np.testing.assert_allclose(y, want, atol=1e-6, rtol=0)
+    np.testing.assert_array_equal(picks.sizes, sizes)
+    np.testing.assert_array_equal(y, again)
+    compacted = mla_moe.held_capacity(n * 4, len(held), 48) is not None
+    assert compacted == (n == 600 and len(held) < 48)
+    # uniform picks: the held rows fit one pass
+    assert picks.overflow is None if not compacted else int(picks.overflow) == 0
+    assert sizes.sum() > 0 and np.abs(want).max() > 1e-4
+
+
+@pytest.mark.parametrize("tokens, passes", [(400, 2), (600, 3)])
+def test_held_rows_beyond_a_pass_take_further_passes_and_none_is_dropped(
+        tokens, passes):
+    """(v-c) a selection bias that sends every pick to the twelve held
+    experts: ``tokens x 4`` held rows (a mask switches the other tokens
+    off) against a pass of 896, so the loop behind the first pass runs
+    once and twice; the same sum."""
+    held = list(range(3, 48, 4))
+    p, x, chosen, weights = _expert_inputs(600, held, biased=True)
+    cap = mla_moe.held_capacity(600 * 4, 12, 48)
+    assert np.isin(chosen, held).all() and cap == 896
+    assert -(-tokens * 4 // cap) == passes
+    mask = np.arange(600) < tokens
+    want, sizes = _plain_experts(p, x, chosen, weights, held, mask)
+    y, picks = _routed(p, x, chosen, weights, held, 48, mask)
+    again, _ = _routed(p, x, chosen, weights, held, 48, mask)
+    np.testing.assert_allclose(y, want, atol=1e-6, rtol=0)
+    np.testing.assert_array_equal(picks.sizes, sizes)
+    np.testing.assert_array_equal(y, again)
+    assert int(picks.overflow) == 1 and sizes.sum() == tokens * 4
+    # a token mask that leaves one pass's worth of rows: no further pass
+    mask = np.arange(600) < cap // 4
+    y, picks = _routed(p, x, chosen, weights, held, 48, mask)
+    want, _ = _plain_experts(p, x, chosen, weights, held, mask)
+    np.testing.assert_allclose(y, want, atol=1e-6, rtol=0)
+    assert int(picks.overflow) == 0 and int(picks.sizes.sum()) == cap
+
+
+def test_a_layer_that_holds_every_expert_has_no_pass_loop():
+    """(v-d) the capacity follows the held share: with every expert held,
+    or too few rows to save any, the program is the uncompacted body (no
+    loop, no row added to its token); a held share of many rows has a first
+    pass and the loop's, each with its add."""
+    # whole tiles of 128 rows, an odd number of them
+    assert mla_moe.held_capacity(1536, 16, 768) == 384   # a step, 1 in 48
+    assert mla_moe.held_capacity(12288, 16, 768) == 640  # a chunk's block
+    assert mla_moe.held_capacity(10240, 64, 256) == 3456  # a block, 1 in 4
+    assert mla_moe.held_capacity(960, 64, 256) is None    # its step: 640
+    assert mla_moe.held_capacity(6144, 128, 128) is None  # every expert
+    assert mla_moe.held_capacity(64, 1, 48) is None       # a handful of rows
+
+    def program(held, n):
+        p, x, chosen, weights = _expert_inputs(n, held)
+        return str(jax.make_jaxpr(
+            lambda *a: mla_moe.routed_experts(*a, held, 48)
+        )(p, x, chosen, weights))
+
+    # the group sizes' bincount is the one scatter of the uncompacted body
+    for text in (program(list(range(48)), 600), program([17], 16)):
+        assert "while" not in text and text.count("scatter-add") == 1
+    shared = program(list(range(3, 48, 4)), 600)
+    assert "while" in shared and shared.count("scatter-add") == 3
 
 
 def test_the_16_bit_tree_and_its_bundle_keep_every_leaf_bit_for_bit(
