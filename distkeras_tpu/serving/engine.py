@@ -59,18 +59,9 @@ from distkeras_tpu.serving.scheduler import (
     WindowedBatcher,
     WrongRoleError,
 )
-from distkeras_tpu.utils.profiling import annotate
+from distkeras_tpu.utils.profiling import annotate, span as _span
 
 logger = logging.getLogger(__name__)
-
-
-def _span(name, **args):
-    """``annotate`` with arguments: a span on the profiler's timeline
-    (so on the device trace's clock) that carries integers the program
-    has counted anyway. With no trace running it is a flag test."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def _host_bytes(tree) -> int:
@@ -4401,8 +4392,8 @@ class ServingEngine:
             batcher.stop()
         if batcher is not None:
             logger.info(
-                "serving engine stopped: overlap %s",
-                batcher.overlap_stats(),
+                "serving engine stopped: overlap %s; loop %s",
+                batcher.overlap_stats(), batcher.loop_stats(),
             )
         self._predict_batcher.close()
         self.peer_fabric.close()  # pooled peer sockets do not leak
@@ -5163,6 +5154,9 @@ class ServingEngine:
             # device actually computed (overlap mode or the sequential
             # control — the instrument reads the same either way)
             out["overlap"] = batcher.overlap_stats()
+            # how the scheduler thread spent the time between its
+            # iterations, and the stalls it met (``loop_stats``)
+            out["loop"] = batcher.loop_stats()
         if self.shed_gate is not None:
             # overload-gate state for routers and dkt_top: the current
             # brownout rung, whether the CoDel side is shedding, and

@@ -75,11 +75,14 @@ docs/ARCHITECTURE.md "Failure modes & recovery".
 from __future__ import annotations
 
 import collections
+import logging
 import queue as _queue
 import threading
 import time
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 
 _NO_EVICT = object()  # "no eviction pending" sentinel (step loop)
@@ -109,6 +112,12 @@ _NO_SPAN = _NoSpan()
 def _no_span(name, **args):
     return _NO_SPAN
 
+
+# a gap this long between two working iterations, with work held at the
+# first one's close, is a stall (``ContinuousBatcher._note_gap``): the
+# longest sound iteration on record is 0.17 s, the holes that one
+# serving run in a dozen shows are 2-4 s
+STALL_GAP_S = 0.5
 
 # why a call of the overlapped loop did not dispatch ahead of the step
 # in the air (``ContinuousBatcher._lookahead_refusal``); each has a
@@ -621,6 +630,16 @@ class ContinuousBatcher:
         # ``serving/iter`` span and the recorder's iteration line both
         # read them from here
         self._iter_counts: dict = {}
+        # how the loop spent its time between iterations (``loop_stats``);
+        # the scheduler thread alone writes it, readers take torn reads
+        self._loop = {
+            "waits": 0, "wait_s": 0.0, "idle_passes": 0, "iterations": 0,
+            "stalls": 0, "longest_gap_s": 0.0, "longest_iter_s": 0.0,
+            "longest_iter_cpu_s": 0.0,
+        }
+        # the last working iteration's close, while it left work held:
+        # (monotonic s, the thread's CPU ns, waits, idle_passes); else None
+        self._held_since: tuple | None = None
         from distkeras_tpu.obs import MetricsRegistry, OverlapLedger
 
         self.registry = registry if registry is not None else MetricsRegistry()
@@ -904,12 +923,63 @@ class ContinuousBatcher:
         dispatched. Emitted token order per request is identical —
         only where the wall-clock goes differs."""
         run = self._step_overlapped if self.overlap else self._step_sequential
+        loop = self._loop
         if self.idle:
+            loop["idle_passes"] += 1
             return run()  # a pass over an idle bank: no span for it
+        t0, cpu0 = time.monotonic(), time.thread_time_ns()
+        if self._held_since is not None:
+            self._note_gap(t0, cpu0)
         with self._span("serving/iter") as it:
             progressed = run()
             it.set_metadata(**self._iter_counts)
+        t1, cpu1 = time.monotonic(), time.thread_time_ns()
+        loop["iterations"] += 1
+        if t1 - t0 > loop["longest_iter_s"]:
+            loop["longest_iter_s"] = t1 - t0
+            loop["longest_iter_cpu_s"] = (cpu1 - cpu0) / 1e9
+        if not progressed:
+            loop["idle_passes"] += 1
+        # unlocked reads: only this thread fills or frees a slot or the
+        # air, and a request queued a moment later shows at the next close
+        held = (
+            self._inflight is not None or len(self._queue) > 0
+            or any(s is not None for s in self._slots)
+        )
+        self._held_since = (
+            (t1, cpu1, loop["waits"], loop["idle_passes"])
+            if held else None
+        )
         return progressed
+
+    def _note_gap(self, now: float, cpu_now: int) -> None:
+        """The gap between the last working iteration, which left work
+        held, and the one that opens ``now`` (``cpu_now``: this thread's
+        CPU clock then); over ``STALL_GAP_S`` it is
+        a stall, with what tells its three causes apart: ``waits`` > 0,
+        the loop believed it had nothing to do; ``waits`` 0 and
+        ``cpu_s`` near 0, this thread was not run; ``cpu_s`` near
+        ``gap_s``, it was busy in something no span names."""
+        t_close, cpu_close, waits, idle_passes = self._held_since
+        loop = self._loop
+        gap = now - t_close
+        if gap > loop["longest_gap_s"]:
+            loop["longest_gap_s"] = gap
+        if gap <= STALL_GAP_S:
+            return
+        loop["stalls"] += 1
+        stall = {
+            "gap_s": round(gap, 4),
+            "cpu_s": round((cpu_now - cpu_close) / 1e9, 4),
+            "waits": loop["waits"] - waits,
+            "idle_passes": loop["idle_passes"] - idle_passes,
+            "queue_depth": len(self._queue),
+            "held": sum(s is not None for s in self._slots),
+            "in_air": self._inflight is not None,
+        }
+        if self.recorder is not None:
+            self.recorder.record("scheduler.stall", **stall)
+        logger.warning("scheduler stall: %s", stall)
 
     def _step_sequential(self) -> bool:
         """The strictly sequential iteration (the pre-overlap loop,
@@ -2258,6 +2328,7 @@ class ContinuousBatcher:
         else:
             out["qos"] = {"enabled": False}
         out["overlap"] = self.overlap_stats()
+        out["loop"] = self.loop_stats()
         st = self.stepper
         if getattr(st, "speculative", False):
             drafted = int(getattr(st, "spec_drafted_tokens", 0))
@@ -2311,10 +2382,37 @@ class ContinuousBatcher:
             "discarded_slot_steps": c["discarded_slot_steps"],
         }
 
+    def loop_stats(self) -> dict:
+        """The ``loop`` block of ``stats()`` and ``health()``: how the
+        scheduler thread spent the time between its working iterations,
+        kept without a profiler. ``waits`` / ``wait_s`` are its parks in
+        ``wait_for_work``, ``idle_passes`` the ``step()`` calls over an
+        idle bank or that made no progress, ``iterations`` the working
+        ones; ``longest_iter_s`` the longest of those and
+        ``longest_iter_cpu_s`` this thread's CPU time inside it (near 0:
+        it stood still in there; near ``longest_iter_s``: it was busy),
+        ``longest_gap_s`` the longest gap between two of them while work
+        was held at the first one's close, ``stalls`` the gaps over
+        ``STALL_GAP_S`` (each a ``scheduler.stall`` recorder line and a
+        WARNING: ``_note_gap``)."""
+        out = dict(self._loop)
+        for key in ("wait_s", "longest_gap_s", "longest_iter_s",
+                    "longest_iter_cpu_s"):
+            out[key] = round(out[key], 4)
+        return out
+
     def wait_for_work(self, timeout=0.05):
         """Engine-loop helper: park until a submit/drain signal."""
-        self._work.wait(timeout)
-        self._work.clear()
+        t0 = time.monotonic()
+        with self._span("serving/wait") as sp:
+            woken = self._work.wait(timeout)
+            self._work.clear()
+            sp.set_metadata(
+                woken=int(woken), queue_depth=len(self._queue),
+                held=sum(s is not None for s in self._slots),
+            )
+        self._loop["waits"] += 1
+        self._loop["wait_s"] += time.monotonic() - t0
 
 
 class _Ticket:

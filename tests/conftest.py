@@ -85,7 +85,7 @@ def _synthetic_run_has_scoped_ops(request, monkeypatch):
         import os
 
         from benchmark.layer_metrics import (
-            _gqa_ops, _scoped_ops, _shortcut_ops)
+            _gqa_ops, _scoped_ops, _shortcut_ops, _thread_spans)
 
         # the shortcut layer's two metrics (``_shortcut_ops.py``) read the
         # dense path's scope and the identity picks' counters: a small cut
@@ -95,9 +95,13 @@ def _synthetic_run_has_scoped_ops(request, monkeypatch):
         # the grouped-query block's three metrics (``_gqa_ops.py``) read the
         # attention scopes, the paged kernel's calls and the window pool's
         # counters: a small cut of a traced run of their cell too
+        # the four metrics of the scheduler thread's waits and work (PR 37,
+        # ``_thread_spans.py``) read the CPU clocks on every thread's spans,
+        # which PR 25's cut lacks: a cut of a traced run of ``serve_backlog``
         for module, name in ((_scoped_ops, "scoped_ops_small.json"),
                              (_shortcut_ops, "shortcut_ops_small.json"),
-                             (_gqa_ops, "gqa_ops_small.json")):
+                             (_gqa_ops, "gqa_ops_small.json"),
+                             (_thread_spans, "thread_spans_small.json")):
             with open(os.path.join(fixtures, name)) as f:
                 plain = json.load(f)["plain"]
             monkeypatch.setattr(module, "run_profile",

@@ -23,7 +23,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark.layer_metrics import _program_spans  # noqa: E402
+from benchmark.layer_metrics import _program_spans, _thread_spans  # noqa: E402
 from distkeras_tpu.serving import scheduler  # noqa: E402
 from distkeras_tpu.serving.scheduler import ContinuousBatcher, ServeRequest  # noqa: E402
 from test_serving import FakeStepper  # noqa: E402
@@ -285,3 +285,278 @@ def test_tokens_are_the_same_with_the_spans_on_and_off(overlap, monkeypatch):
     assert sum(a["emitted"] for n, a in rec.spans if n == "serving/emit") == 3 + 4 + 5
     assert sum(a["admitted"] for n, a in rec.spans if n == "serving/admit") == 3
     assert "pages_in_use" not in iters[0]  # a dense bank has no pool
+
+
+# --------------------- PR 37: CPU clocks, the loop's wait, the stream threads
+
+
+def test_traced_spans_carry_cpu_clocks_and_the_streams_and_waits_show(tmp_path):
+    """Under a trace every ``serving/*`` span of the scheduler's thread says
+    how long the thread ran (``cpu_ns`` <= its duration) and what the whole
+    process burned meanwhile (``proc_cpu_ns`` >= ``cpu_ns``); each streamed
+    chunk's send is a plain span on its connection's thread, and the loop's
+    park once the load stops is one on the scheduler's."""
+    import threading
+    import time
+
+    from distkeras_tpu.serving import ServingClient, ServingEngine, ServingServer
+
+    # the server stops its engine with itself: one of this test's own
+    engine = ServingEngine(_lm(), num_slots=2, paged=True, page_size=4,
+                           prefill_chunk=4)
+    server = ServingServer(engine, host="127.0.0.1", port=0).start()
+    try:
+        _generate(engine, 2)  # compiles, off the traced drive
+        def drive():
+            got = [None] * 3
+
+            def client(i):
+                cli = ServingClient("127.0.0.1", server.port)
+                try:
+                    stream = cli.generate_stream(
+                        (np.arange(4 + i, dtype=np.int32) * 5) % 61, 6)
+                    got[i] = [t for chunk in stream for t in chunk]
+                finally:
+                    cli.close()
+
+            threads = [threading.Thread(target=client, args=(i,))
+                       for i in range(3)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120)
+            assert not any(t.is_alive() for t in threads)
+            time.sleep(0.12)  # the bank is idle: the loop parks, 50 ms a time
+            return got
+
+        got, plain = _traced(tmp_path, drive)
+    finally:
+        server.shutdown()
+    assert [len(g) for g in got] == [6, 6, 6]
+    for name, _start, dur, _thread, args in plain["spans"]:
+        if name == "serving/stream_send":  # a plain span: no clock of its own
+            assert "cpu_ns" not in args
+            continue
+        assert 0 <= args["cpu_ns"] <= dur, (name, args, dur)
+        assert args["proc_cpu_ns"] >= args["cpu_ns"], (name, args)
+    threads = _thread_spans.threads(plain)  # rows of [start, dur, name, args]
+    sched = [t for t, rows in threads.items()
+             if any(n == "serving/iter" for _s, _d, n, _a in rows)]
+    assert len(sched) == 1
+    sends = {t: [r for r in rows if r[2] == "serving/stream_send"]
+             for t, rows in threads.items()}
+    assert not sends[sched[0]]
+    by_req = {}
+    for t, rows in sends.items():
+        for _s, _d, _n, a in rows:
+            by_req.setdefault(a["req"], set()).add(t)
+    # one stream's sends share an identifier and a thread
+    assert len(by_req) == 3 and all(len(ts) == 1 for ts in by_req.values())
+    assert sum(a["tokens"] for rows in sends.values()
+               for _s, _d, _n, a in rows) == sum(len(g) for g in got)
+    waits = [r for r in threads[sched[0]] if r[2] == "serving/wait"]
+    assert waits and all(
+        a["woken"] in (0, 1) and a["held"] == 0 and a["queue_depth"] == 0
+        for _s, _d, _n, a in waits[-1:])
+    # a park that ran its 50 ms out stood still for nearly all of them
+    timed_out = [(d, a) for _s, d, _n, a in waits if a["woken"] == 0]
+    assert timed_out and all(d > 40e6 and a["cpu_ns"] < d / 4
+                             for d, a in timed_out)
+    last_iter_end = max(s + d for s, d, n, _a in threads[sched[0]]
+                        if n == "serving/iter")
+    assert any(s >= last_iter_end for s, _d, _n, _a in waits)
+
+
+@pytest.mark.parametrize("overlap", [False, True], ids=["sequential", "overlapped"])
+def test_with_no_trace_running_the_factory_reads_no_clock(overlap, monkeypatch):
+    from distkeras_tpu.utils import profiling
+
+    def refuse(*a, **kw):
+        raise AssertionError("an untraced span read a CPU clock")
+
+    with_recorded = _serve(_Recorded(), overlap)
+    monkeypatch.setattr(profiling, "thread_time_ns", refuse)
+    monkeypatch.setattr(profiling, "process_time_ns", refuse)
+    assert _serve(profiling.span, overlap) == with_recorded
+    with profiling.span("serving/wait") as sp:
+        sp.set_metadata(woken=1)
+
+
+class _SlowOnce(FakeStepper):
+    """A device call that takes ``seconds`` once, at its ``at``-th step."""
+
+    def __init__(self, at, seconds, **kw):
+        super().__init__(**kw)
+        self._at, self._seconds, self._calls = at, seconds, 0
+
+    def step(self, active):
+        import time
+
+        self._calls += 1
+        if self._calls == self._at:
+            time.sleep(self._seconds)
+        return super().step(active)
+
+
+@pytest.mark.parametrize("overlap", [False, True], ids=["sequential", "overlapped"])
+def test_a_long_iteration_is_no_stall(overlap):
+    b = ContinuousBatcher(_SlowOnce(2, 0.6), overlap=overlap)
+    req = b.submit(ServeRequest(np.arange(3), 5))
+    while not b.idle:
+        b.step()
+    assert len(req.result(1)) == 3 + 5
+    loop = b.loop_stats()
+    assert loop["longest_iter_s"] > 0.6
+    assert loop["longest_iter_cpu_s"] < 0.1  # it slept in there: no CPU time
+    assert loop["stalls"] == 0 and loop["longest_gap_s"] < 0.1
+    assert b.stats()["loop"]["iterations"] == loop["iterations"] > 0
+
+
+@pytest.mark.parametrize("overlap", [False, True], ids=["sequential", "overlapped"])
+def test_a_bank_that_only_prefills_is_no_stall(overlap):
+    """Twelve iterations of 50 ms that each spend a chunk of one prompt's
+    prefill and emit nothing: a gap is taken between iterations, not between
+    tokens."""
+    import time
+
+    class SlowChunks(FakeStepper):
+        def prefill_chunk(self, slot, budget):
+            time.sleep(0.05)
+            return super().prefill_chunk(slot, budget)
+
+    st = SlowChunks(num_slots=1, max_len=64)
+    b = ContinuousBatcher(st, overlap=overlap, prefill_chunk=2)
+    req = b.submit(ServeRequest(np.arange(25), 2))
+    t0 = time.monotonic()
+    while not b.idle:
+        b.step()
+    assert time.monotonic() - t0 > 0.6 and len(st.chunks) == 12
+    assert len(req.result(1)) == 25 + 2
+    loop = b.loop_stats()
+    assert loop["stalls"] == 0 and loop["longest_gap_s"] < 0.1
+    assert loop["iterations"] >= 12
+
+
+@pytest.mark.parametrize("overlap", [False, True], ids=["sequential", "overlapped"])
+def test_a_bank_left_idle_between_two_requests_is_no_stall(overlap):
+    import time
+
+    b = ContinuousBatcher(FakeStepper(), overlap=overlap)
+    b.submit(ServeRequest(np.arange(3), 2))
+    while not b.idle:
+        b.step()
+    before = b.loop_stats()
+    t0 = time.monotonic()
+    while time.monotonic() - t0 < 0.6:  # the engine's loop over an idle bank
+        assert b.step() is False
+        b.wait_for_work()
+    b.submit(ServeRequest(np.arange(3), 2))
+    while not b.idle:
+        b.step()
+    loop = b.loop_stats()
+    assert loop["waits"] - before["waits"] >= 10
+    assert loop["wait_s"] - before["wait_s"] > 0.5
+    assert loop["idle_passes"] - before["idle_passes"] >= 10
+    assert loop["stalls"] == 0 and loop["longest_gap_s"] < 0.1
+
+
+@pytest.mark.parametrize("overlap", [False, True], ids=["sequential", "overlapped"])
+def test_a_held_bank_that_is_not_stepped_for_0_6_s_is_one_stall(overlap, caplog):
+    """The batcher alone: a gap between two working iterations with a tenant
+    in a slot. The thread slept, so the stall says it neither waited for work
+    nor ran."""
+    import logging
+    import time
+
+    from distkeras_tpu.obs import FlightRecorder
+
+    rec = FlightRecorder(capacity=64)
+    b = ContinuousBatcher(FakeStepper(), overlap=overlap, recorder=rec)
+    req = b.submit(ServeRequest(np.arange(3), 6))
+    b.step()
+    b.step()
+    with caplog.at_level(logging.WARNING, logger=scheduler.logger.name):
+        time.sleep(0.6)
+        while not b.idle:
+            b.step()
+    assert len(req.result(1)) == 3 + 6
+    loop = b.loop_stats()
+    assert loop["stalls"] == 1 and 0.6 <= loop["longest_gap_s"] < 1.0
+    (line,) = rec.events("scheduler.stall")
+    assert line["gap_s"] == pytest.approx(loop["longest_gap_s"], abs=1e-3)
+    assert line["waits"] == 0 and line["idle_passes"] == 0
+    assert line["cpu_s"] < 0.1 and line["held"] == 1 and line["queue_depth"] == 0
+    assert line["in_air"] is overlap
+    warned = [r for r in caplog.records if "scheduler stall" in r.getMessage()]
+    assert len(warned) == 1 and warned[0].levelno == logging.WARNING
+    assert "'gap_s': " in warned[0].getMessage()
+
+
+@pytest.mark.parametrize("overlap", [False, True], ids=["sequential", "overlapped"])
+def test_the_engine_s_loop_records_a_stall_and_its_counts_add_up(overlap):
+    """The ``scheduler.loop`` fault seam sleeps 0.6 s once between two
+    iterations with a slot held: one stall, with no wait inside it and no CPU
+    time; ``stats()["loop"]`` and ``health()["loop"]`` carry the block."""
+    from distkeras_tpu.faults import FaultPlan
+    from distkeras_tpu.serving import ServingEngine
+
+    engine = ServingEngine(_lm(), num_slots=2, paged=True, page_size=4,
+                           prefill_chunk=4, overlap=overlap)
+    engine.start()
+    try:
+        _generate(engine, 1)  # compiles
+        before = engine.stats()["loop"]
+        assert before["stalls"] == 0
+        plan = FaultPlan().arm(
+            "scheduler.loop", action="delay", delay=0.6, times=1, after=3,
+            when=lambda ctx: ctx["busy"])
+        with plan:
+            out = _generate(engine, 2, steps=10)
+        assert plan.fired("scheduler.loop") == 1
+        assert [len(o) for o in out] == [3 + 10, 4 + 10]
+        loop = engine.stats()["loop"]
+        assert loop["stalls"] == 1 and 0.6 <= loop["longest_gap_s"] < 1.5
+        (line,) = engine.recorder.events("scheduler.stall")
+        assert line["waits"] == 0 and line["cpu_s"] < 0.1
+        assert line["held"] >= 1 and line["gap_s"] >= 0.6
+        # the counts add up: every step() call is a working iteration or
+        # an idle pass (or both, where a working one made no progress), and
+        # the loop parks only after a call that made no progress
+        assert loop["iterations"] > before["iterations"]
+        assert engine.health()["loop"]["stalls"] == 1
+    finally:
+        engine.stop()
+    # the counts add up once the thread has ended: every step() call was a
+    # working iteration or an idle pass (both, where a working one made no
+    # progress), and the loop parks only after a call that made no progress
+    batcher = engine.batcher
+    loop = batcher.loop_stats()
+    calls = batcher._sched_iters
+    assert loop["iterations"] + loop["idle_passes"] >= calls > loop["iterations"]
+    assert loop["waits"] <= loop["idle_passes"]
+    assert loop["wait_s"] <= 0.05 * loop["waits"] + 0.5
+
+
+def test_a_stall_s_warning_reaches_stderr_with_no_handler_installed():
+    """Neither the package nor the benchmark installs a log handler: Python's
+    last-resort handler prints a WARNING, so an untraced benchmark run shows a
+    stall on its standard error."""
+    import subprocess
+
+    code = (
+        "import time, numpy as np, sys\n"
+        f"sys.path.insert(0, {os.path.join(REPO, 'tests')!r})\n"
+        "from test_serving import FakeStepper\n"
+        "from distkeras_tpu.serving.scheduler import ContinuousBatcher, ServeRequest\n"
+        "b = ContinuousBatcher(FakeStepper())\n"
+        "b.submit(ServeRequest(np.arange(3), 4))\n"
+        "b.step(); time.sleep(0.55)\n"
+        "while not b.idle: b.step()\n"
+        "print(b.loop_stats()['stalls'])\n"
+    )
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO}
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120, env=env)
+    assert done.returncode == 0, done.stderr
+    assert done.stdout.strip() == "1"
+    assert "scheduler stall: {'gap_s': 0.5" in done.stderr
