@@ -3922,6 +3922,10 @@ class ServingEngine:
         self._failed = False  # permanently degraded (see _failed_reason)
         self._failed_reason = None
         self._last_crash = None
+        # the sender of the server in front of this engine (a
+        # ``ServingServer`` puts its own here): its counters are
+        # ``stats()["streams"]``
+        self.stream_sender = None
         # engine-level gauges (scrape-time callbacks over state the
         # engine already keeps) and per-phase request-latency
         # histograms (log-bucketed: 0.1 ms .. ~52 s in 20 buckets),
@@ -4392,8 +4396,10 @@ class ServingEngine:
             batcher.stop()
         if batcher is not None:
             logger.info(
-                "serving engine stopped: overlap %s; loop %s",
+                "serving engine stopped: overlap %s; loop %s; streams %s",
                 batcher.overlap_stats(), batcher.loop_stats(),
+                None if self.stream_sender is None
+                else self.stream_sender.stats(),
             )
         self._predict_batcher.close()
         self.peer_fabric.close()  # pooled peer sockets do not leak
@@ -4423,10 +4429,13 @@ class ServingEngine:
         only label metrics; with one they pick the WFQ share and the
         priority class (higher = more urgent, may preempt).
 
-        ``stream``: the scheduler pushes each iteration's emitted
-        tokens into the request's chunk FIFO (``req.next_chunk``) as
-        they are generated — the server's streaming ``generate``
-        drains it to the wire per chunk.
+        ``stream``: True = the scheduler pushes each iteration's
+        emitted tokens into the request's chunk FIFO
+        (``req.next_chunk``) as they are generated, for an in-process
+        consumer. The server's streaming ``generate`` passes a sink in
+        its place (``push(req, tokens | None)`` and ``wake``): every
+        stream of the server hands over to one sender thread, woken
+        once an iteration (``ServeRequest``, ``server.StreamSender``).
 
         ``kv_peers``: the fleet router's page-affinity hint — a list
         of ``{"endpoint": [host, port], "epoch": E, "len": n}`` dicts
@@ -4653,8 +4662,8 @@ class ServingEngine:
         """The decode worker's half: admit a TRANSFERRED slot — a
         ``kv_transfer`` wire frame (bytes) or an already-decoded state
         dict — and decode it to completion. Returns the ``ServeRequest``
-        handle (``wait`` for the sequence; ``stream=True`` for the
-        chunk FIFO the server drains). The resumed stream is pinned
+        handle (``wait`` for the sequence; ``stream`` as
+        ``submit``'s). The resumed stream is pinned
         token-identical to an uninterrupted decode of the same
         (prompt, params) on one engine — the PR 12 swap identity,
         now crossing a process boundary.
@@ -5157,6 +5166,10 @@ class ServingEngine:
             # how the scheduler thread spent the time between its
             # iterations, and the stalls it met (``loop_stats``)
             out["loop"] = batcher.loop_stats()
+        if self.stream_sender is not None:
+            # the server's one sender thread: how often the scheduler
+            # woke it and how many frames a wake wrote
+            out["streams"] = self.stream_sender.stats()
         if self.shed_gate is not None:
             # overload-gate state for routers and dkt_top: the current
             # brownout rung, whether the CoDel side is shedding, and
@@ -5204,6 +5217,8 @@ class ServingEngine:
             if self._stepper._moe_layers:
                 # the expert layers' routing, summed over decode steps
                 out["moe"] = dict(self._stepper.moe_stats)
+        if self.stream_sender is not None:
+            out["streams"] = self.stream_sender.stats()
         out["restarts"] = self._restarts
         out["watchdog_trips"] = self._watchdog_trips
         out["status"] = self.health()["status"]

@@ -75,6 +75,7 @@ docs/ARCHITECTURE.md "Failure modes & recovery".
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
 import queue as _queue
 import threading
@@ -346,13 +347,21 @@ class ServeRequest:
         self._swap = None
         self.sampling = sampling  # SamplingParams | None (= greedy)
         self.n = 1 if sampling is None else int(sampling.n)
-        # streaming delivery: the scheduler pushes each iteration's
-        # emitted tokens into a bounded-by-construction FIFO (at most
-        # max_new_tokens entries + one sentinel) that the server's
-        # connection thread drains — token delivery never runs under
-        # the scheduler lock or blocks on a slow client socket
+        # streaming delivery: the scheduler hands each iteration's
+        # emitted tokens over, bounded by construction (at most
+        # max_new_tokens entries + one sentinel) — token delivery never
+        # runs under the scheduler lock or blocks on a slow client
+        # socket. ``stream=True``: a FIFO of the request's own, drained
+        # by ``next_chunk`` (in-process consumers). A SINK in its place
+        # (the server's: ``push(req, tokens | None)`` and ``wake``)
+        # takes the hand-overs of all its requests in one queue, and
+        # the batcher wakes it once an iteration (``_emitting``)
         self.stream = bool(stream)
-        self._chunks = _queue.SimpleQueue() if self.stream else None
+        self._sink = stream if hasattr(stream, "push") else None
+        self._chunks = (
+            _queue.SimpleQueue()
+            if self.stream and self._sink is None else None
+        )
         # first CHUNK FLUSHED to the wire (streaming path) — stamped by
         # the server thread after the send completes; the honest TTFT
         # (``latency()`` prefers it over the scheduler-side append)
@@ -387,17 +396,21 @@ class ServeRequest:
         self.finished = time.monotonic()
         self._swap = None  # host KV rows released with the request
         self._done.set()
-        if self._chunks is not None:
-            # terminal sentinel AFTER the result is readable: the
-            # draining thread sees every chunk, then None, then reads
-            # ``error``/``result()`` without racing the finish
+        # terminal sentinel AFTER the result is readable: the draining
+        # thread sees every chunk, then None, then reads
+        # ``error``/``result()`` without racing the finish
+        if self._sink is not None:
+            self._sink.push(self, None)
+        elif self._chunks is not None:
             self._chunks.put(None)
 
     def _push_chunk(self, toks) -> None:
         """One scheduler iteration's emitted tokens for the draining
         (server) thread. Called by the batcher BEFORE any eviction this
         iteration triggers, so the sentinel can never overtake data."""
-        if self._chunks is not None:
+        if self._sink is not None:
+            self._sink.push(self, list(toks))
+        elif self._chunks is not None:
             self._chunks.put(list(toks))
 
     def next_chunk(self, timeout=None):
@@ -621,6 +634,9 @@ class ContinuousBatcher:
         # the last collect raised: the next call runs one step deep
         self._step_failed = False
         self._lock = threading.Lock()
+        # the stream senders to wake where the emission under way
+        # closes (``_emitting``); None outside one. Under the lock.
+        self._stream_wakes: set | None = None
         self._work = threading.Event()  # signals the engine loop
         self._draining = False
         self._stopped = False
@@ -1515,7 +1531,7 @@ class ContinuousBatcher:
             "pages_in_use": c.get("pages_in_use"),
             "page_waits": c.get("page_waits"),
         }
-        with self._lock:
+        with self._emitting():
             if reqs is not None:
                 # THE GUARD of the two-deep loop: a slot of this step's
                 # mask may have been evicted at the collect of the step
@@ -1618,6 +1634,8 @@ class ContinuousBatcher:
                     # sentinel must never overtake the final tokens
                     self.counters["streamed_chunks"] += 1
                     req._push_chunk(new_toks)
+                    if req._sink is not None:
+                        self._stream_wakes.add(req._sink.wake)
                 if pending_evict is not _NO_EVICT:
                     self._evict(i, req, pending_evict)
                 emitted_total += emitted
@@ -2142,8 +2160,8 @@ class ContinuousBatcher:
                     # preemption pairing: a swapped request dying typed
                     # in the queue is its swap-out's terminal partner
                     self.counters["swapped_failed"] += 1
-                req._finish(
-                    DeadlineExceededError("deadline expired in queue")
+                self._finish_request(
+                    req, DeadlineExceededError("deadline expired in queue")
                 )
                 continue
             return req
@@ -2175,12 +2193,40 @@ class ContinuousBatcher:
                 self.counters["pool_exhausted"] += 1
             else:
                 self.counters["deadline_exceeded"] += 1
-            req._finish(error)
+            self._finish_request(req, error)
             return
         if any(r is req for r in self._slots):
             return  # sibling completions still decoding / forking
         self.counters["completed"] += 1
-        req._finish(None)
+        self._finish_request(req, None)
+
+    def _finish_request(self, req, error):
+        """Complete ``req`` (caller holds the lock) and wake the sender
+        of its stream for the sentinel: with the iteration's chunks
+        where ``_emit`` closes, at once anywhere else (a deadline, a
+        stop, a restart, the watchdog)."""
+        req._finish(error)
+        if req._sink is not None:
+            if self._stream_wakes is None:
+                req._sink.wake()
+            else:
+                self._stream_wakes.add(req._sink.wake)
+
+    @contextlib.contextmanager
+    def _emitting(self):
+        """The lock across one emission, and behind it ONE wake of each
+        stream sender the emission handed anything to: a server's
+        streams share a sender, so an iteration wakes one thread where
+        a FIFO a request woke one a streaming slot."""
+        wakes = set()
+        with self._lock:
+            self._stream_wakes = wakes
+            try:
+                yield
+            finally:
+                self._stream_wakes = None
+        for wake in wakes:
+            wake()
 
     # -- drain / shutdown ---------------------------------------------------
 
@@ -2222,7 +2268,7 @@ class ContinuousBatcher:
                     # with it (pairing: preemptions == resumes +
                     # swap_in_failures + swapped_failed)
                     self.counters["swapped_failed"] += 1
-                req._finish(fail())
+                self._finish_request(req, fail())
             self._prefill_left.clear()
             self._prefill_fifo.clear()
             self._awaiting_fork.clear()
@@ -2233,7 +2279,7 @@ class ContinuousBatcher:
                     self.stepper.release(i)
                     if id(req) not in failed:
                         failed.add(id(req))
-                        req._finish(fail())
+                        self._finish_request(req, fail())
         self._work.set()
 
     # -- introspection ------------------------------------------------------

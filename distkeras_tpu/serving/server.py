@@ -29,7 +29,11 @@ Verbs (header ``{"verb": ...}``):
   sequence payload (or a typed error frame). TTFT becomes a real
   first-byte measurement: ``ServeRequest.first_sent`` is stamped when
   the first chunk frame flushes. After the terminal frame the
-  connection returns to request/reply discipline.
+  connection returns to request/reply discipline. The chunk frames
+  of EVERY stream are written by the server's one sender thread
+  (``StreamSender``), which the scheduler wakes once an iteration;
+  the connection's thread sleeps from ``submit`` to the sentinel and
+  writes the terminal frame itself.
 - ``prefill`` (disaggregated serving): same request shape as
   ``generate``; the engine runs admission + chunked prefill only and
   replies with the finished slot's state as a ``kv_transfer`` wire
@@ -77,6 +81,9 @@ generate) so client-side failures join server-side spans.
 
 from __future__ import annotations
 
+import collections
+import logging
+import selectors
 import socket
 import threading
 import time
@@ -84,10 +91,16 @@ import time
 import numpy as np
 
 from distkeras_tpu import faults
-from distkeras_tpu.networking import recv_data, send_data
+from distkeras_tpu.networking import (
+    recv_data,
+    send_data,
+    send_nowait,
+    wire_bytes,
+)
 from distkeras_tpu.obs import stamp_error_trace as _stamp_trace
-from distkeras_tpu.serving.scheduler import ServingError
+from distkeras_tpu.serving.scheduler import EngineStoppedError, ServingError
 from distkeras_tpu.utils.profiling import annotate
+from distkeras_tpu.utils.profiling import span as timeline_span
 from distkeras_tpu.utils.serialization import (
     deserialize_params,
     pack_frame,
@@ -96,6 +109,327 @@ from distkeras_tpu.utils.serialization import (
 )
 
 _PROTOCOL = 1
+
+logger = logging.getLogger(__name__)
+
+_KILL = object()  # handed over in a chunk's place: the stream is given up
+
+
+class _Stream:
+    """One streamed request's connection as the sender holds it, and the
+    sink its request is submitted with in a chunk FIFO's place: the
+    scheduler calls ``push`` under its lock for every chunk and for the
+    sentinel, and ``wake`` once where its emission closes."""
+
+    __slots__ = ("sender", "wake", "sock", "buf", "marks", "sent",
+                 "ending", "blocked", "dead", "done")
+
+    def __init__(self, sender, sock):
+        self.sender = sender
+        self.wake = sender.wake  # equal for every stream of a sender
+        self.sock = sock
+        self.buf = bytearray()  # the outbox: bytes the socket has not taken
+        # the outbox's frames, none wholly out yet: (its end among the
+        # stream's bytes, request, tokens, hand-over instant)
+        self.marks = collections.deque()
+        self.sent = 0  # bytes the socket took, over the stream's life
+        self.ending = False  # the sentinel's turn has come
+        self.blocked = False  # waiting for the socket to take more
+        self.dead = False
+        # set with the sentinel's turn come and the outbox empty, or dead
+        self.done = threading.Event()
+
+    def push(self, req, tokens):
+        # the hand-over's instant only where a trace will show it
+        t0 = time.monotonic() if tokens and req.trace is not None else None
+        self.sender._handed.append((self, req, tokens, t0))
+
+
+class StreamSender:
+    """The one thread that writes every stream's chunk frames.
+
+    The scheduler appends ``(stream, request, tokens | None)`` to one
+    queue in the order things happen (``_Stream.push``, under the lock
+    its emission holds, so a sentinel never overtakes data) and wakes
+    this thread ONCE an iteration. A pass takes everything handed
+    over, packs each chunk's frame as the connection threads did
+    (``pack_frame`` behind ``send_data``'s length prefix: the wire's
+    bytes are unchanged) and writes each connection's frames in one
+    ``send`` that never blocks (``MSG_DONTWAIT``; the socket's mode is
+    left alone for its connection thread's ``recv``) and keeps the
+    interpreter lock (``networking.send_nowait``): a pass is one turn
+    at the lock, not one a frame, among the client threads its frames
+    wake.
+
+    Back-pressure: what a full socket buffer refuses stays in that
+    stream's outbox (at most ``max_new_tokens`` frames, as its FIFO
+    held), and the thread waits for that socket to turn writable
+    beside its own wake. A slow or stalled reader delays only its own
+    stream. A stream dies on a write error (the client went away; its
+    decode completes idle) or an injected ``server.reply`` drop: its
+    connection's thread, woken through ``done``, closes the socket.
+
+    No system call on the scheduler's side while nothing is blocked:
+    the wake is an event then, and a byte on a socket pair only while
+    this thread sits in ``select``."""
+
+    def __init__(self):
+        self._handed = collections.deque()
+        self._wake = threading.Event()
+        self._lock = threading.Lock()  # start / stop, the live set, wakes
+        self._thread = None
+        self._stopping = False
+        self._selecting = False
+        self._streams: set = set()  # opened, not yet done
+        self._blocked = 0  # of them, waiting for writability
+        self._selector = self._wake_r = self._wake_w = None
+        self._wakes = 0
+        self._counts = dict.fromkeys(
+            ("frames_sent", "coalesced_frames", "would_block",
+             "outbox_peak", "dead_streams"), 0)
+
+    # -- the scheduler's and the connection threads' side -------------------
+
+    def open(self, sock) -> _Stream:
+        """The sink to submit a streamed request with; starts the
+        thread with the first."""
+        with self._lock:
+            if self._stopping:
+                raise EngineStoppedError("server stopping")
+            if self._thread is None:
+                self._selector = selectors.DefaultSelector()
+                self._wake_r, self._wake_w = socket.socketpair()
+                self._wake_r.setblocking(False)
+                self._selector.register(self._wake_r, selectors.EVENT_READ)
+                self._thread = threading.Thread(
+                    target=self._run, name="serving-stream-sender",
+                    daemon=True,
+                )
+                self._thread.start()
+            stream = _Stream(self, sock)
+            self._streams.add(stream)
+        return stream
+
+    def discard(self, stream):
+        """Forget a stream whose request was never admitted."""
+        with self._lock:
+            self._streams.discard(stream)
+
+    def kill(self, stream, timeout=5.0):
+        """Give ``stream`` up from outside (its thread's guard ran
+        out): the sender lets go of the socket, then sets ``done``."""
+        self._handed.append((stream, None, _KILL, None))
+        self.wake()
+        stream.done.wait(timeout)
+
+    def wake(self):
+        with self._lock:
+            self._wakes += 1
+        self._wake.set()
+        if self._selecting:
+            try:
+                self._wake_w.send(b"\0")
+            except OSError:
+                pass  # stopped underneath: nothing left to wake
+
+    def stop(self):
+        """End the thread: what is still open dies (``done`` set), so
+        no connection thread is left waiting on a stream."""
+        with self._lock:
+            self._stopping = True
+            thread = self._thread
+        if thread is None:
+            return
+        self.wake()
+        thread.join(timeout=10)
+        if not thread.is_alive():
+            self._selector.close()
+            self._wake_r.close()
+            self._wake_w.close()
+
+    def stats(self) -> dict:
+        """``sender_wakes``: wakes the scheduler sent (one an emitting
+        iteration, one a finish outside an emission); ``frames_sent``:
+        chunk frames wholly written; ``frames_per_wake``: their ratio;
+        ``coalesced_frames``: frames queued behind bytes still waiting,
+        so sharing a write; ``would_block``: writes a full socket
+        buffer cut short; ``outbox_peak``: most frames one stream had
+        waiting; ``dead_streams``: streams ended by a write error, an
+        injected drop or the server's stop."""
+        out = dict(self._counts)
+        out["sender_wakes"] = self._wakes
+        out["frames_per_wake"] = out["frames_sent"] / max(self._wakes, 1)
+        return out
+
+    # -- the thread ---------------------------------------------------------
+
+    def _run(self):
+        try:
+            while not self._stopping:
+                self._flush(self._wait())
+            self._flush(())  # what the engine's stop has just handed over
+        except Exception:  # noqa: BLE001 — the thread's boundary
+            logger.exception("stream sender died; its streams die with it")
+        finally:
+            with self._lock:
+                self._stopping = True
+                left = list(self._streams)
+            for stream in left:
+                self._die(stream)
+
+    def _wait(self) -> list:
+        """Sleep until woken or, with bytes refused, until a socket
+        takes more; returns the streams whose socket does."""
+        if not self._blocked:
+            self._wake.wait()
+            self._wake.clear()
+            return []
+        # ``wake`` reads ``_selecting`` after it has set the event, this
+        # reads the event after it has set ``_selecting``: one of the
+        # two sees the other, so no wake is slept through
+        self._selecting = True
+        events = () if self._wake.is_set() else self._selector.select()
+        self._selecting = False
+        self._wake.clear()
+        ready = []
+        for key, _ in events:
+            if key.data is not None:
+                ready.append(key.data)
+                continue
+            try:
+                self._wake_r.recv(4096)
+            except BlockingIOError:
+                pass
+        return ready
+
+    def _flush(self, ready):
+        """One pass: every hand-over so far, a connection at a time,
+        then the sockets that turned writable."""
+        by_stream = {}
+        while True:
+            try:
+                item = self._handed.popleft()
+            except IndexError:
+                break
+            by_stream.setdefault(item[0], []).append(item)
+        if not by_stream and not ready:
+            return
+        with timeline_span("serving/stream_flush") as sp:
+            sent0 = self._counts["frames_sent"]
+            for stream, items in by_stream.items():
+                self._guarded(stream, self._take, items)
+            for stream in ready:
+                if stream not in by_stream:
+                    self._guarded(stream, self._write)
+            sp.set_metadata(frames=self._counts["frames_sent"] - sent0,
+                            streams=len(by_stream))
+
+    def _guarded(self, stream, step, *args):
+        """``step`` on a live stream; a write error is the stream's
+        death (client went away; decode completes idle), and so is
+        anything else, which must not take the other streams along."""
+        if stream.dead:
+            return
+        try:
+            step(stream, *args)
+            if stream.ending and not stream.buf:
+                self._close(stream)
+        except (ConnectionError, OSError):
+            self._die(stream)
+        except Exception:  # noqa: BLE001 — one stream's boundary
+            logger.exception("stream sender: a stream's pass failed")
+            self._die(stream)
+
+    def _take(self, stream, items):
+        """``items``: this pass's hand-overs of one stream, in order.
+        Each chunk becomes a frame in the outbox, under its own
+        ``serving/stream_send`` span; the last one's span holds the
+        write of them all."""
+        chunks = [it for it in items if it[2] is not None]
+        for k, (_st, req, tokens, t0) in enumerate(chunks):
+            if tokens is _KILL:
+                raise ConnectionError("stream given up by its thread")
+            with annotate(
+                "serving/stream_send", req=req.id, tokens=len(tokens)
+            ):
+                frame = pack_frame(
+                    {"ok": True, "stream": "chunk", "tokens": tokens}
+                )
+                act = faults.fire("server.reply", nbytes=len(frame))
+                if act == "drop":
+                    # injected: vanish mid-stream, behind what was sent
+                    self._write(stream)
+                    raise ConnectionError("injected server.reply drop")
+                if stream.buf:
+                    self._counts["coalesced_frames"] += 1
+                stream.buf += wire_bytes(stream.sock, frame)
+                stream.marks.append(
+                    (stream.sent + len(stream.buf), req, len(tokens), t0)
+                )
+                if len(stream.marks) > self._counts["outbox_peak"]:
+                    self._counts["outbox_peak"] = len(stream.marks)
+                if k == len(chunks) - 1:
+                    self._write(stream)
+        if len(chunks) < len(items):
+            stream.ending = True  # the sentinel: nothing follows it
+
+    def _write(self, stream):
+        """Hand the socket the outbox without blocking; stamp the
+        frames that left whole; wait for writability on what stays."""
+        if stream.buf:
+            try:
+                n = send_nowait(stream.sock, stream.buf)
+            except (BlockingIOError, InterruptedError):
+                n = 0
+            if n:
+                stream.sent += n
+                del stream.buf[:n]
+                now = time.monotonic()
+                while stream.marks and stream.marks[0][0] <= stream.sent:
+                    _end, req, ntok, t0 = stream.marks.popleft()
+                    self._counts["frames_sent"] += 1
+                    if req.first_sent is None:
+                        req.first_sent = now  # DELIVERY-time TTFT stamp
+                    if t0 is not None:
+                        # per-chunk trace span (rides the request
+                        # ledger; the timeline's serving.stream_chunk
+                        # children): hand-over to the frame's last byte
+                        req.events.append({
+                            "name": "serving.stream_chunk", "t0": t0,
+                            "t1": now, "tokens": ntok,
+                        })
+            if stream.buf:
+                self._counts["would_block"] += 1
+        if bool(stream.buf) != stream.blocked:
+            if stream.buf:
+                self._selector.register(
+                    stream.sock, selectors.EVENT_WRITE, stream
+                )
+            else:
+                self._selector.unregister(stream.sock)
+            stream.blocked = not stream.blocked
+            self._blocked += 1 if stream.blocked else -1
+
+    def _close(self, stream):
+        with self._lock:
+            self._streams.discard(stream)
+        stream.done.set()
+
+    def _die(self, stream):
+        if stream.done.is_set():
+            return
+        stream.dead = True
+        stream.buf.clear()
+        stream.marks.clear()
+        if stream.blocked:
+            stream.blocked = False
+            self._blocked -= 1
+            try:
+                self._selector.unregister(stream.sock)
+            except (KeyError, ValueError, OSError):
+                pass  # the seam's reset closed the socket itself
+        self._counts["dead_streams"] += 1
+        self._close(stream)
 
 
 class ServingServer:
@@ -126,6 +460,10 @@ class ServingServer:
         self._lock = threading.Lock()
         self._stopping = threading.Event()
         self._shutdown_done = threading.Event()
+        # every streamed request's chunk frames leave through this one
+        # thread (started with the first stream); the engine reports its
+        # counters as ``stats()["streams"]``
+        self._sender = engine.stream_sender = StreamSender()
         reg = getattr(engine, "registry", None)
         if reg is not None:  # server-level gauge rides the engine book
             reg.gauge(
@@ -183,6 +521,9 @@ class ServingServer:
             deadline = time.monotonic() + 5
             for th in threads:
                 th.join(timeout=max(0.0, deadline - time.monotonic()))
+            # a stream still open now (a reader that stopped reading)
+            # dies here, which wakes its connection's thread
+            self._sender.stop()
             with self._lock:
                 lingering = list(self._conns)
             for conn in lingering:
@@ -614,15 +955,16 @@ class ServingServer:
 
     def _serve_stream(self, conn: socket.socket, header: dict,
                       payload: bytes) -> bool:
-        """Streaming ``generate`` / ``kv.transfer``: submit with a
-        chunk FIFO, then drain it to the connection — one
-        ``stream: "chunk"`` frame per scheduler iteration that
-        advanced the slot, then the terminal ``stream: "end"`` frame
-        with the full sequence payload (identity stays assertable
-        downstream) or a typed error frame. Returns False when the
-        connection is no longer usable (died mid-stream / injected
-        drop). The first chunk's flush stamps ``req.first_sent`` —
-        the delivery-time TTFT ``latency()`` reports."""
+        """Streaming ``generate`` / ``kv.transfer``: submit with the
+        sender's sink, which writes one ``stream: "chunk"`` frame per
+        scheduler iteration that advanced the slot while this thread
+        sleeps; then the terminal ``stream: "end"`` frame with the
+        full sequence payload (identity stays assertable downstream)
+        or a typed error frame, from this thread. Returns False when
+        the connection is no longer usable (died mid-stream /
+        injected drop). The first chunk's flush stamps
+        ``req.first_sent`` — the delivery-time TTFT ``latency()``
+        reports."""
         from distkeras_tpu.obs import TraceContext, request_spans, start_span
 
         verb = header.get("verb")
@@ -664,7 +1006,9 @@ class ServingServer:
             except (ConnectionError, OSError):
                 return False
 
+        stream = None
         try:
+            stream = self._sender.open(conn)
             if verb == "generate":
                 from distkeras_tpu.serving.sampling import SamplingParams
 
@@ -679,7 +1023,7 @@ class ServingServer:
                     ),
                     tenant=header.get("tenant"),
                     priority=int(header.get("priority") or 0),
-                    stream=True,
+                    stream=stream,
                     kv_peers=header.get("kv_peers"),
                 )
             else:
@@ -690,52 +1034,28 @@ class ServingServer:
                     trace=ctx,
                     tenant=header.get("tenant"),
                     priority=int(header.get("priority") or 0),
-                    stream=True,
+                    stream=stream,
                 )
-        except ServingError as e:
-            return send_error(e)
         except Exception as e:  # noqa: BLE001 — wire boundary
+            if stream is not None:
+                self._sender.discard(stream)  # nothing was admitted
+            if isinstance(e, ServingError):
+                return send_error(e)
             return send_error(e, code="bad_request")
-        while True:
-            t0 = time.monotonic()
-            try:
-                # generous bound: the engine watchdog fails a wedged
-                # scheduler's requests typed long before this fires —
-                # the timeout is the belt to that suspender
-                chunk = req.next_chunk(timeout=600.0)
-            except TimeoutError as e:
-                send_error(e, code="internal")
-                return False
-            if chunk is None:
-                break
-            # this thread's part of a token's path, on the scheduler's
-            # timeline: from the chunk in hand to the frame sent. A plain
-            # span: CPU clocks on 32-128 sends an iteration cost a traced
-            # run a fifth of its pace (PR 37)
-            with annotate(
-                "serving/stream_send", req=req.id, tokens=len(chunk)
-            ):
-                frame = pack_frame(
-                    {"ok": True, "stream": "chunk",
-                     "tokens": [int(t) for t in chunk]}
-                )
-                act = faults.fire("server.reply", nbytes=len(frame))
-                if act == "drop":
-                    return False  # injected: vanish mid-stream
-                try:
-                    send_data(conn, frame)
-                except (ConnectionError, OSError):
-                    return False  # client went away; decode completes idle
-            now = time.monotonic()
-            if req.first_sent is None:
-                req.first_sent = now  # DELIVERY-time TTFT stamp
-            if ctx is not None:
-                # per-chunk trace span (rides the request ledger; the
-                # timeline's serving.stream_chunk children)
-                req.events.append({
-                    "name": "serving.stream_chunk", "t0": t0,
-                    "t1": now, "tokens": len(chunk),
-                })
+        # generous bound: the engine watchdog fails a wedged scheduler's
+        # requests typed long before this fires — the timeout is the
+        # belt to that suspender
+        if not stream.done.wait(timeout=600.0):
+            self._sender.kill(stream)
+            send_error(
+                TimeoutError(
+                    f"request {req.id}: no stream end in 600.0s"
+                ),
+                code="internal",
+            )
+            return False
+        if stream.dead:
+            return False  # client went away (or injected drop)
         try:
             seq = self.engine.wait(req)  # completion bookkeeping
         except ServingError as e:

@@ -294,8 +294,10 @@ def test_traced_spans_carry_cpu_clocks_and_the_streams_and_waits_show(tmp_path):
     """Under a trace every ``serving/*`` span of the scheduler's thread says
     how long the thread ran (``cpu_ns`` <= its duration) and what the whole
     process burned meanwhile (``proc_cpu_ns`` >= ``cpu_ns``); each streamed
-    chunk's send is a plain span on its connection's thread, and the loop's
-    park once the load stops is one on the scheduler's."""
+    chunk's send is a plain span, one a frame, all on the server's one
+    sender thread (PR 38) inside that thread's ``serving/stream_flush`` a
+    wake, and the loop's park once the load stops is a span on the
+    scheduler's."""
     import threading
     import time
 
@@ -329,7 +331,9 @@ def test_traced_spans_carry_cpu_clocks_and_the_streams_and_waits_show(tmp_path):
             time.sleep(0.12)  # the bank is idle: the loop parks, 50 ms a time
             return got
 
+        wakes0 = engine.stats()["streams"]["sender_wakes"]
         got, plain = _traced(tmp_path, drive)
+        wakes = engine.stats()["streams"]["sender_wakes"] - wakes0
     finally:
         server.shutdown()
     assert [len(g) for g in got] == [6, 6, 6]
@@ -346,14 +350,24 @@ def test_traced_spans_carry_cpu_clocks_and_the_streams_and_waits_show(tmp_path):
     sends = {t: [r for r in rows if r[2] == "serving/stream_send"]
              for t, rows in threads.items()}
     assert not sends[sched[0]]
+    # every send sits on one thread, which is not the scheduler's: a span a
+    # frame, a stream's sends under its request's identifier
+    (sender,) = [t for t, rows in sends.items() if rows]
     by_req = {}
-    for t, rows in sends.items():
-        for _s, _d, _n, a in rows:
-            by_req.setdefault(a["req"], set()).add(t)
-    # one stream's sends share an identifier and a thread
-    assert len(by_req) == 3 and all(len(ts) == 1 for ts in by_req.values())
-    assert sum(a["tokens"] for rows in sends.values()
-               for _s, _d, _n, a in rows) == sum(len(g) for g in got)
+    for _s, _d, _n, a in sends[sender]:
+        by_req[a["req"]] = by_req.get(a["req"], 0) + a["tokens"]
+    assert sorted(by_req.values()) == [6, 6, 6]
+    assert len(sends[sender]) == sum(len(g) for g in got)  # a token a frame
+    # a flush a wake of the sender (two wakes may share a pass), each send
+    # inside one, and the frames on the flushes are the sends
+    flushes = [r for r in threads[sender] if r[2] == "serving/stream_flush"]
+    assert 1 <= len(flushes) <= wakes
+    assert sum(a["frames"] for _s, _d, _n, a in flushes) == len(sends[sender])
+    assert all(a["streams"] >= 1 for _s, _d, _n, a in flushes)
+    assert all(any(fs <= s and s + d <= fs + fd for fs, fd, _n, _a in flushes)
+               for s, d, _n, _a in sends[sender])
+    assert not any(r[2] == "serving/stream_flush"
+                   for t, rows in threads.items() if t != sender for r in rows)
     waits = [r for r in threads[sched[0]] if r[2] == "serving/wait"]
     assert waits and all(
         a["woken"] in (0, 1) and a["held"] == 0 and a["queue_depth"] == 0
