@@ -25,6 +25,23 @@ A layer is::
     x = x + concat_j(g_j a_j) Wo
     u = RMSNorm(x);  x = x + FFN(u)
 
+With ``qk_norm`` every head of ``q`` and ``k`` is RMS-normalised (one gain
+over ``Dh``, shared by the heads) before the rotation. With ``select``
+(``{"heads": J, "head_dim": Di, "topk": K}``: a learned indexer, after
+DeepSeek-V3.2's) a query reads only the ``K`` cached positions its indexer
+scores highest::
+
+    qI = rot(h WqI) (J heads of Di);  kI = rot(LayerNorm(h WkI)) (ONE head of
+    Di, cached a token and layer beside k and v);  w = h Ww (J,)
+    I[t, s] = (J Di)^-1/2 sum_j w[t, j] relu(qI[t, j] . kI[s]),  float32
+    S_t = the K positions s <= t of largest I[t, s] (all while t + 1 <= K;
+          a tie at the threshold goes to the lower position)
+    a_j = softmax over s in S_t alone
+
+The indexer's arithmetic (``index_inputs``, ``index_scores``, ``select_mask``,
+``select_rows``) is written once here and used by ``apply``, the chunk
+program and the step program; the selection is exact.
+
 ``FFN`` is a gated SiLU MLP (``ffn_width``), or ``n_experts`` routed
 experts (softmax scores, the ``top_k`` largest, normalised over the picks
 with ``norm_topk``, times ``routed_scale``) plus one shared expert of
@@ -150,6 +167,157 @@ def attend_blocked(q, k, v, qpos, kpos0, window=None, key_block=512,
     return rows((q, qpos))
 
 
+# ---------------------------------------------------- the exact selection
+
+
+def _ordered_bits(s):
+    """float32 -> uint32 whose unsigned order is the floats' own (-0.0 as
+    +0.0, so that equal scores have equal bits); every float lies above 0."""
+    b = jax.lax.bitcast_convert_type(
+        jnp.where(s == 0.0, 0.0, s).astype(jnp.float32), jnp.int32)
+    b = b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+    return jax.lax.bitcast_convert_type(b, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+
+def _kth_largest(u, k: int):
+    """The ``k``-th largest of each row of ``u`` ``(m, t)`` uint32, found
+    bit by bit from the top: 32 counting passes, no sort. 0 for a row with
+    fewer than ``k`` values above 0."""
+    def bit(i, best):
+        cand = best | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = jnp.sum(u >= cand[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= k, cand, best)
+
+    return jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros(u.shape[:1], jnp.uint32))
+
+
+def _running_count(x):
+    """The inclusive running count of a bool ``(m, t)`` along its rows, as
+    two products with a triangle of ones (within blocks of 128 positions,
+    then over the blocks' totals): exact in float32, and a long
+    ``cumsum`` costs the TPU's compiler seconds an instance."""
+    m, t = x.shape
+    lane = math.gcd(t, 128)
+    ones = jnp.ones((), jnp.bfloat16)
+    upto = jnp.triu(jnp.full((lane, lane), ones))  # [l, k]: l <= k
+    within = _einsum("mbl,lk->mbk", x.reshape(m, t // lane, lane), upto)
+    before = jnp.triu(jnp.full((t // lane,) * 2, ones), 1)  # b < c
+    offset = _einsum("mb,bc->mc", within[..., -1], before)
+    return (within + offset[..., None]).reshape(m, t).astype(jnp.int32)
+
+
+def select_mask(scores, visible, k: int):
+    """Which keys each query selects, as a mask: ``scores`` ``(m, t)``
+    float32, ``visible`` ``(m, t)`` bool -> ``(m, t)`` bool with exactly
+    ``min(visible, k)`` keys a row, the ``k`` of largest score, a tie at
+    the threshold to the lower position. The threshold is the exact
+    ``k``-th largest score (``_kth_largest``); where some row has more
+    keys AT the threshold than it has room for, those are ranked by
+    position (``_running_count``: the rare path, under a ``cond``)."""
+    u = jnp.where(visible, _ordered_bits(scores), jnp.uint32(0))
+    thr = _kth_largest(u, k)[:, None]
+    above, ties = u > thr, (u == thr) & visible
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    crowded = jnp.any(jnp.sum(ties, axis=-1, dtype=jnp.int32) > room)
+    return above | jax.lax.cond(
+        crowded,
+        lambda: ties & (_running_count(ties) <= room[:, None]),
+        lambda: ties,
+    )
+
+
+def select_rows(scores, visible, k: int):
+    """The same selection as positions: ``(idx (m, k'), valid (m, k'))``,
+    ``k' = min(k, t)``; ``valid`` is False where a row has fewer visible
+    keys than ``k'``. ``lax.top_k`` puts the lower index first among equal
+    values, which is the tie rule (-0.0 goes in as +0.0: a sort may tell
+    them apart, a score does not)."""
+    masked = jnp.where(visible, jnp.where(scores == 0.0, 0.0, scores),
+                       -jnp.inf)
+    _, idx = jax.lax.top_k(masked, min(k, scores.shape[-1]))
+    return idx, jnp.take_along_axis(visible, idx, axis=-1)
+
+
+def attend_selected(q, keys_of, qpos, chosen_of, extent, extents=(),
+                    key_block=512, query_block=256):
+    """Grouped-query attention for a prefill chunk whose queries each read
+    the keys their indexer picks: ``q`` ``(n, H, Dh)`` at positions
+    ``qpos`` ``(n,)`` (ascending) against the cached positions ``0 ..
+    extent - 1``; ``keys_of(t') -> (k, v)`` ``(t', Hkv, Dh)`` gives the
+    first ``t'`` of them, ``chosen_of(lo, m, t') -> (m, t')`` bool which of
+    them queries ``lo .. lo + m`` read (the indexer's scores and
+    ``select_mask``, under the caller's scope). Queries go ``query_block``
+    at a time: their selection, then the key blocks up to their last
+    position folded into a running softmax under the selection's mask (a
+    product over every visible key: more work than the selected keys
+    alone, the same result). ``extents`` (ascending, below ``extent``):
+    everything is done at the first extent that holds the chunk's last
+    position, under a ``switch``, so that a short context does not pay for
+    the longest."""
+    n, nh, hd = q.shape
+    scale = 1.0 / np.sqrt(hd)
+    extents = [e for e in extents if e < extent] + [extent]
+
+    def at_extent(te):
+        def run():
+            k, v = keys_of(te)
+            kvh = k.shape[1]
+            g = nh // kvh
+            kb = min(key_block, te)
+            blocks = -(-te // kb)
+            pad = blocks * kb - te
+            ke, ve = (jnp.pad(c, ((0, pad), (0, 0), (0, 0))) for c in (k, v))
+
+            def rows(lo, m):
+                qg = jax.lax.dynamic_slice_in_dim(q, lo, m, 0).reshape(
+                    m, kvh, g, hd)
+                last = jax.lax.dynamic_index_in_dim(
+                    qpos, lo + m - 1, keepdims=False)
+                sel = jnp.pad(chosen_of(lo, m, te), ((0, 0), (0, pad)))
+                hi = jnp.clip(last // kb + 1, 0, blocks)
+
+                def fold(j, carry):
+                    mx, l, acc = carry
+                    off = j * kb
+                    kj = jax.lax.dynamic_slice_in_dim(ke, off, kb, 0)
+                    vj = jax.lax.dynamic_slice_in_dim(ve, off, kb, 0)
+                    see = jax.lax.dynamic_slice_in_dim(sel, off, kb, 1)
+                    s = _einsum("mkgd,tkd->kgmt", qg, kj) * scale
+                    s = jnp.where(see[None, None], s, -jnp.inf)
+                    m_new = jnp.maximum(mx, s.max(axis=-1))
+                    # a row with no key yet keeps -inf: exp(-inf - 0) = 0
+                    safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+                    w = jnp.exp(s - safe[..., None])
+                    fix = jnp.exp(mx - safe)
+                    l = l * fix + w.sum(axis=-1)
+                    acc = acc * fix[..., None] + _einsum(
+                        "kgmt,tkd->kgmd", w, vj)
+                    return m_new, l, acc
+
+                _, l, acc = jax.lax.fori_loop(0, hi, fold, (
+                    jnp.full((kvh, g, m), -jnp.inf, jnp.float32),
+                    jnp.zeros((kvh, g, m), jnp.float32),
+                    jnp.zeros((kvh, g, m, hd), jnp.float32),
+                ))
+                o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+                return o.transpose(2, 0, 1, 3).reshape(m, nh, hd)
+
+            if n > query_block and n % query_block == 0:
+                o = jax.lax.map(lambda lo: rows(lo, query_block),
+                                jnp.arange(0, n, query_block))
+                return o.reshape(n, nh, hd)
+            return rows(0, n)
+
+        return run
+
+    if len(extents) == 1:
+        return at_extent(extent)()
+    # the first extent that holds the chunk's last position
+    which = jnp.sum(qpos[-1] >= jnp.asarray(extents[:-1]))
+    return jax.lax.switch(which, [at_extent(e) for e in extents])
+
+
 @register_layer
 class GroupedQueryMoEBlock(Layer):
     """One pre-RMSNorm layer of grouped-query attention (``num_heads``
@@ -160,7 +328,12 @@ class GroupedQueryMoEBlock(Layer):
     "factor", "original", "beta_fast", "beta_slow", "attention_factor"}``),
     then a gated MLP (``n_experts=0``: width ``ffn_width``) or an expert
     layer (``n_experts`` routed experts of ``expert_width``, ``top_k`` a
-    token, plus a shared expert of ``shared_width``).
+    token, plus a shared expert of ``shared_width``; 0: none).
+    ``qk_norm``: an RMSNorm a head on ``q`` and ``k`` before the rotation.
+    ``select`` (``{"heads", "head_dim", "topk"}``; None: every key): the
+    indexer whose scores pick the ``topk`` cached positions a query reads;
+    such a block caches one selector key of ``select["head_dim"]`` a token
+    beside its keys and values, and has no window.
 
     ``experts_held``: as ``LatentMoEBlock``: the routed experts this layer
     holds (ids; None = all); the router keeps its width and its ``top_k``,
@@ -173,13 +346,18 @@ class GroupedQueryMoEBlock(Layer):
     causal = True
     token_block = 1024  # tokens whose FFN runs at once (a long chunk)
     key_block = 512  # cache positions a prefill chunk folds at once
+    # a selecting block: the most tokens one prefill-chunk program takes
+    # (its scores are tokens x cached positions), and the cached extents
+    # its chunk scores and selects at, as multiples of ``topk``
+    chunk_tokens = 2048
+    select_extents = (4, 8, 16)
     _std = 0.02
 
     def __init__(self, num_heads, kv_heads, head_dim, rope, window=None,
                  gate="per_head", ffn_width=0, n_experts=0, top_k=0,
                  expert_width=0, shared_width=0, routed_scale=1.0,
                  norm_topk=True, epsilon=1e-6, experts_held=None,
-                 out_scale=1.0):
+                 out_scale=1.0, qk_norm=False, select=None):
         self.num_heads = int(num_heads)
         self.kv_heads = int(kv_heads)
         self.head_dim = int(head_dim)
@@ -198,6 +376,16 @@ class GroupedQueryMoEBlock(Layer):
             None if experts_held is None else [int(e) for e in experts_held]
         )
         self.out_scale = float(out_scale)
+        self.qk_norm = bool(qk_norm)
+        self.select = None if select is None else {
+            k: int(select[k]) for k in ("heads", "head_dim", "topk")}
+        if self.select is not None and (
+                self.window is not None or self.select["head_dim"] % 2
+                or min(self.select.values()) < 1):
+            raise ValueError(
+                f"select {self.select} with window {self.window}: an "
+                f"indexer has heads of an even size, topk >= 1 and no "
+                f"window beside it")
         if self.num_heads % self.kv_heads:
             raise ValueError(
                 f"{self.num_heads} query heads are not a multiple of "
@@ -255,7 +443,7 @@ class GroupedQueryMoEBlock(Layer):
 
     def init(self, rng, in_shape):
         d = in_shape[-1]
-        ks = iter(jax.random.split(rng, 16))
+        ks = iter(jax.random.split(rng, 19 if self.select else 16))
         std, dt = self._std, jnp.float32
         nh, kvh, hd = self.num_heads, self.kv_heads, self.head_dim
 
@@ -272,6 +460,18 @@ class GroupedQueryMoEBlock(Layer):
                               std * self.out_scale, dt)}
         if self.gate:
             attn["wgate"] = _normal(next(ks), (d, nh), std, dt)
+        if self.qk_norm:
+            attn["q_norm"] = {"gamma": jnp.ones((hd,), dt)}
+            attn["k_norm"] = {"gamma": jnp.ones((hd,), dt)}
+        if self.select:
+            nj, di = self.select["heads"], self.select["head_dim"]
+            attn["index"] = {
+                "wq": _normal(next(ks), (d, nj * di), std, dt),
+                "wk": _normal(next(ks), (d, di), std, dt),
+                "ww": _normal(next(ks), (d, nj), std, dt),
+                "norm": {"gamma": jnp.ones((di,), dt),
+                         "beta": jnp.zeros((di,), dt)},
+            }
         params = {"ln1": {"gamma": jnp.ones((d,), dt)}, "attn": attn,
                   "ln2": {"gamma": jnp.ones((d,), dt)}}
         if self.n_experts:
@@ -279,29 +479,49 @@ class GroupedQueryMoEBlock(Layer):
                 "router": {"wr": _normal(next(ks), (d, self.n_experts),
                                          std, dt)},
                 "experts": mlp(self.expert_width, (len(self.held),)),
-                "shared": mlp(self.shared_width),
             }
+            if self.shared_width:
+                params["ffn"]["shared"] = mlp(self.shared_width)
         else:
             params["ffn"] = mlp(self.ffn_width)
         return params, {}, in_shape
 
     # -- the arithmetic, once -----------------------------------------------
 
+    def _qkv(self, a, h, pos):
+        """``q`` ``(..., H, Dh)``, ``k``, ``v`` ``(..., Hkv, Dh)`` of the
+        normed input ``h``: projected, normalised a head with
+        ``qk_norm``, ``q`` and ``k`` rotated by ``pos``."""
+        lead = h.shape[:-1]
+        nh, kvh, hd = self.num_heads, self.kv_heads, self.head_dim
+        q = matmul(h, a["wq"]).reshape(*lead, nh, hd)
+        if self.qk_norm:
+            q = rms_norm(q, a["q_norm"]["gamma"], self.epsilon)
+        q = self.rotate(q, pos)
+        k = matmul(h, a["wk"]).reshape(*lead, kvh, hd)
+        if self.qk_norm:
+            k = rms_norm(k, a["k_norm"]["gamma"], self.epsilon)
+        k = self.rotate(k, pos)
+        return q, k, matmul(h, a["wv"]).reshape(*lead, kvh, hd)
+
     def attention(self, p, x, pos, mask, attend=None):
         """``x + Wo(gate * Attn(RMSNorm(x)))``; ``x`` ``(..., d)`` at
         ``pos`` ``(...)``. ``attend(q (..., H, Dh), k_new, v_new (...,
         Hkv, Dh)) -> (..., H, Dh)`` owns the cache: where the new keys
         and values go and what is attended. None (``apply``): the
-        sequence's own keys under ``mask`` ``(B|1, n, t)``."""
+        sequence's own keys under ``mask`` ``(B|1, n, t)``. A block that
+        selects hands ``attend`` a fourth argument, the indexer's
+        ``(qI, kI_new, w)`` (:meth:`index_inputs`), and ``attend`` opens
+        the ``attn/index`` and ``attn/sparse`` scopes around its parts."""
+        if self.select:
+            return self._attention_selected(p, x, pos, mask, attend)
         a = p["attn"]
         lead = x.shape[:-1]
-        nh, kvh, hd = self.num_heads, self.kv_heads, self.head_dim
+        nh, hd = self.num_heads, self.head_dim
         scope = "attn/window" if self.window is not None else "attn/full"
         with jax.named_scope(scope):
             h = rms_norm(x, p["ln1"]["gamma"], self.epsilon)
-            q = self.rotate(matmul(h, a["wq"]).reshape(*lead, nh, hd), pos)
-            k = self.rotate(matmul(h, a["wk"]).reshape(*lead, kvh, hd), pos)
-            v = matmul(h, a["wv"]).reshape(*lead, kvh, hd)
+            q, k, v = self._qkv(a, h, pos)
             if attend is None:
                 cd = a["wk"].dtype  # as a served cache holds them
                 o = attend_dense(q, k.astype(cd), v.astype(cd), mask)
@@ -310,6 +530,77 @@ class GroupedQueryMoEBlock(Layer):
             if self.gate:
                 o = o * jax.nn.sigmoid(matmul(h, a["wgate"]))[..., None]
             return x + matmul(o.reshape(*lead, nh * hd), a["wo"])
+
+    # -- the indexer ---------------------------------------------------------
+
+    def index_inputs(self, pi, h, pos):
+        """What the scores are made of, from the normed input ``h`` ``(...,
+        d)`` at ``pos``: ``qI`` ``(..., J, Di)`` rotated, the token's own
+        selector key ``kI`` ``(..., Di)`` (LayerNorm, then rotated; what
+        the cache holds), and the heads' weights ``w`` ``(..., J)`` with
+        the score's scale ``(J Di)^-1/2`` in them; float32."""
+        lead = h.shape[:-1]
+        nj, di = self.select["heads"], self.select["head_dim"]
+        theta = float(self.rope["theta"])
+        qi = rope(matmul(h, pi["wq"]).reshape(*lead, nj, di),
+                  pos[..., None], theta)
+        ki = matmul(h, pi["wk"])
+        mu = jnp.mean(ki, axis=-1, keepdims=True)
+        ki = (ki - mu) * jax.lax.rsqrt(
+            jnp.mean((ki - mu) ** 2, axis=-1, keepdims=True) + self.epsilon)
+        ki = (ki * pi["norm"]["gamma"].astype(jnp.float32)
+              + pi["norm"]["beta"].astype(jnp.float32))
+        ki = rope(ki[..., None, :], pos[..., None], theta)[..., 0, :]
+        return qi, ki, matmul(h, pi["ww"]) * (nj * di) ** -0.5
+
+    @staticmethod
+    def index_scores(qi, w, ki, packed: int = 1):
+        """``I[.., n, s] = sum_j w[.., n, j] relu(qI[.., n, j] . kI[.., s])``
+        in float32: ``qi`` ``(..., n, J, Di)``, ``w`` ``(..., n, J)``,
+        ``ki`` the cached selector keys in the cache's dtype, ``(..., t,
+        Di)`` or, ``packed`` tokens a row as a pool holds them, ``(..., t /
+        packed, packed x Di)``: the query then goes against each part of
+        a row with zeros beside it, so that the rows are read as they lie
+        and no key is moved. Returns ``(..., n, t)``."""
+        if packed == 1:
+            dots = _einsum("...njd,...td->...njt", qi, ki)
+            return jnp.sum(jax.nn.relu(dots) * w[..., None], axis=-2)
+        di = qi.shape[-1]
+        # (..., n, r, J, packed x Di): head j of part r lies in r's values
+        parts = jnp.stack([
+            jnp.pad(qi, [(0, 0)] * (qi.ndim - 1)
+                    + [(r * di, (packed - 1 - r) * di)])
+            for r in range(packed)], axis=-3)
+        dots = _einsum("...nrjc,...tc->...nrjt", parts, ki)
+        s = jnp.sum(jax.nn.relu(dots) * w[..., None, :, None], axis=-2)
+        # (..., n, r, t / packed) -> position t' x packed + r
+        return jnp.swapaxes(s, -1, -2).reshape(*s.shape[:-2], -1)
+
+    def _attention_selected(self, p, x, pos, mask, attend):
+        a = p["attn"]
+        lead = x.shape[:-1]
+        with jax.named_scope("attn/sparse"):
+            h = rms_norm(x, p["ln1"]["gamma"], self.epsilon)
+            q, k, v = self._qkv(a, h, pos)
+        with jax.named_scope("attn/index"):
+            index = self.index_inputs(a["index"], h, pos)
+        if attend is not None:
+            o = attend(q, k, v, index)
+        else:
+            cd = a["wk"].dtype  # as a served cache holds them
+            with jax.named_scope("attn/index"):
+                qi, ki, w = index
+                scores = self.index_scores(qi, w, ki.astype(cd))
+                b, n, t = scores.shape
+                keep = select_mask(
+                    scores.reshape(b * n, t),
+                    jnp.broadcast_to(mask, scores.shape).reshape(b * n, t),
+                    self.select["topk"]).reshape(b, n, t)
+            with jax.named_scope("attn/sparse"):
+                o = attend_dense(q, k.astype(cd), v.astype(cd), keep)
+        with jax.named_scope("attn/sparse"):
+            return x + matmul(
+                o.reshape(*lead, self.num_heads * self.head_dim), a["wo"])
 
     def ffn(self, p, u, token_mask=None):
         """``u`` ``(n, d)`` -> ``(y, Picks | None)``; more than
@@ -336,8 +627,9 @@ class GroupedQueryMoEBlock(Layer):
         y, picks = routed_experts(
             p["experts"], u, chosen, w, self.held, self.n_experts,
             token_mask)
-        with jax.named_scope("moe/shared"):
-            y = y + gated_mlp(p["shared"], u)
+        if self.shared_width:
+            with jax.named_scope("moe/shared"):
+                y = y + gated_mlp(p["shared"], u)
         return y, picks
 
     def forward(self, p, x, pos, mask, attend=None, token_mask=None):
@@ -374,5 +666,6 @@ class GroupedQueryMoEBlock(Layer):
             "shared_width": self.shared_width,
             "routed_scale": self.routed_scale, "norm_topk": self.norm_topk,
             "epsilon": self.epsilon, "experts_held": self.experts_held,
-            "out_scale": self.out_scale,
+            "out_scale": self.out_scale, "qk_norm": self.qk_norm,
+            "select": self.select,
         }

@@ -586,6 +586,75 @@ def laguna_lm(
     return model
 
 
+def keye_lm(
+    vocab_size=256,
+    seq_len=128,
+    hidden_size=64,
+    num_attention_heads=4,
+    num_key_value_heads=2,
+    head_dim=16,
+    moe_intermediate_size=32,
+    num_experts=8,
+    num_experts_per_tok=3,
+    num_hidden_layers=2,
+    sa_config=None,
+    rope_theta=10000.0,
+    norm_topk_prob=True,
+    rms_norm_eps=1e-6,
+    qk_norm=True,
+    experts_held=None,
+    seed=0,
+):
+    """Causal language model of grouped-query blocks whose keys a learned
+    indexer selects (the language model of the ``KeyeVL2`` model type, under
+    its published keys): Embedding without a position table -> one
+    ``GroupedQueryMoEBlock`` a layer -> RMSNorm -> an untied head without
+    bias. Every layer alike: ``num_attention_heads`` query heads over
+    ``num_key_value_heads`` K/V heads of ``head_dim``, an RMSNorm a head on
+    ``q`` and ``k`` (``qk_norm``), plain rotary positions (``rope_theta``;
+    a text token's three position components are equal, and the sectioned
+    rotation is then the plain one), no gate, no window; the indexer of
+    ``sa_config`` (``indexer_num_heads`` heads of ``indexer_head_dim``
+    against ONE cached selector key a token, ``indexer_num_kv_heads`` 1;
+    the ``topk`` positions of largest score are attended; ``q_chunk_size``
+    / ``kv_chunk_size`` name tiles of the computation and change no
+    result); then ``num_experts`` routed experts of
+    ``moe_intermediate_size`` (``num_experts_per_tok`` a token, softmax
+    scores normalised over the picks with ``norm_topk_prob``) and no shared
+    expert. ``experts_held``: the routed experts every layer holds (None:
+    all). Serves through the paged ``ServingEngine`` with a selector key's
+    pool beside the keys' and values' under one page table."""
+    from distkeras_tpu.models.gqa_moe import GroupedQueryMoEBlock
+    from distkeras_tpu.models.mla_moe import RMSNorm
+
+    sa = dict(sa_config or {"indexer_num_heads": 2, "indexer_head_dim": 8,
+                            "indexer_num_kv_heads": 1, "topk": 8})
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError("the indexer caches one selector key a token")
+    n = int(num_hidden_layers)
+    model = Sequential(
+        [
+            Embedding(vocab_size, hidden_size, with_positions=False),
+            *[GroupedQueryMoEBlock(
+                num_attention_heads, num_key_value_heads, head_dim,
+                {"theta": float(rope_theta)}, gate=None,
+                n_experts=num_experts, top_k=num_experts_per_tok,
+                expert_width=moe_intermediate_size, shared_width=0,
+                norm_topk=norm_topk_prob, epsilon=rms_norm_eps,
+                experts_held=experts_held, out_scale=(2 * n) ** -0.5,
+                qk_norm=qk_norm,
+                select={"heads": sa["indexer_num_heads"],
+                        "head_dim": sa["indexer_head_dim"],
+                        "topk": sa["topk"]},
+            ) for _ in range(n)],
+            RMSNorm(rms_norm_eps),
+            Dense(vocab_size, use_bias=False),
+        ]
+    )
+    model.build((seq_len,), seed=seed)
+    return model
+
+
 ZOO = {
     "mnist_mlp": mnist_mlp,
     "mnist_cnn": mnist_cnn,

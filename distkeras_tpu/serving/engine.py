@@ -484,6 +484,8 @@ class _InflightStep:
                 # the expert layers' routing counters ride the tokens'
                 # fetch (no second device sync)
                 toks = st._note_routing(toks, int(active.sum()), sp)
+            if st._select:
+                st._note_selection(active, sp)
             owed = self.owed()
             st._lens[owed] = np.minimum(st._lens[owed] + 1, st._lens_cap)
             # the RNG counter mirrors the length discipline exactly: a
@@ -624,7 +626,11 @@ class DecodeStepper:
         # by a block's class
         self.layout = self._gen.block_kind
         self._face = self._LAYOUTS[self.layout]
-        self._paged_only = self._PAGED_ONLY.get(self.layout)
+        # the indexer of a block that selects the keys it reads (None:
+        # every cached key is read): ``{"heads", "head_dim", "topk"}``
+        self._select = getattr(self._gen._blocks[0], "select", None)
+        self._paged_only = self._PAGED_ONLY.get(
+            "gqa/select" if self._select else self.layout)
         self.prefix_caches_off = None
         if self._paged_only:
             from distkeras_tpu.ops.quantization import count_quantized
@@ -648,6 +654,17 @@ class DecodeStepper:
         if self.num_slots < 1:
             raise ValueError(f"num_slots must be >= 1; got {num_slots}")
         self.max_len = int(model.input_shape[0])
+        # the most tokens one prefill-chunk program takes: a block whose
+        # chunk costs tokens x cached positions says so (``chunk_tokens``)
+        selecting = [b for b in self._gen._blocks
+                     if getattr(b, "select", None)]
+        self.chunk_cap = min(
+            [self.max_len] + [int(b.chunk_tokens) for b in selecting])
+        # ... and the fewest a chunk program is built for, a sixteenth of
+        # that (shorter chunks are padded to it: such a block's chunk
+        # program is a switch of extents a layer, and a dozen of them cost
+        # minutes of set-up; five buckets, 128 to 2,048, cost half)
+        self.chunk_floor = max(1, self.chunk_cap // 16) if selecting else 1
         self.seed = int(seed)
         self.drafter = speculative if speculative else None
         self.draft_k = int(draft_k)
@@ -766,6 +783,15 @@ class DecodeStepper:
                 self.layout, hd, self._gen.kv_dtype, self.mesh,
                 self.page_size,
             )
+            if self._select:
+                # the kernels walk whole pages; the selected rows lie on
+                # nearly as many pages as there are rows
+                self.attention = (
+                    "gather: the rows an indexer selects, by token")
+            # a step program that costs the same at every table width is
+            # compiled once, at the widest
+            self._one_step_extent = (
+                self.attention == "kernel" or bool(self._select))
             self._caches = None
             self._window_alloc = None
             self._pools = getattr(self, self._face["pools"])(
@@ -816,6 +842,10 @@ class DecodeStepper:
             # layer-steps whose held rows took more than one pass
             "overflow_passes": 0,
         }
+        # a selecting block: over the decode steps, the cached positions
+        # the active slots held and those their queries read
+        self.select_stats = {"steps": 0, "keys_cached": 0,
+                             "keys_selected": 0}
         self.host_arg_bytes_step = 0  # of the last decode-step call
         self._step_fns = {}  # masked flag -> compiled decode step
         self._admit_fns = {}  # prefill-length bucket -> compiled admit
@@ -945,6 +975,13 @@ class DecodeStepper:
             out["window_positions_max"] = self._ring * self.page_size
             out["window_pages_a_slot"] = self._ring
             out["window"] = self._window_alloc.stats()
+        if self._select:
+            # the second kind of cached row under the one table
+            out["bytes_per_token_by_kind"] = {
+                kind: self.kv_bytes_per_token(kind)
+                for kind in ("full", "index")
+            }
+            out["select"] = dict(self._select)
         # mesh geometry: the pool's TOTAL bytes are mesh-invariant;
         # what changes with tp:N is how many land per shard
         out["mesh"] = self.mesh_spec
@@ -1085,21 +1122,26 @@ class DecodeStepper:
 
     def kv_bytes_per_token(self, kind=None) -> int:
         """Bytes one cached token takes over all layers: keys and values
-        of every K/V head, or one latent row an attention, read off the
-        pools' own shapes. ``kind`` (``"full"`` | ``"window"``): over the
+        of every K/V head, or one latent row an attention, and a
+        selecting block's selector key, read off the pools' own shapes.
+        ``kind`` (``"full"`` | ``"window"`` | ``"index"``): over the
         layers of that kind alone, where layers differ (a window layer
-        holds a token only while it is inside the window)."""
+        holds a token only while it is inside the window), or over the
+        selector keys alone."""
         if not self.paged:
             return (np.dtype(self._gen.kv_dtype).itemsize * 2 * self._nh
                     * self._hd * len(self._gen._stages))
         total = 0
         for blk, arrs in zip(self._gen._blocks, self._pools):
-            windowed = getattr(blk, "window", None) is not None
-            if kind is None or windowed == (kind == "window"):
-                total += sum(
-                    int(np.prod(a.shape[1:] if a.ndim == 2 else a.shape[2:]))
-                    * a.dtype.itemsize for a in arrs
-                )
+            mine = "window" if getattr(blk, "window", None) is not None \
+                else "full"
+            for j, a in enumerate(arrs):
+                index = j == 2 and bool(getattr(blk, "select", None))
+                if kind in (None, "index" if index else mine):
+                    total += (
+                        int(np.prod(a.shape[1:] if a.ndim == 2
+                                    else a.shape[2:])) * a.dtype.itemsize
+                        // (self._index_packing if index else 1))
         return total
 
     # -- the blocks that only the paged engine serves --------------------------
@@ -1113,6 +1155,17 @@ class DecodeStepper:
             "prefix_caches": (
                 "latent page layout: the host PrefixStore and the "
                 "DevicePrefixIndex hold (p, H, Dh) K/V rows only"),
+        },
+        "gqa/select": {
+            "what": "a grouped-query block whose keys an indexer selects",
+            "why": "its cache holds a selector key a token and layer "
+                   "beside the keys and values, under one page table, and "
+                   "a query reads the rows its indexer picks, which only "
+                   "the paged step and chunk programs of one chip do",
+            "prefix_caches": (
+                "selecting layout: the host PrefixStore and the "
+                "DevicePrefixIndex hold (p, H, Dh) K/V rows of one head "
+                "count and no selector keys"),
         },
         "gqa": {
             "what": "a grouped-query block with window layers",
@@ -1210,11 +1263,13 @@ class DecodeStepper:
         blocks = self._gen._blocks
         shapes = {(b.kv_heads, b.head_dim) for b in blocks}
         windows = {b.window for b in blocks} - {None}
-        if len(shapes) != 1 or len(windows) > 1:
+        selects = {repr(b.select) for b in blocks}
+        if len(shapes) != 1 or len(windows) > 1 or len(selects) != 1:
             raise ValueError(
-                f"a paged pool has one (K/V heads, head size) and one "
-                f"window size a model; got {sorted(shapes)} and windows "
-                f"{sorted(windows)}"
+                f"a paged pool has one (K/V heads, head size), one "
+                f"window size and one indexer a model; got "
+                f"{sorted(shapes)}, windows {sorted(windows)} and "
+                f"indexers {sorted(selects)}"
             )
         return (*shapes.pop(), windows.pop() if windows else None)
 
@@ -1231,12 +1286,21 @@ class DecodeStepper:
         Every pool is ``(pages x page_size, Hkv x Dh)``, the row-major
         flattening of ``(pages, page_size, Hkv, Dh)``: a head is a
         lane-aligned slice of a row, which is how the kernel takes a K/V
-        head's keys out of a copied page (``ops/paged_attention.py``)."""
+        head's keys out of a copied page (``ops/paged_attention.py``).
+
+        A block that selects has a THIRD pool, its selector keys: one
+        key of ``select["head_dim"]`` values a token, in the pages of the
+        same table and budget as the keys and values (reserved, released
+        and counted with them). A row of it holds a page's keys side by
+        side (``_index_packing``), ``(pages, page_size x Di)``: at 64
+        values a key a token then costs its 128 bytes, and not a 128-lane
+        row of 256, and a page is ONE row to gather."""
         import jax.numpy as jnp
 
         from distkeras_tpu.serving.paging import PageAllocator
 
         ps = self.page_size
+        pack = self._index_packing
         window_pages = 0
         if self._window is not None:
             self._ring = -(-self._window // ps) + 1
@@ -1252,9 +1316,23 @@ class DecodeStepper:
                     self._gen.kv_dtype,
                 )
                 for _ in range(2)
-            )
+            ) + ((
+                jnp.zeros(
+                    (num_pages * ps // pack,
+                     pack * blk.select["head_dim"]),
+                    self._gen.kv_dtype),
+            ) if blk.select else ())
             for blk in self._gen._blocks
         ]
+
+    @property
+    def _index_packing(self) -> int:
+        """Selector keys a row of their pool holds: a page's. A gather out
+        of the device's memory costs by the row, 10 to 16 ns each whatever
+        its size up to a kilobyte (PERF.md, PR 39), and the step gathers
+        every page a slot holds: a page a row is an eighth of the rows of
+        two keys a row."""
+        return self.page_size if self._select else 1
 
     # -- the latent-attention block ------------------------------------------
 
@@ -1307,6 +1385,23 @@ class DecodeStepper:
             * self._gen._blocks[-1].top_k,
         )
         return toks
+
+    def _note_selection(self, active, span):
+        """A selecting block's counters of one decode step, from the
+        host's own lengths (no fetch): ``keys_cached``, the cached
+        positions the active slots' queries could see, and
+        ``keys_selected``, those they read (``min(cached, topk)`` each);
+        on the ``serving/collect`` span and summed for
+        ``stats()["select"]``."""
+        cached = self._lens[active].astype(np.int64)
+        seen = int(cached.sum())
+        read = int(np.minimum(cached, self._select["topk"]).sum())
+        m = self.select_stats
+        self.select_stats = {
+            "steps": m["steps"] + 1, "keys_cached": m["keys_cached"] + seen,
+            "keys_selected": m["keys_selected"] + read,
+        }
+        span.set_metadata(keys_cached=seen, keys_selected=read)
 
     def kv_shard_bytes(self) -> int:
         """K/V bytes RESIDENT PER SHARD — the number a capacity planner
@@ -1995,7 +2090,8 @@ class DecodeStepper:
         the chunk SHRINKS to the largest pow2 that fits rather than
         compiling an arbitrary-length tail program — near-capacity
         traffic must not break the O(log T) compile discipline."""
-        cb = _bucket_pow2(n, self.max_len)
+        n = min(n, self.chunk_cap)
+        cb = max(_bucket_pow2(n, self.max_len), self.chunk_floor)
         room = (
             len(self._tables[slot]) * self.page_size - pos
             if self.paged
@@ -2044,9 +2140,10 @@ class DecodeStepper:
         """Pow2 bucket covering every OCCUPIED slot's table — the step
         / verify program key. Occupied (not active) so blame-probe
         masks never change the program mid-blame."""
-        if self.attention == "kernel":
+        if self._one_step_extent:
             # the kernel reads a slot's own pages whatever the table's
-            # width: one step program, at the widest table
+            # width, and a selecting step the rows it picks: one step
+            # program, at the widest table
             return self._max_pages_bucket
         m = max((len(t) for t in self._tables), default=0)
         return _bucket_pow2(max(1, m), self._max_pages_bucket)
@@ -2055,7 +2152,7 @@ class DecodeStepper:
         """Every bucket ``_table_bucket`` can return: what the warm
         methods compile the step program at."""
         top = self._max_pages_bucket
-        if self.attention == "kernel":
+        if self._one_step_extent:
             return [top]
         return [1 << i for i in range(top.bit_length())]
 
@@ -2328,9 +2425,10 @@ class DecodeStepper:
         rows through slot 0, overwritten before anything attends
         them — the standing restore argument)."""
         with self._warm():
-            cb = 1
+            top = self.chunk_cap if self.paged else self.max_len
+            cb = self.chunk_floor if self.paged else 1
             while True:
-                cbb = min(cb, self.max_len)
+                cbb = min(cb, top)
                 toks = np.zeros((1, cbb), np.int32)
                 if self.paged:
                     # paged admission runs ONE program family (every
@@ -2343,7 +2441,7 @@ class DecodeStepper:
                     # the first live chunk would pay the mint without
                     # the _compiling() watchdog grace
                     if self._tables[0]:
-                        if cb >= self.max_len:
+                        if cb >= top:
                             break
                         cb <<= 1
                         continue
@@ -2369,7 +2467,7 @@ class DecodeStepper:
                         self._params, self._caches, toks,
                         np.int32(0), np.int32(0),
                     )
-                if cb >= self.max_len:
+                if cb >= top:
                     break
                 cb <<= 1
             if not self.paged:
@@ -3165,6 +3263,9 @@ class DecodeStepper:
         full, rings = tables if ring else (tables, None)
         lengths = jnp.where(active, pos + 1, 0)
 
+        if self._select:
+            return self._select_rows(full, rows, pos, active)
+
         def stage(blk, moe, p, pm, x, pool):
             w = blk.window
             table = full if w is None else rings
@@ -3210,6 +3311,142 @@ class DecodeStepper:
 
         return stage
 
+    def _select_rows(self, table, rows, pos, active):
+        """Grouped pages of a block that selects, one token a slot. Under
+        ``attn/index``: the token's selector key written into its part of
+        its page's row of the selector pool, every slot's selector rows
+        gathered at the table's extent (a page a row, as they lie), the
+        scores, and the exact ``topk`` positions. Under ``attn/sparse``:
+        the token's key and value written, the physical rows of the
+        selected positions through the table (``table[slot, s // page] x
+        page + s % page``), keys and values of THOSE rows and no others
+        gathered by token, grouped-query attention over them: what a step
+        reads of K and V does not grow with the cached length beyond
+        ``topk`` rows a slot and layer."""
+        import jax
+        import jax.numpy as jnp
+
+        from distkeras_tpu.models.gqa_moe import attend_dense, select_rows
+
+        b, ps = self.num_slots, self.page_size
+        kvh, hd = self._nh, self._hd
+        topk, di = self._select["topk"], self._select["head_dim"]
+        # no position lies past the context row: its pages, not the bucket
+        table = table[:, : -(-self.max_len // ps)]
+        extent = table.shape[1] * ps
+        page = table[rows, jnp.clip(pos // ps, 0, table.shape[1] - 1)]
+        at = page * ps + pos % ps  # rows of the flat K/V pools
+        part = (jnp.arange(ps * di) // di)[None, :] == (pos % ps)[:, None]
+        visible = jnp.arange(extent)[None, :] <= pos[:, None]
+
+        def stage(blk, moe, p, pm, x, pool):
+            written = []
+
+            def attend(q, k_new, v_new, index):
+                qi, ki_new, wi = index  # (B, J, Di), (B, Di), (B, J)
+                ck, cv, ci = pool
+                with jax.named_scope("attn/index"):
+                    # a slot that is not decoding writes nothing: its
+                    # row's index is out of range, and dropped
+                    mine = jnp.where(
+                        part, jnp.tile(ki_new.astype(ci.dtype), (1, ps)),
+                        ci[page])
+                    ci = ci.at[jnp.where(active, page, ci.shape[0])].set(
+                        mine, mode="drop")
+                    scores = blk.index_scores(
+                        qi[:, None], wi[:, None], ci[table], ps)[:, 0]
+                    idx, valid = select_rows(scores, visible, topk)
+                with jax.named_scope("attn/sparse"):
+                    kv = [
+                        c.at[jnp.where(active, at, c.shape[0])].set(
+                            new.reshape(b, kvh * hd).astype(c.dtype),
+                            mode="drop")
+                        for c, new in ((ck, k_new), (cv, v_new))
+                    ]
+                    phys = table[rows[:, None], idx // ps] * ps + idx % ps
+                    kg, vg = (c[phys].reshape(b, -1, kvh, hd) for c in kv)
+                    written.extend((*kv, ci))
+                    return attend_dense(
+                        q[:, None], kg, vg, valid[:, None])[:, 0]
+
+            x, picks = blk.forward(
+                p, x, pos, None, attend, token_mask=active
+            )
+            return x, tuple(written), picks
+
+        return stage
+
+    def _select_chunk(self, trow, pos):
+        """Grouped pages of a block that selects, one slot's chunk. Under
+        ``attn/index``: the slot's selector rows gathered into its logical
+        row of keys, the chunk's own written into it (and the rows back to
+        their pages) FIRST, then the scores a tile of queries at a time and
+        the exact selection a query. Under ``attn/sparse``: the chunk's keys
+        and values scattered to their pages, the slot's pages gathered,
+        and the visible key blocks folded under the selection's mask
+        (``attend_selected``), at the first of the block's extents that
+        holds the chunk's last position."""
+        import jax
+        import jax.numpy as jnp
+
+        from distkeras_tpu.models.gqa_moe import (
+            attend_selected, select_mask)
+
+        ps = self.page_size
+        kvh, hd = self._nh, self._hd
+        topk, di = self._select["topk"], self._select["head_dim"]
+        cb = pos.shape[0]
+        trow = trow[: -(-self.max_len // ps)]
+        extent = trow.shape[0] * ps
+        fpos = trow[jnp.clip(pos // ps, 0, trow.shape[0] - 1)] * ps \
+            + pos % ps  # (cb,)
+
+        def stage(blk, moe, p, pm, x, pool):
+            written = []
+            extents = [m * topk for m in blk.select_extents
+                       if m * topk < extent]
+
+            def attend(q, k_new, v_new, index):
+                qi, ki_new, wi = index  # (1, cb, J, Di), (1, cb, Di), ..
+                ck, cv, ci = pool
+                with jax.named_scope("attn/index"):
+                    keys = ci[trow].reshape(extent, di).at[pos].set(
+                        ki_new[0].astype(ci.dtype), mode="drop")
+                    ci = ci.at[trow].set(keys.reshape(-1, ps * di))
+
+                def chosen_of(lo, m, te):
+                    with jax.named_scope("attn/index"):
+                        scores = blk.index_scores(
+                            jax.lax.dynamic_slice_in_dim(qi[0], lo, m, 0),
+                            jax.lax.dynamic_slice_in_dim(wi[0], lo, m, 0),
+                            keys[:te])
+                        at = jax.lax.dynamic_slice_in_dim(pos, lo, m, 0)
+                        return select_mask(
+                            scores, jnp.arange(te)[None, :] <= at[:, None],
+                            topk)
+
+                with jax.named_scope("attn/sparse"):
+                    kv = [
+                        c.at[fpos].set(
+                            new[0].reshape(cb, kvh * hd).astype(c.dtype))
+                        for c, new in ((ck, k_new), (cv, v_new))
+                    ]
+                    written.extend((*kv, ci))
+
+                    def keys_of(te):
+                        at = (trow[: -(-te // ps), None] * ps
+                              + jnp.arange(ps)).reshape(-1)[:te]
+                        return tuple(c[at].reshape(te, kvh, hd) for c in kv)
+
+                    return attend_selected(
+                        q[0], keys_of, pos, chosen_of, extent, extents,
+                        blk.key_block)[None]
+
+            x, picks = blk.forward(p, x, pos[None], None, attend)
+            return x, tuple(written), picks
+
+        return stage
+
     def _gqa_chunk(self, pbt: int, where, start, pos):
         """Grouped pages, one slot's chunk (``attend_blocked``: the keys
         a query can see and no others). A full layer: the chunk's keys
@@ -3228,6 +3465,8 @@ class DecodeStepper:
         kvh, hd = self._nh, self._hd
         cb = pos.shape[0]
         trow, rrow, n = where if ring else (where, None, None)
+        if self._select:
+            return self._select_chunk(trow, pos)
         fpos = self._flat_positions(trow, pos, pbt)  # (cb,)
         ridx = (trow[:, None] * ps + jnp.arange(ps)).reshape(-1)
 
@@ -3849,7 +4088,8 @@ class ServingEngine:
             # replies with this error instead of refusing to boot
             self._decode_err = e
         if self._stepper is not None and prefill_chunk == "auto":
-            prefill_chunk = max(16, self._stepper.max_len // 8)
+            prefill_chunk = max(16, min(self._stepper.max_len // 8,
+                                        self._stepper.chunk_cap))
         from distkeras_tpu.serving.resilience import as_shed_gate
 
         # the overload gate rides _batcher_cfg so a supervisor-rebuilt
@@ -5217,6 +5457,8 @@ class ServingEngine:
             if self._stepper._moe_layers:
                 # the expert layers' routing, summed over decode steps
                 out["moe"] = dict(self._stepper.moe_stats)
+            if self._stepper._select:
+                out["select"] = dict(self._stepper.select_stats)
         if self.stream_sender is not None:
             out["streams"] = self.stream_sender.stats()
         out["restarts"] = self._restarts

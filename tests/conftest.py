@@ -13,6 +13,8 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 
+_PINS_THE_LIST_S_LAST_FOUR = (
+    "test_the_benchmark_names_the_four_metrics_for_the_serving_cells")
 _PINS_THE_LIST_S_END = (
     "test_the_benchmark_names_the_six_metrics_for_the_serving_cell_alone")
 
@@ -33,6 +35,18 @@ def pytest_collection_modifyitems(config, items):
         or it.get_closest_marker("e2e") is not None
     ))
     for it in items:
+        if it.name == _PINS_THE_LIST_S_LAST_FOUR:
+            # strict: the `benchmark` PR that repairs the pinned test has
+            # to take this mark away; what the test holds beside its two
+            # pins is held in tests/benchmark/test_benchmark_keye.py
+            it.add_marker(pytest.mark.xfail(strict=True, reason=(
+                "tests/benchmark/test_benchmark_thread_spans.py (PR 37) pins "
+                "BENCHMARK.json's last four per_layer entries to its own "
+                "and their workloads to the four serving cells of its day; "
+                "the contract puts a later PR's metrics at the list's end "
+                "and appends a new cell to the lists it reports (PR 39 did "
+                "both), and only a `benchmark` PR may edit that file "
+                "(PERF.md section 7)")))
         if it.name == _PINS_THE_LIST_S_END:
             it.add_marker(pytest.mark.xfail(strict=False, reason=(
                 "tests/benchmark/test_benchmark_program_spans.py (PR 25) pins "
@@ -85,7 +99,7 @@ def _synthetic_run_has_scoped_ops(request, monkeypatch):
         import os
 
         from benchmark.layer_metrics import (
-            _gqa_ops, _scoped_ops, _shortcut_ops, _thread_spans)
+            _gqa_ops, _scoped_ops, _select_ops, _shortcut_ops, _thread_spans)
 
         # the shortcut layer's two metrics (``_shortcut_ops.py``) read the
         # dense path's scope and the identity picks' counters: a small cut
@@ -98,7 +112,12 @@ def _synthetic_run_has_scoped_ops(request, monkeypatch):
         # the four metrics of the scheduler thread's waits and work (PR 37,
         # ``_thread_spans.py``) read the CPU clocks on every thread's spans,
         # which PR 25's cut lacks: a cut of a traced run of ``serve_backlog``
+        # the four metrics of a block that selects its keys (PR 39,
+        # ``_select_ops.py``) read the ``attn/index`` and ``attn/sparse``
+        # scopes in steps and chunks and the selection's counters: a cut of
+        # a traced run of ``serve_backlog_keye``
         for module, name in ((_scoped_ops, "scoped_ops_small.json"),
+                             (_select_ops, "select_ops_small.json"),
                              (_shortcut_ops, "shortcut_ops_small.json"),
                              (_gqa_ops, "gqa_ops_small.json"),
                              (_thread_spans, "thread_spans_small.json")):

@@ -309,6 +309,70 @@ def test_the_grouped_step_and_chunk_programs_compile_for_v5e(one_chip):
     assert step.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
+    """The stepper's own step program and a 2,048-token chunk program of a
+    block whose keys an indexer selects, at the configuration's widths
+    (keye-vl-2.0-30b-a3b-6l-ep8: hidden 2048, 32 query heads over 4 K/V
+    heads of 128 with a norm a head, an indexer of 16 heads of 64 that picks
+    2,048, experts of 768, top 8 of 128 router outputs, theta 1e7, 32 slots,
+    pages of 16, a context row of 49,152 positions), with what is no width
+    cut so that the CPU holds it: 2 layers, 2 experts held a layer, 512 rows
+    of vocabulary, 4,096 pages. The step gathers 2,048 K and V rows a slot
+    and the selector keys a page to a row of 1,024 values; neither program copies a
+    pool; the compiler's count of their transients fits beside the pools."""
+    import numpy as np
+
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.serving.engine import DecodeStepper
+
+    model = zoo.keye_lm(
+        vocab_size=512, seq_len=49152, hidden_size=2048,
+        num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+        moe_intermediate_size=768, num_experts=128, num_experts_per_tok=8,
+        num_hidden_layers=2,
+        sa_config={"indexer_num_heads": 16, "indexer_head_dim": 64,
+                   "indexer_num_kv_heads": 1, "topk": 2048},
+        rope_theta=1e7, experts_held=[0, 1])
+    model.params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                                model.params)
+    st = DecodeStepper(model, num_slots=32, paged=True, page_size=16,
+                       num_pages=4096, kv_dtype=jnp.bfloat16)
+    assert st.attention.startswith("gather: the rows an indexer selects")
+    assert st._index_packing == 16 and st.chunk_cap == 2048
+    assert [a.shape for a in st._pools[0]] == [
+        (65536, 512), (65536, 512), (4096, 1024)]
+    # 4 x 128 keys and values and a selector key of 64, bfloat16, a layer
+    assert st.kv_bytes_per_token() == 2 * (2048 + 128)
+    pbt = st._max_pages_bucket
+    assert pbt == 4096 and st._step_table_buckets() == [pbt]
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one_chip), tree)
+
+    step = st._build_step_fn_paged(pbt).lower(*shapes((
+        st._params, st._ctx, st._pools, st._lens.copy(),
+        np.zeros(32, bool), st._tables_array(pbt),
+        *st._sampling_args()))).compile()
+    chunk = st._build_chunk_fn_paged(2048, pbt).lower(*shapes((
+        st._params, st._pools, np.zeros((1, 2048), np.int32),
+        st._chunk_where(0, pbt, 0), np.int32(0)))).compile()
+    text = step.as_text()
+    # the selected rows, K and V of each layer; nothing of the K/V row width
+    # at the table's extent
+    assert text.count("bf16[32,2048,512]") >= 4
+    assert "[32,49152,512]" not in text and "[32,65536,512]" not in text
+    assert "bf16[32,3072,1024]" in text  # the selector keys, a page a row
+    for compiled in (step, chunk):
+        for shape in ("[65536,512]", "[4096,1024]"):
+            copies = [ln for ln in compiled.as_text().splitlines()
+                      if " copy(" in ln and shape in ln.split("=")[0]]
+            assert not copies, copies[:3]
+    assert step.memory_analysis().temp_size_in_bytes < 1.0e9
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
 # the latent cell's shapes (kanana-2-30b-a3b-8l: 64 slots, 32 heads, rows of
 # 512 + 64 values padded to 640, 8,960 pages of 16 tokens, a table of 512
 # pages: 128 KB of scalar-prefetched table), the shortcut layer's cell

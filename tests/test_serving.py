@@ -1452,14 +1452,22 @@ def test_the_scheduler_s_iteration_carries_the_window_pool():
     assert req.done and st.window_pages[0] == 0
 
 
+def _keye(**kw):
+    from distkeras_tpu.models import zoo
+
+    return zoo.keye_lm(vocab_size=61, seq_len=256, hidden_size=32, **kw)
+
+
+@pytest.mark.parametrize("block", ["window", "select"])
 @pytest.mark.parametrize("feature", [
     "dense_bank", "speculative", "mesh", "int8", "prefix_store", "fork",
     "swap_out", "swap_in", "role", "solo_generator"])
 def test_what_the_engine_cannot_do_for_the_grouped_block_is_refused_typed(
-        feature, tp_mesh):
-    """Each thing the grouped-query block with window layers cannot do yet
-    is a ``BlockUnsupportedError`` that names it, at construction where a
-    construction argument asks for it."""
+        feature, block, tp_mesh):
+    """Each thing the grouped-query block with window layers, or the one
+    whose keys an indexer selects, cannot do yet is a
+    ``BlockUnsupportedError`` that names it and the block, at construction
+    where a construction argument asks for it."""
     from distkeras_tpu.models.mla_moe import BlockUnsupportedError
     from distkeras_tpu.ops.quantization import quantize_model
     from distkeras_tpu.predictors import CachedSequenceGenerator
@@ -1474,7 +1482,8 @@ def test_what_the_engine_cannot_do_for_the_grouped_block_is_refused_typed(
         "swap_out": "swap-out", "swap_in": "swap-in", "role": "role",
         "solo_generator": "solo cached generators",
     }
-    model = _laguna()
+    model = _laguna() if block == "window" else _keye()
+    what = {"window": "window layers", "select": "an indexer selects"}[block]
     paged = dict(num_slots=2, paged=True, page_size=4, num_pages=40)
     with pytest.raises(BlockUnsupportedError, match=names[feature]) as err:
         if feature == "dense_bank":
@@ -1503,7 +1512,97 @@ def test_what_the_engine_cannot_do_for_the_grouped_block_is_refused_typed(
             else:
                 st.swap_in(1, {"len": 3})
     if feature != "solo_generator":
-        assert "window layers" in str(err.value)
+        assert what in str(err.value)
+
+
+def _selecting_stepper(num_slots=3, num_pages=60, **kw):
+    from distkeras_tpu.serving.engine import DecodeStepper
+
+    return DecodeStepper(_keye(), num_slots=num_slots, paged=True,
+                         page_size=4, num_pages=num_pages, **kw)
+
+
+def test_selector_pages_are_reserved_and_released_with_their_k_v_pages():
+    """One table and one budget: a page of the table is a page of the
+    selector pool too (a third pool a layer of ``num_pages`` pages, four
+    8-value keys a row), so what admission reserves and release frees is
+    counted once, by the one allocator; exhaustion is typed and holds
+    nothing; the bytes a token costs are split by kind."""
+    from distkeras_tpu.serving.scheduler import PoolExhaustedError
+
+    st = _selecting_stepper()
+    assert st._window_alloc is None and st.window_pages is None
+    assert [a.shape for a in st._pools[0]] == [(240, 32), (240, 32), (60, 32)]
+    st.begin_admit(0, np.arange(1, 41) % 61, max_new=20)   # 15 pages
+    st.begin_admit(1, np.arange(1, 121) % 61, max_new=40)  # 40 pages
+    assert st._kv_alloc.pages_in_use == 55
+    with pytest.raises(PoolExhaustedError):
+        st.begin_admit(2, np.arange(1, 30) % 61, max_new=4)  # 9 > 4 left
+    assert st._kv_alloc.pages_in_use == 55 and st._tables[2] == []
+    assert 2 not in st._pending
+    stats = st.paged_stats()
+    assert stats["pages_in_use"] == 55 and "window" not in stats
+    assert stats["bytes_per_token_by_kind"] == {
+        "full": 2 * (2 * 2 * 16 * 4), "index": 2 * 8 * 4}
+    assert stats["bytes_per_token"] == st.kv_bytes_per_token() == 576
+    assert stats["attention"].startswith("gather: the rows an indexer")
+    for slot in range(2):
+        st.release(slot)
+    assert st._kv_alloc.pages_in_use == 0 and st._tables == [[], [], []]
+    st.begin_admit(2, np.arange(1, 30) % 61, max_new=4)  # and it admits now
+    assert st._kv_alloc.pages_in_use == 9
+
+
+def test_the_selection_s_counters_are_on_the_collect_span_and_in_stats():
+    """``keys_cached`` (the cached positions the active slots' queries could
+    see) and ``keys_selected`` (``min(cached, topk)`` a slot) of every decode
+    step, from the host's own lengths: on the ``serving/collect`` span and
+    summed in ``select_stats``; the iteration's page counters as they are."""
+    from distkeras_tpu.serving import engine as engine_mod
+
+    st = _selecting_stepper()
+    seen = []
+
+    class Span:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kw):
+            seen.append((self.name, kw))
+
+    real = engine_mod._span
+    engine_mod._span = lambda name, **kw: Span(name)
+    try:
+        st.admit(0, np.arange(1, 21) % 61, max_new=8)  # 20 positions: > topk
+        st.admit(1, np.arange(1, 5) % 61, max_new=8)   # 4: under topk = 8
+        for _ in range(3):
+            st.step(np.array([True, True, False]))
+    finally:
+        engine_mod._span = real
+    rows = [kw for name, kw in seen
+            if name == "serving/collect" and "keys_cached" in kw]
+    assert [r["keys_cached"] for r in rows] == [24, 26, 28]
+    assert [r["keys_selected"] for r in rows] == [12, 13, 14]
+    assert all("experts_hit" in kw for name, kw in seen
+               if name == "serving/collect" and "keys_cached" not in kw)
+    assert st.select_stats == {"steps": 3, "keys_cached": 78,
+                               "keys_selected": 39}
+    b = ContinuousBatcher(_selecting_stepper(), queue_capacity=8,
+                          prefill_chunk=16)
+    req = b.submit(ServeRequest(np.arange(1, 30) % 61, 3))
+    for _ in range(8):
+        b.step()
+        if req.done:
+            break
+        counts = dict(b._iter_counts)
+    assert counts["pages_total"] == 59 and counts["pages_in_use"] == 8
+    assert "window_pages_total" not in counts
 
 
 def test_the_default_prefix_cache_is_switched_off_and_says_so():

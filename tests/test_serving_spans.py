@@ -129,6 +129,40 @@ def test_pages_in_use_on_the_span_is_the_allocator_s(paged_engine, tmp_path):
     assert paged_engine.stats()["paged"]["pages_in_use"] <= max(held)
 
 
+def test_collect_span_carries_the_selection_s_counters(tmp_path):
+    """A block whose keys an indexer selects: every ``serving/collect`` span
+    of a traced drive carries ``keys_cached`` and ``keys_selected`` beside
+    the routing counters, in the profile as the benchmark's reader finds
+    them, and ``stats()["select"]`` sums the same steps."""
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.serving import ServingEngine
+
+    engine = ServingEngine(
+        zoo.keye_lm(vocab_size=61, seq_len=64, hidden_size=32), num_slots=2,
+        paged=True, page_size=4, prefill_chunk=8)
+    engine.start()
+    try:
+        _generate(engine, 2)  # compiles, off the traced drive
+        before = engine.stats()["select"]
+        _, plain = _traced(tmp_path, lambda: [
+            list(engine.submit(np.arange(1, 20 + i, dtype=np.int32) % 61,
+                               5).result(120)) for i in range(2)])
+        after = engine.stats()["select"]
+    finally:
+        engine.stop()
+    rows = [a for n, _s, _d, _t, a in plain["spans"]
+            if n == "serving/collect" and "keys_cached" in a]
+    assert len(rows) == after["steps"] - before["steps"] == 10
+    assert all("experts_hit" in a for a in rows)
+    # one request at a time: 19..23 and 20..24 cached positions, 8 read
+    assert sorted(a["keys_cached"] for a in rows) == sorted(
+        list(range(19, 24)) + list(range(20, 25)))
+    assert {a["keys_selected"] for a in rows} == {8}
+    assert after["keys_cached"] - before["keys_cached"] == sum(
+        a["keys_cached"] for a in rows)
+    assert after["keys_selected"] - before["keys_selected"] == 80
+
+
 def test_step_span_says_how_the_step_attended(paged_engine, tmp_path):
     """``attention`` on every ``serving/step`` span is the word
     ``stats()["paged"]["attention"]`` gives: the fixture's heads of 16
