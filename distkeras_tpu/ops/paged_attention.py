@@ -12,7 +12,7 @@ time into a double-buffered VMEM block, one asynchronous copy a page,
 the next block in flight while the current one is multiplied and
 folded into a running softmax. A slot's loop ends at ``ceil(length /
 page_size)`` pages; a slot of length 0 copies nothing and returns
-zeros. There is one body a page layout, and the two share the copy
+zeros. There is one body a page layout, and they share the copy
 scaffolding's shape and ``_product``, nothing else:
 
 **Layout ``"kv"``** (``paged_decode_attention``, ``_kernel``): pools
@@ -100,6 +100,46 @@ ONE bfloat16 term against a bfloat16 pool (what ``models.mla_moe._operands``
 gives every product of that block), float32 at ``HIGHEST`` against a float32
 pool; scale, mask, running maximum, normaliser and output are float32.
 
+**Layout ``"index"``** (``paged_index_scores``, ``_index_kernel``; PR 40):
+the selector keys of a block whose queries read the keys an indexer picks
+(``models.gqa_moe``): ONE pool ``(num_pages, rows, 128)``, a page its
+``page_size`` keys of ``Di`` values in order as ``rows`` rows of whole lanes,
+``128 / Di`` keys side by side a row (``index_page_shape``: at 16 keys of 64
+a page is one ``(8, 128)`` bfloat16 tile of 2 KB, the same bytes as the
+gather body's one row of 1,024 values a page). No softmax and no values:
+the body returns the scores themselves, ``(B, T)`` float32, and the exact
+``top_k`` stays in XLA.
+
+*What a block is.* ``block_pages`` pages as copied are ``(block_pages x
+rows, 128)``: row ``r`` of page ``p`` holds keys ``r x 128 / Di ..`` of the
+page. The query ``qi (J, Di)`` goes against each key of a row with zeros
+beside it, as ``index_scores(..., packed)`` builds its parts: ``(128 / Di x
+J, 128) @ block^T -> (128 / Di x J, block rows)``, ``relu``, times ``w``,
+summed over the ``J`` heads of each part: ``(128 / Di, block rows)`` a
+block, written to the block's own place of the output; the one
+transposition from part-major to positions stays in XLA (6 MB a layer at
+the selecting cell's shapes). *What it costs* there (32 slots, 16 heads of
+64, blocks of 64 pages): 128 KB copied and ``2 x 32 x 128 x 512`` = 4.2e6
+operations a block, twice the multiplications the scores need; 75e6 bytes
+a layer at the cell's lengths are 0.09 ms of the v5e's bandwidth and the
+products 0.02 ms of its MXU: the body is bound by the pace at which its
+copies are issued, one of 2 KB a page, some 36,600 a layer: 17 ns a copy
+where every copy site is unrolled, 38 ns at one copy an iteration of a
+loop, 21 ns at ``_INDEX_UNROLL`` = 8 copies an iteration, which is how
+they go (0.76 ms a layer; PERF.md §6, PR 40): wholly unrolled, the
+body's 400 copy sites cost every process that builds the step program
+seconds of tracing, 12 s of a warm set-up. A page is copied once;
+what a slot's last, short block does not copy keeps what the buffer held,
+which only reaches scores past the slot's length (a score is one row's
+alone), and those are left undefined: the step masks by ``visible``
+before ``top_k``. A slot of length 0 copies nothing.
+
+*Precision.* As the gather body's ``index_scores``: the query enters as
+ONE bfloat16 term against a bfloat16 pool, accumulated in float32
+(``models.mla_moe._operands``); float32 at ``HIGHEST`` against a float32
+pool; ``relu``, the weights ``w``, the sum over heads and the output are
+float32. No score is approximated and no key skipped.
+
 ``decode_attention_path`` is the one place that says whether a kernel
 serves a shape (as ``flash_attention.effective_path`` does for the
 trainer's kernel); the engine reads it when it builds its step program.
@@ -132,7 +172,9 @@ def decode_attention_path(layout, head_dim, kv_dtype, mesh=None,
     """``"kernel"`` where a kernel of this module serves the paged decode
     step (:func:`paged_decode_attention` for layout ``"kv"``,
     :func:`paged_latent_attention` for ``"latent"``, the grouped body of
-    :func:`paged_decode_attention` for ``"gqa"``), else ``"gather:
+    :func:`paged_decode_attention` for ``"gqa"``, and for ``"index"``,
+    the selector keys of a block that selects, with ``head_dim`` the
+    selector key's, :func:`paged_index_scores`), else ``"gather:
     <why>"`` — read from what the stepper can see of itself, never from
     a knob or a model's name."""
     if mesh is not None:
@@ -157,6 +199,19 @@ def decode_attention_path(layout, head_dim, kv_dtype, mesh=None,
         if page_size is not None and page_size % _SUBLANES:
             return (f"gather: grouped pages of {page_size} rows are not "
                     f"whole tiles of {_SUBLANES} rows")
+    elif layout == "index":
+        # a page of selector keys is ``page_size x head_dim`` values held
+        # as rows of whole lanes, keys side by side, and a copy starts and
+        # ends on a tile of the pool, as for the latent
+        lanes = _index_lanes(head_dim)
+        if lanes is None:
+            return (f"gather: selector keys of {head_dim} do not tile "
+                    f"rows of {_LANES} lanes")
+        if page_size is not None and page_size * head_dim % (
+                _SUBLANES * lanes):
+            return (f"gather: selector pages of {page_size} keys of "
+                    f"{head_dim} are not whole tiles of {_SUBLANES} rows "
+                    f"of {lanes}")
     else:
         return f"gather: the {layout} page layout has its own stage body"
     if jnp.dtype(kv_dtype) not in (jnp.dtype(jnp.bfloat16),
@@ -662,5 +717,177 @@ def paged_latent_attention(qc, pool, table, lengths, page_size, rank, scale,
         qc, pool, table, lengths, page_size=int(page_size), rank=int(rank),
         scale=float(scale),
         block_pages=min(int(block_pages), max(1, table.shape[1])),
+        interpret=pallas_interpret(),
+    )
+
+
+# ------------------------------------------- the selector keys of an indexer
+
+# pages a block of the selector body: chosen on the v5e inside the selecting
+# cell's step program (32 slots, pages of 16 keys of 64 bfloat16 values: 2 KB,
+# a table of 3,072, 36,600 pages a layer): blocks of 32 / 64 / 128 pages read
+# 0.71 / 0.62 / 0.59 ms a layer with the copies unrolled, and 128 pages
+# double the buffers for 0.4% of the step (PERF.md §6, PR 40)
+INDEX_BLOCK_PAGES = 64
+# the query as ONE bfloat16 term against a bfloat16 pool: what
+# ``GroupedQueryMoEBlock.index_scores`` gives it (``mla_moe._operands``)
+_INDEX_TERMS = 1
+# page copies an iteration of the loop that issues (or awaits) them
+_INDEX_UNROLL = 8
+
+
+def _index_lanes(head_dim):
+    """The width of a row of selector keys in the kernel's pool: whole
+    lanes that whole keys fill (two keys of 64 side by side in 128), None
+    where keys of this size fill no such row."""
+    if head_dim % _LANES == 0:
+        return head_dim
+    return _LANES if _LANES % head_dim == 0 else None
+
+
+def index_page_shape(page_size, head_dim):
+    """``(rows, lanes)`` of a page of selector keys as the kernel's pool
+    holds it, where ``decode_attention_path("index", ...)`` says
+    ``"kernel"``: the page's keys in order, ``lanes // head_dim`` side by
+    side a row (its row-major flattening is the ``page_size x head_dim``
+    values of the gather body's one row a page)."""
+    lanes = _index_lanes(head_dim)
+    return page_size * head_dim // lanes, lanes
+
+
+def _index_kernel(block_pages, pbt, nj, lens_ref, table_ref, q_ref, w_ref,
+                  pool_hbm, o_ref, buf, sems):
+    b = pl.program_id(0)
+    _, rpp, _ = pool_hbm.shape  # rows a page
+    parts = q_ref.shape[1] // nj  # keys a row
+    ps = rpp * parts
+    length = lens_ref[b]
+    npages = (length + ps - 1) // ps
+    nblocks = (npages + block_pages - 1) // block_pages
+
+    def for_block(i, slot, act):
+        """Start (or wait for) the copies of block ``i``'s own pages:
+        ``_INDEX_UNROLL`` at a time in a loop, then the rest one by one
+        (wholly unrolled, some 400 copy sites cost every process that
+        builds the step program seconds of tracing; one copy an
+        iteration is half as fast: PERF.md §6, PR 40)."""
+        def copy(j):
+            page = table_ref[b * pbt + i * block_pages + j]
+            act(pltpu.make_async_copy(
+                pool_hbm.at[page],
+                buf.at[slot, pl.ds(pl.multiple_of(j * rpp, rpp), rpp)],
+                sems.at[slot],
+            ))
+
+        def group(g, carry):
+            for u in range(_INDEX_UNROLL):
+                copy(g * _INDEX_UNROLL + u)
+            return carry
+
+        def one(j, carry):
+            copy(j)
+            return carry
+
+        n = jnp.minimum(block_pages, npages - i * block_pages)
+        whole = n // _INDEX_UNROLL
+        jax.lax.fori_loop(0, whole, group, 0)
+        jax.lax.fori_loop(whole * _INDEX_UNROLL, n, one, 0)
+
+    @pl.when(nblocks > 0)
+    def _():
+        for_block(0, 0, lambda c: c.start())
+
+    q = q_ref[0]  # (parts x J, lanes) float32: head j of part r in r's lanes
+    w = w_ref[0]  # (parts x J, 1) float32
+
+    def block(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < nblocks)
+        def _():
+            for_block(i + 1, 1 - slot, lambda c: c.start())
+
+        for_block(i, slot, lambda c: c.wait())
+        # what a short block does not copy keeps what the buffer held: a
+        # score is one row's alone, and those rows lie past the length
+        dots = _product(q, buf[slot], 1, _INDEX_TERMS)  # (parts x J, rows)
+        s = jnp.maximum(dots, 0.0) * w
+        o_ref[0, i] = jnp.concatenate([
+            jnp.sum(s[r * nj:(r + 1) * nj], axis=0, keepdims=True)
+            for r in range(parts)
+        ], axis=0)  # (parts, rows)
+        return carry
+
+    jax.lax.fori_loop(0, nblocks, block, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
+def _paged_index_scores(qi, w, pool, table, lengths, *, block_pages,
+                        interpret):
+    b, nj, di = qi.shape
+    _, rpp, lanes = pool.shape
+    parts = lanes // di
+    pbt = table.shape[1]
+    pad = -pbt % block_pages  # never read, as above
+    if pad:
+        table = jnp.pad(table, ((0, 0), (0, pad)))
+    nb = (pbt + pad) // block_pages
+    rows = block_pages * rpp
+    # the query against each part of a row with zeros beside it, as
+    # ``index_scores(..., packed)`` builds them
+    qp = jnp.stack([
+        jnp.pad(qi.astype(jnp.float32),
+                ((0, 0), (0, 0), (r * di, (parts - 1 - r) * di)))
+        for r in range(parts)], axis=1).reshape(b, parts * nj, lanes)
+    wp = jnp.tile(w.astype(jnp.float32), (1, parts))[..., None]
+    kernel = functools.partial(_index_kernel, block_pages, pbt + pad, nj)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, parts * nj, lanes), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, parts * nj, 1), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, nb, parts, rows),
+                                   lambda i, *_: (i, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, lanes), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, nb, parts, rows), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="paged_index_scores",
+    )(
+        lengths.astype(jnp.int32), table.astype(jnp.int32).reshape(-1),
+        qp, wp, pool,
+    )
+    # (B, block, part, row) -> position (block x rows + row) x parts + part
+    return jnp.swapaxes(out, -1, -2).reshape(b, -1)[:, : pbt * rpp * parts]
+
+
+def paged_index_scores(qi, w, pool, table, lengths, block_pages=None):
+    """The indexer's scores of one query a slot over the slot's own
+    selector pages where they lie.
+
+    ``qi``: ``(B, J, Di)``, ``w``: ``(B, J)`` (``GroupedQueryMoEBlock.
+    index_inputs``); ``pool``: ``(num_pages, rows, lanes)`` bfloat16 or
+    float32, a page's ``page_size`` selector keys of ``Di`` values in order
+    (:func:`index_page_shape`); ``table``, ``lengths``: as for
+    :func:`paged_decode_attention`. Returns ``(B, pages x page_size)``
+    float32: ``sum_j w[b, j] relu(qi[b, j] . key[b, s])`` at positions ``s <
+    lengths[b]``, as ``index_scores`` gives it over the gathered pages;
+    what lies past a slot's length is not defined (whatever the buffer
+    held: the caller masks it)."""
+    return _paged_index_scores(
+        qi, w, pool, table, lengths,
+        block_pages=min(int(block_pages or INDEX_BLOCK_PAGES),
+                        max(1, table.shape[1])),
         interpret=pallas_interpret(),
     )

@@ -783,11 +783,19 @@ class DecodeStepper:
                 self.layout, hd, self._gen.kv_dtype, self.mesh,
                 self.page_size,
             )
+            # how the decode step scores a selecting block's selector keys:
+            # "kernel" (each slot's own selector pages, in place) or
+            # "gather: <why>"; None for a block that selects nothing
+            self.selector = None
             if self._select:
                 # the kernels walk whole pages; the selected rows lie on
                 # nearly as many pages as there are rows
                 self.attention = (
                     "gather: the rows an indexer selects, by token")
+                self.selector = decode_attention_path(
+                    "index", self._select["head_dim"], self._gen.kv_dtype,
+                    self.mesh, self.page_size,
+                )
             # a step program that costs the same at every table width is
             # compiled once, at the widest
             self._one_step_extent = (
@@ -982,6 +990,7 @@ class DecodeStepper:
                 for kind in ("full", "index")
             }
             out["select"] = dict(self._select)
+            out["selector"] = self.selector
         # mesh geometry: the pool's TOTAL bytes are mesh-invariant;
         # what changes with tp:N is how many land per shard
         out["mesh"] = self.mesh_spec
@@ -1137,11 +1146,14 @@ class DecodeStepper:
                 else "full"
             for j, a in enumerate(arrs):
                 index = j == 2 and bool(getattr(blk, "select", None))
-                if kind in (None, "index" if index else mine):
-                    total += (
-                        int(np.prod(a.shape[1:] if a.ndim == 2
-                                    else a.shape[2:])) * a.dtype.itemsize
-                        // (self._index_packing if index else 1))
+                if kind not in (None, "index" if index else mine):
+                    continue
+                if index:  # a page's keys under one leading index
+                    values = int(np.prod(a.shape[1:])) // self.page_size
+                else:  # a row a token
+                    values = int(np.prod(
+                        a.shape[1:] if a.ndim == 2 else a.shape[2:]))
+                total += values * a.dtype.itemsize
         return total
 
     # -- the blocks that only the paged engine serves --------------------------
@@ -1291,16 +1303,18 @@ class DecodeStepper:
         A block that selects has a THIRD pool, its selector keys: one
         key of ``select["head_dim"]`` values a token, in the pages of the
         same table and budget as the keys and values (reserved, released
-        and counted with them). A row of it holds a page's keys side by
-        side (``_index_packing``), ``(pages, page_size x Di)``: at 64
-        values a key a token then costs its 128 bytes, and not a 128-lane
-        row of 256, and a page is ONE row to gather."""
+        and counted with them). A page's keys lie side by side in the
+        pool's leading index (``_index_page``): ``(pages, page_size x
+        Di)``, or where the step's kernel reads them in place ``(pages,
+        page_size x Di / 128, 128)``, the same values in the same order
+        as whole tiles: at 64 values a key a token costs its 128 bytes,
+        and not a 128-lane row of 256, a page is ONE index to gather, and
+        one tile-aligned copy of the kernel's."""
         import jax.numpy as jnp
 
         from distkeras_tpu.serving.paging import PageAllocator
 
         ps = self.page_size
-        pack = self._index_packing
         window_pages = 0
         if self._window is not None:
             self._ring = -(-self._window // ps) + 1
@@ -1317,22 +1331,28 @@ class DecodeStepper:
                 )
                 for _ in range(2)
             ) + ((
-                jnp.zeros(
-                    (num_pages * ps // pack,
-                     pack * blk.select["head_dim"]),
-                    self._gen.kv_dtype),
+                jnp.zeros((num_pages, *self._index_page),
+                          self._gen.kv_dtype),
             ) if blk.select else ())
             for blk in self._gen._blocks
         ]
 
     @property
-    def _index_packing(self) -> int:
-        """Selector keys a row of their pool holds: a page's. A gather out
-        of the device's memory costs by the row, 10 to 16 ns each whatever
-        its size up to a kilobyte (PERF.md, PR 39), and the step gathers
-        every page a slot holds: a page a row is an eighth of the rows of
-        two keys a row."""
-        return self.page_size if self._select else 1
+    def _index_page(self) -> tuple:
+        """A page of the selector pool as it is held, the ``page_size x
+        Di`` values of its keys in order: one row where the step gathers
+        them (a gather out of the device's memory costs by the row, 10 to
+        23 ns each up to 2 KB, PERF.md, PR 39: a page a row is an eighth
+        of the rows of two keys a row), rows of 128 lanes that are whole
+        tiles where ``paged_index_scores`` copies a slot's own pages and
+        gathers nothing (``self.selector == "kernel"``, PR 40). The chunk
+        gathers one slot's pages by the leading index either way."""
+        from distkeras_tpu.ops.paged_attention import index_page_shape
+
+        di = self._select["head_dim"]
+        if self.selector == "kernel":
+            return index_page_shape(self.page_size, di)
+        return (self.page_size * di,)
 
     # -- the latent-attention block ------------------------------------------
 
@@ -3314,9 +3334,13 @@ class DecodeStepper:
     def _select_rows(self, table, rows, pos, active):
         """Grouped pages of a block that selects, one token a slot. Under
         ``attn/index``: the token's selector key written into its part of
-        its page's row of the selector pool, every slot's selector rows
-        gathered at the table's extent (a page a row, as they lie), the
-        scores, and the exact ``topk`` positions. Under ``attn/sparse``:
+        its page of the selector pool, then the scores of every cached
+        position: where ``self.selector == "kernel"`` by
+        ``paged_index_scores`` over each slot's OWN selector pages where
+        they lie, to its own length (no gathered copy; PR 40), otherwise
+        over every slot's selector rows gathered at the table's extent (a
+        page a row, as they lie); then the exact ``topk`` positions of
+        what a slot can see. Under ``attn/sparse``:
         the token's key and value written, the physical rows of the
         selected positions through the table (``table[slot, s // page] x
         page + s % page``), keys and values of THOSE rows and no others
@@ -3327,10 +3351,13 @@ class DecodeStepper:
         import jax.numpy as jnp
 
         from distkeras_tpu.models.gqa_moe import attend_dense, select_rows
+        from distkeras_tpu.ops.paged_attention import paged_index_scores
 
         b, ps = self.num_slots, self.page_size
         kvh, hd = self._nh, self._hd
         topk, di = self._select["topk"], self._select["head_dim"]
+        in_place = self.selector == "kernel"
+        lengths = jnp.where(active, pos + 1, 0)
         # no position lies past the context row: its pages, not the bucket
         table = table[:, : -(-self.max_len // ps)]
         extent = table.shape[1] * ps
@@ -3350,11 +3377,15 @@ class DecodeStepper:
                     # row's index is out of range, and dropped
                     mine = jnp.where(
                         part, jnp.tile(ki_new.astype(ci.dtype), (1, ps)),
-                        ci[page])
+                        ci[page].reshape(b, -1))
                     ci = ci.at[jnp.where(active, page, ci.shape[0])].set(
-                        mine, mode="drop")
-                    scores = blk.index_scores(
-                        qi[:, None], wi[:, None], ci[table], ps)[:, 0]
+                        mine.reshape(b, *ci.shape[1:]), mode="drop")
+                    if in_place:
+                        scores = paged_index_scores(
+                            qi, wi, ci, table, lengths)
+                    else:
+                        scores = blk.index_scores(
+                            qi[:, None], wi[:, None], ci[table], ps)[:, 0]
                     idx, valid = select_rows(scores, visible, topk)
                 with jax.named_scope("attn/sparse"):
                     kv = [
@@ -3412,7 +3443,7 @@ class DecodeStepper:
                 with jax.named_scope("attn/index"):
                     keys = ci[trow].reshape(extent, di).at[pos].set(
                         ki_new[0].astype(ci.dtype), mode="drop")
-                    ci = ci.at[trow].set(keys.reshape(-1, ps * di))
+                    ci = ci.at[trow].set(keys.reshape(-1, *ci.shape[1:]))
 
                 def chosen_of(lo, m, te):
                     with jax.named_scope("attn/index"):
@@ -3584,6 +3615,8 @@ class DecodeStepper:
         ) as span:
             if self.paged:
                 span.set_metadata(attention=self.attention)
+                if self.selector:
+                    span.set_metadata(selector=self.selector)
                 self._ctx, self._pools, toks = fn(
                     self._params, self._ctx, self._pools, *host
                 )

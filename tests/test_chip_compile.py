@@ -318,10 +318,14 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
     pages of 16, a context row of 49,152 positions), with what is no width
     cut so that the CPU holds it: 2 layers, 2 experts held a layer, 512 rows
     of vocabulary, 4,096 pages. The step gathers 2,048 K and V rows a slot
-    and the selector keys a page to a row of 1,024 values; neither program copies a
-    pool; the compiler's count of their transients fits beside the pools."""
+    and scores the selector keys where their pages lie (``paged_index_scores``
+    a layer, under ``attn/index``: no gather of the selector rows at the
+    table's extent); a page of the selector pool is a tile of 8 rows of two
+    keys; neither program copies or transposes a pool; the compiler's count
+    of their transients fits beside the pools."""
     import numpy as np
 
+    import distkeras_tpu.ops.paged_attention as pa
     from distkeras_tpu.models import zoo
     from distkeras_tpu.serving.engine import DecodeStepper
 
@@ -338,9 +342,10 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
     st = DecodeStepper(model, num_slots=32, paged=True, page_size=16,
                        num_pages=4096, kv_dtype=jnp.bfloat16)
     assert st.attention.startswith("gather: the rows an indexer selects")
-    assert st._index_packing == 16 and st.chunk_cap == 2048
+    assert st.selector == "kernel" and st.paged_stats()["selector"] == "kernel"
+    assert st._index_page == (8, 128) and st.chunk_cap == 2048
     assert [a.shape for a in st._pools[0]] == [
-        (65536, 512), (65536, 512), (4096, 1024)]
+        (65536, 512), (65536, 512), (4096, 8, 128)]
     # 4 x 128 keys and values and a selector key of 64, bfloat16, a layer
     assert st.kv_bytes_per_token() == 2 * (2048 + 128)
     pbt = st._max_pages_bucket
@@ -351,24 +356,39 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                            sharding=one_chip), tree)
 
-    step = st._build_step_fn_paged(pbt).lower(*shapes((
-        st._params, st._ctx, st._pools, st._lens.copy(),
-        np.zeros(32, bool), st._tables_array(pbt),
-        *st._sampling_args()))).compile()
-    chunk = st._build_chunk_fn_paged(2048, pbt).lower(*shapes((
-        st._params, st._pools, np.zeros((1, 2048), np.int32),
-        st._chunk_where(0, pbt, 0), np.int32(0)))).compile()
+    real = pa.pallas_interpret
+    pa.pallas_interpret = lambda: False  # compile the kernel, as the chip
+    try:
+        step = st._build_step_fn_paged(pbt).lower(*shapes((
+            st._params, st._ctx, st._pools, st._lens.copy(),
+            np.zeros(32, bool), st._tables_array(pbt),
+            *st._sampling_args()))).compile()
+        chunk = st._build_chunk_fn_paged(2048, pbt).lower(*shapes((
+            st._params, st._pools, np.zeros((1, 2048), np.int32),
+            st._chunk_where(0, pbt, 0), np.int32(0)))).compile()
+    finally:
+        pa.pallas_interpret = real
     text = step.as_text()
     # the selected rows, K and V of each layer; nothing of the K/V row width
     # at the table's extent
     assert text.count("bf16[32,2048,512]") >= 4
     assert "[32,49152,512]" not in text and "[32,65536,512]" not in text
-    assert "bf16[32,3072,1024]" in text  # the selector keys, a page a row
+    # the selector keys: a kernel call a layer under the scope that
+    # ``index_decode_roofline`` reads, and nothing of them at the table's
+    # extent (the gather body's ``ci[table]`` and its packed products)
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "paged_index_scores" in ln]
+    assert len(calls) == 2, calls
+    for call in calls:
+        assert "/attn/index/" in call.split('op_name="')[1].split('"')[0]
+    for gone in ("[32,3072,1024]", "[32,3072,8,128]", "[32,24576,128]"):
+        assert gone not in text, gone
     for compiled in (step, chunk):
-        for shape in ("[65536,512]", "[4096,1024]"):
-            copies = [ln for ln in compiled.as_text().splitlines()
-                      if " copy(" in ln and shape in ln.split("=")[0]]
-            assert not copies, copies[:3]
+        for shape in ("[65536,512]", "[4096,8,128]", "[4096,1024]"):
+            moved = [ln for ln in compiled.as_text().splitlines()
+                     if (" copy(" in ln or " transpose(" in ln)
+                     and shape in ln.split("=")[0]]
+            assert not moved, moved[:3]
     assert step.memory_analysis().temp_size_in_bytes < 1.0e9
     assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
 
@@ -442,3 +462,86 @@ def test_latent_pages_of_four_rows_are_refused_by_mosaic(one_chip):
     with pytest.raises(Exception, match="aligned to tiling"):
         _latent_step(one_chip, 8, 4, 40, 32, 4, 64, 16,
                      jnp.bfloat16).compile()
+
+
+# the selecting cell's shapes (keye-vl-2.0-30b-a3b-6l-ep8: 32 slots, an
+# indexer of 16 heads of 64, 47,616 pages of 16 tokens, a table of 3,072
+# pages: 393 KB of scalar-prefetched table; a page of selector keys one tile
+# of 8 rows of two keys), and a float32 pool
+INDEX_SHAPES = [
+    pytest.param(32, 16, 64, (8, 128), 47616, 3072, jnp.bfloat16,
+                 id="keye-bf16"),
+    pytest.param(8, 4, 64, (8, 128), 64, 16, jnp.float32, id="f32-pool"),
+]
+
+
+def _index_step(one_chip, b, nj, di, page, pages, pbt, dtype):
+    from distkeras_tpu.ops.paged_attention import (
+        INDEX_BLOCK_PAGES,
+        _paged_index_scores,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(qi, w, new, pool, at, table, lengths):
+        # the token's page read, changed and written back, as the step does
+        pool = pool.at[at].set((pool[at] + new.astype(pool.dtype)))
+        with jax.named_scope("attn/index"):
+            scores = _paged_index_scores(
+                qi, w, pool, table, lengths,
+                block_pages=min(INDEX_BLOCK_PAGES, pbt), interpret=False)
+        return scores, pool
+
+    idx = s((b,), jnp.int32)
+    return jax.jit(step, donate_argnums=(3,)).lower(
+        s((b, nj, di), jnp.float32), s((b, nj), jnp.float32),
+        s((b, *page), jnp.float32), s((pages, *page), dtype), idx,
+        s((b, pbt), jnp.int32), idx,
+    )
+
+
+@pytest.mark.parametrize("b,nj,di,page,pages,pbt,dtype", INDEX_SHAPES)
+def test_paged_index_scores_compiles_for_v5e(
+    one_chip, b, nj, di, page, pages, pbt, dtype
+):
+    """The selecting step's page write, then the kernel over the written
+    selector pool: the module holds the kernel under the ``attn/index``
+    scope that ``index_decode_roofline`` reads its operations by, scores
+    of the table's whole extent come back, and the pool is neither copied
+    nor transposed."""
+    compiled = _index_step(
+        one_chip, b, nj, di, page, pages, pbt, dtype).compile()
+    text = compiled.as_text()
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert "/attn/index/" in call.split('op_name="')[1].split('"')[0], call
+    assert f"f32[{b},{pbt * 16}]" in text  # (B, T) scores
+    pool_shape = f"[{pages},{page[0]},{page[1]}]"
+    moved = [ln for ln in text.splitlines()
+             if (" copy(" in ln or " transpose(" in ln)
+             and pool_shape in ln.split("=")[0]]
+    assert not moved, moved
+
+
+@pytest.mark.parametrize("page,di,page_size", [
+    pytest.param((4, 128), 64, 8, id="pages-of-four-rows"),
+    pytest.param((1, 1024), 64, 16, id="a-page-a-row-as-the-gather-holds-it"),
+])
+def test_selector_pages_off_the_tiling_are_refused_by_mosaic(
+        one_chip, page, di, page_size):
+    """Why the selector pool is held ``(pages, 8, 128)`` where the kernel
+    reads it, and why ``decode_attention_path`` keeps smaller pages on the
+    gather body: Mosaic copies whole tiles, and neither half a tile nor the
+    gather body's one row of 1,024 values a page is one."""
+    from distkeras_tpu.ops.paged_attention import (
+        decode_attention_path,
+        index_page_shape,
+    )
+
+    assert index_page_shape(16, 64) == (8, 128)
+    if page_size == 8:
+        assert decode_attention_path(
+            "index", di, jnp.bfloat16, None, page_size).startswith("gather")
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _index_step(one_chip, 8, 4, di, page, 64, 16,
+                    jnp.bfloat16).compile()

@@ -45,6 +45,15 @@ CONFIG = {
     "assumed": {"initializer_range": 0.02},
 }
 
+# the same with selector keys of 64: on pages of 16 a page of them is a tile
+# of 8 rows of two keys, which ``paged_index_scores`` reads in place
+KERNEL_CONFIG = {
+    **CONFIG,
+    "sa_config": {**CONFIG["sa_config"], "indexer_head_dim": 64},
+}
+# how the step scores the selector keys -> (configuration, page size)
+SELECTORS = {"gather": (CONFIG, 4), "kernel": (KERNEL_CONFIG, 16)}
+
 # float32 weights and a float32 cache on both sides, every product at
 # precision HIGHEST (the CPU's float32 either way): logits of size 0.4 read
 # 1e-7 to 4e-7 apart; a K/V cache rounded to float16 moves them by 1e-5 and
@@ -59,9 +68,18 @@ def fam():
 
 @pytest.fixture(scope="module")
 def tiny(fam):
+    return _seeded(fam, CONFIG)
+
+
+@pytest.fixture(scope="module")
+def tiny_kernel(fam):
+    return _seeded(fam, KERNEL_CONFIG)
+
+
+def _seeded(fam, config):
     """(widths, the seeded bfloat16 weights, the same values as float32 with
     gains and shifts that are not 1 and 0, so that the norms are seen)."""
-    w = fam.widths(CONFIG)
+    w = fam.widths(config)
     weights = fam.make_weights(w, 7)
     keys = iter(jax.random.split(jax.random.PRNGKey(11), 64))
 
@@ -185,13 +203,14 @@ def test_selected_attention_is_dense_attention_under_the_selection_s_mask():
         np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
 
 
-def _stepper_logits(model, prompt, n_new, kv_dtype, chunk, round_selector=None):
+def _stepper_logits(model, prompt, n_new, kv_dtype, chunk, round_selector=None,
+                    page_size=4):
     """Prefill ``prompt`` in chunks of ``chunk`` and decode ``n_new`` tokens
     through the paged stepper; the logits of every decode step, read off the
     step program itself, and the stepper. ``round_selector``: a dtype the
     selector pools are rounded through once the prompt is prefilled."""
-    st = DecodeStepper(model, num_slots=3, paged=True, page_size=4,
-                       num_pages=60, kv_dtype=kv_dtype)
+    st = DecodeStepper(model, num_slots=3, paged=True, page_size=page_size,
+                       num_pages=240 // page_size, kv_dtype=kv_dtype)
     assert st.attention.startswith("gather: the rows an indexer selects")
     seen = []
     norm, real = st._gen._final_ln, st._gen._final_ln.apply
@@ -224,33 +243,42 @@ def _stepper_logits(model, prompt, n_new, kv_dtype, chunk, round_selector=None):
     return chunks, toks, np.stack([h[slot] for h in seen]) @ head, st
 
 
+@pytest.mark.parametrize("selector", ["gather", "kernel"])
 @pytest.mark.parametrize("chunk", [16, 5, 64], ids=[
     "whole-pages", "odd-chunks", "one-chunk"])
 def test_chunked_prefill_then_paged_decode_gives_the_reference_s_logits(
-        fam, tiny, chunk):
+        fam, tiny, tiny_kernel, chunk, selector):
     """Logits, not tokens: every decode step's logits against the
     reference's full forward over the prompt and the served tokens, for a
     request of 65 positions, eight times ``topk``: every chunk after the
-    first and every step selects, through the selector pool's packed rows
-    (a page a row: 4 keys of 8 values), in chunks that are and are not
-    whole pages. The same comparison fails from a K/V cache rounded to
+    first and every step selects, in chunks that are and are not whole
+    pages. ``gather``: through the selector pool's packed rows (a page a
+    row: 4 keys of 8 values) gathered at the table's extent; ``kernel``:
+    selector keys of 64 on pages of 16, a page a tile of 8 rows of two
+    keys, scored where the pages lie by ``paged_index_scores``
+    (interpreted). The same comparison fails from a K/V cache rounded to
     float16, and from a selector cache rounded to 8 bits (float8, 3 bits of
     mantissa): float16 moves no score of these 36 selections across its
     threshold (the comparison sees the selector's precision only through a
     changed pick, and then by 0.05: one key of 8 is another)."""
-    w, weights, f32 = tiny
+    w, weights, f32 = tiny if selector == "gather" else tiny_kernel
+    ps = SELECTORS[selector][1]
     prompt = np.random.default_rng(1).integers(0, w["vocab"], 53)
     with jax.default_matmul_precision("highest"):
         chunks, toks, got, st = _stepper_logits(
-            _model(fam, w, f32), prompt, 12, None, chunk)
+            _model(fam, w, f32), prompt, 12, None, chunk, page_size=ps)
         _, toks16, got16, _ = _stepper_logits(
             _model(fam, w, f32), prompt, 12, None, chunk,
-            round_selector=jnp.float8_e4m3fn)
+            round_selector=jnp.float8_e4m3fn, page_size=ps)
         _, tokskv, gotkv, _ = _stepper_logits(
-            _model(fam, w, f32), prompt, 12, jnp.float16, chunk)
+            _model(fam, w, f32), prompt, 12, jnp.float16, chunk, page_size=ps)
     assert chunks == -(-52 // chunk)
-    assert st.layout == "gqa" and st._index_packing == 4
-    assert st._kv_alloc.pages_in_use == -(-65 // 4)
+    assert st.selector.startswith(selector)
+    assert st.paged_stats()["selector"] == st.selector
+    assert st.layout == "gqa"
+    assert st._index_page == {"gather": (32,), "kernel": (8, 128)}[selector]
+    assert st._pools[0][2].shape == (240 // ps, *st._index_page)
+    assert st._kv_alloc.pages_in_use == -(-65 // ps)
     seq = np.concatenate([prompt, toks])
     ref = _reference_logits(fam, w, weights, seq)[len(prompt) - 1:-1]
     np.testing.assert_allclose(got, ref, atol=LOGIT_TOL, rtol=0)
@@ -312,7 +340,7 @@ def test_the_lowered_step_gathers_topk_rows_a_slot_whatever_the_table(
     st = DecodeStepper(model, num_slots=3, paged=True, page_size=2,
                        num_pages=160)
     pbt = st._max_pages_bucket
-    assert pbt == max_len // 2 and st._index_packing == 2
+    assert pbt == max_len // 2 and st._index_page == (16,)
     text = st._build_step_fn_paged(pbt).lower(
         st._params, st._ctx, st._pools, st._lens.copy(), np.zeros(3, bool),
         st._tables_array(pbt), *st._sampling_args()).as_text()
@@ -323,6 +351,64 @@ def test_the_lowered_step_gathers_topk_rows_a_slot_whatever_the_table(
     assert len(kv_rows) == 2 * 3  # K and V, a layer
     assert f"3x{max_len}x32x" not in text.replace("x32xf32", "x32x")
     assert f"3x{max_len // 2}x16" in gathers  # selector rows, a page each
+
+
+def test_the_lowered_step_of_a_kernel_selector_gathers_no_selector_rows(
+        fam, tiny_kernel):
+    """Where the selector's path is ``"kernel"`` the step program holds the
+    kernel's call a layer under ``attn/index`` and no gather of selector
+    rows at the table's extent, as one row a page or as the pool holds a
+    page; the K and V rows it gathers are still ``topk`` a slot; the page
+    RMW of the token's own selector key is the one gather of the pool."""
+    w, _, f32 = tiny_kernel
+    model = fam.build_program_model({**w, "seq": 256}, f32, {})
+    st = DecodeStepper(model, num_slots=3, paged=True, page_size=16,
+                       num_pages=60)
+    pbt = st._max_pages_bucket
+    assert st.selector == "kernel" and pbt == 16
+    assert st._pools[0][2].shape == (60, 8, 128)
+    text = st._build_step_fn_paged(pbt).lower(
+        st._params, st._ctx, st._pools, st._lens.copy(), np.zeros(3, bool),
+        st._tables_array(pbt), *st._sampling_args()).as_text(debug_info=True)
+    gathers = re.findall(r'"?stablehlo\.gather"?.*-> tensor<([0-9x]+)xf32>',
+                         text)
+    assert gathers.count("3x8x32") == 2 * 3  # K and V rows, a layer
+    assert gathers.count("3x8x128") == 3  # the token's page, a layer
+    for gone in ("3x16x1024", "3x16x8x128", "3x128x128"):
+        assert gone not in gathers, gone
+    assert text.count("paged_index_scores") >= 3
+    assert re.search(r"attn/index[^\n]*paged_index_scores", text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_the_step_s_selection_is_select_mask_s_on_the_kernel_s_scores(dtype):
+    """``select_rows`` over what ``paged_index_scores`` returns, NaN past a
+    slot's length included, picks exactly the keys ``select_mask`` picks
+    from the same scores: ``visible`` masks what the kernel leaves
+    undefined before ``top_k`` sees it."""
+    from distkeras_tpu.ops.paged_attention import paged_index_scores
+
+    rng = np.random.default_rng(3)
+    ps, di, nj, k = 16, 64, 2, 24
+    lengths = np.array([5 * ps + 3, 20, 0, 7 * ps], np.int32)
+    table = np.zeros((4, 8), np.int32)
+    free = iter(rng.permutation(np.arange(1, 32)))
+    for i, n in enumerate(-(-lengths // ps)):
+        table[i, :n] = [next(free) for _ in range(n)]
+    pool = rng.normal(size=(32, 8, 128)).astype(np.float32)
+    pool[0] = np.nan  # the null page, which every unheld entry names
+    scores = paged_index_scores(
+        rng.normal(size=(4, nj, di)).astype(np.float32),
+        rng.normal(size=(4, nj)).astype(np.float32),
+        jnp.asarray(pool, dtype), table, lengths)
+    visible = jnp.arange(8 * ps)[None, :] < lengths[:, None]
+    idx, valid = gqa_moe.select_rows(scores, visible, k)
+    mask = np.asarray(gqa_moe.select_mask(scores, visible, k))
+    assert mask.sum(-1).tolist() == [k, 20, 0, k]
+    for i in range(4):
+        got = np.asarray(idx[i])[np.asarray(valid[i])]
+        assert sorted(got) == list(np.nonzero(mask[i])[0]), i
 
 
 def test_the_experts_shares_add_up_to_the_whole_layer(fam, tiny):
@@ -404,20 +490,24 @@ def test_the_scopes_are_in_apply_chunk_and_step_alike(fam, tiny):
         assert "moe/shared" not in text and "attn/full" not in text
 
 
-def test_the_serving_engine_serves_the_reference_s_tokens(fam, tiny, tmp_path):
+@pytest.mark.parametrize("selector", ["gather", "kernel"])
+def test_the_serving_engine_serves_the_reference_s_tokens(
+        fam, tiny, tiny_kernel, tmp_path, selector):
     """Through ``quantize_model(bits=16)``, a bundle and
     ``ServingEngine.from_bundle(paged=True)``: concurrent requests several
     ``topk`` long, prefill in chunks beside decode, greedy; every served
     token's reference logit against the reference's best; the selection's
-    and the routing's counters."""
+    and the routing's counters. ``gather``: selector keys of 8 on pages of
+    8, gathered; ``kernel``: keys of 64 on pages of 16, scored in place."""
     from distkeras_tpu.utils.serialization import save_serving_bundle
 
-    w, weights, f32 = tiny
+    w, weights, f32 = tiny if selector == "gather" else tiny_kernel
+    ps = {"gather": 8, "kernel": 16}[selector]
     model = quantize_model(_model(fam, w, weights), bits=16)
     path = str(tmp_path / "tiny.dkt")
     save_serving_bundle(path, model)
     eng = ServingEngine.from_bundle(
-        path, num_slots=4, paged=True, page_size=8, num_pages=120,
+        path, num_slots=4, paged=True, page_size=ps, num_pages=960 // ps,
         prefill_chunk=16)
     eng._stepper.warmup()
     eng._stepper.warm_prefill_buckets()
@@ -438,7 +528,10 @@ def test_the_serving_engine_serves_the_reference_s_tokens(fam, tiny, tmp_path):
     assert health["status"] == "serving" and stats["restarts"] == 0
     paged = stats["paged"]
     assert paged["layout"] == "gqa" and paged["pages_in_use"] == 0
-    assert paged["bytes_per_token_by_kind"]["index"] == 3 * 32
+    assert paged["selector"].startswith(selector)
+    assert paged["attention"].startswith("gather: the rows an indexer")
+    assert paged["bytes_per_token_by_kind"]["index"] == (
+        3 * 4 * w["index_dim"])
     sel = stats["select"]
     assert sel["steps"] == stats["moe"]["steps"] > 0
     assert 8 * sel["steps"] <= sel["keys_selected"] < sel["keys_cached"]

@@ -10,6 +10,13 @@ differ by the order of float32 accumulation alone; the tolerance is that
 bound and nothing wider: a ``q`` rounded to one bfloat16 term would miss
 it by two orders of magnitude.
 
+``paged_index_scores`` (the selector keys of a block that selects: a page's
+keys side by side in whole tiles, no softmax) is held to
+``GroupedQueryMoEBlock.index_scores`` over the gathered pages, the step's
+gather body, at every position within a slot's length: to the order of the
+float32 sums (both round the query to one bfloat16 term against a bfloat16
+pool); what lies past a length is not compared, and no page past it is read.
+
 ``paged_latent_attention`` (the latent layout: one array of rows that
 are keys and values, no head axis) is held the same way to the absorbed
 attention over the gathered pages, ``models.mla_moe.attend_absorbed``'s
@@ -24,11 +31,15 @@ import pytest
 
 import jax.numpy as jnp
 
+from distkeras_tpu.models.gqa_moe import GroupedQueryMoEBlock
 from distkeras_tpu.ops.paged_attention import (
     BLOCK_PAGES,
+    INDEX_BLOCK_PAGES,
     LATENT_BLOCK_PAGES,
     decode_attention_path,
+    index_page_shape,
     paged_decode_attention,
+    paged_index_scores,
     paged_latent_attention,
 )
 
@@ -200,9 +211,8 @@ def _check_latent(qc, pool, table, lengths, block_pages):
     return got
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16", "f32"])
-@pytest.mark.parametrize("lengths,pbt,block_pages", [
+# (lengths, the table's pages, pages a block; None: the body's default)
+RAGGED = pytest.mark.parametrize("lengths,pbt,block_pages", [
     # a page's edges, a slot that is not decoding, the table's full extent
     ([1, 15, 16, 17, 0, 8 * PS], 8, 4),
     # a block's edges (4 pages), and ragged slots ending inside a block
@@ -210,8 +220,13 @@ def _check_latent(qc, pool, table, lengths, block_pages):
     # a table far wider than the longest slot, not a whole number of blocks
     ([2 * PS + 3, 7, 0, PS], 37, 8),
     # a table narrower than the default block: the block clamps to it
-    ([3 * PS - 1, 7], 3, LATENT_BLOCK_PAGES),
+    ([3 * PS - 1, 7], 3, None),
 ], ids=["page-edges", "block-edges", "wide-table", "narrow-table"])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@RAGGED
 def test_latent_kernel_is_absorbed_attention_over_the_gathered_pages(
         lengths, pbt, block_pages, dtype):
     rng = np.random.default_rng(len(lengths) + pbt)
@@ -220,7 +235,8 @@ def test_latent_kernel_is_absorbed_attention_over_the_gathered_pages(
     table = _latent_tables(rng, lengths, pbt, num_pages)
     qc = rng.normal(size=(len(lengths), 4, WIDTH)).astype(np.float32)
     pool = _latent_pool(rng, num_pages, dtype)
-    got = _check_latent(qc, pool, table, lengths, block_pages)
+    got = _check_latent(qc, pool, table, lengths,
+                        block_pages or LATENT_BLOCK_PAGES)
     assert not got[lengths == 0].any()
 
 
@@ -245,6 +261,128 @@ def test_latent_kernel_never_reads_pages_past_a_slot_s_length(dtype):
         table[i, n:] = np.setdiff1d(np.arange(num_pages), held)[0]
     qc = rng.normal(size=(len(lengths), 2, WIDTH)).astype(np.float32)
     _check_latent(qc, pool, table, lengths, block_pages=4)
+
+
+# ------------------------------------------- the selector keys of an indexer
+
+NJ = 4  # the indexer's heads
+
+
+def _index_pool(rng, num_pages, di, dtype, ps=PS):
+    """The selector pool as the kernel holds it, ``(pages, rows, lanes)``;
+    its row-major flattening a page is the gather body's one row a page."""
+    flat = jnp.asarray(rng.normal(size=(num_pages, ps * di)), dtype)
+    return flat.reshape(num_pages, *index_page_shape(ps, di))
+
+
+def _check_index(qi, w, pool, table, lengths, block_pages, ps=PS):
+    """The kernel against ``index_scores`` over the pages gathered at the
+    table's extent, where a slot can see."""
+    got = np.asarray(paged_index_scores(
+        qi, w, pool, table, lengths, block_pages=block_pages))
+    b, t = len(lengths), table.shape[1] * ps
+    assert got.shape == (b, t) and got.dtype == np.float32
+    rows = pool.reshape(pool.shape[0], -1)[table]  # (B, pages, ps x Di)
+    want = np.asarray(GroupedQueryMoEBlock.index_scores(
+        jnp.asarray(qi)[:, None], jnp.asarray(w)[:, None], rows, ps))[:, 0]
+    seen = np.arange(t)[None, :] < lengths[:, None]
+    assert np.isfinite(got[seen]).all()
+    # float32 sums of exact products: Di terms a head, then J heads
+    di = qi.shape[-1]
+    values = float(np.nanmax(np.abs(np.asarray(pool, np.float32))))
+    tol = (di + NJ) * EPS * values * float(
+        (np.abs(w) * np.abs(qi).sum(-1)).sum(-1).max())
+    np.testing.assert_allclose(got[seen], want[seen], rtol=0, atol=tol)
+    return got
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@RAGGED
+def test_index_kernel_is_index_scores_over_the_gathered_pages(
+        lengths, pbt, block_pages, dtype):
+    rng = np.random.default_rng(len(lengths) + pbt)
+    lengths = np.array(lengths, np.int32)
+    num_pages = 48
+    table = _latent_tables(rng, lengths, pbt, num_pages)
+    qi = rng.normal(size=(len(lengths), NJ, 64)).astype(np.float32)
+    w = rng.normal(size=(len(lengths), NJ)).astype(np.float32)
+    pool = _index_pool(rng, num_pages, 64, dtype)
+    assert pool.shape == (48, 8, 128)  # two keys a row, a page a tile
+    _check_index(qi, w, pool, table, lengths, block_pages)
+
+
+@pytest.mark.parametrize("di,ps", [(128, 8), (32, 32), (256, 8)],
+                         ids=["a-key-a-row", "four-keys-a-row",
+                              "a-key-two-tiles-wide"])
+def test_index_kernel_at_other_keys_a_row(di, ps):
+    """A key of 128 is a row, keys of 32 lie four side by side, a key of
+    256 is a row two lanes' tiles wide: the page is whole tiles each time."""
+    rng = np.random.default_rng(di)
+    lengths = np.array([3 * ps + 1, ps, 0, 5 * ps - 1], np.int32)
+    table = np.zeros((4, 6), np.int32)
+    free = iter(rng.permutation(np.arange(1, 24)))
+    for i, n in enumerate(-(-lengths // ps)):
+        table[i, :n] = [next(free) for _ in range(n)]
+    assert decode_attention_path(
+        "index", di, jnp.bfloat16, None, ps) == "kernel"
+    pool = _index_pool(rng, 24, di, jnp.bfloat16, ps)
+    assert pool.shape[1] % 8 == 0 and pool.shape[2] % 128 == 0
+    qi = rng.normal(size=(4, NJ, di)).astype(np.float32)
+    w = rng.normal(size=(4, NJ)).astype(np.float32)
+    _check_index(qi, w, pool, table, lengths, block_pages=2, ps=ps)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_index_kernel_over_permuted_and_shared_pages(dtype):
+    """Pages out of order in the pool, and two slots that read the same
+    pages (a shared prompt) to different lengths."""
+    rng = np.random.default_rng(23)
+    lengths = np.array([6 * PS + 3, 4 * PS + 9, 2 * PS], np.int32)
+    table = np.zeros((3, 8), np.int32)
+    table[0, :7] = [17, 3, 29, 5, 11, 2, 23]
+    table[1, :5] = [17, 3, 29, 5, 8]  # the first four shared with slot 0
+    table[2, :2] = [31, 1]
+    qi = rng.normal(size=(3, NJ, 64)).astype(np.float32)
+    w = rng.normal(size=(3, NJ)).astype(np.float32)
+    pool = _index_pool(rng, 32, 64, dtype)
+    got = _check_index(qi, w, pool, table, lengths, block_pages=4)
+    assert not np.array_equal(got[0, :4 * PS], got[1, :4 * PS])  # own query
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_index_kernel_never_reads_pages_past_a_slot_s_length(dtype):
+    """As the latent body's: every page no slot holds within its length is
+    NaN, the null page and what the table's later entries name among them;
+    none is copied, so every score a slot can see is finite and right."""
+    rng = np.random.default_rng(11)
+    lengths = np.array([5 * PS + 2, PS, 0, 2 * PS + 9], np.int32)
+    num_pages, pbt = 32, 8
+    table = _latent_tables(rng, lengths, pbt, num_pages)
+    held = np.unique(np.concatenate(
+        [table[i, :-(-n // PS)] for i, n in enumerate(lengths)]))
+    free = np.setdiff1d(np.arange(num_pages), held)
+    rows = np.array(_index_pool(rng, num_pages, 64, jnp.float32))
+    rows[free] = np.nan
+    for i, n in enumerate(-(-lengths // PS)):
+        table[i, n:] = free[0]
+    qi = rng.normal(size=(len(lengths), NJ, 64)).astype(np.float32)
+    w = rng.normal(size=(len(lengths), NJ)).astype(np.float32)
+    _check_index(qi, w, jnp.asarray(rows, dtype), table, lengths,
+                 block_pages=4)
+
+
+def test_index_kernel_s_default_block_is_the_step_s():
+    """The block the step runs with, over a table wider than it."""
+    rng = np.random.default_rng(5)
+    lengths = np.array([INDEX_BLOCK_PAGES * PS + 7, 3], np.int32)
+    table = _latent_tables(rng, lengths, INDEX_BLOCK_PAGES + 5, 80)
+    qi = rng.normal(size=(2, NJ, 64)).astype(np.float32)
+    w = rng.normal(size=(2, NJ)).astype(np.float32)
+    _check_index(qi, w, _index_pool(rng, 80, 64, jnp.bfloat16), table,
+                 lengths, block_pages=None)
 
 
 # ------------------------------------------- fewer K/V heads, and a window
@@ -384,6 +522,14 @@ def test_equal_heads_without_a_first_position_is_the_kernel_it_was(
         ("gqa", 64, jnp.bfloat16, None, "gather: heads of 64"),
         ("gqa", 128, jnp.float16, None, "gather: no kernel for a float16"),
         ("gqa", 128, jnp.bfloat16, object(), "gather: Mosaic kernels"),
+        ("index", 64, jnp.bfloat16, None, "kernel"),
+        ("index", 128, jnp.float32, None, "kernel"),
+        ("index", 32, jnp.bfloat16, None, "kernel"),
+        ("index", 8, jnp.float32, None, "kernel"),  # by the key alone
+        ("index", 48, jnp.bfloat16, None, "gather: selector keys of 48"),
+        ("index", 192, jnp.bfloat16, None, "gather: selector keys of 192"),
+        ("index", 64, jnp.float16, None, "gather: no kernel for a float16"),
+        ("index", 64, jnp.bfloat16, object(), "gather: Mosaic kernels"),
     ],
 )
 def test_where_the_kernel_engages(layout, head_dim, kv_dtype, mesh, want):
@@ -406,6 +552,27 @@ def test_a_latent_page_is_whole_tiles_of_the_pool(page_size, want):
     assert grouped.startswith(want.replace("latent", "grouped")), grouped
     assert decode_attention_path(
         "kv", 128, jnp.bfloat16, None, page_size) == "kernel"
+
+
+@pytest.mark.parametrize("head_dim,page_size,want", [
+    (64, 16, "kernel"),  # the selecting cell's: 8 rows of two keys
+    (64, 32, "kernel"), (128, 8, "kernel"), (32, 32, "kernel"),
+    (64, 8, "gather: selector pages of 8 keys of 64"),  # 4 rows
+    (8, 4, "gather: selector pages of 4 keys of 8"),  # a quarter of a row
+    (64, 24, "gather: selector pages of 24 keys of 64"),  # 12 rows
+    (128, 4, "gather: selector pages of 4 keys of 128"),
+])
+def test_a_selector_page_is_whole_tiles_of_the_pool(head_dim, page_size, want):
+    """A page of selector keys is ``page_size x Di`` values as rows of
+    whole lanes, and a copy covers whole tiles of 8 rows (Mosaic refuses
+    less, ``test_chip_compile``)."""
+    got = decode_attention_path(
+        "index", head_dim, jnp.bfloat16, None, page_size)
+    assert got.startswith(want), got
+    if got == "kernel":
+        rows, lanes = index_page_shape(page_size, head_dim)
+        assert rows % 8 == 0 and lanes % 128 == 0
+        assert rows * lanes == page_size * head_dim
 
 
 # ------------------------------------------------------ through the engine

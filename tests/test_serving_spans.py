@@ -129,17 +129,26 @@ def test_pages_in_use_on_the_span_is_the_allocator_s(paged_engine, tmp_path):
     assert paged_engine.stats()["paged"]["pages_in_use"] <= max(held)
 
 
-def test_collect_span_carries_the_selection_s_counters(tmp_path):
+@pytest.mark.parametrize("selector,sa,page_size", [
+    ("gather: selector pages of 4 keys of 8", None, 4),
+    ("kernel", {"indexer_num_heads": 2, "indexer_head_dim": 64,
+                "indexer_num_kv_heads": 1, "topk": 8}, 16),
+], ids=["gather", "kernel"])
+def test_collect_span_carries_the_selection_s_counters(
+        tmp_path, selector, sa, page_size):
     """A block whose keys an indexer selects: every ``serving/collect`` span
     of a traced drive carries ``keys_cached`` and ``keys_selected`` beside
     the routing counters, in the profile as the benchmark's reader finds
-    them, and ``stats()["select"]`` sums the same steps."""
+    them, and ``stats()["select"]`` sums the same steps; every
+    ``serving/step`` span says beside ``attention`` how the step scored the
+    selector keys (``selector``: gathered, or by the kernel in place), the
+    word of ``stats()["paged"]["selector"]``."""
     from distkeras_tpu.models import zoo
     from distkeras_tpu.serving import ServingEngine
 
     engine = ServingEngine(
-        zoo.keye_lm(vocab_size=61, seq_len=64, hidden_size=32), num_slots=2,
-        paged=True, page_size=4, prefill_chunk=8)
+        zoo.keye_lm(vocab_size=61, seq_len=64, hidden_size=32, sa_config=sa),
+        num_slots=2, paged=True, page_size=page_size, prefill_chunk=8)
     engine.start()
     try:
         _generate(engine, 2)  # compiles, off the traced drive
@@ -148,8 +157,18 @@ def test_collect_span_carries_the_selection_s_counters(tmp_path):
             list(engine.submit(np.arange(1, 20 + i, dtype=np.int32) % 61,
                                5).result(120)) for i in range(2)])
         after = engine.stats()["select"]
+        paged = engine.stats()["paged"]
     finally:
         engine.stop()
+    its = _program_spans.iterations(plain)
+    assert paged["selector"].startswith(selector)
+    assert set(_program_spans.span_values(
+        its, "serving/step", "selector")) == {paged["selector"]}
+    (said,) = set(_program_spans.span_values(
+        its, "serving/step", "attention"))
+    # a span's argument ends at its first comma (the annotation's syntax)
+    assert paged["attention"].startswith(said) and said.startswith(
+        "gather: the rows an indexer selects")
     rows = [a for n, _s, _d, _t, a in plain["spans"]
             if n == "serving/collect" and "keys_cached" in a]
     assert len(rows) == after["steps"] - before["steps"] == 10
