@@ -90,32 +90,36 @@ def yarn_frequencies(n, theta, factor, original, beta_fast, beta_slow):
     return (f / factor) * ramp + f * (1 - ramp)
 
 
-def attend_dense(q, k, v, mask):
+def attend_dense(q, k, v, mask, scale=None):
     """Grouped-query attention with every key at once: ``q`` ``(B, n, H,
     Dh)``, ``k``/``v`` ``(B, t, Hkv, Dh)``, ``mask`` ``(B|1, n, t)``;
-    query head ``j`` reads K/V head ``j // (H / Hkv)``."""
+    query head ``j`` reads K/V head ``j // (H / Hkv)``. ``scale``: what
+    multiplies the scores (None: ``1 / sqrt(Dh)``)."""
     b, n, nh, hd = q.shape
     kvh = k.shape[2]
     qg = q.reshape(b, n, kvh, nh // kvh, hd)
-    s = _einsum("bnkgd,btkd->bkgnt", qg, k) / np.sqrt(hd)
+    s = _einsum("bnkgd,btkd->bkgnt", qg, k)
+    s = s / np.sqrt(hd) if scale is None else s * scale
     s = jnp.where(mask[:, None, None], s, -jnp.inf)
     o = _einsum("bkgnt,btkd->bnkgd", jax.nn.softmax(s, axis=-1), v)
     return o.reshape(b, n, nh, hd)
 
 
 def attend_blocked(q, k, v, qpos, kpos0, window=None, key_block=512,
-                   query_block=1024):
+                   query_block=1024, scale=None):
     """The same attention for a prefill chunk, done for the keys a query
     can see and no others: ``q`` ``(n, H, Dh)`` at positions ``qpos``
     ``(n,)`` (ascending), ``k``/``v`` ``(t, Hkv, Dh)`` at positions
     ``kpos0 + arange(t)`` (a key at a negative position does not exist).
     Queries go ``query_block`` at a time; each folds the key blocks from
     its first visible key (``qpos - window + 1`` with a window, else the
-    first key) to its own last position into a running softmax."""
+    first key) to its own last position into a running softmax. ``scale``:
+    what multiplies the scores (None: ``1 / sqrt(Dh)``)."""
     n, nh, hd = q.shape
     t, kvh = k.shape[0], k.shape[1]
     g = nh // kvh
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
     if t % key_block:
         pad = -t % key_block  # keys past every query: never visible
         k = jnp.pad(k, ((0, pad), (0, 0), (0, 0)))
@@ -240,7 +244,7 @@ def select_rows(scores, visible, k: int):
 
 
 def attend_selected(q, keys_of, qpos, chosen_of, extent, extents=(),
-                    key_block=512, query_block=256):
+                    key_block=512, query_block=256, scale=None):
     """Grouped-query attention for a prefill chunk whose queries each read
     the keys their indexer picks: ``q`` ``(n, H, Dh)`` at positions
     ``qpos`` ``(n,)`` (ascending) against the cached positions ``0 ..
@@ -254,9 +258,11 @@ def attend_selected(q, keys_of, qpos, chosen_of, extent, extents=(),
     alone, the same result). ``extents`` (ascending, below ``extent``):
     everything is done at the first extent that holds the chunk's last
     position, under a ``switch``, so that a short context does not pay for
-    the longest."""
+    the longest. ``scale``: what multiplies the scores (None: ``1 /
+    sqrt(Dh)``)."""
     n, nh, hd = q.shape
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
     extents = [e for e in extents if e < extent] + [extent]
 
     def at_extent(te):
@@ -325,7 +331,8 @@ class GroupedQueryMoEBlock(Layer):
     the last that many positions, None = all) with a sigmoid gate a head
     (``gate="per_head"``; None: no gate) and rotary positions (``rope``:
     ``{"theta", "partial" (the share of a head that turns), and for YaRN
-    "factor", "original", "beta_fast", "beta_slow", "attention_factor"}``),
+    "factor", "original", "beta_fast", "beta_slow", "attention_factor"}``;
+    None: no rotation at all, and no position enters the layer),
     then a gated MLP (``n_experts=0``: width ``ffn_width``) or an expert
     layer (``n_experts`` routed experts of ``expert_width``, ``top_k`` a
     token, plus a shared expert of ``shared_width``; 0: none).
@@ -333,7 +340,10 @@ class GroupedQueryMoEBlock(Layer):
     ``select`` (``{"heads", "head_dim", "topk"}``; None: every key): the
     indexer whose scores pick the ``topk`` cached positions a query reads;
     such a block caches one selector key of ``select["head_dim"]`` a token
-    beside its keys and values, and has no window.
+    beside its keys and values, and has no window. ``softmax_scale``:
+    what multiplies the scores (None: ``1 / sqrt(head_dim)``);
+    ``residual_scale``: what multiplies each branch (the attention's
+    output, the FFN's) as it enters the residual stream.
 
     ``experts_held``: as ``LatentMoEBlock``: the routed experts this layer
     holds (ids; None = all); the router keeps its width and its ``top_k``,
@@ -357,11 +367,15 @@ class GroupedQueryMoEBlock(Layer):
                  gate="per_head", ffn_width=0, n_experts=0, top_k=0,
                  expert_width=0, shared_width=0, routed_scale=1.0,
                  norm_topk=True, epsilon=1e-6, experts_held=None,
-                 out_scale=1.0, qk_norm=False, select=None):
+                 out_scale=1.0, qk_norm=False, select=None,
+                 softmax_scale=None, residual_scale=1.0):
         self.num_heads = int(num_heads)
         self.kv_heads = int(kv_heads)
         self.head_dim = int(head_dim)
-        self.rope = dict(rope)
+        self.rope = None if rope is None else dict(rope)
+        self.softmax_scale = (
+            None if softmax_scale is None else float(softmax_scale))
+        self.residual_scale = float(residual_scale)
         self.window = None if window is None else int(window)
         self.gate = gate
         self.ffn_width = int(ffn_width)
@@ -386,6 +400,8 @@ class GroupedQueryMoEBlock(Layer):
                 f"select {self.select} with window {self.window}: an "
                 f"indexer has heads of an even size, topk >= 1 and no "
                 f"window beside it")
+        if self.select is not None and self.rope is None:
+            raise ValueError("an indexer's keys are rotated: it needs rope")
         if self.num_heads % self.kv_heads:
             raise ValueError(
                 f"{self.num_heads} query heads are not a multiple of "
@@ -393,7 +409,8 @@ class GroupedQueryMoEBlock(Layer):
         if gate not in (None, "per_head"):
             raise ValueError(f"gate {gate!r}: 'per_head' or None")
         rot = self.rotary_dim
-        if rot < 2 or rot % 2 or rot > self.head_dim:
+        if self.rope is not None and (
+                rot < 2 or rot % 2 or rot > self.head_dim):
             raise ValueError(f"rotary part {rot} of heads of {head_dim}")
         if self.n_experts:
             held = self.held
@@ -414,6 +431,8 @@ class GroupedQueryMoEBlock(Layer):
 
     @property
     def rotary_dim(self) -> int:
+        if self.rope is None:
+            return 0
         return int(round(self.head_dim * float(self.rope.get("partial", 1))))
 
     def _rope_args(self):
@@ -431,7 +450,9 @@ class GroupedQueryMoEBlock(Layer):
     def rotate(self, x, pos):
         """``x`` ``(..., heads, head_dim)`` at ``pos`` ``(...)``: the
         leading ``rotary_dim`` values of each head turned, the rest as
-        they are."""
+        they are (without ``rope``: all of them, in float32)."""
+        if self.rope is None:
+            return x.astype(jnp.float32)
         rot = self.rotary_dim
         freq, factor = self._rope_args()
         turned = rope(x[..., :rot], pos[..., None], float(self.rope["theta"]),
@@ -524,12 +545,18 @@ class GroupedQueryMoEBlock(Layer):
             q, k, v = self._qkv(a, h, pos)
             if attend is None:
                 cd = a["wk"].dtype  # as a served cache holds them
-                o = attend_dense(q, k.astype(cd), v.astype(cd), mask)
+                o = attend_dense(q, k.astype(cd), v.astype(cd), mask,
+                                 self.softmax_scale)
             else:
                 o = attend(q, k, v)
             if self.gate:
                 o = o * jax.nn.sigmoid(matmul(h, a["wgate"]))[..., None]
-            return x + matmul(o.reshape(*lead, nh * hd), a["wo"])
+            return self._into(x, matmul(o.reshape(*lead, nh * hd), a["wo"]))
+
+    def _into(self, x, y):
+        """A branch's output into the residual stream."""
+        r = self.residual_scale
+        return x + (y if r == 1.0 else r * y)
 
     # -- the indexer ---------------------------------------------------------
 
@@ -597,10 +624,11 @@ class GroupedQueryMoEBlock(Layer):
                     jnp.broadcast_to(mask, scores.shape).reshape(b * n, t),
                     self.select["topk"]).reshape(b, n, t)
             with jax.named_scope("attn/sparse"):
-                o = attend_dense(q, k.astype(cd), v.astype(cd), keep)
+                o = attend_dense(q, k.astype(cd), v.astype(cd), keep,
+                                 self.softmax_scale)
         with jax.named_scope("attn/sparse"):
-            return x + matmul(
-                o.reshape(*lead, self.num_heads * self.head_dim), a["wo"])
+            return self._into(x, matmul(
+                o.reshape(*lead, self.num_heads * self.head_dim), a["wo"]))
 
     def ffn(self, p, u, token_mask=None):
         """``u`` ``(n, d)`` -> ``(y, Picks | None)``; more than
@@ -644,7 +672,7 @@ class GroupedQueryMoEBlock(Layer):
         if token_mask is not None:
             token_mask = jnp.broadcast_to(token_mask, lead).reshape(-1)
         y, picks = self.ffn(p["ffn"], u.reshape(-1, d), token_mask)
-        return x + y.reshape(*lead, d), picks
+        return self._into(x, y.reshape(*lead, d)), picks
 
     def apply(self, params, state, x, train=False, rng=None):
         b, n, _ = x.shape
@@ -668,4 +696,8 @@ class GroupedQueryMoEBlock(Layer):
             "epsilon": self.epsilon, "experts_held": self.experts_held,
             "out_scale": self.out_scale, "qk_norm": self.qk_norm,
             "select": self.select,
+            **({} if self.softmax_scale is None
+               else {"softmax_scale": self.softmax_scale}),
+            **({} if self.residual_scale == 1.0
+               else {"residual_scale": self.residual_scale}),
         }

@@ -66,6 +66,7 @@ def layer_from_config(cfg: dict):
         # the blocks that live beside this module register when imported:
         # a process that only loads a bundle has not imported them yet
         import distkeras_tpu.models.gqa_moe  # noqa: F401
+        import distkeras_tpu.models.mamba2  # noqa: F401
         import distkeras_tpu.models.mla_moe  # noqa: F401
         import distkeras_tpu.parallel.expert_parallel  # noqa: F401
     return _LAYER_REGISTRY[name](**cfg)
@@ -337,12 +338,15 @@ class Embedding(Layer):
 
     No reference counterpart (the reference has no sequence workloads,
     SURVEY §5.7); the entry layer of the rebuild's transformer family.
+    ``multiplier``: what a token's row is multiplied by as it enters the
+    residual stream (1: nothing).
     """
 
-    def __init__(self, vocab_size, dim, with_positions=True):
+    def __init__(self, vocab_size, dim, with_positions=True, multiplier=1.0):
         self.vocab_size = int(vocab_size)
         self.dim = int(dim)
         self.with_positions = bool(with_positions)
+        self.multiplier = float(multiplier)
 
     def init(self, rng, in_shape):
         (t,) = in_shape
@@ -359,6 +363,8 @@ class Embedding(Layer):
 
     def apply(self, params, state, x, train=False, rng=None):
         y = params["tokens"][x.astype(jnp.int32)]
+        if self.multiplier != 1.0:
+            y = y * self.multiplier
         if self.with_positions:
             y = y + params["positions"][None, : y.shape[1]]
         return y, state
@@ -369,7 +375,42 @@ class Embedding(Layer):
             "vocab_size": self.vocab_size,
             "dim": self.dim,
             "with_positions": self.with_positions,
+            **({} if self.multiplier == 1.0
+               else {"multiplier": self.multiplier}),
         }
+
+
+@register_layer
+class TiedHead(Layer):
+    """The output head of a model whose head IS its embedding: ``logits =
+    x E^T / logits_scaling`` with ``E`` the ``tokens`` table of layer
+    ``tied_to`` (the ``Embedding``). It has no parameters of its own:
+    ``Model.apply`` and the serving programs hand it that layer's
+    (``params_of``), so the table is held, and read by a step, once."""
+
+    def __init__(self, vocab_size, logits_scaling=1.0, tied_to=0):
+        self.vocab_size = int(vocab_size)
+        self.logits_scaling = float(logits_scaling)
+        self.params_of = int(tied_to)
+
+    def init(self, rng, in_shape):
+        return {}, {}, (*in_shape[:-1], self.vocab_size)
+
+    def logits(self, p_emb, x):
+        """``x`` ``(..., d)`` against the table ``(V, d)``: both operands in
+        the table's dtype, float32 out (``models.mla_moe._einsum``)."""
+        from distkeras_tpu.models.mla_moe import _einsum
+
+        y = _einsum("...d,vd->...v", x, p_emb["tokens"])
+        return y if self.logits_scaling == 1.0 else y / self.logits_scaling
+
+    def apply(self, params, state, x, train=False, rng=None):
+        return self.logits(params, x), state
+
+    def get_config(self):
+        return {"layer": "TiedHead", "vocab_size": self.vocab_size,
+                "logits_scaling": self.logits_scaling,
+                "tied_to": self.params_of}
 
 
 @register_layer

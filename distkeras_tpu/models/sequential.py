@@ -129,8 +129,11 @@ class Model:
         )
         new_state = {}
         for i, layer in enumerate(self.layers):
+            # a layer that has no parameters of its own but another's (a
+            # tied head: the embedding's) says whose: ``params_of``
+            src = getattr(layer, "params_of", i)
             x, new_state[str(i)] = layer.apply(
-                params[str(i)], state[str(i)], x, train, rngs[i]
+                params[str(src)], state[str(i)], x, train, rngs[i]
             )
         return x, new_state
 

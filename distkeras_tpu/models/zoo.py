@@ -655,6 +655,92 @@ def keye_lm(
     return model
 
 
+def granite_hybrid_lm(
+    vocab_size=256,
+    seq_len=128,
+    hidden_size=32,
+    num_attention_heads=4,
+    num_key_value_heads=2,
+    shared_intermediate_size=64,
+    layer_types=("mamba", "mamba", "mamba", "attention",
+                 "mamba", "mamba", "mamba", "attention"),
+    mamba_n_heads=8,
+    mamba_d_head=8,
+    mamba_d_state=16,
+    mamba_n_groups=1,
+    mamba_d_conv=4,
+    mamba_expand=2,
+    mamba_chunk_size=8,
+    embedding_multiplier=12.0,
+    residual_multiplier=0.22,
+    attention_multiplier=None,
+    logits_scaling=8.0,
+    rms_norm_eps=1e-5,
+    seed=0,
+):
+    """Causal language model of Mamba-2 layers beside grouped-query
+    attention layers (the ``granitemoehybrid`` model type with no routed
+    experts, under its published keys): Embedding without a position table,
+    times ``embedding_multiplier`` -> one block a layer, ``layer_types[l]``
+    ``"mamba"`` (``Mamba2Block``: ``mamba_n_heads`` heads of
+    ``mamba_d_head`` = ``mamba_expand x hidden_size`` values, a state of
+    ``mamba_d_state``, ``mamba_n_groups`` 1, a convolution of
+    ``mamba_d_conv``, blocks of ``mamba_chunk_size``) or ``"attention"``
+    (``GroupedQueryMoEBlock``: ``num_attention_heads`` query heads over
+    ``num_key_value_heads`` K/V heads of ``hidden_size /
+    num_attention_heads``, NO rotation and no position table, scores times
+    ``attention_multiplier``; None: ``1 / sqrt(head size)``), each followed
+    by a gated MLP of ``shared_intermediate_size``, every branch times
+    ``residual_multiplier`` -> RMSNorm -> the embedding as the head
+    (``TiedHead``), logits over ``logits_scaling``. A Mamba layer's state is
+    held in float32. Serves through the paged
+    ``ServingEngine``: a state and a convolution tail a slot for the Mamba
+    layers beside the attention layers' pages."""
+    from distkeras_tpu.models.gqa_moe import GroupedQueryMoEBlock
+    from distkeras_tpu.models.layers import TiedHead
+    from distkeras_tpu.models.mamba2 import Mamba2Block
+    from distkeras_tpu.models.mla_moe import RMSNorm
+
+    if hidden_size % num_attention_heads or (
+            mamba_n_heads * mamba_d_head != mamba_expand * hidden_size):
+        raise ValueError(
+            f"hidden_size {hidden_size}: {num_attention_heads} attention "
+            f"heads, {mamba_n_heads} Mamba heads of {mamba_d_head} at "
+            f"expand {mamba_expand}")
+    # the model's own residual_multiplier is its scaling of a branch by
+    # depth: the output projections are not damped a second time
+    out_scale = 1.0
+
+    def block(kind):
+        if kind == "mamba":
+            return Mamba2Block(
+                mamba_n_heads, mamba_d_head, mamba_d_state,
+                shared_intermediate_size, n_groups=mamba_n_groups,
+                conv_width=mamba_d_conv, chunk=mamba_chunk_size,
+                epsilon=rms_norm_eps, residual_scale=residual_multiplier,
+                out_scale=out_scale)
+        if kind != "attention":
+            raise ValueError(f"layer type {kind!r}")
+        return GroupedQueryMoEBlock(
+            num_attention_heads, num_key_value_heads,
+            hidden_size // num_attention_heads, None, gate=None,
+            ffn_width=shared_intermediate_size, epsilon=rms_norm_eps,
+            out_scale=out_scale, softmax_scale=attention_multiplier,
+            residual_scale=residual_multiplier)
+
+    model = Sequential(
+        [
+            Embedding(vocab_size, hidden_size, with_positions=False,
+                      multiplier=embedding_multiplier),
+            *[block(kind) for kind in layer_types],
+            RMSNorm(rms_norm_eps),
+            TiedHead(vocab_size, logits_scaling),
+        ]
+    )
+    model.build((seq_len,), seed=seed)
+    return model
+
+
 ZOO = {
     "mnist_mlp": mnist_mlp,
     "mnist_cnn": mnist_cnn,

@@ -167,8 +167,25 @@ _LANES = 128
 _SUBLANES = 8
 
 
+def heads_side_by_side(head_dim, kv_heads) -> int:
+    """How many K/V heads of ``head_dim`` lie side by side in one group of
+    128 lanes of a flat pool's row, where whole groups hold all ``kv_heads``
+    of them: 1 for heads that are a whole number of 128 lanes, 2 for 8 heads
+    of 64, 0 where no grouping fits (heads of 96; one K/V head of 64).
+    :func:`paged_decode_attention` then reads such a group as ONE head of 128
+    lanes, each query zero outside its own head's lanes."""
+    if not head_dim or not kv_heads:
+        return 0
+    if head_dim % _LANES == 0:
+        return 1
+    if _LANES % head_dim:
+        return 0
+    side = _LANES // head_dim
+    return side if kv_heads % side == 0 else 0
+
+
 def decode_attention_path(layout, head_dim, kv_dtype, mesh=None,
-                          page_size=None):
+                          page_size=None, kv_heads=None):
     """``"kernel"`` where a kernel of this module serves the paged decode
     step (:func:`paged_decode_attention` for layout ``"kv"``,
     :func:`paged_latent_attention` for ``"latent"``, the grouped body of
@@ -176,7 +193,13 @@ def decode_attention_path(layout, head_dim, kv_dtype, mesh=None,
     the selector keys of a block that selects, with ``head_dim`` the
     selector key's, :func:`paged_index_scores`), else ``"gather:
     <why>"`` — read from what the stepper can see of itself, never from
-    a knob or a model's name."""
+    a knob or a model's name. A model with layers that cache nothing a
+    token (a state a slot: the stepper's ``"ssm"`` layout) asks as
+    ``"gqa"``, with the K/V heads of its layers that DO cache rows: the
+    layers that hold a state have no pages, and no say here. (A layer's own
+    softmax scale is no ground for the gather: the kernels have ``1 /
+    sqrt(head_dim)`` written in, and the stepper folds another scale into
+    the query.)"""
     if mesh is not None:
         return "gather: Mosaic kernels are not partitioned over a mesh"
     if layout == "kv":
@@ -193,7 +216,9 @@ def decode_attention_path(layout, head_dim, kv_dtype, mesh=None,
         # a grouped page is ``page_size`` rows of ``Hkv x Dh`` values in
         # the flat pool: a head is a lane-aligned slice of a row, and a
         # copy starts and ends on a tile of the pool, as for the latent
-        if head_dim % _LANES:
+        # (narrower heads that fill the lanes side by side, ``kv_heads``
+        # given: the grouped body reads each group as one head)
+        if head_dim % _LANES and not heads_side_by_side(head_dim, kv_heads):
             return (f"gather: heads of {head_dim} are not a whole number "
                     f"of {_LANES} lanes")
         if page_size is not None and page_size % _SUBLANES:
@@ -394,7 +419,14 @@ def paged_decode_attention(q, ck, cv, table, lengths, first=None, *,
 
     With ``Hkv == Hq``, a 4-D pool, no first position and no ring this is
     ``_kernel``, the program it was before K/V heads could be fewer.
-    Everything else is ``_grouped_kernel``."""
+    Everything else is ``_grouped_kernel``. Heads narrower than the 128
+    lanes that lie side by side in a flat row (``heads_side_by_side``: 8 K/V
+    heads of 64 are 4 groups of 128) go through the same body with each
+    group read as ONE head: a query is zero outside its own head's lanes,
+    so its scores are its own head's, and of the group's weighted values
+    its own head's lanes are kept. No key is moved and the kernel is what
+    it was; it multiplies twice the values it needs, which a step that
+    waits for memory does not feel."""
     if ck.ndim == 4 and ck.shape[2] == q.shape[1] and first is None \
             and not ring:
         return _paged_decode_attention(
@@ -406,6 +438,12 @@ def paged_decode_attention(q, ck, cv, table, lengths, first=None, *,
     if ck.ndim == 4:
         page_size = ck.shape[1]
         ck, cv = (c.reshape(c.shape[0] * c.shape[1], -1) for c in (ck, cv))
+    hd = q.shape[-1]
+    side = heads_side_by_side(hd, ck.shape[1] // hd)
+    if side > 1:
+        return _narrow_heads(q, ck, cv, table, lengths, first, side,
+                             page_size=page_size, ring=ring,
+                             block_pages=block_pages)
     bp = int(block_pages or GROUPED_BLOCK_PAGES)
     if ring:
         # equal blocks that cover the ring: 33 pages go 11 at a time
@@ -417,6 +455,25 @@ def paged_decode_attention(q, ck, cv, table, lengths, first=None, *,
         ring=int(ring), block_pages=min(bp, max(1, table.shape[1])),
         interpret=pallas_interpret(),
     )
+
+
+def _narrow_heads(q, ck, cv, table, lengths, first, side, **kw):
+    """``paged_decode_attention`` for K/V heads of ``Dh = 128 / side`` lanes:
+    query head ``j`` reads K/V head ``j // g``, which lies in lanes ``((j //
+    g) % side) x Dh ..`` of group ``(j // g) // side``. The query goes in
+    ``side x Dh`` wide, zero outside those lanes and times ``sqrt(side)``
+    (the body divides by the square root of the width it sees); of the
+    output's ``side x Dh`` values the same lanes come back."""
+    b, nh, hd = q.shape
+    g = nh // (ck.shape[1] // hd)
+    part = (jnp.arange(nh) // g) % side  # (Hq,): which head of its group
+    mine = part[:, None] == (jnp.arange(side * hd) // hd)[None, :]
+    wide = jnp.where(mine[None], jnp.tile(q.astype(jnp.float32), (1, 1, side)),
+                     0.0) * (side ** 0.5)
+    out = paged_decode_attention(wide, ck, cv, table, lengths, first, **kw)
+    return jnp.take_along_axis(
+        out.reshape(b, nh, side, hd), part[None, :, None, None], axis=2
+    )[:, :, 0]
 
 
 # ------------------------------------------- fewer K/V heads, and a window

@@ -498,11 +498,14 @@ class CachedSequenceGenerator(SequenceGenerator):
         Dense (``zoo.mla_moe_lm``, ``zoo.longcat_flash_lm``: kind
         ``"latent"``, whose cache is ``cached_rows`` latent rows a token
         and layer; ``zoo.laguna_lm``: kind ``"gqa"``, grouped-query keys
-        and values with a window by layer). The block says its kind; its
+        and values with a window by layer; ``zoo.granite_hybrid_lm``:
+        kind ``"ssm"``, layers that cache a state a sequence and nothing
+        a token, which may stand beside ``"gqa"`` layers, and the
+        embedding as the head). The block says its kind; its
         class is not asked. The paged ``DecodeStepper`` serves them; the
         solo generators here keep a dense (B, T, H, Dh) cache and refuse
         them (``_decode_prologue``)."""
-        from distkeras_tpu.models.layers import Dense, Embedding
+        from distkeras_tpu.models.layers import Dense, Embedding, TiedHead
         from distkeras_tpu.models.mla_moe import RMSNorm
 
         mid = layers[1:-2]
@@ -511,11 +514,12 @@ class CachedSequenceGenerator(SequenceGenerator):
             len(layers) >= 4
             and isinstance(layers[0], Embedding)
             and isinstance(layers[-2], RMSNorm)
-            and isinstance(layers[-1], Dense)
-            and len(kinds) == 1 and kinds <= {"latent", "gqa"}
+            and isinstance(layers[-1], (Dense, TiedHead))
+            and (kinds in ({"latent"}, {"gqa"}) or (
+                "ssm" in kinds and kinds <= {"ssm", "gqa"}))
         ):
             return False
-        self.block_kind = kinds.pop()
+        self.block_kind = "ssm" if "ssm" in kinds else kinds.pop()
         self._emb = layers[0]
         self._stages = [(blk, i + 1, None, None) for i, blk in enumerate(mid)]
         self._blocks = mid
@@ -594,7 +598,8 @@ class CachedSequenceGenerator(SequenceGenerator):
             raise BlockUnsupportedError(
                 "the solo cached generators keep a dense (B, T, H, Dh) "
                 "K/V cache of one head count; a block that caches latent "
-                "rows, or grouped keys and values with a window by layer "
+                "rows, grouped keys and values with a window by layer, or "
+                "a state a sequence and nothing a token "
                 f"(kind {self.block_kind!r}), decodes through the paged "
                 "ServingEngine only"
             )

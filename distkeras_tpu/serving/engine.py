@@ -486,6 +486,8 @@ class _InflightStep:
                 toks = st._note_routing(toks, int(active.sum()), sp)
             if st._select:
                 st._note_selection(active, sp)
+            if st._state_layers:
+                st._note_state(active, sp)
             owed = self.owed()
             st._lens[owed] = np.minimum(st._lens[owed] + 1, st._lens_cap)
             # the RNG counter mirrors the length discipline exactly: a
@@ -619,7 +621,9 @@ class DecodeStepper:
         self.model = model
         # the block kind IS the page layout: "kv" (keys and values of
         # every head, one budget), "latent" (a latent row an attention),
-        # "gqa" (grouped keys and values, a budget a layer kind). It picks
+        # "gqa" (grouped keys and values, a budget a layer kind), "ssm"
+        # (layers that hold a state a slot and nothing a token, beside
+        # grouped layers under the page table). It picks
         # what the step / chunk programs are built from (``_LAYOUTS``) and
         # what is refused and why (``_PAGED_ONLY``) — decided here, when
         # programs are built, never inside a traced function, and never
@@ -655,9 +659,13 @@ class DecodeStepper:
             raise ValueError(f"num_slots must be >= 1; got {num_slots}")
         self.max_len = int(model.input_shape[0])
         # the most tokens one prefill-chunk program takes: a block whose
-        # chunk costs tokens x cached positions says so (``chunk_tokens``)
+        # chunk costs tokens x cached positions says so (``chunk_tokens``),
+        # and so does a block that holds a state a slot (its chunk is a
+        # loop over blocks of positions: a longer one gains nothing, and
+        # every bucket is a whole-depth program to compile)
         selecting = [b for b in self._gen._blocks
-                     if getattr(b, "select", None)]
+                     if getattr(b, "select", None)
+                     or getattr(b, "slot_state", None)]
         self.chunk_cap = min(
             [self.max_len] + [int(b.chunk_tokens) for b in selecting])
         # ... and the fewest a chunk program is built for, a sixteenth of
@@ -779,10 +787,18 @@ class DecodeStepper:
             # how the decode step attends, by what this stepper is:
             # "kernel" (each slot's own pages, in place) or "gather:
             # <why>" (the gathered extent of the longest table)
-            self.attention = decode_attention_path(
-                self.layout, hd, self._gen.kv_dtype, self.mesh,
-                self.page_size,
-            )
+            # (the layers of an "ssm" model that cache rows are grouped;
+            # the grouped body is told the K/V heads, since narrow ones
+            # that fill the lanes side by side ride it)
+            grouped = self.layout in ("gqa", "ssm")
+            if grouped and hd is None:
+                self.attention = "none: no layer caches keys and values"
+            else:
+                self.attention = decode_attention_path(
+                    "gqa" if grouped else self.layout, hd,
+                    self._gen.kv_dtype, self.mesh, self.page_size,
+                    **({"kv_heads": nh} if grouped else {}),
+                )
             # how the decode step scores a selecting block's selector keys:
             # "kernel" (each slot's own selector pages, in place) or
             # "gather: <why>"; None for a block that selects nothing
@@ -854,6 +870,23 @@ class DecodeStepper:
         # the active slots held and those their queries read
         self.select_stats = {"steps": 0, "keys_cached": 0,
                              "keys_selected": 0}
+        # layers that hold a state a slot (``slot_state``): how many, what
+        # one slot holds over all of them, and of that the states alone,
+        # which a decode step reads and writes for every decoding slot
+        held = [b.slot_state for b in self._gen._blocks
+                if getattr(b, "slot_state", None)]
+        self._state_layers = len(held)
+        self.state_bytes_a_slot = sum(
+            int(np.prod(shape)) * np.dtype(dt).itemsize
+            for arrays in held for shape, dt in arrays)
+        self._state_bytes_a_step_slot = 2 * sum(
+            int(np.prod(arrays[0][0])) * np.dtype(arrays[0][1]).itemsize
+            for arrays in held)
+        # over the decode steps: the bytes of state they read and wrote;
+        # and the slots whose state an admission reset, in all and since
+        # the last collect
+        self.state_stats = {"steps": 0, "state_bytes": 0, "resets": 0}
+        self._state_resets_new = 0
         self.host_arg_bytes_step = 0  # of the last decode-step call
         self._step_fns = {}  # masked flag -> compiled decode step
         self._admit_fns = {}  # prefill-length bucket -> compiled admit
@@ -991,6 +1024,15 @@ class DecodeStepper:
             }
             out["select"] = dict(self._select)
             out["selector"] = self.selector
+        if self._state_layers:
+            # what a slot holds whatever its length, beside what a token
+            # costs in the layers that cache rows
+            out["bytes_per_token_by_kind"] = {
+                "full": self.kv_bytes_per_token("full")}
+            out["state_layers"] = self._state_layers
+            out["state_bytes_a_slot"] = self.state_bytes_a_slot
+            out["state_bytes_total"] = (
+                self.state_bytes_a_slot * self.num_slots)
         # mesh geometry: the pool's TOTAL bytes are mesh-invariant;
         # what changes with tp:N is how many land per shard
         out["mesh"] = self.mesh_spec
@@ -1012,6 +1054,14 @@ class DecodeStepper:
         """True when the draft source needs each slot's host-side
         sequence so far (prompt + emitted) — the batcher builds them."""
         return self.drafter is not None and self.drafter.wants_sequences
+
+    @property
+    def state_a_slot(self) -> bool:
+        """True where some block holds a state a slot: a step that ran on
+        the device has advanced the state of every slot of its mask,
+        whether or not its tokens reach the host, so the scheduler cannot
+        probe again from where a step whose collect raised began."""
+        return self._state_layers > 0
 
     def _fire(self, site, **ctx):
         """Fault seam, silenced for nested (draft) steppers: seams
@@ -1123,11 +1173,17 @@ class DecodeStepper:
         what makes tp1/tp2/tp4 bench rows an equal-byte comparison."""
         import jax
 
-        arrs = self._pools if self.paged else self._caches
+        arrs = self._row_pools() if self.paged else self._caches
         return sum(
             int(np.prod(a.shape)) * a.dtype.itemsize
             for a in jax.tree_util.tree_leaves(arrs)
         )
+
+    def _row_pools(self) -> list:
+        """The pools of the layers that cache rows a token: not a layer's
+        state a slot, which no token count sizes."""
+        return [arrs for blk, arrs in zip(self._gen._blocks, self._pools)
+                if not getattr(blk, "slot_state", None)]
 
     def kv_bytes_per_token(self, kind=None) -> int:
         """Bytes one cached token takes over all layers: keys and values
@@ -1142,6 +1198,8 @@ class DecodeStepper:
                     * self._hd * len(self._gen._stages))
         total = 0
         for blk, arrs in zip(self._gen._blocks, self._pools):
+            if getattr(blk, "slot_state", None):
+                continue  # a state a slot: nothing a token
             mine = "window" if getattr(blk, "window", None) is not None \
                 else "full"
             for j, a in enumerate(arrs):
@@ -1178,6 +1236,17 @@ class DecodeStepper:
                 "selecting layout: the host PrefixStore and the "
                 "DevicePrefixIndex hold (p, H, Dh) K/V rows of one head "
                 "count and no selector keys"),
+        },
+        "ssm": {
+            "what": "a block that holds a state a slot",
+            "why": "its memory is a fixed block a slot that every step "
+                   "rewrites, not rows a token: what shares, exports, "
+                   "swaps or rolls back a sequence needs a snapshot of "
+                   "that state, which no program takes yet",
+            "prefix_caches": (
+                "state layout: a shared prefix's pages say nothing of "
+                "the state after it, and no snapshot of a state is "
+                "kept at page boundaries"),
         },
         "gqa": {
             "what": "a grouped-query block with window layers",
@@ -1217,6 +1286,12 @@ class DecodeStepper:
         "gqa": {"shape": "_grouped_cache_shape",
                 "pools": "_build_grouped_pools",
                 "rows": "_gqa_rows", "chunk": "_gqa_chunk"},
+        # pools that DIFFER by layer: a state and a convolution tail a
+        # slot where the block says ``slot_state``, grouped K/V pages
+        # under the one table where it caches rows
+        "ssm": {"shape": "_grouped_cache_shape",
+                "pools": "_build_hybrid_pools",
+                "rows": "_hybrid_rows", "chunk": "_hybrid_chunk"},
     }
 
     def _kv_cache_shape(self):
@@ -1269,17 +1344,25 @@ class DecodeStepper:
 
     def _grouped_cache_shape(self):
         """``(K/V heads, head size, window)`` that the grouped blocks
-        say: the first two alike in every layer (a slot's table serves
-        every layer of a kind), one window size among the window layers
-        (None: no window layer)."""
-        blocks = self._gen._blocks
+        say: the first two alike in every layer that caches rows (a
+        slot's table serves every layer of a kind), one window size among
+        the window layers (None: no window layer). A layer that caches
+        nothing a token (a state a slot: ``slot_state``) has no say here
+        and no pool of rows; a model of such layers alone gives ``(None,
+        None, None)``."""
+        blocks = [b for b in self._gen._blocks
+                  if not getattr(b, "slot_state", None)]
+        if not blocks:
+            return None, None, None
         shapes = {(b.kv_heads, b.head_dim) for b in blocks}
         windows = {b.window for b in blocks} - {None}
         selects = {repr(b.select) for b in blocks}
         if len(shapes) != 1 or len(windows) > 1 or len(selects) != 1:
             raise ValueError(
                 f"a paged pool has one (K/V heads, head size), one "
-                f"window size and one indexer a model; got "
+                f"window size and one indexer over the layers of a model "
+                f"that cache rows a token (a layer that holds a state a "
+                f"slot instead is not asked); got "
                 f"{sorted(shapes)}, windows {sorted(windows)} and "
                 f"indexers {sorted(selects)}"
             )
@@ -1334,6 +1417,33 @@ class DecodeStepper:
                 jnp.zeros((num_pages, *self._index_page),
                           self._gen.kv_dtype),
             ) if blk.select else ())
+            for blk in self._gen._blocks
+        ]
+
+    def _build_hybrid_pools(self, num_pages, kvh, hd):
+        """Pools that differ by layer. A layer that says ``slot_state``
+        (a state and a convolution tail) gets those arrays with the slot
+        as their leading index: ``(num_slots, H, P, N)`` and ``(num_slots,
+        K - 1, C)``, float32 both (``slot_state`` gives the dtypes). They are
+        indexed by SLOT and not through the table: every sequence has
+        exactly one of each, whatever its length, every step rewrites it
+        whole, and nothing of it is ever shared, so a page table would
+        name one fixed page a slot. A slot takes them with the slot and
+        gives them back with it; no allocator. A layer that caches rows
+        gets the grouped layout's flat K/V pools, ``(pages x page_size,
+        Hkv x Dh)``, from the one budget ``num_pages``."""
+        import jax.numpy as jnp
+
+        if self._window is not None or self._select:
+            raise ValueError(
+                "beside layers that hold a state a slot, the layers that "
+                "cache rows have no window and no indexer yet")
+        return [
+            tuple(jnp.zeros((self.num_slots, *shape), dt)
+                  for shape, dt in blk.slot_state)
+            if getattr(blk, "slot_state", None) else
+            tuple(jnp.zeros((num_pages * self.page_size, kvh * hd),
+                            self._gen.kv_dtype) for _ in range(2))
             for blk in self._gen._blocks
         ]
 
@@ -1422,6 +1532,26 @@ class DecodeStepper:
             "keys_selected": m["keys_selected"] + read,
         }
         span.set_metadata(keys_cached=seen, keys_selected=read)
+
+    def _state_bytes(self, active) -> int:
+        """The bytes of state one decode step reads and writes: every
+        decoding slot's state, in and out, over the layers that hold one;
+        from the host's own count, no fetch."""
+        return int(np.count_nonzero(active)) * self._state_bytes_a_step_slot
+
+    def _note_state(self, active, span):
+        """The state layers' counters of one collected step: the bytes of
+        state it moved (``_state_bytes``) summed for ``stats()["state"]``,
+        and on the ``serving/collect`` span the slots whose state an
+        admission reset since the last collect."""
+        m, new = self.state_stats, self._state_resets_new
+        self._state_resets_new = 0
+        self.state_stats = {
+            "steps": m["steps"] + 1,
+            "state_bytes": m["state_bytes"] + self._state_bytes(active),
+            "resets": m["resets"] + new,
+        }
+        span.set_metadata(state_resets=new)
 
     def kv_shard_bytes(self) -> int:
         """K/V bytes RESIDENT PER SHARD — the number a capacity planner
@@ -1552,11 +1682,14 @@ class DecodeStepper:
             (params[str(bi)], None if mi is None else params[str(mi)])
             for (_, bi, _, mi) in self._gen._stages
         ]
+        # a head that is another layer's table (a tied head: the
+        # embedding's) says whose parameters it reads: ``params_of``
+        head_of = getattr(self._gen._head, "params_of", n_layers - 1)
         return (
             bp,
             params["0"],
             params[str(n_layers - 2)],
-            params[str(n_layers - 1)],
+            params[str(head_of)],
         )
 
     def _embed(self, p_emb, tok, pos):
@@ -1565,6 +1698,9 @@ class DecodeStepper:
         import jax.numpy as jnp
 
         x = p_emb["tokens"][tok]
+        scale = getattr(self._gen._emb, "multiplier", 1.0)
+        if scale != 1.0:
+            x = x * scale
         if "positions" in p_emb:
             n_pos = p_emb["positions"].shape[0]
             x = x + p_emb["positions"][jnp.minimum(pos, n_pos - 1)]
@@ -1704,6 +1840,11 @@ class DecodeStepper:
             self._restore_prefix(slot, kv)
         self._pending[slot] = prompt
         self._prefill_pos[slot] = start
+        if self._state_layers:
+            # the slot's state starts from zero: the program that runs its
+            # position 0 (the first chunk, or the step of a prompt of one
+            # token) does it, by its ``where``; no host write
+            self._state_resets_new += 1
         if self.drafter is not None:
             # kept for draft admission once the slot turns decodable;
             # the proposal cache is stale the moment slot composition
@@ -2117,6 +2258,14 @@ class DecodeStepper:
             if self.paged
             else self._tp - pos
         )
+        if self._state_layers:
+            # a chunk of a state layout is never built under its floor (a
+            # bucket below it is one no warm-up compiled: a whole-depth
+            # program minted by live traffic): what a bucket holds beyond
+            # the slot's own pages are positions that do not exist, whose
+            # rows land on the null page and which advance no state; the
+            # table's own extent is what a bucket may not pass
+            room = self._max_pages_bucket * self.page_size - pos
         if cb > room:
             cb = 1 << (room.bit_length() - 1)  # largest pow2 <= room
             n = min(n, cb)
@@ -2139,7 +2288,7 @@ class DecodeStepper:
             host = (toks, self._chunk_where(slot, pbt, n), np.int32(pos))
             with _span(
                 "serving/prefill_chunk",
-                host_arg_bytes=self._host_arg_bytes(host),
+                host_arg_bytes=self._host_arg_bytes(host), tokens=n,
             ):
                 self._pools = fn(self._params, self._pools, *host)
             return n
@@ -2192,6 +2341,9 @@ class DecodeStepper:
         the chunk's count of real tokens (a ring takes no write that is
         not a real token's: behind it lies what the window still reads)."""
         row = self._table_row(slot, pbt)
+        if self._state_layers:
+            # a state is indexed by slot, and not advanced by padding
+            return row, np.int32(slot), np.int32(n)
         if self._window_alloc is None:
             return row
         ring = self._padded(self._window_tables[slot], self._ring)
@@ -2817,9 +2969,11 @@ class DecodeStepper:
         if self._paged_only:
             from distkeras_tpu.models.mla_moe import matmul
 
+            tied = getattr(self._gen._head, "logits", None)
             return (
                 functools.partial(cache, pbt),
-                lambda p_head, x: matmul(x, p_head["kernel"]),  # bf16
+                tied or (
+                    lambda p_head, x: matmul(x, p_head["kernel"])),  # bf16
             )
         if chunk:
             return functools.partial(cache, pbt), head
@@ -3304,7 +3458,12 @@ class DecodeStepper:
                     )
                     for c, new in zip(pool, (k_new, v_new))
                 )
+                scale = getattr(blk, "softmax_scale", None)
                 if in_place:
+                    if scale is not None:
+                        # the kernel's scale is 1 / sqrt(Dh): the layer's
+                        # own goes into the query
+                        q = q * (scale * np.sqrt(hd))
                     return paged_decode_attention(
                         q, *kv, table, lengths,
                         None if w is None else jnp.maximum(pos + 1 - w, 0),
@@ -3322,7 +3481,8 @@ class DecodeStepper:
                            + kpos % ps)
                     see = (kpos >= 0) & (kpos > pos[:, None] - w)
                 kg, vg = (c[idx].reshape(b, -1, kvh, hd) for c in kv)
-                return attend_dense(q[:, None], kg, vg, see[:, None])[:, 0]
+                return attend_dense(
+                    q[:, None], kg, vg, see[:, None], scale)[:, 0]
 
             x, picks = blk.forward(
                 p, x, pos, None, attend, token_mask=active
@@ -3503,6 +3663,7 @@ class DecodeStepper:
 
         def stage(blk, moe, p, pm, x, pool):
             w = blk.window
+            scale = getattr(blk, "softmax_scale", None)
             kv = []
 
             def rows_of(new, c):  # (1, cb, Hkv, Dh) as the pool holds it
@@ -3514,7 +3675,8 @@ class DecodeStepper:
                               for c, new in zip(pool, (k_new, v_new)))
                     kg, vg = (c[ridx].reshape(-1, kvh, hd) for c in kv)
                     return attend_blocked(
-                        q[0], kg, vg, pos, 0, None, blk.key_block)[None]
+                        q[0], kg, vg, pos, 0, None, blk.key_block,
+                        scale=scale)[None]
                 # the window before the chunk, out of the ring as it is
                 prev = start - w + jnp.arange(w)  # negative: no such key
                 pidx = rrow[(prev // ps) % ring] * ps + prev % ps
@@ -3536,10 +3698,73 @@ class DecodeStepper:
                         jax.lax.dynamic_slice_in_dim(mine, off, m, 0),
                         mode="drop"))
                 return attend_blocked(
-                    q[0], *keys, pos, start - w, w, blk.key_block)[None]
+                    q[0], *keys, pos, start - w, w, blk.key_block,
+                    scale=scale)[None]
 
             x, picks = blk.forward(p, x, pos[None], None, attend)
             return x, tuple(kv), picks
+
+        return stage
+
+    def _hybrid_rows(self, pbt: int, tables, rows, pos, active):
+        """Pools that differ by layer, one token a slot. A layer that
+        caches rows is the grouped layout's (``_gqa_rows``). A layer that
+        holds a state a slot (``slot_state``) reads and writes its arrays
+        in place, by slot: a slot at position 0 starts from zeros (its
+        admission's reset: there is nothing before position 0), and a
+        slot that is not decoding keeps its state and its tail as they
+        were (K/V gets away with a write out of range; a state has to be
+        told)."""
+        grouped = self._gqa_rows(pbt, tables, rows, pos, active) \
+            if self._nh is not None else None
+        fresh = pos == 0
+
+        def stage(blk, moe, p, pm, x, pool):
+            if not getattr(blk, "slot_state", None):
+                return grouped(blk, moe, p, pm, x, pool)
+            carry = self._zero_where(fresh, pool)
+            x, carry = blk.forward(p, x, carry, keep=active, step=True)
+            return x, carry, None
+
+        return stage
+
+    @staticmethod
+    def _zero_where(fresh, arrays):
+        """``arrays`` (a slot, or slots, leading) with zeros where ``fresh``
+        (a flag, or one a slot): a sequence's state before its position 0.
+        The one place a state is reset: in the program that runs position
+        0, by a select, never by a host write."""
+        import jax.numpy as jnp
+
+        fresh = jnp.asarray(fresh)
+        return tuple(
+            jnp.where(fresh.reshape(fresh.shape + (1,) * (a.ndim - fresh.ndim)),
+                      jnp.zeros((), a.dtype), a) for a in arrays)
+
+    def _hybrid_chunk(self, pbt: int, where, start, pos):
+        """Pools that differ by layer, one slot's chunk: ``where`` is
+        ``(table row, slot, n)``. A layer that caches rows is the grouped
+        layout's (``_gqa_chunk``). A layer that holds a state a slot takes
+        the slot's state and tail (zeros where the chunk starts at
+        position 0), carries them over the chunk's ``n`` real tokens and
+        no further (the pow2 padding behind them advances nothing), and
+        writes them back to the slot: the next chunk, and then the step,
+        go on from there."""
+        import jax
+
+        trow, slot, n = where
+        grouped = self._gqa_chunk(pbt, trow, start, pos) \
+            if self._nh is not None else None
+
+        def stage(blk, moe, p, pm, x, pool):
+            if not getattr(blk, "slot_state", None):
+                return grouped(blk, moe, p, pm, x, pool)
+            carry = self._zero_where(start == 0, tuple(
+                jax.lax.dynamic_index_in_dim(a, slot, 0) for a in pool))
+            x, carry = blk.forward(p, x, carry, n_valid=n)
+            return x, tuple(
+                jax.lax.dynamic_update_index_in_dim(a, new[0], slot, 0)
+                for a, new in zip(pool, carry)), None
 
         return stage
 
@@ -3617,6 +3842,8 @@ class DecodeStepper:
                 span.set_metadata(attention=self.attention)
                 if self.selector:
                     span.set_metadata(selector=self.selector)
+                if self._state_layers:
+                    span.set_metadata(state_bytes=self._state_bytes(active))
                 self._ctx, self._pools, toks = fn(
                     self._params, self._ctx, self._pools, *host
                 )
@@ -5492,6 +5719,14 @@ class ServingEngine:
                 out["moe"] = dict(self._stepper.moe_stats)
             if self._stepper._select:
                 out["select"] = dict(self._stepper.select_stats)
+            if self._stepper._state_layers:
+                # the layers that hold a state a slot: the bytes of state
+                # the decode steps moved, the slots reset by an admission
+                out["state"] = {
+                    **self._stepper.state_stats,
+                    "layers": self._stepper._state_layers,
+                    "state_bytes_a_slot": self._stepper.state_bytes_a_slot,
+                }
         if self.stream_sender is not None:
             out["streams"] = self.stream_sender.stats()
         out["restarts"] = self._restarts

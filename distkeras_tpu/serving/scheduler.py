@@ -1207,7 +1207,10 @@ class ContinuousBatcher:
         fails it is dropped un-collected BEFORE the probes (it assumed
         an advance that did not happen, and nothing of it has reached
         the host), and the probes run against the slots of ``inf``'s
-        mask that still hold their request. Returns ``(toks, counts,
+        mask that still hold their request. Where the stepper holds a
+        state a slot (``state_a_slot``) a dispatched step HAS advanced
+        what it touched: there are no probes, and every slot of either
+        mask is blamed. Returns ``(toks, counts,
         blamed, used_verify, failed)`` in the variable-advance shape."""
         active = inf.active
         try:
@@ -1234,6 +1237,19 @@ class ContinuousBatcher:
         if later is not None:
             later.drop()
             self._inflight = None
+        if inf.handle is not None and getattr(
+                self.stepper, "state_a_slot", False):
+            # a state a slot: the step ran on the device before its
+            # collect raised (and ``later`` behind it), so every slot of
+            # either mask holds a state ahead of the tokens it delivered,
+            # where pages would only be written again. No probe starts
+            # from the state the failed step saw: all of them fail,
+            # typed, as a blamed slot does
+            if later is not None:
+                with self._lock:
+                    held = held | later.held(self._slots)
+            return (None, None, [int(i) for i in np.flatnonzero(held)],
+                    np.zeros(len(active), bool), True)
         return (*self._assign_blame(held, inf.seqs), True)
 
     def _preempt_phase(self, blocked) -> bool:

@@ -545,3 +545,70 @@ def test_selector_pages_off_the_tiling_are_refused_by_mosaic(
     with pytest.raises(Exception, match="aligned to tiling"):
         _index_step(one_chip, 8, 4, di, page, 64, 16,
                     jnp.bfloat16).compile()
+
+
+def test_the_state_step_and_chunk_programs_compile_for_v5e(one_chip):
+    """The stepper's own step program and a 1,024-token chunk program of
+    Mamba-2 layers beside a grouped-query layer at the configuration's widths
+    (granite-4.0-h-micro: hidden 2048, 64 Mamba heads of 64 with a state of
+    128, convolution 4, blocks of 256; 32 query heads over 8 K/V heads of 64,
+    no rotation; gated MLPs of 8192; 64 slots, pages of 16, a context row of
+    8,192 positions), with what is no width cut so that the CPU holds it: one
+    period of 3 Mamba layers and an attention layer, 512 rows of vocabulary,
+    1,024 pages. The 8 K/V heads of 64 lie side by side in pairs of 128
+    lanes and ride the grouped kernel (``heads_side_by_side``), so there is
+    one step program and no gathered copy of a slot's keys; the state is
+    float32, ``(64, 64, 64, 128)`` a layer, and neither program copies it
+    (donated, updated in place); the compiler's count of their transients
+    fits beside the weights, the states and the pool."""
+    import numpy as np
+
+    import distkeras_tpu.ops.paged_attention as pa
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.serving.engine import DecodeStepper
+
+    model = zoo.granite_hybrid_lm(
+        vocab_size=512, seq_len=8192, hidden_size=2048,
+        num_attention_heads=32, num_key_value_heads=8,
+        shared_intermediate_size=8192,
+        layer_types=("mamba", "mamba", "mamba", "attention"),
+        mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+        mamba_chunk_size=256, attention_multiplier=0.015625)
+    model.params = jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16) if a.ndim >= 2 else a, model.params)
+    st = DecodeStepper(model, num_slots=64, paged=True, page_size=16,
+                       num_pages=1024, kv_dtype=jnp.bfloat16)
+    assert st.layout == "ssm" and st.attention == "kernel"
+    assert st._step_table_buckets() == [512]
+    assert [a.shape for a in st._pools[0]] == [(64, 64, 64, 128),
+                                               (64, 3, 4352)]
+    assert st.state_bytes_a_slot == 3 * (2097152 + 3 * 4352 * 4)
+    pbt = st._max_pages_bucket
+    assert pbt == 512
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one_chip), tree)
+
+    real = pa.pallas_interpret
+    pa.pallas_interpret = lambda: False  # compile the kernel, as the chip
+    try:
+        step = st._build_step_fn_paged(pbt).lower(*shapes((
+            st._params, st._ctx, st._pools, st._lens.copy(),
+            np.zeros(64, bool), st._tables_array(pbt),
+            *st._sampling_args()))).compile()
+        chunk = st._build_chunk_fn_paged(1024, pbt).lower(*shapes((
+            st._params, st._pools, np.zeros((1, 1024), np.int32),
+            st._chunk_where(0, pbt, 0), np.int32(0)))).compile()
+    finally:
+        pa.pallas_interpret = real
+    assert step.as_text().count("tpu_custom_call") >= 1  # the one attention
+    for compiled in (step, chunk):
+        for shape in ("[64,64,64,128]", f"[{1024 * 16},512]"):
+            copies = [ln for ln in compiled.as_text().splitlines()
+                      if " copy(" in ln and shape in ln.split("=")[0]]
+            assert not copies, copies[:3]
+    # 64 slots' step: nothing as large as one layer's states beside them
+    assert step.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.5e9
