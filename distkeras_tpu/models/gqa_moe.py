@@ -13,7 +13,8 @@ arithmetic for all of its uses, with the cache behind ``attend`` as
   pages and attends what the layer may see (``attend_blocked``);
 - the serving engine's decode-step program: one token a slot; ``attend``
   writes the token's key and value and reads the slot's own pages where
-  they lie (``ops.paged_attention.paged_decode_attention``).
+  they lie (``ops.paged_attention.paged_decode_attention``; under the
+  selection's mask where the block selects).
 
 A layer is::
 
@@ -40,7 +41,13 @@ scores highest::
 
 The indexer's arithmetic (``index_inputs``, ``index_scores``, ``select_mask``,
 ``select_rows``) is written once here and used by ``apply``, the chunk
-program and the step program; the selection is exact.
+program and the step program; the selection is exact. It has two forms, one
+a reader: ``select_mask`` (which keys, as a mask: counting passes, no sort)
+for whatever attends under a mask: ``apply``, the chunk's ``attend_selected``
+and, where a kernel serves the stepper's shapes, the decode step's
+``paged_decode_attention(..., chosen=mask)`` over the slot's own pages;
+``select_rows`` (the same keys as positions: ``lax.top_k``) for the step's
+gather body, which reads the selected rows by token.
 
 ``FFN`` is a gated SiLU MLP (``ffn_width``), or ``n_experts`` routed
 experts (softmax scores, the ``top_k`` largest, normalised over the picks

@@ -64,7 +64,8 @@ products are float32 at ``HIGHEST``. Scale, mask, running maximum,
 normaliser and output are float32.
 
 **Layout ``"gqa"``** (``paged_decode_attention`` with fewer K/V heads than
-query heads, a first position, or a ring; ``_grouped_kernel``; PR 35): pools
+query heads, a first position, a ring, or a selection; ``_grouped_kernel``;
+PR 35): pools
 ``(num_pages x page_size, Hkv x Dh)``, the row-major flattening of
 ``(num_pages, page_size, Hkv, Dh)``, held flat for the reason the latent
 pool is (a 4-D bfloat16 pool of 8 heads tiles its two minor axes ``(8, 128)``
@@ -94,6 +95,41 @@ page and masks the positions before it. With ``ring`` the table has ``ring``
 columns and logical page ``p`` lies in column ``p % ring``: a window layer's
 pages are overwritten in place once they are behind the window
 (``serving/engine.py`` ``_build_grouped_pools``).
+
+*Under a selection* (``chosen``, PR 42): a block whose queries read the keys
+an indexer picks (``models.gqa_moe``, ``select``) hands its selection on as a
+mask ``(B, T)`` over the slot's logical positions, and the same body attends
+the slot's own pages under it: a program takes its slot's row as ``(blocks, 1,
+block_pages x page_size)`` int32 in VMEM (192 KB at a table of 3,072 pages),
+block ``i`` reads part ``i`` (whole lanes, no dynamic lane offset), and the
+``where`` that masks by ``first`` and ``length`` masks by it too. No key is
+approximated and none skipped: the softmax runs over exactly the chosen keys,
+as ``attend_selected`` has it in the chunk. It reads EVERY cached page of the
+slot where the gather body read ``topk`` rows, ten times the bytes at a tenth
+of the keys chosen, and is the faster because pages stream where rows are
+paid for one by one (XLA's gather: 14 ns a row of 1 KB, 73 GB/s). *What a
+block costs* at the selecting cell's rows (4 K/V heads of 128: a row is 1 KB,
+a page 16 KB a pool, half the grouped cell's): the same products a byte as
+above, and twice the copies a byte, which is what paces it: 36,677 pages a
+layer (587,000 cached keys on 32 slots, 1.2 GB) take **2.42 ms at blocks of
+16 pages, 2.14 at 32, 2.03 at 64** where the memory alone would take 1.47
+(my chip runs, PR 42, the cell's own step program: 58 ns a page of two
+copies at 32, some 26 ns a copy as PR 40 found for copies under a test, and
+0.2 us a block). Under a selection a block therefore keeps the grouped
+cell's bytes and not its pages (32 pages of these rows; 64 pages cost every
+process that builds the step program 4 s more of tracing, 384 copy sites,
+for 5% of the kernel). Whole blocks copied without a test a page read 1.90
+ms at 32 and 1.81 at 64 for 3 to 8 s more of tracing: left out. The gather
+body it replaces took 1.84 ms of gathers and 1.33 of attention a layer,
+after a sort of 1.37.
+
+*A block with nothing in it.* Without a selection block 0 holds position
+``first`` and every row's running maximum is finite from the start; under
+one a block of 512 positions may hold no chosen key, the first block too,
+``m_new`` stays ``-inf`` and ``exp(-inf - -inf)`` is NaN: the masked form
+subtracts 0 where the maximum is not finite yet (``attend_selected``'s
+``safe``). Whether ``chosen`` is there is decided when the program is
+built; without it the call has the operands and the body it had.
 
 *Precision.* As the latent body: the query and the softmax weights enter as
 ONE bfloat16 term against a bfloat16 pool (what ``models.mla_moe._operands``
@@ -395,7 +431,8 @@ def _paged_decode_attention(q, ck, cv, table, lengths, *, block_pages,
 
 
 def paged_decode_attention(q, ck, cv, table, lengths, first=None, *,
-                           page_size=None, ring=0, block_pages=None):
+                           page_size=None, ring=0, block_pages=None,
+                           chosen=None):
     """Single-query attention of ``B`` slots over a paged pool.
 
     ``q``: ``(B, Hq, Dh)``; ``ck``, ``cv``: the pools of keys and of
@@ -412,13 +449,19 @@ def paged_decode_attention(q, ck, cv, table, lengths, first=None, *,
     ``(B,)`` int32, the positions a slot attends (its own newest
     included), 0 for a slot that is not decoding; ``first``: ``(B,)``
     int32, the first position a slot attends (None: 0; a window layer:
-    ``max(0, length - window)``): pages wholly before it are not copied.
+    ``max(0, length - window)``): pages wholly before it are not copied;
+    ``chosen``: ``(B, pages x page_size)`` bool (or anything whose nonzero
+    means chosen), which of its positions a slot's query reads (None:
+    all): the exact selection of a block that selects
+    (``models.gqa_moe.select_mask``), with no first position and no ring.
     Returns ``(B, Hq, Dh)`` float32: ``softmax(q . k / sqrt(Dh)) . v``
-    over positions ``first <= s < length`` with query head ``j`` on K/V
-    head ``j // (Hq / Hkv)``, zeros where the length is 0.
+    over positions ``first <= s < length`` (that are chosen) with query
+    head ``j`` on K/V head ``j // (Hq / Hkv)``, zeros where the length is
+    0 or nothing is chosen.
 
-    With ``Hkv == Hq``, a 4-D pool, no first position and no ring this is
-    ``_kernel``, the program it was before K/V heads could be fewer.
+    With ``Hkv == Hq``, a 4-D pool, no first position, no ring and no
+    selection this is ``_kernel``, the program it was before K/V heads
+    could be fewer.
     Everything else is ``_grouped_kernel``. Heads narrower than the 128
     lanes that lie side by side in a flat row (``heads_side_by_side``: 8 K/V
     heads of 64 are 4 groups of 128) go through the same body with each
@@ -427,8 +470,12 @@ def paged_decode_attention(q, ck, cv, table, lengths, first=None, *,
     its own head's lanes are kept. No key is moved and the kernel is what
     it was; it multiplies twice the values it needs, which a step that
     waits for memory does not feel."""
+    if chosen is not None and (first is not None or ring):
+        raise ValueError(
+            "a selection is over the table's own positions from 0: it goes "
+            "with no first position and no ring")
     if ck.ndim == 4 and ck.shape[2] == q.shape[1] and first is None \
-            and not ring:
+            and not ring and chosen is None:
         return _paged_decode_attention(
             q, ck, cv, table, lengths,
             block_pages=min(int(block_pages or BLOCK_PAGES),
@@ -443,15 +490,21 @@ def paged_decode_attention(q, ck, cv, table, lengths, first=None, *,
     if side > 1:
         return _narrow_heads(q, ck, cv, table, lengths, first, side,
                              page_size=page_size, ring=ring,
-                             block_pages=block_pages)
+                             block_pages=block_pages, chosen=chosen)
     bp = int(block_pages or GROUPED_BLOCK_PAGES)
+    if chosen is not None and not block_pages:
+        # under a selection a block keeps the grouped cell's BYTES, so
+        # narrower rows go more pages a block (the selecting cell's rows of
+        # 1 KB: 32). Measured there alone (PERF.md §6, PR 42); the maskless
+        # programs keep the block they were measured at
+        bp *= max(1, _GROUPED_ROW_BYTES // (ck.shape[1] * ck.dtype.itemsize))
     if ring:
         # equal blocks that cover the ring: 33 pages go 11 at a time
         bp = -(-int(ring) // -(-int(ring) // bp))
     if first is None:
         first = jnp.zeros_like(lengths)
     return _paged_grouped_attention(
-        q, ck, cv, table, lengths, first, page_size=int(page_size),
+        q, ck, cv, table, lengths, first, chosen, page_size=int(page_size),
         ring=int(ring), block_pages=min(bp, max(1, table.shape[1])),
         interpret=pallas_interpret(),
     )
@@ -481,13 +534,18 @@ def _narrow_heads(q, ck, cv, table, lengths, first, side, **kw):
 # pages a block of the grouped body (16 tokens x 8 heads x 128 a page at
 # the grouped cell's widths: 256 tokens, 1 MiB of keys and values)
 GROUPED_BLOCK_PAGES = 16
+# a row of the pools that block was chosen at: 8 K/V heads of 128, bfloat16
+_GROUPED_ROW_BYTES = 2048
 # the query and the softmax weights as ONE bfloat16 term: what the
 # grouped block's products take everywhere (``models.mla_moe._operands``)
 _GROUPED_TERMS = 1
 
 
-def _grouped_kernel(block_pages, pbt, ps, kvh, ring, lens_ref, first_ref,
-                    table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems):
+def _grouped_kernel(block_pages, pbt, ps, kvh, ring, masked, lens_ref,
+                    first_ref, table_ref, q_ref, *refs):
+    # under a selection the slot's row of ``chosen`` stands before the pools
+    chosen_ref = refs[0] if masked else None
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sems = refs[1:] if masked else refs
     b = pl.program_id(0)
     rows, hd = q_ref.shape[1], q_ref.shape[2]  # Hkv x padded group, Dh
     gp = rows // kvh
@@ -553,11 +611,22 @@ def _grouped_kernel(block_pages, pbt, ps, kvh, ring, lens_ref, first_ref,
             for h in range(kvh)
         ], axis=0) * scale  # (rows, tokens)
         pos = (page0 + i * block_pages) * ps + tok
-        s = jnp.where((pos >= first) & (pos < length), s, -jnp.inf)
-        # block 0 holds position ``first``: every row's maximum is finite
+        keep = (pos >= first) & (pos < length)
+        if masked:
+            # the block's own part of the row: (1, tokens) over the rows
+            keep = keep & (chosen_ref[0, i] != 0)
+        s = jnp.where(keep, s, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)  # 0 where masked
-        corr = jnp.exp(m - m_new)
+        if masked:
+            # a block may hold no chosen key, the first one too: a row with
+            # no key yet keeps -inf, and exp(-inf - 0) = 0 where exp(-inf -
+            # -inf) is NaN (``models.gqa_moe.attend_selected``'s ``safe``)
+            safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        else:
+            # block 0 holds position ``first``: every row's maximum is finite
+            safe = m_new
+        p = jnp.exp(s - safe)  # 0 where masked
+        corr = jnp.exp(m - safe)
         l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
         pv = jnp.concatenate([
             _product(p[h * gp:(h + 1) * gp], v[:, h * hd:(h + 1) * hd], 0,
@@ -581,8 +650,8 @@ def _grouped_kernel(block_pages, pbt, ps, kvh, ring, lens_ref, first_ref,
     jax.jit,
     static_argnames=("page_size", "ring", "block_pages", "interpret"),
 )
-def _paged_grouped_attention(q, ck, cv, table, lengths, first, *, page_size,
-                             ring, block_pages, interpret):
+def _paged_grouped_attention(q, ck, cv, table, lengths, first, chosen=None,
+                             *, page_size, ring, block_pages, interpret):
     b, nh, hd = q.shape
     kvh = ck.shape[1] // hd
     g = nh // kvh
@@ -594,9 +663,22 @@ def _paged_grouped_attention(q, ck, cv, table, lengths, first, *, page_size,
     pad = 0 if ring else -pbt % block_pages  # never read, as above
     if pad:
         table = jnp.pad(table, ((0, 0), (0, pad)))
+    masked = chosen is not None
     kernel = functools.partial(
-        _grouped_kernel, block_pages, pbt + pad, page_size, kvh, ring)
+        _grouped_kernel, block_pages, pbt + pad, page_size, kvh, ring, masked)
     buf = (2, block_pages * page_size, kvh * hd)
+    mask_spec, mask = [], []
+    if masked:
+        # a slot's row as ``(blocks, 1, a block's positions)``: a block
+        # takes its part by its number, whole lanes
+        toks = block_pages * page_size
+        nb = (pbt + pad) // block_pages
+        mask = [jnp.pad(
+            chosen.astype(jnp.int32),
+            ((0, 0), (0, nb * toks - pbt * page_size)),
+        ).reshape(b, nb, 1, toks)]
+        mask_spec = [pl.BlockSpec((1, nb, 1, toks),
+                                  lambda i, *_: (i, 0, 0, 0))]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -604,6 +686,7 @@ def _paged_grouped_attention(q, ck, cv, table, lengths, first, *, page_size,
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, kvh * gp, hd), lambda i, *_: (i, 0, 0)),
+                *mask_spec,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -624,7 +707,7 @@ def _paged_grouped_attention(q, ck, cv, table, lengths, first, *, page_size,
     )(
         lengths.astype(jnp.int32), first.astype(jnp.int32),
         table.astype(jnp.int32).reshape(-1),
-        qg.reshape(b, kvh * gp, hd), ck, cv,
+        qg.reshape(b, kvh * gp, hd), *mask, ck, cv,
     )
     return out.reshape(b, kvh, gp, hd)[:, :, :g].reshape(b, nh, hd)
 

@@ -799,15 +799,17 @@ class DecodeStepper:
                     self._gen.kv_dtype, self.mesh, self.page_size,
                     **({"kv_heads": nh} if grouped else {}),
                 )
-            # how the decode step scores a selecting block's selector keys:
+            # a block that selects asks as any grouped stepper does: where
+            # the answer is "kernel" the grouped body attends the slot's own
+            # pages under the selection's mask (the selected rows lie on
+            # nearly as many pages as there are rows, and whole pages stream
+            # at ten times the rate rows are gathered at); "gather: <why>"
+            # gathers the selected rows by token (``_select_rows``).
+            # how the decode step scores such a block's selector keys:
             # "kernel" (each slot's own selector pages, in place) or
             # "gather: <why>"; None for a block that selects nothing
             self.selector = None
             if self._select:
-                # the kernels walk whole pages; the selected rows lie on
-                # nearly as many pages as there are rows
-                self.attention = (
-                    "gather: the rows an indexer selects, by token")
                 self.selector = decode_attention_path(
                     "index", self._select["head_dim"], self._gen.kv_dtype,
                     self.mesh, self.page_size,
@@ -3499,24 +3501,40 @@ class DecodeStepper:
         ``paged_index_scores`` over each slot's OWN selector pages where
         they lie, to its own length (no gathered copy; PR 40), otherwise
         over every slot's selector rows gathered at the table's extent (a
-        page a row, as they lie); then the exact ``topk`` positions of
-        what a slot can see. Under ``attn/sparse``:
-        the token's key and value written, the physical rows of the
-        selected positions through the table (``table[slot, s // page] x
-        page + s % page``), keys and values of THOSE rows and no others
-        gathered by token, grouped-query attention over them: what a step
-        reads of K and V does not grow with the cached length beyond
-        ``topk`` rows a slot and layer."""
+        page a row, as they lie); then the exact selection of ``topk`` of
+        what a slot can see. Under ``attn/sparse``: the token's key and
+        value written, then one of two bodies, by ``self.attention``:
+
+        ``"kernel"`` (PR 42): the selection as a mask (``select_mask``: 32
+        counting passes, no sort) handed to ``paged_decode_attention`` as
+        ``chosen``: the grouped body streams each slot's own K and V pages
+        where they lie and attends under the mask. It reads every cached
+        page of the slot, ten times the bytes of the selected rows at
+        ``topk`` a tenth of the cache, at the rate whole pages stream at.
+
+        ``"gather: <why>"`` (a mesh, a pool the kernels do not read, pages
+        that are not whole tiles, narrow heads): the selection as
+        positions (``select_rows``: ``lax.top_k``), their physical rows
+        through the table (``table[slot, s // page] x page + s % page``),
+        keys and values of THOSE rows and no others gathered by token,
+        grouped-query attention over them: what this body reads of K and V
+        does not grow with the cached length beyond ``topk`` rows a slot
+        and layer, and it pays by the row.
+
+        The softmax runs over the same keys either way."""
         import jax
         import jax.numpy as jnp
 
-        from distkeras_tpu.models.gqa_moe import attend_dense, select_rows
-        from distkeras_tpu.ops.paged_attention import paged_index_scores
+        from distkeras_tpu.models.gqa_moe import (
+            attend_dense, select_mask, select_rows)
+        from distkeras_tpu.ops.paged_attention import (
+            paged_decode_attention, paged_index_scores)
 
         b, ps = self.num_slots, self.page_size
         kvh, hd = self._nh, self._hd
         topk, di = self._select["topk"], self._select["head_dim"]
         in_place = self.selector == "kernel"
+        streamed = self.attention == "kernel"
         lengths = jnp.where(active, pos + 1, 0)
         # no position lies past the context row: its pages, not the bucket
         table = table[:, : -(-self.max_len // ps)]
@@ -3546,7 +3564,10 @@ class DecodeStepper:
                     else:
                         scores = blk.index_scores(
                             qi[:, None], wi[:, None], ci[table], ps)[:, 0]
-                    idx, valid = select_rows(scores, visible, topk)
+                    if streamed:
+                        chosen = select_mask(scores, visible, topk)
+                    else:
+                        idx, valid = select_rows(scores, visible, topk)
                 with jax.named_scope("attn/sparse"):
                     kv = [
                         c.at[jnp.where(active, at, c.shape[0])].set(
@@ -3554,9 +3575,13 @@ class DecodeStepper:
                             mode="drop")
                         for c, new in ((ck, k_new), (cv, v_new))
                     ]
+                    written.extend((*kv, ci))
+                    if streamed:
+                        return paged_decode_attention(
+                            q, *kv, table, lengths, page_size=ps,
+                            chosen=chosen)
                     phys = table[rows[:, None], idx // ps] * ps + idx % ps
                     kg, vg = (c[phys].reshape(b, -1, kvh, hd) for c in kv)
-                    written.extend((*kv, ci))
                     return attend_dense(
                         q[:, None], kg, vg, valid[:, None])[:, 0]
 

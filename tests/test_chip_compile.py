@@ -191,40 +191,48 @@ def test_paged_decode_attention_compiles_for_v5e(
 
 # the grouped cell's shapes (laguna-s-2.1-5l-ep4: 96 slots, 8 K/V heads of
 # 128, pages of 16 tokens): a full layer's 48 query heads over a table of
-# 1,024 pages, a window layer's 72 over a ring of 33 from a first position
+# 1,024 pages, a window layer's 72 over a ring of 33 from a first position;
+# and the selecting cell's (keye-vl-2.0-30b-a3b-6l-ep8: 32 slots, 32 query
+# heads over 4 K/V heads of 128, a table of 3,072 pages) under the
+# selection's mask, a slot's row of 49,152 positions a program
 GROUPED_SHAPES = [
-    pytest.param(96, 48, 43008, 1024, 0, id="laguna-full"),
-    pytest.param(96, 72, 96 * 33 + 1, 33, 33, id="laguna-window"),
+    pytest.param(96, 48, 8, 43008, 1024, 0, False, id="laguna-full"),
+    pytest.param(96, 72, 8, 96 * 33 + 1, 33, 33, False, id="laguna-window"),
+    pytest.param(32, 32, 4, 47616, 3072, 0, True, id="keye-selected"),
 ]
 
 
-@pytest.mark.parametrize("b,nh,pages,pbt,ring", GROUPED_SHAPES)
+@pytest.mark.parametrize("b,nh,kvh,pages,pbt,ring,masked", GROUPED_SHAPES)
 def test_grouped_paged_attention_compiles_for_v5e(
-    one_chip, b, nh, pages, pbt, ring
+    one_chip, b, nh, kvh, pages, pbt, ring, masked
 ):
     """The decode step's row write into the flat pool, then the grouped
     kernel over the written pool: Mosaic takes a K/V head's lane-aligned
     slice of a copied page and a group of 6 or 9 queries padded to whole
-    tiles; the module holds the kernel and no copy of a pool."""
+    tiles, and under a selection a block's own part of the slot's row of
+    ``chosen`` by the block's number; the module holds the kernel and no
+    copy of a pool."""
     from distkeras_tpu.ops.paged_attention import (
         GROUPED_BLOCK_PAGES,
         _paged_grouped_attention,
     )
 
-    kvh, hd, ps = 8, 128, 16
+    hd, ps = 128, 16
     bp = GROUPED_BLOCK_PAGES
     if ring:
         bp = -(-ring // -(-ring // bp))
+    if masked:  # the grouped cell's bytes a block: 32 pages of 1 KB rows
+        bp *= 2048 // (kvh * hd * 2)
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    def step(q, new, ck, cv, at, table, lengths, first):
+    def step(q, new, ck, cv, at, table, lengths, first, *chosen):
         ck = ck.at[at].set(new.astype(ck.dtype))
         cv = cv.at[at].set(new.astype(cv.dtype))
         o = _paged_grouped_attention(
-            q, ck, cv, table, lengths, first, page_size=ps, ring=ring,
-            block_pages=bp, interpret=False,
+            q, ck, cv, table, lengths, first, *chosen, page_size=ps,
+            ring=ring, block_pages=bp, interpret=False,
         )
         return o, ck, cv
 
@@ -233,6 +241,7 @@ def test_grouped_paged_attention_compiles_for_v5e(
     text = jax.jit(step, donate_argnums=(2, 3)).lower(
         s((b, nh, hd), jnp.float32), s((b, kvh * hd), jnp.float32), pool,
         pool, idx, s((b, pbt), jnp.int32), idx, idx,
+        *([s((b, pbt * ps), jnp.bool_)] if masked else []),
     ).compile().as_text()
     assert "tpu_custom_call" in text
     pool_shape = f"[{pages * ps},{kvh * hd}]"
@@ -317,12 +326,14 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
     2,048, experts of 768, top 8 of 128 router outputs, theta 1e7, 32 slots,
     pages of 16, a context row of 49,152 positions), with what is no width
     cut so that the CPU holds it: 2 layers, 2 experts held a layer, 512 rows
-    of vocabulary, 4,096 pages. The step gathers 2,048 K and V rows a slot
-    and scores the selector keys where their pages lie (``paged_index_scores``
-    a layer, under ``attn/index``: no gather of the selector rows at the
-    table's extent); a page of the selector pool is a tile of 8 rows of two
-    keys; neither program copies or transposes a pool; the compiler's count
-    of their transients fits beside the pools."""
+    of vocabulary, 4,096 pages. The step scores the selector keys where their
+    pages lie (``paged_index_scores`` a layer, under ``attn/index``: no
+    gather of the selector rows at the table's extent) and attends the K and
+    V pages where they lie under the selection's mask
+    (``paged_decode_attention`` a layer, under ``attn/sparse``: no sort of
+    the scores, no gather of 2,048 rows a slot); a page of the selector pool
+    is a tile of 8 rows of two keys; neither program copies or transposes a
+    pool; the compiler's count of their transients fits beside the pools."""
     import numpy as np
 
     import distkeras_tpu.ops.paged_attention as pa
@@ -341,7 +352,7 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
                                 model.params)
     st = DecodeStepper(model, num_slots=32, paged=True, page_size=16,
                        num_pages=4096, kv_dtype=jnp.bfloat16)
-    assert st.attention.startswith("gather: the rows an indexer selects")
+    assert st.attention == "kernel" == st.paged_stats()["attention"]
     assert st.selector == "kernel" and st.paged_stats()["selector"] == "kernel"
     assert st._index_page == (8, 128) and st.chunk_cap == 2048
     assert [a.shape for a in st._pools[0]] == [
@@ -369,10 +380,18 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
     finally:
         pa.pallas_interpret = real
     text = step.as_text()
-    # the selected rows, K and V of each layer; nothing of the K/V row width
-    # at the table's extent
-    assert text.count("bf16[32,2048,512]") >= 4
+    # K and V: a kernel call a layer under the scope that
+    # ``sparse_attn_decode_roofline`` reads; no rows gathered, the selected
+    # by token or all at the table's extent, and no sort of the scores
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "paged_decode_attention" in ln]
+    assert len(calls) == 2, calls
+    for call in calls:
+        assert "/attn/sparse/" in call.split('op_name="')[1].split('"')[0]
+    assert "bf16[32,2048,512]" not in text
     assert "[32,49152,512]" not in text and "[32,65536,512]" not in text
+    assert not [ln for ln in text.splitlines()
+                if " sort(" in ln and "49152" in ln.split("=")[0]]
     # the selector keys: a kernel call a layer under the scope that
     # ``index_decode_roofline`` reads, and nothing of them at the table's
     # extent (the gather body's ``ci[table]`` and its packed products)

@@ -51,8 +51,16 @@ KERNEL_CONFIG = {
     **CONFIG,
     "sa_config": {**CONFIG["sa_config"], "indexer_head_dim": 64},
 }
-# how the step scores the selector keys -> (configuration, page size)
-SELECTORS = {"gather": (CONFIG, 4), "kernel": (KERNEL_CONFIG, 16)}
+# and with K/V heads of 128: a page of keys is whole tiles too, and the
+# grouped body attends the slot's pages under the selection's mask
+STREAM_CONFIG = {**KERNEL_CONFIG, "head_dim": 128}
+# how the step scores the selector keys and reads K and V -> (configuration,
+# page size, what ``selector`` and ``attention`` say)
+SELECTORS = {
+    "gather": (CONFIG, 4, "gather", "gather: heads of 16"),
+    "kernel": (KERNEL_CONFIG, 16, "kernel", "gather: heads of 16"),
+    "streamed": (STREAM_CONFIG, 16, "kernel", "kernel"),
+}
 
 # float32 weights and a float32 cache on both sides, every product at
 # precision HIGHEST (the CPU's float32 either way): logits of size 0.4 read
@@ -74,6 +82,16 @@ def tiny(fam):
 @pytest.fixture(scope="module")
 def tiny_kernel(fam):
     return _seeded(fam, KERNEL_CONFIG)
+
+
+@pytest.fixture(scope="module")
+def tiny_stream(fam):
+    return _seeded(fam, STREAM_CONFIG)
+
+
+@pytest.fixture
+def tinies(tiny, tiny_kernel, tiny_stream):
+    return {"gather": tiny, "kernel": tiny_kernel, "streamed": tiny_stream}
 
 
 def _seeded(fam, config):
@@ -211,7 +229,6 @@ def _stepper_logits(model, prompt, n_new, kv_dtype, chunk, round_selector=None,
     selector pools are rounded through once the prompt is prefilled."""
     st = DecodeStepper(model, num_slots=3, paged=True, page_size=page_size,
                        num_pages=240 // page_size, kv_dtype=kv_dtype)
-    assert st.attention.startswith("gather: the rows an indexer selects")
     seen = []
     norm, real = st._gen._final_ln, st._gen._final_ln.apply
 
@@ -243,11 +260,15 @@ def _stepper_logits(model, prompt, n_new, kv_dtype, chunk, round_selector=None,
     return chunks, toks, np.stack([h[slot] for h in seen]) @ head, st
 
 
-@pytest.mark.parametrize("selector", ["gather", "kernel"])
-@pytest.mark.parametrize("chunk", [16, 5, 64], ids=[
-    "whole-pages", "odd-chunks", "one-chunk"])
+_CHUNKS = {"whole-pages": 16, "odd-chunks": 5, "one-chunk": 64}
+
+
+@pytest.mark.parametrize("chunk,selector", [
+    pytest.param(chunk, selector, id=f"{name}-{selector}")
+    for selector in ("gather", "kernel") for name, chunk in _CHUNKS.items()
+] + [pytest.param(16, "streamed", id="whole-pages-streamed")])
 def test_chunked_prefill_then_paged_decode_gives_the_reference_s_logits(
-        fam, tiny, tiny_kernel, chunk, selector):
+        fam, tinies, chunk, selector):
     """Logits, not tokens: every decode step's logits against the
     reference's full forward over the prompt and the served tokens, for a
     request of 65 positions, eight times ``topk``: every chunk after the
@@ -256,13 +277,19 @@ def test_chunked_prefill_then_paged_decode_gives_the_reference_s_logits(
     row: 4 keys of 8 values) gathered at the table's extent; ``kernel``:
     selector keys of 64 on pages of 16, a page a tile of 8 rows of two
     keys, scored where the pages lie by ``paged_index_scores``
-    (interpreted). The same comparison fails from a K/V cache rounded to
-    float16, and from a selector cache rounded to 8 bits (float8, 3 bits of
-    mantissa): float16 moves no score of these 36 selections across its
-    threshold (the comparison sees the selector's precision only through a
-    changed pick, and then by 0.05: one key of 8 is another)."""
-    w, weights, f32 = tiny if selector == "gather" else tiny_kernel
-    ps = SELECTORS[selector][1]
+    (interpreted); both gather the selected K and V rows by token (heads
+    of 16). ``streamed``: K/V heads of 128 besides, so the step hands the
+    selection on as a mask and ``paged_decode_attention`` attends the
+    slot's own pages under it (interpreted): the same logits, from no
+    ``top_k`` and no gathered row. The same comparison fails from a K/V
+    cache rounded to float16 (for which there is no kernel: that stepper
+    of the ``streamed`` case gathers), and from a selector cache rounded
+    to 8 bits (float8, 3 bits of mantissa): float16 moves no score of these
+    36 selections across its threshold (the comparison sees the selector's
+    precision only through a changed pick, and then by 0.05: one key of 8
+    is another)."""
+    w, weights, f32 = tinies[selector]
+    _, ps, says_selector, says_attention = SELECTORS[selector]
     prompt = np.random.default_rng(1).integers(0, w["vocab"], 53)
     with jax.default_matmul_precision("highest"):
         chunks, toks, got, st = _stepper_logits(
@@ -270,13 +297,17 @@ def test_chunked_prefill_then_paged_decode_gives_the_reference_s_logits(
         _, toks16, got16, _ = _stepper_logits(
             _model(fam, w, f32), prompt, 12, None, chunk,
             round_selector=jnp.float8_e4m3fn, page_size=ps)
-        _, tokskv, gotkv, _ = _stepper_logits(
+        _, tokskv, gotkv, stkv = _stepper_logits(
             _model(fam, w, f32), prompt, 12, jnp.float16, chunk, page_size=ps)
     assert chunks == -(-52 // chunk)
-    assert st.selector.startswith(selector)
+    assert st.selector.startswith(says_selector)
+    assert st.attention.startswith(says_attention)
+    assert stkv.attention.startswith("gather: ")
     assert st.paged_stats()["selector"] == st.selector
+    assert st.paged_stats()["attention"] == st.attention
     assert st.layout == "gqa"
-    assert st._index_page == {"gather": (32,), "kernel": (8, 128)}[selector]
+    assert st._index_page == {
+        "gather": (32,), "kernel": (8, 128)}[says_selector]
     assert st._pools[0][2].shape == (240 // ps, *st._index_page)
     assert st._kv_alloc.pages_in_use == -(-65 // ps)
     seq = np.concatenate([prompt, toks])
@@ -377,6 +408,40 @@ def test_the_lowered_step_of_a_kernel_selector_gathers_no_selector_rows(
     for gone in ("3x16x1024", "3x16x8x128", "3x128x128"):
         assert gone not in gathers, gone
     assert text.count("paged_index_scores") >= 3
+    assert re.search(r"attn/index[^\n]*paged_index_scores", text)
+
+
+def test_the_lowered_step_of_a_streaming_stepper_sorts_and_gathers_no_rows(
+        fam, tiny_stream):
+    """Where ``attention`` is ``"kernel"`` too (K/V heads of 128 on pages of
+    16) the step program holds the grouped kernel's call a layer under
+    ``attn/sparse`` beside the selector's under ``attn/index``, and neither
+    a ``lax.top_k`` of the scores (the sort of the gather body) nor a gather
+    of K/V rows (rows of Hkv x Dh = 256 values), by token or at the table's
+    extent: the selection travels as a mask. The page RMW of the token's own
+    selector key is the one gather of a pool."""
+    w, _, f32 = tiny_stream
+    model = fam.build_program_model({**w, "seq": 256}, f32, {})
+    st = DecodeStepper(model, num_slots=3, paged=True, page_size=16,
+                       num_pages=60)
+    pbt = st._max_pages_bucket
+    assert st.selector == st.attention == "kernel" and pbt == 16
+    assert st._step_table_buckets() == [pbt]
+    assert [a.shape for a in st._pools[0]] == [
+        (960, 256), (960, 256), (60, 8, 128)]
+    text = st._build_step_fn_paged(pbt).lower(
+        st._params, st._ctx, st._pools, st._lens.copy(), np.zeros(3, bool),
+        st._tables_array(pbt), *st._sampling_args()).as_text(debug_info=True)
+    # the router's top 3 of 16 is the one ``top_k`` left: none over the
+    # table's 256 positions
+    assert re.search(r"top_k[^\n]*tensor<3x16xf32>", text)
+    assert not re.search(r"top_k[^\n]*tensor<3x256xf32>", text)
+    gathers = re.findall(r'"?stablehlo\.gather"?.*-> tensor<([0-9x]+)xf32>',
+                         text)
+    assert not [g for g in gathers if g.endswith("x256")], gathers
+    assert gathers.count("3x8x128") == 3  # the token's page, a layer
+    assert text.count("paged_decode_attention") >= 3
+    assert re.search(r"attn/sparse[^\n]*_paged_grouped_attention", text)
     assert re.search(r"attn/index[^\n]*paged_index_scores", text)
 
 
@@ -490,46 +555,55 @@ def test_the_scopes_are_in_apply_chunk_and_step_alike(fam, tiny):
         assert "moe/shared" not in text and "attn/full" not in text
 
 
-@pytest.mark.parametrize("selector", ["gather", "kernel"])
+@pytest.mark.parametrize("selector", ["gather", "kernel", "streamed"])
 def test_the_serving_engine_serves_the_reference_s_tokens(
-        fam, tiny, tiny_kernel, tmp_path, selector):
+        fam, tinies, tmp_path, selector):
     """Through ``quantize_model(bits=16)``, a bundle and
     ``ServingEngine.from_bundle(paged=True)``: concurrent requests several
     ``topk`` long, prefill in chunks beside decode, greedy; every served
     token's reference logit against the reference's best; the selection's
     and the routing's counters. ``gather``: selector keys of 8 on pages of
-    8, gathered; ``kernel``: keys of 64 on pages of 16, scored in place."""
+    8, gathered; ``kernel``: keys of 64 on pages of 16, scored in place;
+    ``streamed``: K/V heads of 128 besides, attended where the pages lie
+    under the selection's mask (``attention`` says ``"kernel"``)."""
     from distkeras_tpu.utils.serialization import save_serving_bundle
 
-    w, weights, f32 = tiny if selector == "gather" else tiny_kernel
-    ps = {"gather": 8, "kernel": 16}[selector]
+    w, weights, f32 = tinies[selector]
+    ps = {"gather": 8, "kernel": 16, "streamed": 16}[selector]
     model = quantize_model(_model(fam, w, weights), bits=16)
     path = str(tmp_path / "tiny.dkt")
     save_serving_bundle(path, model)
-    eng = ServingEngine.from_bundle(
-        path, num_slots=4, paged=True, page_size=ps, num_pages=960 // ps,
-        prefill_chunk=16)
-    eng._stepper.warmup()
-    eng._stepper.warm_prefill_buckets()
-    eng.start()
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, w["vocab"], n).astype(np.int32)
                for n in (5, 37, 60, 90, 12, 70)]
-    out = {}
 
-    def go(i):
-        out[i] = np.asarray(eng.generate(prompts[i], 16))
+    def serve(attention=None):
+        eng = ServingEngine.from_bundle(
+            path, num_slots=4, paged=True, page_size=ps, num_pages=960 // ps,
+            prefill_chunk=16)
+        if attention:  # before any program is built: the step reads it then
+            eng._stepper.attention = attention
+        eng._stepper.warmup()
+        eng._stepper.warm_prefill_buckets()
+        eng.start()
+        out = {}
 
-    threads = [threading.Thread(target=go, args=(i,)) for i in range(6)]
-    [t.start() for t in threads]
-    [t.join() for t in threads]
-    stats, health = eng.stats(), eng.health()
-    eng.stop()
+        def go(i):
+            out[i] = np.asarray(eng.generate(prompts[i], 16))
+
+        threads = [threading.Thread(target=go, args=(i,)) for i in range(6)]
+        [t.start() for t in threads]
+        [t.join() for t in threads]
+        stats, health = eng.stats(), eng.health()
+        eng.stop()
+        return out, stats, health
+
+    out, stats, health = serve()
     assert health["status"] == "serving" and stats["restarts"] == 0
     paged = stats["paged"]
     assert paged["layout"] == "gqa" and paged["pages_in_use"] == 0
-    assert paged["selector"].startswith(selector)
-    assert paged["attention"].startswith("gather: the rows an indexer")
+    assert paged["selector"].startswith(SELECTORS[selector][2])
+    assert paged["attention"].startswith(SELECTORS[selector][3])
     assert paged["bytes_per_token_by_kind"]["index"] == (
         3 * 4 * w["index_dim"])
     sel = stats["select"]
@@ -538,6 +612,18 @@ def test_the_serving_engine_serves_the_reference_s_tokens(
     moe = stats["moe"]
     assert moe["experts_total"] == 16 and moe["zero_picks"] == 0
     assert moe["held_picks"] == moe["routed_tokens"] * 9
+    if selector == "streamed":
+        # heads of 128 round other picks than heads of 16 (one request
+        # reads 0.11 from either body): held to the gather body's tokens,
+        # the same stepper told to gather, and to its counters
+        rows, gathered, _ = serve("gather: the test says so")
+        assert gathered["paged"]["attention"].startswith("gather: the test")
+        for count in ("keys_cached", "keys_selected"):  # steps: the mix's
+            assert gathered["select"][count] == sel[count]
+        for i, seq in out.items():
+            assert len(seq) == len(prompts[i]) + 16
+            np.testing.assert_array_equal(seq, rows[i])
+        return
     with jax.default_matmul_precision("highest"):
         for i, seq in out.items():
             assert len(seq) == len(prompts[i]) + 16

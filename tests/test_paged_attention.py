@@ -458,6 +458,180 @@ def test_grouped_queries_over_eight_kv_heads(hq, windowed, dtype):
     assert not got[0].any()
 
 
+# which keys a slot's query reads, as the selecting step hands them on: a
+# case -> (lengths, topk, scores a slot or None for seeded ones); blocks of
+# two pages (32 positions), four K/V heads of 128 under 32 queries
+_FAR, _NEAR = 1.0e3, -1.0e3
+
+
+def _dense_under(q, ck, cv, table, chosen, kvh):
+    """``attend_dense`` under ``chosen`` over every slot's pages gathered at
+    the table's extent: what the selecting step's gather body computes."""
+    from distkeras_tpu.models.gqa_moe import attend_dense
+
+    b, t = chosen.shape
+    hd = q.shape[-1]
+    rows = (table[:, :, None] * PS + np.arange(PS)).reshape(b, t)
+    return np.asarray(attend_dense(
+        jnp.asarray(q)[:, None], ck[rows].reshape(b, t, kvh, hd),
+        cv[rows].reshape(b, t, kvh, hd), chosen[:, None]))[:, 0]
+
+
+def _selection_cases():
+    ramp = np.arange(12 * PS, dtype=np.float32)
+    return {
+        # the k highest scores lie past the first block of 32 positions
+        "first-block-empty": ([9 * PS + 3, 8 * PS], 24, [ramp, ramp]),
+        # they lie in the first and the last block: nothing between
+        "middle-block-empty": ([10 * PS, 7 * PS + 9], 20, [
+            np.where((ramp < 10) | (ramp >= 9 * PS), _FAR - ramp, _NEAR),
+            np.where((ramp < 10) | (ramp >= 6 * PS), _FAR - ramp, _NEAR)]),
+        "a-slot-of-length-0": ([0, 6 * PS + 1, 0], 16, None),
+        # fewer visible keys than k: every one of them is chosen
+        "fewer-visible-than-topk": ([20, 3 * PS, 1], 64, None),
+        "a-length-inside-a-page": ([4 * PS + 5, 2 * PS + 15, 7], 12, None),
+        # eight keys AT the threshold and room for three: the lowest three
+        "a-tie-at-the-threshold": ([8 * PS, 5 * PS + 2], 8, [
+            np.where(ramp % 7 == 3, 2.0, np.where(ramp % 5 == 0, 1.0, 0.0)),
+            np.where(ramp % 9 == 1, 2.0, 1.0)]),
+    }
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(_selection_cases()))
+def test_the_grouped_body_under_a_selection_is_dense_attention_under_its_mask(
+        case, dtype):
+    """``chosen``: the grouped body attends each slot's own pages under the
+    selection's mask and gives what ``attend_dense`` gives under the same
+    mask over the pages gathered at the table's extent: the exact selection
+    of ``select_mask`` from scores that leave the first block of positions
+    without a chosen key, a middle block without one, a slot of length 0
+    (zeros), fewer visible keys than ``topk``, a length that ends inside a
+    page, and a tie at the threshold. A running softmax that meets a block
+    with nothing in it must not turn ``-inf - -inf`` into NaN."""
+    from distkeras_tpu.models.gqa_moe import select_mask
+
+    lengths, topk, scores = _selection_cases()[case]
+    rng = np.random.default_rng(len(case))
+    lengths = np.asarray(lengths, np.int32)
+    b, hq, kvh, pbt, num_pages = len(lengths), 32, 4, 12, 48
+    t = pbt * PS
+    if scores is None:
+        scores = rng.normal(size=(b, t)).astype(np.float32)
+    scores = jnp.asarray(np.stack(scores)[:, :t], jnp.float32)
+    visible = jnp.arange(t)[None, :] < lengths[:, None]
+    chosen = select_mask(scores, visible, topk)
+    counts = np.asarray(chosen).sum(-1)
+    assert counts.tolist() == np.minimum(lengths, topk).tolist()
+    if case.endswith("-block-empty"):
+        empty = 0 if case.startswith("first") else 1
+        assert not np.asarray(chosen)[:, empty * 2 * PS:(empty + 1) * 2 * PS
+                                      ].any()
+    table = np.zeros((b, pbt), np.int32)
+    free = iter(rng.permutation(np.arange(1, num_pages)))
+    for i, n in enumerate(-(-lengths // PS)):
+        table[i, :n] = [next(free) for _ in range(n)]
+    q = rng.normal(size=(b, hq, HD)).astype(np.float32)
+    ck, cv = (jnp.asarray(rng.normal(size=(num_pages * PS, kvh * HD)), dtype)
+              for _ in range(2))
+    got = np.asarray(paged_decode_attention(
+        q, ck, cv, table, lengths, page_size=PS, block_pages=2,
+        chosen=chosen))
+    want = _dense_under(q, ck, cv, table, chosen, kvh)
+    big = float(np.abs(np.asarray(cv, np.float32)).max())
+    # float32: the order of the sums; bfloat16: the one term the query and
+    # the weights are rounded to on both sides, at another running maximum
+    tol = 130 * EPS * big if dtype == jnp.float32 else 0.03 * big
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    live = lengths > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=0, atol=tol)
+    assert not got[~live].any()
+    if case == "fewer-visible-than-topk":
+        # every visible key chosen: the selection masks nothing more
+        np.testing.assert_array_equal(got, paged_decode_attention(
+            q, ck, cv, table, lengths, page_size=PS, block_pages=2))
+
+
+def test_a_selection_rides_with_heads_that_lie_side_by_side():
+    """K/V heads of 64, two in a group of 128 lanes (``heads_side_by_side``):
+    ``chosen`` goes through the wide query's call untouched and the lanes
+    that come back are the head's own under the same mask."""
+    from distkeras_tpu.models.gqa_moe import select_mask
+
+    rng = np.random.default_rng(64)
+    lengths = np.array([5 * PS + 2, 0, 3 * PS], np.int32)
+    b, hq, kvh, hd, pbt, num_pages = 3, 8, 2, 64, 6, 24
+    t = pbt * PS
+    chosen = select_mask(
+        jnp.asarray(rng.normal(size=(b, t)), jnp.float32),
+        jnp.arange(t)[None, :] < lengths[:, None], 20)
+    table = np.zeros((b, pbt), np.int32)
+    free = iter(rng.permutation(np.arange(1, num_pages)))
+    for i, n in enumerate(-(-lengths // PS)):
+        table[i, :n] = [next(free) for _ in range(n)]
+    q = rng.normal(size=(b, hq, hd)).astype(np.float32)
+    ck, cv = (jnp.asarray(rng.normal(size=(num_pages * PS, kvh * hd)),
+                          jnp.float32) for _ in range(2))
+    got = np.asarray(paged_decode_attention(
+        q, ck, cv, table, lengths, page_size=PS, block_pages=2,
+        chosen=chosen))
+    want = _dense_under(q, ck, cv, table, chosen, kvh)
+    tol = 130 * EPS * float(np.abs(np.asarray(cv)).max())
+    np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], rtol=0, atol=tol)
+    assert np.isfinite(got).all() and not got[1].any()
+
+
+def test_a_selection_is_one_more_operand_and_the_maskless_call_has_none():
+    """Whether ``chosen`` is there is decided when the program is built: the
+    maskless call's kernel takes the six operands it took (lengths, first
+    positions and table prefetched, the queries, the two pools) and its body
+    compares nothing with a selection; under ``chosen`` the slot's row
+    stands before the pools. A selection goes with no first position and no
+    ring (its positions are the table's own, from 0)."""
+    import jax
+
+    q = np.zeros((2, 16, HD), np.float32)
+    ck = cv = jnp.zeros((8 * PS, 2 * HD), jnp.bfloat16)
+    table, lengths = np.zeros((2, 4), np.int32), np.array([5, 40], np.int32)
+
+    def call_of(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: paged_decode_attention(
+            *a, page_size=PS, **kw))(q, ck, cv, table, lengths)
+        (inner,) = [e.params["jaxpr"] for e in jaxpr.eqns
+                    if "jaxpr" in e.params]  # the jitted wrapper
+        (call,) = [e for e in inner.eqns if e.primitive.name == "pallas_call"]
+        return call
+
+    plain = call_of()
+    assert [v.aval.shape for v in plain.invars] == [
+        (2,), (2,), (8,), (2, 16, HD), (8 * PS, 2 * HD), (8 * PS, 2 * HD)]
+    body = str(plain.params["jaxpr"])
+    masked = call_of(chosen=np.ones((2, 4 * PS), bool))
+    assert [v.aval.shape for v in masked.invars] == [
+        (2,), (2,), (8,), (2, 16, HD), (2, 1, 1, 4 * PS),
+        (8 * PS, 2 * HD), (8 * PS, 2 * HD)]
+    # the row's test and the guard of a block with nothing in it: one
+    # comparison and one choice more than the maskless body, which has
+    # neither
+    for op, more in ((" ne ", 1), ("name=_where", 1), (" exp ", 0)):
+        assert str(masked.params["jaxpr"]).count(op) == body.count(op) + more
+    # a block under a selection keeps the grouped cell's bytes (16 pages of
+    # 2 KB rows): 64 pages of these bfloat16 rows of 512 bytes, 32 of the
+    # same rows in float32
+    wide = np.zeros((2, 128), np.int32)
+    for pool, blocks in ((ck, 2), (ck.astype(jnp.float32), 4)):
+        call = jax.make_jaxpr(lambda *a: paged_decode_attention(
+            *a, page_size=PS, chosen=np.ones((2, 128 * PS), bool)))(
+                q, pool, pool, wide, lengths)
+        assert f"i32[2,{blocks},1,{128 // blocks * PS}]" in str(call)
+    for first, ring in ((None, 4), (lengths, 0)):
+        with pytest.raises(ValueError, match="no first position and no ring"):
+            paged_decode_attention(
+                q, ck, cv, table, lengths, first, page_size=PS, ring=ring,
+                chosen=np.ones((2, 4 * PS), bool))
+
+
 def test_a_4d_pool_of_fewer_kv_heads_is_the_flat_pool():
     """``(pages, page, Hkv, Dh)`` as the docstring gives the pool: accepted,
     and the same numbers as its row-major flattening."""

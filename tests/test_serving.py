@@ -1545,7 +1545,7 @@ def test_selector_pages_are_reserved_and_released_with_their_k_v_pages():
     assert stats["bytes_per_token_by_kind"] == {
         "full": 2 * (2 * 2 * 16 * 4), "index": 2 * 8 * 4}
     assert stats["bytes_per_token"] == st.kv_bytes_per_token() == 576
-    assert stats["attention"].startswith("gather: the rows an indexer")
+    assert stats["attention"].startswith("gather: heads of 16")
     for slot in range(2):
         st.release(slot)
     assert st._kv_alloc.pages_in_use == 0 and st._tables == [[], [], []]

@@ -129,25 +129,34 @@ def test_pages_in_use_on_the_span_is_the_allocator_s(paged_engine, tmp_path):
     assert paged_engine.stats()["paged"]["pages_in_use"] <= max(held)
 
 
-@pytest.mark.parametrize("selector,sa,page_size", [
-    ("gather: selector pages of 4 keys of 8", None, 4),
-    ("kernel", {"indexer_num_heads": 2, "indexer_head_dim": 64,
-                "indexer_num_kv_heads": 1, "topk": 8}, 16),
-], ids=["gather", "kernel"])
+_KEYS_OF_64 = {"indexer_num_heads": 2, "indexer_head_dim": 64,
+               "indexer_num_kv_heads": 1, "topk": 8}
+
+
+@pytest.mark.parametrize("selector,attention,sa,page_size,head_dim", [
+    ("gather: selector pages of 4 keys of 8", "gather: heads of 16", None, 4,
+     16),
+    ("kernel", "gather: heads of 16", _KEYS_OF_64, 16, 16),
+    ("kernel", "kernel", _KEYS_OF_64, 16, 128),
+], ids=["gather", "kernel", "streamed"])
 def test_collect_span_carries_the_selection_s_counters(
-        tmp_path, selector, sa, page_size):
+        tmp_path, selector, attention, sa, page_size, head_dim):
     """A block whose keys an indexer selects: every ``serving/collect`` span
     of a traced drive carries ``keys_cached`` and ``keys_selected`` beside
     the routing counters, in the profile as the benchmark's reader finds
     them, and ``stats()["select"]`` sums the same steps; every
-    ``serving/step`` span says beside ``attention`` how the step scored the
-    selector keys (``selector``: gathered, or by the kernel in place), the
-    word of ``stats()["paged"]["selector"]``."""
+    ``serving/step`` span says beside ``attention`` (how the step reads K
+    and V: the selected rows gathered by token, or with heads of 128 the
+    slot's pages where they lie under the selection's mask: ``"kernel"``)
+    how the step scored the selector keys (``selector``: gathered, or by the
+    kernel in place), the words of ``stats()["paged"]``; the counters are
+    the same whichever body attends."""
     from distkeras_tpu.models import zoo
     from distkeras_tpu.serving import ServingEngine
 
     engine = ServingEngine(
-        zoo.keye_lm(vocab_size=61, seq_len=64, hidden_size=32, sa_config=sa),
+        zoo.keye_lm(vocab_size=61, seq_len=64, hidden_size=32, sa_config=sa,
+                    head_dim=head_dim),
         num_slots=2, paged=True, page_size=page_size, prefill_chunk=8)
     engine.start()
     try:
@@ -168,7 +177,7 @@ def test_collect_span_carries_the_selection_s_counters(
         its, "serving/step", "attention"))
     # a span's argument ends at its first comma (the annotation's syntax)
     assert paged["attention"].startswith(said) and said.startswith(
-        "gather: the rows an indexer selects")
+        attention)
     rows = [a for n, _s, _d, _t, a in plain["spans"]
             if n == "serving/collect" and "keys_cached" in a]
     assert len(rows) == after["steps"] - before["steps"] == 10
