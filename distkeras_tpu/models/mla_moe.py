@@ -48,6 +48,11 @@ import jax
 import jax.numpy as jnp
 
 from distkeras_tpu.models.layers import Layer, register_layer
+from distkeras_tpu.ops.grouped_matmul import (
+    grouped_form,
+    grouped_matmul,
+    plain_grouped_matmul,
+)
 
 
 class BlockUnsupportedError(NotImplementedError):
@@ -98,11 +103,16 @@ def matmul(x, w):
 
 
 def _grouped_mm(x, w, sizes):
-    """Rows of ``x`` sorted by group against the stacked ``(G, k, m)``
-    weights, each group over its own rows (``jax.lax.ragged_dot``), in the
-    weights' dtype, float32 out."""
-    return jax.lax.ragged_dot(*_operands(x, w), sizes,
-                              preferred_element_type=jnp.float32)
+    """Rows of ``x`` sorted by group against the stacked ``(G, k, n)``
+    weights, each group over its own rows, in the weights' dtype, float32
+    out, the rows past the last group zero. Which form multiplies is read
+    off the widths (``ops.grouped_matmul.grouped_form``): the kernel
+    ``grouped_matmul`` where ``k`` and ``n`` are whole groups of 128 lanes
+    (compiled on a TPU, interpreted elsewhere), ``jax.lax.ragged_dot``
+    otherwise (the tests' widths of 16-64)."""
+    kernel = grouped_form(*w.shape[1:]) == "kernel"
+    return (grouped_matmul if kernel else plain_grouped_matmul)(
+        *_operands(x, w), sizes)
 
 
 def _grouped_mlp(p, xs, sizes):
@@ -173,12 +183,16 @@ def held_capacity(rows, e_held, n_outputs):
     """Rows one pass of ``routed_experts`` takes, from what it can read
     off its arguments: of ``rows`` (token, pick) pairs a share of
     ``e_held / n_outputs`` belong to a held expert under even routing;
-    a quarter more and 128 rows of room, in tiles of 128 rows, and an odd
-    number of them: the TPU's grouped product takes the largest power of
-    two that divides its rows (up to 512) as its row tile and multiplies
-    a whole tile for every expert that has a row in it, so 128-row tiles
-    keep an expert's few rows bound by its weights' bytes, where a tile
-    of 512 is bound by the tile's products. None where the pass would be
+    a quarter more and 128 rows of room, in tiles of 128 rows (the row
+    tile of ``ops.grouped_matmul``, which multiplies a whole tile for
+    every expert that has a row in it), and an odd number of them. The
+    odd number is history (PR 36): XLA's ``ragged_dot`` kernel takes the
+    largest power of two that divides its rows (up to 512) as its row
+    tile, so an odd count of 128-row tiles kept an expert's few rows bound
+    by its weights' bytes where a tile of 512 is bound by the tile's
+    products; the kernel's tile is 128 whatever the rows (PR 43), the
+    widths that keep ``ragged_dot`` still gain by it, and the count stays
+    as the programs and their tests have it. None where the pass would be
     more than half of ``rows`` (every expert held, a handful of tokens, a
     quarter of the experts under a decode step's rows): compacting saves
     less there than its own sort and scatter cost."""
@@ -190,7 +204,7 @@ def held_capacity(rows, e_held, n_outputs):
 def routed_experts(p, x, chosen, weights, held, n_experts, token_mask=None):
     """The held experts' part of the routed output, no token dropped:
     the (token, choice) pairs are sorted by expert and the three products
-    run as grouped products (``jax.lax.ragged_dot``) over the stacked
+    run as grouped products (``_grouped_mm``) over the stacked
     ``(E_held, in, out)`` weights, each expert over exactly the rows
     routed to it. A pair whose expert is not held here (or whose token
     ``token_mask`` switches off) adds nothing and costs no product.

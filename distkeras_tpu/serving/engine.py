@@ -857,6 +857,15 @@ class DecodeStepper:
         self._moe_layers = sum(
             1 for blk in self._gen._blocks if getattr(blk, "n_experts", 0)
         )
+        # how the expert layers' grouped products multiply, by their widths
+        # (``models.mla_moe._grouped_mm`` asks the same): "kernel"
+        # (``ops/grouped_matmul.py``) or "ragged_dot"
+        self.grouped = None
+        if self._moe_layers:
+            from distkeras_tpu.ops.grouped_matmul import grouped_form
+
+            self.grouped = grouped_form(
+                self._gen._emb.dim, self._gen._blocks[-1].expert_width)
         self.moe_stats = {
             "steps": 0, "experts_hit_sum": 0.0, "expert_load_max_sum": 0,
             "experts_total": (
@@ -2291,6 +2300,7 @@ class DecodeStepper:
             with _span(
                 "serving/prefill_chunk",
                 host_arg_bytes=self._host_arg_bytes(host), tokens=n,
+                **({"grouped": self.grouped} if self.grouped else {}),
             ):
                 self._pools = fn(self._params, self._pools, *host)
             return n
@@ -3867,6 +3877,8 @@ class DecodeStepper:
                 span.set_metadata(attention=self.attention)
                 if self.selector:
                     span.set_metadata(selector=self.selector)
+                if self.grouped:
+                    span.set_metadata(grouped=self.grouped)
                 if self._state_layers:
                     span.set_metadata(state_bytes=self._state_bytes(active))
                 self._ctx, self._pools, toks = fn(
@@ -5741,7 +5753,8 @@ class ServingEngine:
             out["paged"] = self._stepper.paged_stats()
             if self._stepper._moe_layers:
                 # the expert layers' routing, summed over decode steps
-                out["moe"] = dict(self._stepper.moe_stats)
+                out["moe"] = {"grouped": self._stepper.grouped,
+                              **self._stepper.moe_stats}
             if self._stepper._select:
                 out["select"] = dict(self._stepper.select_stats)
             if self._stepper._state_layers:

@@ -13,6 +13,7 @@ process, and nothing touches it at import: only one process may hold the
 TPU library, and under pytest-xdist every worker imports every test file.
 """
 
+import contextlib
 import functools
 
 import jax
@@ -52,10 +53,71 @@ def _no_persistent_cache():
     compilation_cache.reset_cache()
 
 
+@contextlib.contextmanager
+def _kernels_compiled():
+    """Code that asks ``jax.default_backend()`` sees the CPU here and would
+    interpret its kernels: the whole-program tests steer every kernel module
+    to compile, as the chip does."""
+    import distkeras_tpu.ops.grouped_matmul as gm
+    import distkeras_tpu.ops.paged_attention as pa
+
+    real = pa.pallas_interpret, gm.pallas_interpret
+    pa.pallas_interpret = gm.pallas_interpret = lambda: False
+    try:
+        yield
+    finally:
+        pa.pallas_interpret, gm.pallas_interpret = real
+
+
 def _compile(fn, *shapes):
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text
     return text
+
+
+def _grouped_calls(compiled):
+    """The experts' grouped products of a compiled program: the kernel's
+    custom calls (``ops/grouped_matmul.py``) with their scope paths."""
+    calls = [ln.split('op_name="')[1].split('"')[0]
+             for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln and "grouped_matmul" in ln]
+    assert all("/moe/experts/" in path for path in calls), calls
+    return calls
+
+
+# (rows, k, n, groups): the expert cells' grouped products, a decode step's
+# and a prefill chunk's (kanana: every expert held; the others a share)
+GROUPED_MATMUL_SHAPES = [
+    pytest.param(384, 2048, 768, 128, id="kanana-step-up"),
+    pytest.param(384, 768, 2048, 128, id="kanana-step-down"),
+    pytest.param(6144, 2048, 768, 128, id="kanana-chunk-up"),
+    pytest.param(6144, 768, 2048, 128, id="kanana-chunk-down"),
+    pytest.param(384, 6144, 2048, 16, id="longcat-step-up"),
+    pytest.param(384, 2048, 6144, 16, id="longcat-step-down"),
+    pytest.param(960, 3072, 1024, 64, id="laguna-step-up"),
+    pytest.param(960, 1024, 3072, 64, id="laguna-step-down"),
+    pytest.param(200, 2048, 768, 16, id="rows-not-whole-tiles"),
+]
+
+
+@pytest.mark.parametrize("m,k,n,g", GROUPED_MATMUL_SHAPES)
+def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, g):
+    """The experts' grouped product (``ops/grouped_matmul.py``) at the
+    cells' widths, bfloat16 as served: Mosaic takes the blocks
+    ``weight_block`` chooses (two of up to 8 MB in fast memory, the need
+    stated in ``vmem_limit_bytes`` where it passes the default) and the
+    scalar-prefetched schedule."""
+    from distkeras_tpu.ops import grouped_matmul as gm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tn = gm.weight_block(k, n, jnp.bfloat16)
+    assert k * tn * 2 <= gm.BLOCK_BYTES
+    _compile(
+        functools.partial(gm._grouped_matmul, tn=tn, interpret=False),
+        sds((m, k), jnp.bfloat16), sds((g, k, n), jnp.bfloat16),
+        sds((g,), jnp.int32))
 
 
 # (batch, seq, heads, head_dim, dtype): chip_smoke's head dim 256 and the
@@ -261,7 +323,6 @@ def test_the_grouped_step_and_chunk_programs_compile_for_v5e(one_chip):
     neither copies a pool."""
     import numpy as np
 
-    import distkeras_tpu.ops.paged_attention as pa
     from distkeras_tpu.models import zoo
     from distkeras_tpu.serving.engine import DecodeStepper
 
@@ -294,9 +355,7 @@ def test_the_grouped_step_and_chunk_programs_compile_for_v5e(one_chip):
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                            sharding=one_chip), tree)
 
-    real = pa.pallas_interpret
-    pa.pallas_interpret = lambda: False  # compile the kernel, as the chip
-    try:
+    with _kernels_compiled():
         step = st._build_step_fn_paged(pbt).lower(*shapes((
             st._params, st._ctx, st._pools, st._lens.copy(),
             np.zeros(96, bool), st._tables_array(pbt),
@@ -304,10 +363,17 @@ def test_the_grouped_step_and_chunk_programs_compile_for_v5e(one_chip):
         chunk = st._build_chunk_fn_paged(2048, pbt).lower(*shapes((
             st._params, st._pools, np.zeros((1, 2048), np.int32),
             st._chunk_where(0, pbt, 0), np.int32(0)))).compile()
-    finally:
-        pa.pallas_interpret = real
     text = step.as_text()
     assert text.count("tpu_custom_call") >= 5  # a kernel call a layer
+    # four expert layers' three grouped products, in a layer's first pass
+    # and in its loop's (2 experts of 256 held: the rows are compacted),
+    # the kernel's, under the scope ``moe_decode_roofline`` reads, and no
+    # ``ragged-dot``
+    # (the chunk walks its three window layers in one loop's body)
+    assert len(_grouped_calls(step)) == 4 * 3 * 2
+    assert len(_grouped_calls(chunk)) == 3 * 3 * 2
+    for compiled in (step, chunk):
+        assert "ragged" not in compiled.as_text()
     for compiled in (step, chunk):
         for rows in (2048 * 16, (96 * 33 + 1) * 16):
             copies = [ln for ln in compiled.as_text().splitlines()
@@ -336,7 +402,6 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
     pool; the compiler's count of their transients fits beside the pools."""
     import numpy as np
 
-    import distkeras_tpu.ops.paged_attention as pa
     from distkeras_tpu.models import zoo
     from distkeras_tpu.serving.engine import DecodeStepper
 
@@ -367,9 +432,7 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                            sharding=one_chip), tree)
 
-    real = pa.pallas_interpret
-    pa.pallas_interpret = lambda: False  # compile the kernel, as the chip
-    try:
+    with _kernels_compiled():
         step = st._build_step_fn_paged(pbt).lower(*shapes((
             st._params, st._ctx, st._pools, st._lens.copy(),
             np.zeros(32, bool), st._tables_array(pbt),
@@ -377,8 +440,6 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
         chunk = st._build_chunk_fn_paged(2048, pbt).lower(*shapes((
             st._params, st._pools, np.zeros((1, 2048), np.int32),
             st._chunk_where(0, pbt, 0), np.int32(0)))).compile()
-    finally:
-        pa.pallas_interpret = real
     text = step.as_text()
     # K and V: a kernel call a layer under the scope that
     # ``sparse_attn_decode_roofline`` reads; no rows gathered, the selected
@@ -389,6 +450,10 @@ def test_the_selecting_step_and_chunk_programs_compile_for_v5e(one_chip):
     for call in calls:
         assert "/attn/sparse/" in call.split('op_name="')[1].split('"')[0]
     assert "bf16[32,2048,512]" not in text
+    # two expert layers' three grouped products each, the kernel's
+    for compiled in (step, chunk):
+        assert len(_grouped_calls(compiled)) == 6
+        assert "ragged" not in compiled.as_text()
     assert "[32,49152,512]" not in text and "[32,65536,512]" not in text
     assert not [ln for ln in text.splitlines()
                 if " sort(" in ln and "49152" in ln.split("=")[0]]
@@ -582,7 +647,6 @@ def test_the_state_step_and_chunk_programs_compile_for_v5e(one_chip):
     fits beside the weights, the states and the pool."""
     import numpy as np
 
-    import distkeras_tpu.ops.paged_attention as pa
     from distkeras_tpu.models import zoo
     from distkeras_tpu.serving.engine import DecodeStepper
 
@@ -610,9 +674,7 @@ def test_the_state_step_and_chunk_programs_compile_for_v5e(one_chip):
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                            sharding=one_chip), tree)
 
-    real = pa.pallas_interpret
-    pa.pallas_interpret = lambda: False  # compile the kernel, as the chip
-    try:
+    with _kernels_compiled():
         step = st._build_step_fn_paged(pbt).lower(*shapes((
             st._params, st._ctx, st._pools, st._lens.copy(),
             np.zeros(64, bool), st._tables_array(pbt),
@@ -620,8 +682,6 @@ def test_the_state_step_and_chunk_programs_compile_for_v5e(one_chip):
         chunk = st._build_chunk_fn_paged(1024, pbt).lower(*shapes((
             st._params, st._pools, np.zeros((1, 1024), np.int32),
             st._chunk_where(0, pbt, 0), np.int32(0)))).compile()
-    finally:
-        pa.pallas_interpret = real
     assert step.as_text().count("tpu_custom_call") >= 1  # the one attention
     for compiled in (step, chunk):
         for shape in ("[64,64,64,128]", f"[{1024 * 16},512]"):

@@ -180,24 +180,32 @@ def test_the_kernel_s_decode_step_gives_the_reference_s_logits(fam, tiny):
     assert toks == list(ref.argmax(axis=-1))
 
 
-def test_a_paged_engine_decodes_the_latent_block_through_the_kernel():
+@pytest.mark.parametrize("hidden, expert_width, grouped", [
+    (32, 16, "ragged_dot"), (128, 128, "kernel")])
+def test_a_paged_engine_decodes_the_latent_block_through_the_kernel(
+        hidden, expert_width, grouped):
     """A tiny ``zoo.mla_moe_lm`` on a paged engine with pages of 8 rows:
     ``stats()["paged"]["attention"]`` says ``"kernel"``, one step program
     is compiled (at the widest table), and concurrent greedy requests of
     unequal length, one slot idle beside them, decode the tokens of the
-    un-paged forward (``model.apply`` over the growing sequence)."""
+    un-paged forward (``model.apply`` over the growing sequence). With a
+    hidden width and experts of whole lanes the step's and the chunk's
+    grouped products are the kernel's too, and ``stats()["moe"]`` says
+    so."""
     from distkeras_tpu.models import zoo
 
     lm = zoo.mla_moe_lm(
-        vocab_size=61, seq_len=48, hidden_size=32, num_heads=2,
+        vocab_size=61, seq_len=48, hidden_size=hidden, num_heads=2,
         qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
-        kv_lora_rank=16, intermediate_size=32, moe_intermediate_size=16,
+        kv_lora_rank=16, intermediate_size=32,
+        moe_intermediate_size=expert_width,
         n_routed_experts=4, num_experts_per_tok=2, num_layers=2, seed=0)
     eng = ServingEngine(lm, num_slots=3, paged=True, page_size=8,
                         prefill_chunk=8)
     eng.start()
     try:
         assert eng.stats()["paged"]["attention"] == "kernel"
+        assert eng.stats()["moe"]["grouped"] == grouped
         rng = np.random.default_rng(0)
         prompts = [rng.integers(0, 61, n).astype(np.int32) for n in (5, 19)]
         reqs = [eng.submit(p, 9) for p in prompts]
@@ -254,6 +262,8 @@ def test_the_serving_engine_serves_the_reference_s_tokens(fam, tiny, tmp_path):
     assert paged["bytes_per_token"] == 3 * 128 * 4
     assert paged["prefix_caches"].startswith("off")
     moe = stats["moe"]
+    # hidden 32 against experts of 16: not whole lanes, so the plain form
+    assert moe["grouped"] == "ragged_dot"
     assert moe["steps"] > 0 and moe["experts_total"] == 8
     assert 0 < moe["experts_hit_sum"] / moe["steps"] <= 8
     assert moe["expert_load_max_sum"] >= moe["steps"]
@@ -451,7 +461,7 @@ def _plain_experts(p, x, chosen, weights, held, token_mask):
 def _expert_inputs(n, held, outputs=48, top_k=4, d=16, width=8, biased=False):
     """Seeded stacked experts, hidden states and a router over ``outputs``;
     ``biased``: a selection bias that sends every pick to a held expert."""
-    rng = np.random.default_rng(n + len(held))
+    rng = np.random.default_rng(n + len(held) + (d != 16))
 
     def normal(*shape):
         return (0.1 * rng.normal(size=shape)).astype(np.float32)
@@ -479,16 +489,23 @@ def _routed(p, x, chosen, weights, held, outputs, token_mask):
 @pytest.mark.parametrize("held", [
     list(range(48)), list(range(3, 48, 4)), [17]],
     ids=["all_held", "1_in_4", "1_in_48"])
-def test_routed_experts_is_the_plain_sum_over_held_picks(held, n, masked):
+@pytest.mark.parametrize("d, width", [(16, 8), (128, 256)],
+                         ids=["ragged_dot", "kernel"])
+def test_routed_experts_is_the_plain_sum_over_held_picks(d, width, held, n,
+                                                         masked):
     """(v-b) the grouped passes against a loop over tokens and picks, for
     every held share of the router's width, below and above the rows at
-    which a share is compacted; the same bits from call to call."""
-    p, x, chosen, weights = _expert_inputs(n, held)
+    which a share is compacted; the same bits from call to call. At widths
+    of whole lanes the three products are the kernel's (interpreted here),
+    the uncompacted body's and a compacted pass's alike."""
+    p, x, chosen, weights = _expert_inputs(n, held, d=d, width=width)
     mask = (np.arange(n) % 5 != 0) if masked else None
     want, sizes = _plain_experts(p, x, chosen, weights, held, mask)
     y, picks = _routed(p, x, chosen, weights, held, 48, mask)
     again, _ = _routed(p, x, chosen, weights, held, 48, mask)
-    np.testing.assert_allclose(y, want, atol=1e-6, rtol=0)
+    # sums of 128 and 256 float32 terms round where sums of 16 and 8 do
+    np.testing.assert_allclose(y, want, atol=1e-6 if d == 16 else 2e-5,
+                               rtol=0)
     np.testing.assert_array_equal(picks.sizes, sizes)
     np.testing.assert_array_equal(y, again)
     compacted = mla_moe.held_capacity(n * 4, len(held), 48) is not None
@@ -496,6 +513,29 @@ def test_routed_experts_is_the_plain_sum_over_held_picks(held, n, masked):
     # uniform picks: the held rows fit one pass
     assert picks.overflow is None if not compacted else int(picks.overflow) == 0
     assert sizes.sum() > 0 and np.abs(want).max() > 1e-4
+
+
+@pytest.mark.parametrize("k, n, form", [
+    (16, 8, "ragged_dot"), (8, 16, "ragged_dot"), (64, 64, "ragged_dot"),
+    (128, 64, "ragged_dot"), (128, 256, "kernel"), (256, 128, "kernel"),
+])
+def test_the_grouped_product_takes_its_form_from_the_widths(k, n, form):
+    """``_grouped_mm``'s rule: the kernel where ``k`` and ``n`` are whole
+    groups of 128 lanes, ``ragged_dot`` otherwise; the program holds the
+    one it says and not the other, and both are the same product."""
+    assert mla_moe.grouped_form(k, n) == form
+    rng = np.random.default_rng(k + n)
+    x = jnp.asarray(rng.normal(size=(24, k)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(3, k, n)), jnp.float32)
+    sizes = jnp.asarray([9, 0, 11], jnp.int32)
+    text = str(jax.make_jaxpr(mla_moe._grouped_mm)(x, w, sizes))
+    assert ("pallas_call" in text) == (form == "kernel")
+    assert ("ragged_dot" in text) == (form == "ragged_dot")
+    with jax.default_matmul_precision("highest"):
+        got = mla_moe._grouped_mm(x, w, sizes)
+        want = jax.lax.ragged_dot(x, w, sizes)
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=0)
+    assert not np.asarray(got)[20:].any()
 
 
 @pytest.mark.parametrize("tokens, passes", [(400, 2), (600, 3)])
@@ -684,3 +724,30 @@ def test_the_tiny_cell_from_altered_weights_is_not_correct(
     assert out["correct"] is False
     gap = {n: v for n, v, _ in out["compared"]}["widest_logit_gap"]
     assert gap > CONFIG["serving"]["check"]["gap_limit"]
+
+
+def test_the_expert_layer_differentiates_through_the_kernel(monkeypatch):
+    """``apply``'s use: ``routed_experts`` at widths of whole lanes under
+    ``jax.grad`` (the kernel's ``custom_vjp``, group sizes traced inside the
+    differentiated function) gives the gradients of the plain form, to the
+    hidden states and to every expert's three matrices."""
+    held = list(range(0, 48, 4))
+    p, x, chosen, weights = (
+        jax.tree.map(jnp.asarray, a)
+        for a in _expert_inputs(40, held, d=128, width=128))
+
+    def loss():  # a function a form: a traced one is not traced again
+        def loss(p, x):
+            y, _ = mla_moe.routed_experts(p, x, chosen, weights, held, 48)
+            return jnp.sum(jnp.sin(y))
+        return loss
+
+    with jax.default_matmul_precision("highest"):
+        assert "pallas_call" in str(jax.make_jaxpr(loss())(p, x))
+        got = jax.jit(jax.grad(loss(), (0, 1)))(p, x)
+        monkeypatch.setattr(mla_moe, "grouped_form", lambda k, n: "ragged_dot")
+        assert "pallas_call" not in str(jax.make_jaxpr(loss())(p, x))
+        want = jax.jit(jax.grad(loss(), (0, 1)))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.abs(b).max()) > 1e-3
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=0)

@@ -167,9 +167,18 @@ def test_collect_span_carries_the_selection_s_counters(
                                5).result(120)) for i in range(2)])
         after = engine.stats()["select"]
         paged = engine.stats()["paged"]
+        grouped = engine.stats()["moe"]["grouped"]
     finally:
         engine.stop()
     its = _program_spans.iterations(plain)
+    # how the expert layers' grouped products multiply (hidden 32: the plain
+    # form), on the step's and the chunk's spans as ``stats()["moe"]`` says
+    assert grouped == "ragged_dot"
+    assert set(_program_spans.span_values(
+        its, "serving/step", "grouped")) == {grouped}
+    chunks = [a for n, _s, _d, _t, a in plain["spans"]
+              if n == "serving/prefill_chunk"]
+    assert chunks and {a["grouped"] for a in chunks} == {grouped}
     assert paged["selector"].startswith(selector)
     assert set(_program_spans.span_values(
         its, "serving/step", "selector")) == {paged["selector"]}
