@@ -43,8 +43,8 @@ servers):
   dispatch/ready/collect stamps turning the decode loop's host bubble
   (iteration wall minus device wall) into the
   ``serving_step_bubble_seconds`` histogram and the
-  ``serving_overlap_efficiency`` gauge, the committed zero-bubble
-  numbers ``bench_serving.py`` and ``dkt_top`` read.
+  ``serving_overlap_efficiency`` gauge, the zero-bubble numbers
+  ``dkt_top`` reads.
 """
 
 from distkeras_tpu.obs.compile_ledger import CompileLedger

@@ -38,8 +38,8 @@ traffic needs), any serving-path mint of a program
 signature never seen before is a STORM — it records an
 ``xla.compile.storm`` flight-recorder event and ticks the
 ``serving_compile_storms`` gauge. Both soaks assert zero storms, and
-``tools/check_bench.py`` holds the committed invariant that timed
-bench passes contain no mints at all — the twice-repeated bench
+``tests/test_serving_mixes.py`` holds the invariant that a pass over a
+warmed engine contains no mints at all — the twice-repeated bench
 post-mortem turned into a standing gate.
 """
 

@@ -25,8 +25,8 @@ answer: a process-cheap typed registry with
 - :class:`MetricsRegistry` — the collection face: ``snapshot()``
   returns JSON-able samples (what the ``metrics`` DKT1 verb ships),
   :func:`render_prometheus` turns samples into the text exposition
-  format, and :func:`parse_prometheus` is the validating reader tests
-  and the bench harness use to prove the dump actually parses.
+  format, and :func:`parse_prometheus` is the validating reader the
+  tests use to prove the dump actually parses.
 
 Naming convention (see docs/ARCHITECTURE.md "Observability"):
 ``<subsystem>_<what>[_<unit>]`` in snake_case — e.g.
@@ -411,7 +411,7 @@ def _render_sample(lines, name, s) -> None:
 def parse_prometheus(text: str) -> list[tuple[str, dict, float]]:
     """Strict-enough validating parser of the text exposition format:
     returns ``(name, labels, value)`` triples, raising ``ValueError``
-    on any malformed line. The bench harness and the schema tests use
+    on any malformed line. The schema tests use
     this to prove the dump the ``metrics`` verb serves actually
     parses — 'Prometheus-style' is a checked claim, not a vibe."""
     out = []

@@ -253,7 +253,7 @@ class FleetRouter:
         short so a silently dead replica fails over in seconds while
         ``request_timeout`` stays long enough for a full generate.
         ``affinity=False`` degrades ``generate`` routing to
-        least-loaded (the A/B baseline in ``bench_fleet.py``).
+        least-loaded (the control of ``tests/test_fleet_mixes.py``).
 
         ``postmortem_dir``: where every replica EJECTION dumps the
         router's post-mortem bundle (recorder ring + rotation books +

@@ -385,8 +385,8 @@ class SingleTrainer(Trainer):
 
     ``prefetch`` (all trainers) defaults to 0: the four committed v5e
     A/Bs measured overlap speedups of 0.74/0.83/0.99/1.12 — a median
-    LOSS — so background staging is opt-in until the interleaved-median
-    protocol (tools/prefetch_ab.py) demonstrates a >= 1.0 win at these
+    LOSS — so background staging is opt-in until an interleaved-median
+    A/B on the chip demonstrates a >= 1.0 win at these
     shapes (VERDICT r3 weak #4). Trajectories are bit-identical either
     way; only throughput is at stake."""
 

@@ -45,7 +45,7 @@ def main():
     train, test = ds.split(0.9, seed=7)
 
     model = cifar10_cnn(seed=0, bn_momentum=0.9)  # short-run eval stats
-    # sgd lr 0.05 (benchmarks.py config-4 calibration): ADAG's center moves
+    # sgd lr 0.05 (found by calibration on this configuration): ADAG's center moves
     # by -lr * mean-grad per commit regardless of the local optimizer, and
     # adam's default 1e-3 leaves the center nearly frozen at demo scales
     trainer = ADAG(

@@ -132,7 +132,7 @@ def main():
         num_classes=args.classes, input_shape=(args.size, args.size, 3),
         seed=0, bn_momentum=0.9,  # short demo runs: eval stats must track
     )
-    # adam lr 1e-3 (benchmarks.py config-5 calibration): a from-scratch
+    # adam lr 1e-3 (found by calibration on this configuration): a from-scratch
     # ResNet under DynSGD stays at a constant prediction with plain sgd;
     # the 1/(staleness+1) delta scaling already provides the per-worker
     # division

@@ -77,7 +77,7 @@ def main():
         model = mnist_cnn(seed=0)
         cls = DOWNPOUR if args.mode == "downpour" else SynchronousDistributedTrainer
         # DOWNPOUR: N workers' window deltas sum at the PS -> local adam lr
-        # scales by 1/N (benchmarks.py config-2 calibration); the sync
+        # scales by 1/N (found by calibration on this configuration); the sync
         # trainer means the global-batch loss, so full lr is right there
         lr = 1e-3 / args.workers if cls is DOWNPOUR else 1e-3
         extra = (

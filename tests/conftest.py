@@ -25,7 +25,7 @@ def pytest_collection_modifyitems(config, items):
     """Unit tier first, harness tier last — deterministically.
 
     ``chaos`` (subprocess fleets, seeded fault storms) and ``e2e``
-    (full bench-harness runs) tests each cost tens of seconds to
+    (the soak smokes of ``test_soaks.py``) tests each cost tens of seconds to
     minutes on this 1-core sandbox; alphabetical collection buries
     them mid-suite where they starve hundreds of sub-second unit
     tests behind them. A stable two-bucket sort keeps every test
@@ -88,7 +88,7 @@ def cpu_devices():
 def tp_mesh(cpu_devices):
     """Factory for serving tensor-parallel meshes on the shared CPU
     topology: ``tp_mesh(2)`` -> the 2-way ``serving_mesh`` every
-    sharded-serving test (and the decode bench) uses."""
+    sharded-serving test uses."""
     from distkeras_tpu.parallel.mesh import serving_mesh
 
     def make(n: int):

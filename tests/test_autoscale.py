@@ -8,8 +8,8 @@ can drive hysteresis, cooldowns, and clamps deterministically; the
 the kill-9-then-replace-same-tick regression) with a duck-typed
 controller; the publisher/deployer tests cover the checkpoint-cadence
 → bundle → rollover chain down to the atomic rename. The loadgen ramp
-preset, the dkt_top fleet column, and the ``check_bench`` autoscale
-gate ride along — every satellite of the elastic-fleet PR has its pin
+preset, the dkt_top fleet column, and the policy run over the seeded
+ramp ride along — every satellite of the elastic-fleet PR has its pin
 here.
 """
 
@@ -24,7 +24,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-import check_bench  # noqa: E402
 import dkt_top  # noqa: E402
 import loadgen  # noqa: E402
 
@@ -572,61 +571,86 @@ class TestDktTopFleetColumn:
         assert "replicas=2" in header and "↑" not in header
 
 
-class TestCheckBenchAutoscaleGate:
-    @staticmethod
-    def record():
-        return {
-            "autoscale": {
-                "outputs_identical": True,
-                "trace": {"process": "ramp", "events": 450},
-                "p99_ratio_static_over_autoscaled": 0.5,
-                "static": {"replicas": 1, "p99_under_ramp_ms": 4000.0},
-                "autoscaled": {
-                    "start_replicas": 1, "scaled_to": 2, "scale_ups": 1,
-                    "join_compile_storms": 0,
-                    "p99_under_ramp_ms": 12000.0,
-                    "replicas_over_time": [[0.0, 1], [17.0, 2]],
-                },
-            },
-        }
+class TestPolicyOnTheRamp:
+    """The pure policy over a seeded ramp of arrivals, under an injected
+    clock and with no fleet: a replica is a queue served at a fixed rate,
+    a tick reads how busy the replicas were and how full their queues are,
+    and what the policy decides is applied at once. The ramp climbs past
+    what one replica serves, so the policy must add a second, and once the
+    ramp is over it must come back."""
 
-    def test_valid_record_passes_self_compare(self):
-        rec = self.record()
-        assert check_bench.compare_autoscale(rec, rec) == []
+    PEAK, N, PERIOD, SEED = 50.0, 450, 6.0, 7
+    SERVICE_RATE, SLOTS, QUEUE, TICK = 30.0, 100, 100, 0.1
 
-    @pytest.mark.parametrize("mutate,needle", [
-        (lambda a: a["autoscaled"].update(join_compile_storms=1),
-         "compile storms"),
-        (lambda a: a["autoscaled"].update(scaled_to=1),
-         "never scaled"),
-        (lambda a: a["autoscaled"].update(
-            replicas_over_time=[[0.0, 2], [17.0, 2]]),
-         "provisioning curve"),
-        (lambda a: a["static"].update(replicas=2), "not 1 replica"),
-        (lambda a: a.update(outputs_identical=False), "not identical"),
-        (lambda a: a.update(trace={"process": "poisson"}),
-         "seeded ramp"),
-        (lambda a: a["autoscaled"].update(p99_under_ramp_ms=0),
-         "not \nmeasured".replace("\n", "")),
-    ])
-    def test_each_invariant_is_load_bearing(self, mutate, needle):
-        rec = self.record()
-        mutate(rec["autoscale"])
-        violations = check_bench.compare_autoscale(rec, self.record())
-        assert any(needle in v for v in violations), violations
+    def ramp(self, seed=SEED):
+        return loadgen.arrivals(
+            "ramp", self.PEAK, n=self.N, seed=seed, period=self.PERIOD,
+            floor_frac=0.2)
 
-    def test_committed_ceiling_catches_a_collapse(self):
-        good, slow = self.record(), self.record()
-        slow["autoscale"]["autoscaled"]["p99_under_ramp_ms"] = (
-            check_bench.AUTOSCALE_P99_CEILING_MS * 2
-        )
-        violations = check_bench.compare_autoscale(good, slow)
-        assert any("ceiling" in v for v in violations)
+    def run(self, arrivals, max_replicas=2, ticks_after=100):
+        """``[(time, replicas)]`` at every change, and the decisions."""
+        clk = FakeClock()
+        p = policy(clk, max_replicas=max_replicas, down_ticks=5,
+                   up_cooldown=1.0, down_cooldown=3.0)
+        replicas, backlog, seen = 1, 0.0, 0
+        curve, decisions = [(0.0, 1)], []
+        end = float(arrivals[-1]) + ticks_after * self.TICK
+        while clk.t < end:
+            clk.advance(self.TICK)
+            arrived = int(np.searchsorted(arrivals, clk.t)) - seen
+            seen += arrived
+            can = replicas * self.SERVICE_RATE * self.TICK
+            done = min(backlog + arrived, can)
+            backlog += arrived - done
+            d = p.decide([
+                ReplicaSignals(
+                    endpoint=("127.0.0.1", 9000 + i),
+                    in_flight=int(round(self.SLOTS * done / can)),
+                    capacity=self.SLOTS,
+                    queue_depth=min(
+                        self.QUEUE, int(round(backlog / replicas))),
+                    queue_capacity=self.QUEUE)
+                for i in range(replicas)])
+            decisions.append((d.action, d.reason))
+            replicas += {SCALE_UP: 1, SCALE_DOWN: -1, HOLD: 0}[d.action]
+            if replicas != curve[-1][1]:
+                curve.append((round(clk.t, 2), replicas))
+        return curve, decisions
 
-    def test_gate_is_registered(self):
-        assert check_bench.COMPARATORS["autoscale"] \
-            is check_bench.compare_autoscale
-        assert check_bench.ARTIFACTS["autoscale"] == "BENCH_FLEET.json"
+    def test_the_policy_scales_up_on_the_ramp(self):
+        arrivals = self.ramp()
+        curve, decisions = self.run(arrivals)
+        # the provisioning curve starts at one replica and climbs while
+        # the ramp is still arriving, on utilization and nothing else
+        assert curve[0] == (0.0, 1)
+        assert curve[1][1] == 2 and curve[1][0] < float(arrivals[-1])
+        assert max(n for _, n in curve) == 2
+        assert (SCALE_UP, "pressure:utilization") in decisions
+        # a fleet held to one replica never grows, whatever the ramp
+        static, held = self.run(arrivals, max_replicas=1)
+        assert static == [(0.0, 1)]
+        assert (HOLD, "at_max") in held
+        assert not any(a == SCALE_UP for a, _ in held)
+
+    def test_the_policy_comes_back_to_one_replica_after_the_ramp(self):
+        arrivals = self.ramp()
+        curve, decisions = self.run(arrivals)
+        assert curve[-1][1] == 1 and curve[-1][0] > float(arrivals[-1])
+        assert decisions.count((SCALE_DOWN, "idle")) == 1
+        # one up and one down: the cooldowns and the streaks keep it
+        # from flapping on the way
+        assert [n for _, n in curve] == [1, 2, 1]
+        assert decisions[-1] == (HOLD, "at_min")
+
+    def test_the_same_seed_gives_the_same_ramp_and_the_same_decisions(self):
+        a, b = self.ramp(), self.ramp()
+        assert np.array_equal(a, b) and len(a) == self.N
+        assert np.all(np.diff(a) >= 0)
+        assert self.run(a) == self.run(b)
+        other = self.ramp(seed=8)
+        assert not np.array_equal(a, other)
+        # another seed is another ramp, and still one that scales up
+        assert max(n for _, n in self.run(other)[0]) == 2
 
 
 class TestAutoscalerThreadLifecycle:

@@ -177,6 +177,52 @@ def test_worker_exhausted_retries_gives_up_others_continue():
     assert t.parameter_server.num_updates == 4  # worker 1's 4 windows landed
 
 
+def test_a_logger_that_raises_in_the_crash_boundary_loses_one_thread_only(
+        monkeypatch):
+    """A metrics logger that raises while a failure is being recorded takes
+    the exception out of ``attempt_partition`` itself. The worker's thread
+    then ends on that exception and on no other (the flag its adoption loop
+    reads is set before the ``try``), and the other worker trains on."""
+    ds = make_data(n=512)
+
+    class AlwaysCrash(DOWNPOURWorker):
+        def finish_window(self):
+            if self.worker_id == 0:
+                raise RuntimeError("hard failure")
+            super().finish_window()
+
+    class Crashy(DOWNPOUR):
+        worker_cls = AlwaysCrash
+
+    class RaisingLogger:
+        def log(self, **record):
+            if record.get("event") == "worker_failure":
+                raise OSError("disk full")
+
+    ended = []
+    monkeypatch.setattr(
+        threading, "excepthook", lambda args: ended.append(args.exc_type))
+    t = Crashy(
+        zoo.mnist_mlp(hidden=16),
+        "sgd",
+        "categorical_crossentropy",
+        learning_rate=0.05,
+        batch_size=32,
+        num_workers=2,
+        communication_window=2,
+        num_epoch=1,
+        mode="threads",
+        label_col="label_onehot",
+        worker_retries=2,
+        elastic=True,
+    )
+    t.metrics_logger = RaisingLogger()
+    t.train(ds)  # must not raise or hang
+    assert ended == [OSError]
+    assert [f["worker_id"] for f in t.failures] == [0]
+    assert t.parameter_server.num_updates == 4  # worker 1's 4 windows landed
+
+
 @pytest.mark.slow
 def test_heartbeat_monitor_flags_silent_worker(tmp_path):
     ds = make_data(n=512)

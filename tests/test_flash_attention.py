@@ -89,7 +89,7 @@ def test_flash_clamped_block_matches_dense():
 
 @pytest.mark.slow
 def test_flash_bf16_matches_dense_and_keeps_dtype():
-    """bf16 is the TPU compute dtype (bench_mfu runs flash under it):
+    """bf16 is the TPU compute dtype (the chip runs flash under it):
     kernels accumulate f32 internally, outputs and grads come back bf16
     and finite, values track the dense path at bf16 tolerance."""
     rng = np.random.default_rng(0)

@@ -158,10 +158,10 @@ def test_ulysses_t2048_gradients_match_dense():
 
 
 def test_flash_t4096_matches_dense_values_and_grads():
-    """seq-4096 = an 8x8 block grid (twice the --long regime's depth) —
-    the correctness pin for the capture queue's `--best` seq-4096 perf
-    row (tools/mfu_attrib.py), so the on-chip number never lands without
-    an off-chip parity proof at the same sequence length."""
+    """seq-4096 = an 8x8 block grid (twice the depth of seq-2048's) —
+    the correctness pin for a seq-4096 run on the chip, so the on-chip
+    number never lands without an off-chip parity proof at the same
+    sequence length."""
     T4 = 4096
     path, bq, bk = effective_path(T4, D)
     assert path == "flash" and T4 // bq == 8 and T4 // bk == 8, (path, bq, bk)

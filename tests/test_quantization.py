@@ -3,9 +3,9 @@
 The reference has no serving/perf tier at all (SURVEY §3.4); this one is
 TPU-first — decode is memory-bound, int8 weights quarter the HBM bytes
 per token while the matmul still runs in the activation dtype. These
-tests pin the numerics off-chip; `bench_decode.py` runs the int8 A/B as
-part of its standard sweep and measures the bytes-to-tokens/sec claim on
-the real chip.
+tests pin the numerics off-chip; the bytes-to-tokens/sec claim is the
+chip's to measure (not measured on the chip by any cell of
+`BENCHMARK.json` yet).
 """
 
 import jax.numpy as jnp

@@ -524,3 +524,58 @@ def test_swallowed_draft_failures_are_counted_in_health(lm, lm_ref, mesh):
     assert health["speculative_draft_failures"] >= 1
     assert spec["draft_failures"] == health["speculative_draft_failures"]
     assert spec["verify_steps"] == 0 and spec["fallback_steps"] >= 1
+
+
+# ------------------------------------- a full bank on every mesh size
+
+
+@pytest.fixture(scope="module")
+def grid():
+    """A model wide enough to shard four ways (128 wide, 4 heads, 2
+    layers), a bank of two slots and a 16-token prompt for each."""
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.predictors import CachedSequenceGenerator
+
+    model = zoo.transformer_lm(
+        vocab_size=512, seq_len=64, d_model=128, num_heads=4, depth=2,
+        seed=0,
+    )
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 512, 16).astype(np.int32) for _ in range(2)]
+    gen = CachedSequenceGenerator(model)
+    refs = [gen.generate(p[None], steps=8)[0][16:].tolist() for p in prompts]
+    return model, prompts, refs
+
+
+def _grid_stepper(model, ways):
+    return DecodeStepper(
+        model, num_slots=2, paged=True, page_size=16, prefix_cache=None,
+        mesh=None if ways == 1 else f"tp:{ways}",
+    )
+
+
+@pytest.mark.parametrize("ways", [2, 4])
+def test_a_full_bank_decodes_the_solo_generator_s_tokens(grid, ways):
+    """Every slot admitted and all decoding at once, the pool sharded by
+    heads over the mesh: each slot's tokens are the solo generator's."""
+    model, prompts, refs = grid
+    st = _grid_stepper(model, ways)
+    for slot, p in enumerate(prompts):
+        st.admit(slot, p, max_new=9)
+    outs = [[] for _ in prompts]
+    for _ in range(8):
+        toks = st.step(np.ones(len(prompts), bool))
+        for slot, out in enumerate(outs):
+            out.append(int(toks[slot]))
+    assert outs == refs
+
+
+def test_the_pool_s_bytes_are_the_same_on_every_mesh_size(grid):
+    """The mesh changes how many bytes land on a shard and never how many
+    there are: what makes one mesh size comparable with another."""
+    model = grid[0]
+    steppers = {ways: _grid_stepper(model, ways) for ways in (1, 2, 4)}
+    total = steppers[1].kv_bytes_total()
+    for ways, st in steppers.items():
+        assert st.kv_bytes_total() == total, ways
+        assert st.kv_shard_bytes() * ways == total, ways
